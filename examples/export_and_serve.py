@@ -12,6 +12,8 @@ from paddle_tpu.static import InputSpec
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     import os
     import tempfile
     model = build_tiny_llama(seed=0, num_hidden_layers=1)

@@ -1,7 +1,6 @@
 """Whole-loop compiled generation: prefill + every decode step in ONE
-XLA program over static KV buffers — the serving hot path (on a v5e this
-decodes the 0.7B zoo Llama at ~0.5K tok/s B=1 / ~4K tok/s B=8; see
-BENCHMARKS.md). Run:
+XLA program over static KV buffers (decode rate on the chip: not measured
+on today's installation). Run:
     JAX_PLATFORMS=cpu python examples/generate_compiled.py
 """
 import numpy as np
@@ -11,6 +10,8 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny())
     model.eval()

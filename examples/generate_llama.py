@@ -9,6 +9,8 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     paddle.seed(0)
     cfg = LlamaConfig.tiny(num_hidden_layers=1, vocab_size=16)
     model = LlamaForCausalLM(cfg)

@@ -17,6 +17,8 @@ from paddle_tpu.quantization import (
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     paddle.seed(0)
     rng = np.random.RandomState(0)
     model = nn.Sequential(nn.Linear(16, 64), nn.ReLU(), nn.Linear(64, 4))

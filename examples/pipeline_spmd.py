@@ -16,6 +16,8 @@ import paddle_tpu.nn as nn
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     n = len(jax.devices())
     pp = 4 if n % 4 == 0 else (2 if n % 2 == 0 else 1)
     mesh = dist.init_mesh({"dp": n // pp, "pp": pp})

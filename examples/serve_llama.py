@@ -20,6 +20,8 @@ from paddle_tpu.serving import Server, ServingEngine
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     model = build_tiny_llama(seed=0, num_hidden_layers=1)
     engine = ServingEngine(model, max_batch=4, max_blocks=32,
                            block_size=4, prefill_chunk=8)

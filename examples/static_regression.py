@@ -8,6 +8,8 @@ from paddle_tpu import static
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     paddle.enable_static()
     rng = np.random.RandomState(0)
     w_true = rng.randn(13, 1).astype(np.float32)

@@ -12,6 +12,8 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     paddle.seed(0)
     cfg = LlamaConfig.tiny()
     model = LlamaForCausalLM(cfg)

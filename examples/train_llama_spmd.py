@@ -15,6 +15,8 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     n = len(__import__("jax").devices())
     mp = 2 if n % 2 == 0 else 1
     mesh = dist.init_mesh({"dp": n // mp, "mp": mp})

@@ -34,6 +34,8 @@ class Corpus:
 
 
 def main():
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     paddle.seed(0)
     cfg = LlamaConfig.tiny()
     net = LlamaForCausalLM(cfg)

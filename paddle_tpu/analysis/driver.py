@@ -28,13 +28,13 @@ __all__ = ["ensure_cpu_mesh", "dp8_bucketed_step", "tiny_llama_step",
 
 
 def ensure_cpu_mesh(devices: int = 8) -> bool:
-    """Arm an N-virtual-device CPU platform when no TPU is plausibly
-    present (same discipline as tests/conftest.py / BENCH_FORCE_CPU:
-    the env must be set before the jax backend initializes). Returns
-    whether the CPU override was applied."""
+    """Arm an N-virtual-device CPU platform when no TPU is selected
+    (same discipline as tests/conftest.py / BENCH_FORCE_CPU: the env
+    must be set before the jax backend initializes). Returns whether
+    the CPU override was applied."""
     env = os.environ
-    from paddle_tpu.device import _tpu_plausible
-    if _tpu_plausible(env):
+    from paddle_tpu.device import tpu_selected
+    if tpu_selected(env):
         return False
     env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")

@@ -62,7 +62,7 @@ RULES = ("gc-eager-jax", "signal-unsafe-call", "trace-attr-mutation",
 #: dotted-name suffixes whose first argument is traced by jax
 _TRACE_WRAPPERS = ("jax.jit", "jit", "jax.value_and_grad",
                    "value_and_grad", "jax.grad", "shard_map",
-                   "shard_map_compat", "pallas_call", "jax.vmap", "vmap",
+                   "pallas_call", "jax.vmap", "vmap",
                    "jax.checkpoint", "jax.remat")
 #: wall-clock / host-randomness dotted names (exact or prefix.)
 _IMPURE_EXACT = {"time.time", "time.time_ns", "time.perf_counter",

@@ -128,23 +128,13 @@ def get_group(gid: int) -> Optional[Group]:
     return Group._registry.get(gid)
 
 
-def _axis_size(axis):
-    """Bound-axis size across jax versions: ``jax.lax.axis_size`` where it
-    exists, else the classic ``psum(1, axis)`` idiom (statically evaluated
-    for named axes; raises the same unbound-name NameError)."""
-    import jax
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
 def _linear_rank(axes):
     """Group-linear rank inside a mapped context (axes[0] major — the
     same flattening order jax collectives use for axis tuples)."""
     import jax
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -166,9 +156,10 @@ def _axes(group) -> Tuple[str, ...]:
 
 def _in_mapped_context(axes) -> bool:
     """True when the named axes are bound (i.e. we are inside shard_map)."""
+    import jax
     try:
         for a in axes:
-            _axis_size(a)
+            jax.lax.axis_size(a)
         return True
     except NameError:  # jax's unbound-axis-name error
         return False
@@ -448,7 +439,7 @@ def scatter(tensor, tensor_list=None, src=0, group=None, sync_op=True):
         # with peak memory 2x the tensor, not the world-size x of the old
         # all_gather+index formulation
         axis = axes[0] if len(axes) == 1 else axes
-        n = _axis_size(axis)
+        n = jax.lax.axis_size(axis)
         chunk = x.shape[0] // n
         recv = jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
                                   tiled=True)
@@ -489,7 +480,7 @@ def p2p_shift(tensor, group=None, shift: int = 1):
     axis = axes[0] if len(axes) == 1 else axes
 
     def f(x):
-        n = _axis_size(axis)
+        n = jax.lax.axis_size(axis)
         perm = [(i, (i + shift) % n) for i in range(n)]
         return jax.lax.ppermute(x, axis, perm)
     with comm_scope("p2p_shift", axes, payload=tensor,
@@ -520,14 +511,13 @@ def shard_map(fn, mesh=None, in_specs=None, out_specs=None,
         return x.data if isinstance(x, Tensor) else x
 
     def run(*args):
-        # lazy: fleet.utils <-> collective would cycle at module scope
-        from .fleet.utils import shard_map_compat
-        inner = shard_map_compat(
+        inner = jax.shard_map(
             lambda *a: jax.tree_util.tree_map(
                 unwrap, fn(*[Tensor(x) if hasattr(x, "dtype") else x
                              for x in a]),
                 is_leaf=lambda v: isinstance(v, Tensor)),
-            mesh, in_specs, out_specs, check_vma=check_rep)
+            mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=check_rep)
         out = inner(*[unwrap(a) for a in args])
         return jax.tree_util.tree_map(
             lambda x: Tensor(x) if hasattr(x, "dtype") else x, out)

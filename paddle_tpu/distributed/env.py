@@ -21,18 +21,6 @@ __all__ = ["init_parallel_env", "get_rank", "get_world_size", "ParallelEnv"]
 _initialized = {"done": False}
 
 
-def _jax_distributed_active() -> bool:
-    """Whether jax.distributed.initialize already ran. NOTE: probing via
-    jax.process_count() would INITIALIZE the backend — exactly what must
-    not happen before initialize — so peek at the (private) client state
-    and fail open if jax reorganizes it."""
-    try:
-        from jax._src import distributed as _jd
-        return getattr(_jd.global_state, "client", None) is not None
-    except Exception:
-        return False
-
-
 def init_parallel_env(mesh_shape: Optional[dict] = None):
     """Bootstrap distributed state and the default mesh.
 
@@ -47,7 +35,7 @@ def init_parallel_env(mesh_shape: Optional[dict] = None):
     coord = os.environ.get("PADDLE_MASTER") or os.environ.get("MASTER_ADDR")
     n_proc = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
     proc_id = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
-    if coord and n_proc > 1 and not _jax_distributed_active():
+    if coord and n_proc > 1 and not jax.distributed.is_initialized():
         explicit = os.environ.get("PADDLE_JAX_COORDINATOR")
         if explicit:
             addr = explicit

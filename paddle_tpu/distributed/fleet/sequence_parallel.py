@@ -108,17 +108,16 @@ def _ring_attention_arrays(q, k, v, mesh, axis, causal, sm_scale):
         a0 = jnp.zeros((b, h, sq, d), jnp.float32)
         # mark the replicated initializers device-varying so the scan carry
         # type matches the rank-dependent outputs (shard_map vma rule)
-        from .utils import pvary_compat
-        m0, l0, a0 = (pvary_compat(x, axis) for x in (m0, l0, a0))
+        from .utils import mark_varying
+        m0, l0, a0 = (mark_varying(x, axis) for x in (m0, l0, a0))
         m, l, acc, _, _ = jax.lax.fori_loop(0, n, step,
                                             (m0, l0, a0, kl, vl))
         out = acc / jnp.maximum(l, 1e-20)[..., None]
         return jnp.swapaxes(out, 1, 2).astype(ql.dtype)
 
     spec = P(None, axis, None, None)
-    from .utils import shard_map_compat
-    return shard_map_compat(per_rank, mesh, (spec, spec, spec),
-                            spec)(q, k, v)
+    return jax.shard_map(per_rank, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
 
 
 def _ring_flash_arrays(q, k, v, mesh, axis, causal, sm_scale):
@@ -282,9 +281,8 @@ def _ring_flash_arrays(q, k, v, mesh, axis, causal, sm_scale):
     spec = P(None, axis, None, None)
     # check_vma off: pallas_call's output avals carry no vma annotation,
     # which the checker (not the semantics) rejects inside shard_map
-    from .utils import shard_map_compat
-    return shard_map_compat(per_rank, mesh, (spec, spec, spec), spec,
-                            check_vma=False)(q, k, v)
+    return jax.shard_map(per_rank, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _ring_flash_tileable(S: int, n: int) -> bool:

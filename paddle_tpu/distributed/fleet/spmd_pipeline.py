@@ -115,10 +115,10 @@ def pipeline_spmd(body_fn: Callable, stacked_params, micro_inputs,
     span = int(t_idx[-1]) + 1
     body = jax.checkpoint(body_fn) if remat else body_fn
 
-    from .utils import pvary_compat
+    from .utils import mark_varying
 
     def _pvary(x):
-        return pvary_compat(x, axis)
+        return mark_varying(x, axis)
 
     def per_stage(params, xs):
         # params leaves [v, 1, ...] (stage slice); xs leaves [M, ...]
@@ -182,9 +182,9 @@ def pipeline_spmd(body_fn: Callable, stacked_params, micro_inputs,
         lambda a: P(None, axis), stacked_params)
     xspec = jax.tree_util.tree_map(lambda a: P(), micro_inputs)
     ospec = jax.tree_util.tree_map(lambda a: P(), micro_inputs)
-    from .utils import shard_map_compat
-    return shard_map_compat(per_stage, mesh, (pspec, xspec), ospec,
-                            axis_names={axis})(stacked_params, micro_inputs)
+    return jax.shard_map(per_stage, mesh=mesh, in_specs=(pspec, xspec),
+                         out_specs=ospec,
+                         axis_names={axis})(stacked_params, micro_inputs)
 
 
 class SpmdPipelineLayer(Layer):
@@ -479,10 +479,10 @@ def _hetero_schedule(branches, padded, shared_params, micro_inputs,
     t_idx = _completion_ticks(S, v, M)
     span = int(t_idx[-1]) + 1
 
-    from .utils import pvary_compat
+    from .utils import mark_varying
 
     def _pvary(x):
-        return pvary_compat(x, axis)
+        return mark_varying(x, axis)
 
     def per_stage(stage_vecs, shared, xs):
         # stage_vecs [v, 1, Lmax] -> [v, Lmax]
@@ -555,9 +555,9 @@ def _hetero_schedule(branches, padded, shared_params, micro_inputs,
     # over non-pp axes. Blocks whose forward builds fresh scan carries
     # (RNNs) must vma-match them to their inputs — see
     # ``fleet.utils.match_vma`` (nn.RNN does this natively).
-    from .utils import shard_map_compat
-    return shard_map_compat(
-        per_stage, mesh, (P(None, axis, None), sspec, xspec), xspec,
+    return jax.shard_map(
+        per_stage, mesh=mesh, in_specs=(P(None, axis, None), sspec, xspec),
+        out_specs=xspec,
         axis_names=set(mesh.axis_names))(padded, shared_params,
                                          micro_inputs)
 

@@ -27,34 +27,7 @@ from . import fs  # noqa: F401
 from .fs import HDFSClient, LocalFS  # noqa: F401
 
 __all__ = ["recompute", "recompute_sequential", "LocalFS", "HDFSClient",
-           "fs", "pvary_compat", "shard_map_compat"]
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=None,
-                     axis_names=None):
-    """``jax.shard_map`` across jax versions: new jax takes
-    ``check_vma``/``axis_names``, older jax only has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` and the
-    inverse ``auto`` set (axes NOT handled manually). Shared by the ring
-    attention and SPMD pipeline kernels and the collective layer."""
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm
-    # The legacy check_rep=True checker false-positives on valid programs
-    # (psum-inside-fori_loop carries, ppermute pipelines — measured: 4
-    # extra test failures with the default on jax 0.4.x), which is why
-    # later jax relaxed it into check_vma. Run the legacy path unchecked
-    # unless the caller explicitly asked for checking.
-    kw = {"check_rep": bool(check_vma)}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+           "fs", "mark_varying", "match_vma"]
 
 
 def match_vma(value, like):
@@ -62,48 +35,22 @@ def match_vma(value, like):
     ``like`` — the fix for fresh constants (scan carries, zero states)
     created INSIDE a shard_map manual region next to varying inputs: the
     scan's carry-in must type-match its carry-out. No-op outside manual
-    regions or on pre-vma jax."""
-    try:
-        want = frozenset(getattr(jax.typeof(like), "vma", frozenset()))
-        have = frozenset(getattr(jax.typeof(value), "vma", frozenset()))
-        missing = tuple(sorted(want - have))
-        if missing:
-            return jax.lax.pcast(value, missing, to="varying")
-    except (AttributeError, TypeError):
-        pass
+    regions."""
+    missing = tuple(sorted(jax.typeof(like).vma - jax.typeof(value).vma))
+    if missing:
+        return jax.lax.pcast(value, missing, to="varying")
     return value
 
 
-def pvary_compat(x, axis):
+def mark_varying(x, axis):
     """Mark a freshly-created invariant array device-varying over ``axis``
     (the shard_map vma rule for scan carries whose other inputs are
-    rank-dependent). No-op when the value is already varying or the running
-    jax predates/postdates the pcast/pvary split — shared by the ring
-    attention and SPMD pipeline kernels."""
-    try:
-        if axis in getattr(jax.typeof(x), "vma", ()):
-            return x
-    except (AttributeError, TypeError):
-        pass
-    pcast_err = None
-    try:
-        return jax.lax.pcast(x, axis, to="varying")
-    except AttributeError:
-        pass
-    except TypeError as e:
-        pcast_err = e
-    try:
-        # pre-pcast jax: the deprecated spelling
-        return jax.lax.pvary(x, axis)
-    except AttributeError:
-        if pcast_err is None:
-            # pre-vma jax (no pcast, no pvary): nothing to mark —
-            # shard_map has no varying-manual-axes typing at all here
-            return x
-        # pcast exists but rejected the call: surface THAT error rather
-        # than leave an invariant carry to fail later with an opaque
-        # shard_map vma mismatch (or mask it with pvary's AttributeError)
-        raise pcast_err
+    rank-dependent). No-op when the value is already varying — ``pcast``
+    refuses a varying→varying cast. Shared by the ring attention and
+    SPMD pipeline kernels."""
+    if axis in jax.typeof(x).vma:
+        return x
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def _owning_layer(function) -> Layer | None:

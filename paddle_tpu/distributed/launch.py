@@ -12,6 +12,10 @@ relaunch.
 CLI::
 
     python -m paddle_tpu.distributed.launch --nproc_per_node 2 train.py
+
+``--nproc_per_node > 1`` is for CPU runs (tests, drills): a TPU chip
+belongs to one process and nothing here partitions a host's chips, so on a
+TPU host the launcher refuses it before spawning anything.
 """
 from __future__ import annotations
 
@@ -202,6 +206,17 @@ def launch(script: str, script_args: Optional[List[str]] = None,
                 f"multi-node elastic: --np max ({np_max}) must equal "
                 f"--nnodes ({nnodes}) — one trainer per host (the TPU "
                 "process shape); min bounds the surviving node count")
+    from paddle_tpu.device import tpu_selected
+    if nproc_per_node > 1 and tpu_selected():
+        # refuse before anything is spawned: nothing here partitions the
+        # host's chips between children, and the second one would fail
+        # or hang on the chips the first holds
+        raise RuntimeError(
+            f"--nproc_per_node {nproc_per_node} on a TPU host: a chip "
+            "belongs to one process, and these trainers would all open "
+            "the same chips. One process drives every local chip (SPMD "
+            "over a mesh); scale with --nnodes, one trainer per host. "
+            "For a CPU run set JAX_PLATFORMS=cpu.")
     world_size = nnodes * nproc_per_node
     if master is None:
         store = TCPStore(is_master=True, world_size=world_size)

@@ -939,38 +939,41 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         attn_mask is not None
         and getattr(attn_mask, "_causal_diag", False)
         and s_q == s_k and tuple(attn_mask.shape)[-2:] == (s_q, s_k))
-    if use_pallas and not mask_trainable:
-        try:
-            import jax as _j
-            if _j.default_backend() == "tpu":
-                from paddle_tpu.ops.pallas.flash_attention import (
-                    flash_attention_bshd)
-                drop = float(dropout_p) if training else 0.0
-                seed = None
-                if drop > 0.0:
-                    # in-kernel position-hashed dropout; fresh seed per
-                    # call from the generator stream (a DIFFERENT pattern
-                    # than the composite's bernoulli — dropout RNG is
-                    # backend-specific by contract)
-                    import jax.random as _jr
-                    seed = _jr.randint(_gen.next_key(), (1,),
-                                       minval=-2**31, maxval=2**31 - 1,
-                                       dtype=jnp.int32)
-                if attn_mask is None or causal_tagged:
-                    return flash_attention_bshd(
-                        query, key, value,
-                        causal=is_causal or causal_tagged,
-                        q_segment_ids=q_segment_ids,
-                        kv_segment_ids=kv_segment_ids,
-                        dropout_p=drop, dropout_seed=seed)
-                bias = _additive_mask(attn_mask)
-                return flash_attention_bshd(
-                    query, key, value, causal=is_causal, bias=bias,
-                    q_segment_ids=q_segment_ids,
-                    kv_segment_ids=kv_segment_ids,
-                    dropout_p=drop, dropout_seed=seed)
-        except Exception:
-            pass
+    # off-TPU, and for lengths the kernel cannot tile, the composite
+    # below is the path — chosen from the backend and the shapes. On TPU
+    # a kernel error raises: it must never turn into the S×S composite
+    use_flash = use_pallas and not mask_trainable \
+        and jax.default_backend() == "tpu"
+    if use_flash:
+        from paddle_tpu.ops.pallas.flash_attention import (
+            flash_attention_bshd, flash_tileable)
+        full_bias = attn_mask is not None and not causal_tagged \
+            and tuple(attn_mask.shape)[-2] != 1
+        use_flash = flash_tileable(s_q, s_k, full_bias)
+    if use_flash:
+        drop = float(dropout_p) if training else 0.0
+        seed = None
+        if drop > 0.0:
+            # in-kernel position-hashed dropout; fresh seed per call from
+            # the generator stream (a DIFFERENT pattern than the
+            # composite's bernoulli — dropout RNG is backend-specific by
+            # contract)
+            seed = jax.random.randint(_gen.next_key(), (1,),
+                                      minval=-2**31, maxval=2**31 - 1,
+                                      dtype=jnp.int32)
+        if attn_mask is None or causal_tagged:
+            return flash_attention_bshd(
+                query, key, value,
+                causal=is_causal or causal_tagged,
+                q_segment_ids=q_segment_ids,
+                kv_segment_ids=kv_segment_ids,
+                dropout_p=drop, dropout_seed=seed)
+        return flash_attention_bshd(
+            query, key, value, causal=is_causal,
+            bias=_additive_mask(attn_mask),
+            q_segment_ids=q_segment_ids,
+            kv_segment_ids=kv_segment_ids,
+            dropout_p=drop, dropout_seed=seed)
 
     drop_key = _gen.next_key() if (dropout_p > 0 and training) else None
     seg_mask = _segment_mask(q_segment_ids, kv_segment_ids)

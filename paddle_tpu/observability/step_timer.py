@@ -24,7 +24,9 @@ __all__ = ["StepTimer", "peak_flops"]
 
 def peak_flops(device) -> float:
     """bf16 peak FLOP/s per chip by device kind (public TPU specs);
-    0 on CPU, where MFU is not meaningful."""
+    0 on CPU, where MFU is not meaningful. A TPU whose ``device_kind``
+    is not in the table raises: a guessed peak makes every MFU built on
+    it wrong without a sign."""
     kind = getattr(device, "device_kind", "").lower()
     table = [
         ("v6e", 918e12), ("trillium", 918e12),
@@ -34,8 +36,10 @@ def peak_flops(device) -> float:
     for key, val in table:
         if key in kind:
             return val
-    if "tpu" in kind:
-        return 275e12  # conservative default for unknown TPU
+    if getattr(device, "platform", "") == "tpu" or "tpu" in kind:
+        raise ValueError(
+            f"no bf16 peak for TPU device_kind {device.device_kind!r}: "
+            "add it to observability.step_timer.peak_flops with its source")
     return 0.0
 
 
@@ -43,11 +47,8 @@ def _detect_peak() -> float:
     import sys
     jax = sys.modules.get("jax")
     if jax is None:
-        return 0.0
-    try:
-        return peak_flops(jax.devices()[0])
-    except Exception:
-        return 0.0
+        return 0.0  # jax not imported: no device to ask
+    return peak_flops(jax.devices()[0])
 
 
 class StepTimer:

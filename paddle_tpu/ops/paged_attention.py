@@ -4,9 +4,11 @@ The serving engine (``paddle_tpu.serving``) stores each layer's KV cache
 as a pool of fixed-size token blocks instead of one contiguous
 ``[B, L, n_kv, hd]`` buffer per batch:
 
-    k_pool / v_pool : [num_blocks + 1, block_size, n_kv, hd]
+    k_pool / v_pool : [num_blocks + 1, n_kv, block_size, hd]
                       (row 0 is the reserved null block; allocatable
-                      block ids run 1..num_blocks)
+                      block ids run 1..num_blocks; head-major inside a
+                      block so one head's page is a whole
+                      ``[block_size, hd]`` tile for the Pallas reader)
     block_tables    : [B, max_blocks_per_seq] int32 — logical block i of
                       row b lives in physical block ``block_tables[b, i]``
     context_lens    : [B] int32 — tokens already cached per row
@@ -33,7 +35,8 @@ This mirrors the vLLM / Ragged-Paged-Attention layout (see
   the real ``context_len`` worth of pages, no dense score tensor.
 
 ``PADDLE_TPU_PAGED_ATTN_IMPL={rpa,gather,auto}`` picks the path
-(``auto``, the default: rpa on TPU, gather elsewhere);
+(``auto``, the default: rpa on TPU, gather elsewhere — off-TPU the
+kernel only runs in Pallas interpret mode, a test vehicle);
 :func:`impl_override` pins it programmatically (the engine's
 ``attn_impl=`` knob, and how parity tests compare both). The serving
 engine feeds the ragged token-packed form (:class:`RaggedLayerCache`);
@@ -55,7 +58,7 @@ __all__ = ["PagedLayerCache", "RaggedLayerCache", "write_to_pool",
            "write_tokens_to_pool", "gather_pool", "paged_attention_step",
            "ragged_gather_attention", "ragged_paged_attention_step",
            "paged_attention_impl", "impl_override", "mesh_override",
-           "quantize_kv_slots", "write_kv_scales_to_pool"]
+           "quantize_kv_slots"]
 
 
 class PagedLayerCache(NamedTuple):
@@ -67,8 +70,8 @@ class PagedLayerCache(NamedTuple):
     ``new_lens`` are shared across layers (one table per sequence), the
     pools are per-layer.
     """
-    k_pool: object        # [num_blocks + 1, block_size, n_kv, hd]
-    v_pool: object        # [num_blocks + 1, block_size, n_kv, hd]
+    k_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
+    v_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
     block_tables: object  # [B, max_blocks_per_seq] int32
     context_lens: object  # [B] int32
     new_lens: object      # [B] int32
@@ -91,16 +94,17 @@ def write_to_pool(pool, new, block_tables, positions, valid):
     ``positions`` [B, S] through ``block_tables``; tokens with
     ``valid == False`` land in the null block."""
     phys, slot = _scatter_indices(block_tables, positions, valid,
-                                  pool.shape[1])
-    return pool.at[phys, slot].set(new.astype(pool.dtype))
+                                  pool.shape[2])
+    return pool.at[phys, :, slot].set(new.astype(pool.dtype))
 
 
 def gather_pool(pool, block_tables):
     """[B, max_blocks_per_seq * block_size, n_kv, hd] contiguous view of
-    each row's paged context (the XLA-gather read path)."""
-    g = pool[block_tables]  # [B, nblk, bs, n_kv, hd]
+    each row's paged context (the XLA-gather read path); a scale pool
+    ``[num_blocks + 1, n_kv, block_size]`` gathers the same way."""
+    g = jnp.swapaxes(pool[block_tables], 2, 3)  # [B, nblk, bs, n_kv, hd]
     B, nblk, bs = g.shape[0], g.shape[1], g.shape[2]
-    return g.reshape(B, nblk * bs, *pool.shape[2:])
+    return g.reshape(B, nblk * bs, *g.shape[3:])
 
 
 def paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
@@ -159,8 +163,8 @@ class RaggedLayerCache(NamedTuple):
     (``ops.pallas.ragged_paged_attention.build_step_maps``) and are
     traced INPUTS — shapes never change, so the engine's one executable
     serves every batch mix."""
-    k_pool: object        # [num_blocks + 1, block_size, n_kv, hd]
-    v_pool: object        # [num_blocks + 1, block_size, n_kv, hd]
+    k_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
+    v_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
     block_tables: object  # [max_seqs + 1, max_blocks_per_seq] int32
     cu_seqlens: object    # [max_seqs + 2] int32 token-span prefix sums
     context_lens: object  # [max_seqs + 1] int32 cached tokens per seq
@@ -170,8 +174,8 @@ class RaggedLayerCache(NamedTuple):
     step_blk: object      # [num_q_tiles, max_steps] int32 kernel work map
     # int8-KV quantization (ISSUE 20): per-token-slot, per-head dequant
     # multipliers paged like the pools; None on unquantized engines
-    k_scale: object = None  # [num_blocks + 1, block_size, n_kv] f32
-    v_scale: object = None  # [num_blocks + 1, block_size, n_kv] f32
+    k_scale: object = None  # [num_blocks + 1, n_kv, block_size] f32
+    v_scale: object = None  # [num_blocks + 1, n_kv, block_size] f32
 
 
 # thread-local: two engines may trace their unified steps concurrently
@@ -247,12 +251,14 @@ def write_tokens_to_pool(pool, new, block_tables, seq_ids, positions):
     """Scatter ``new`` [T, n_kv, hd] into ``pool`` at each token's
     ``positions`` through its sequence's block-table row. Padding tokens
     (sentinel ``seq_ids`` → the all-null table row) land in the null
-    block, exactly like the per-row form's invalid-token redirection."""
-    bs, nblk = pool.shape[1], block_tables.shape[1]
+    block, exactly like the per-row form's invalid-token redirection.
+    Per-token dequant scales [T, n_kv] scatter into a scale pool
+    ``[num_blocks + 1, n_kv, block_size]`` through the same indices."""
+    bs, nblk = pool.shape[2], block_tables.shape[1]
     blk = jnp.clip(positions.astype(jnp.int32) // bs, 0, nblk - 1)
     phys = block_tables[seq_ids, blk]
     slot = jnp.where(phys == 0, 0, positions.astype(jnp.int32) % bs)
-    return pool.at[phys, slot].set(new.astype(pool.dtype))
+    return pool.at[phys, :, slot].set(new.astype(pool.dtype))
 
 
 def quantize_kv_slots(x):
@@ -269,18 +275,6 @@ def quantize_kv_slots(x):
     return q.astype(jnp.int8), scale
 
 
-def write_kv_scales_to_pool(scale_pool, scales, block_tables, seq_ids,
-                            positions):
-    """Scatter per-token dequant ``scales`` [T, n_kv] into the paged
-    scale pool at the same (physical block, slot) the quantized values
-    landed in — padding redirects to the null block like the values."""
-    bs, nblk = scale_pool.shape[1], block_tables.shape[1]
-    blk = jnp.clip(positions.astype(jnp.int32) // bs, 0, nblk - 1)
-    phys = block_tables[seq_ids, blk]
-    slot = jnp.where(phys == 0, 0, positions.astype(jnp.int32) % bs)
-    return scale_pool.at[phys, slot].set(scales.astype(scale_pool.dtype))
-
-
 def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
                             positions, *, scale, k_scale=None,
                             v_scale=None):
@@ -289,7 +283,7 @@ def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
     masked softmax. Semantically identical to the rpa kernel (the parity
     oracle); costs the [T, L_max] materialization the kernel removes."""
     T, n_heads, hd = q.shape
-    n_kv = k_pool.shape[2]
+    n_kv = k_pool.shape[1]
     grp = n_heads // n_kv
     keys = gather_pool(k_pool, block_tables)   # [max_seqs+1, L, n_kv, hd]
     vals = gather_pool(v_pool, block_tables)
@@ -347,10 +341,10 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
                                       positions)
         v_pool = write_tokens_to_pool(v_pool, vq, block_tables, seq_ids,
                                       positions)
-        k_scale = write_kv_scales_to_pool(k_scale, ks, block_tables,
-                                          seq_ids, positions)
-        v_scale = write_kv_scales_to_pool(v_scale, vs, block_tables,
-                                          seq_ids, positions)
+        k_scale = write_tokens_to_pool(k_scale, ks, block_tables,
+                                       seq_ids, positions)
+        v_scale = write_tokens_to_pool(v_scale, vs, block_tables,
+                                       seq_ids, positions)
         out = ragged_gather_attention(
             q, k_pool, v_pool, block_tables, seq_ids, positions,
             scale=scale, k_scale=k_scale, v_scale=v_scale)
@@ -373,19 +367,18 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
             # same factor), metadata replicated. Attention is
             # embarrassingly parallel across heads: no collective is
             # introduced here (the o_proj psum stays GSPMD's).
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             mesh, ax = tp
             heads = P(None, ax, None)
-            pools = P(None, None, ax, None)
+            pools = P(None, ax, None, None)
             rep = P()
-            out = shard_map(
+            out = jax.shard_map(
                 lambda qa, kp, vp, bt, cu, ctx, ssq, sbk:
                     ragged_paged_attention(qa, kp, vp, bt, cu, ctx,
                                            ssq, sbk, sm_scale=scale),
                 mesh=mesh,
                 in_specs=(heads, pools, pools, rep, rep, rep, rep, rep),
-                out_specs=heads, check_rep=False)(
+                out_specs=heads, check_vma=False)(
                 q, k_pool, v_pool, block_tables, cu_seqlens,
                 context_lens, step_seq, step_blk)
         else:

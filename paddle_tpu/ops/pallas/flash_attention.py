@@ -46,8 +46,10 @@ a GPU; SURVEY.md §4 calls out this improvement).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
+import threading
 
 import numpy as np
 
@@ -56,15 +58,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention_bshd", "flash_attention_bhsd"]
+__all__ = ["flash_attention_bshd", "flash_attention_bhsd",
+           "flash_tileable", "spmd_mesh"]
 
 _DEF_BLOCK_Q = 1024  # swept on v5e: 1024/1024 beats 512/512 by ~16% fwd+bwd
 _DEF_BLOCK_K = 1024
 _BIAS_BLOCK = 512    # bias tiles are f32 [bq, bk]: cap so VMEM double-buffers
 _LANES = 128
-# refuse block sizes that can't double-buffer in ~16MB VMEM; callers fall
-# back to the composite instead of paying a doomed Mosaic compile (hit by
-# odd kv lengths — e.g. decode at long context — that force block == seq)
+# refuse block sizes that can't double-buffer in ~16MB VMEM instead of
+# paying a doomed Mosaic compile (hit by odd kv lengths — e.g. decode at
+# long context — that force block == seq); SDPA routes such shapes to the
+# composite up front (flash_tileable)
 _MAX_BLOCK = 2048
 # finite stand-in for -inf (the official TPU flash kernels use the same
 # trick): keeps m/l/alpha arithmetic NaN-free when a tile is fully masked
@@ -161,11 +165,8 @@ def _auto_blocks(b, sq, sk, d, hq, hkv, dtype, causal, bias_kind, has_seg,
 
 
 def _compiler_params():
-    sem = ("parallel", "parallel", "arbitrary")
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _masked_scores(q, k, bias_ref, seg, j, i, *, sm_scale, causal, offset,
@@ -722,6 +723,17 @@ def _pick_block(requested, seq):
     return bigger[0] if bigger else seq
 
 
+def flash_tileable(sq, sk, full_bias=False) -> bool:
+    """Whether the default blocks tile ``(sq, sk)`` inside VMEM — the
+    shape test SDPA routes on before it calls the kernel. False only for
+    a length with no lane-multiple divisor that is too long to stream as
+    one tile (odd kv lengths: eager decode at long context), which the
+    kernel itself refuses with a ValueError."""
+    cap = _BIAS_BLOCK if full_bias else _MAX_BLOCK
+    return (_pick_block(min(_DEF_BLOCK_Q, cap), sq) <= _MAX_BLOCK
+            and _pick_block(min(_DEF_BLOCK_K, cap), sk) <= _MAX_BLOCK)
+
+
 def _norm_bias(bias, b, hq, sq, sk):
     """Normalize bias to (flat [Bb*Hb, Sq|1, Sk], (Bb, Hb, row_bcast)) with
     Bb in {1,B}, Hb in {1,Hq}. A size-1 q dim (the [B, 1, 1, Sk]
@@ -862,6 +874,51 @@ def flash_attention_bhsd(q, k, v, causal=False, sm_scale=None, bias=None,
     return out
 
 
+# thread-local: two TrainSteps may trace concurrently on their own threads
+_spmd_local = threading.local()
+
+
+@contextlib.contextmanager
+def spmd_mesh(mesh):
+    """Pin the mesh a GSPMD program is being traced for (``None`` =
+    single device, a no-op). A ``pallas_call`` is opaque to the
+    partitioner: under a multi-device jit, jax 0.9.0 refuses to lower it
+    at all ("Mosaic kernels cannot be automatically partitioned. Please
+    wrap the call in a shard_map"). ``TrainStep`` wraps its GSPMD trace
+    in this and :func:`flash_attention_bshd` reads it to ``shard_map``
+    the kernel over the batch (``dp``) and head (``mp``) axes, so each
+    chip runs the kernel on its own ``[B/dp, S, H/mp, D]`` shard. The
+    serving route pins its mesh the same way
+    (``ops/paged_attention.mesh_override``)."""
+    prev = getattr(_spmd_local, "mesh", None)
+    _spmd_local.mesh = mesh
+    try:
+        yield
+    finally:
+        _spmd_local.mesh = prev
+
+
+def _spmd_axes(b, hq, hkv):
+    """(mesh, batch_axis|None, head_axis|None) for the pinned mesh: the
+    ``dp`` axis when it divides the batch, the model axis when it divides
+    both head counts (whole GQA groups stay together). None when no mesh
+    is pinned or neither axis splits anything."""
+    mesh = getattr(_spmd_local, "mesh", None)
+    if mesh is None:
+        return None
+    batch = "dp" if mesh.shape.get("dp", 1) > 1 \
+        and b % mesh.shape["dp"] == 0 else None
+    head = None
+    for cand in ("mp", "model", "tp"):
+        n = mesh.shape.get(cand, 1)
+        if n > 1 and hq % n == 0 and hkv % n == 0:
+            head = cand
+            break
+    if batch is None and head is None:
+        return None
+    return mesh, batch, head
+
+
 def flash_attention_bshd(query, key, value, causal=False, sm_scale=None,
                          bias=None, q_segment_ids=None, kv_segment_ids=None,
                          dropout_p=0.0, dropout_seed=None,
@@ -870,7 +927,8 @@ def flash_attention_bshd(query, key, value, causal=False, sm_scale=None,
     Tensor-in/Tensor-out, recorded on the autograd tape. ``key``/``value``
     may carry fewer heads (GQA) and a different sequence length (cross
     attention) than ``query``. ``bias``/segment ids are mask constants —
-    closed over, not taped."""
+    closed over, not taped. Under a pinned :func:`spmd_mesh` the kernel
+    runs per shard of the batch and head axes."""
     from paddle_tpu.core.autograd import apply_op
 
     def _raw(x):
@@ -879,17 +937,51 @@ def flash_attention_bshd(query, key, value, causal=False, sm_scale=None,
     bias_arr = None if bias is None else _raw(bias)
     qseg_arr = None if q_segment_ids is None else _raw(q_segment_ids)
     kvseg_arr = None if kv_segment_ids is None else _raw(kv_segment_ids)
+    seed_arr = None if dropout_seed is None else \
+        jnp.atleast_1d(jnp.asarray(_raw(dropout_seed))).astype(jnp.int32)[:1]
+
+    def kernel(q, k, v, bias_, qseg, kvseg, seed):
+        o = flash_attention_bhsd(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2), causal=causal, sm_scale=sm_scale,
+            bias=bias_, q_segment_ids=qseg, kv_segment_ids=kvseg,
+            dropout_p=dropout_p, dropout_seed=seed,
+            block_q=block_q, block_k=block_k)
+        return jnp.swapaxes(o, 1, 2)
 
     def f(q, k, v):
-        qt = jnp.swapaxes(q, 1, 2)
-        kt = jnp.swapaxes(k, 1, 2)
-        vt = jnp.swapaxes(v, 1, 2)
-        o = flash_attention_bhsd(qt, kt, vt, causal=causal,
-                                 sm_scale=sm_scale, bias=bias_arr,
-                                 q_segment_ids=qseg_arr,
-                                 kv_segment_ids=kvseg_arr,
-                                 dropout_p=dropout_p,
-                                 dropout_seed=dropout_seed,
-                                 block_q=block_q, block_k=block_k)
-        return jnp.swapaxes(o, 1, 2)
+        spmd = _spmd_axes(q.shape[0], q.shape[2], k.shape[2])
+        if spmd is None:
+            return kernel(q, k, v, bias_arr, qseg_arr, kvseg_arr, seed_arr)
+        from jax.sharding import PartitionSpec as P
+        mesh, bax, hax = spmd
+        qkv = P(bax, None, hax, None)
+        seg = P(bax)
+        bias4 = bias_arr
+        bias_spec = None
+        if bias4 is not None:
+            # normalize to 4-D so the batch/head dims are addressable; a
+            # broadcast (size-1) dim stays replicated
+            bias4 = bias4.reshape((1,) * (4 - bias4.ndim) + bias4.shape)
+            bias_spec = P(bax if bias4.shape[0] > 1 else None,
+                          hax if bias4.shape[1] > 1 else None, None, None)
+
+        def shard(q, k, v, bias_, qseg, kvseg, seed):
+            if seed is not None:
+                # the in-kernel dropout hash sees shard-LOCAL head
+                # indices: fold the shard's mesh position into the seed
+                # so shards do not repeat one another's pattern
+                for ax in (bax, hax):
+                    if ax is not None:
+                        seed = seed * jnp.int32(1000003) \
+                            + jax.lax.axis_index(ax)
+            return kernel(q, k, v, bias_, qseg, kvseg, seed)
+
+        # check_vma off: pallas_call's output avals carry no vma
+        # annotation, which the checker (not the semantics) rejects
+        return jax.shard_map(
+            shard, mesh=mesh,
+            in_specs=(qkv, qkv, qkv, bias_spec, seg, seg, P()),
+            out_specs=qkv, check_vma=False)(
+            q, k, v, bias4, qseg_arr, kvseg_arr, seed_arr)
     return apply_op(f, query, key, value, op_name="flash_attention")

@@ -46,11 +46,7 @@ def _interpret() -> bool:
 
 
 def _compiler_params():
-    sem = ("arbitrary",)
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _metadata(offsets_ext, n_blocks: int, n_groups: int, bm: int,

@@ -14,8 +14,11 @@ arxiv 2604.15464) this kernel takes the batch **token-packed**:
     q              : [total_tokens, n_heads, hd] — every sequence's new
                      tokens back to back (prefill chunks with S>1 and
                      decode rows with S=1 in the same flat axis)
-    k_pool/v_pool  : [num_blocks + 1, block_size, n_kv, hd]
-                     (physical block 0 is the reserved null block)
+    k_pool/v_pool  : [num_blocks + 1, n_kv, block_size, hd]
+                     (physical block 0 is the reserved null block; one
+                     head's page is a whole ``[block_size, hd]`` tile, the
+                     block shape Mosaic accepts — a head squeezed out of
+                     the last two dims is not)
     block_tables   : [max_seqs + 1, max_blocks_per_seq] int32 — row
                      ``max_seqs`` is the all-null sentinel row that
                      padding tokens and dead grid steps resolve through
@@ -52,9 +55,10 @@ bounded by its table width, and all sequences in a tile together can't
 hold more pages than the pool has blocks.
 
 Off-TPU the kernel runs in Pallas interpret mode, which is what tier-1
-parity tests exercise on the CPU mesh (`tests/test_ragged_paged_attention.py`).
-``tile_q`` registers through ``ops/pallas/autotune.py`` exactly like
-``flash_attention.py``'s block sizes.
+parity tests exercise on the CPU mesh (`tests/test_ragged_paged_attention.py`);
+on the chip ``chip_smoke.py`` compares the compiled kernel against the
+gather reader. ``tile_q`` registers through ``ops/pallas/autotune.py``
+exactly like ``flash_attention.py``'s block sizes.
 """
 from __future__ import annotations
 
@@ -69,20 +73,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ragged_paged_attention", "build_step_maps", "rpa_tile_q",
-           "rpa_max_steps", "DEFAULT_TILE_Q"]
-
-#: default flat-token tile height; MXU sublane granularity for f32 is 8,
-#: so 8 is the no-waste floor for decode-heavy mixes (each decode row
-#: contributes group-many score rows on top)
-DEFAULT_TILE_Q = 8
+           "rpa_max_steps", "default_tile_q"]
 
 _LANES = 128
 # finite stand-in for -inf (same trick as flash_attention.py): keeps the
 # m/l/alpha arithmetic NaN-free on fully-masked tiles
 _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
-#: tile_q candidates for the runtime autotuner (default first: a sweep
-#: that ties keeps the hand-picked value)
+#: tile_q candidates for the runtime autotuner
 _TILE_CANDIDATES = (8, 16, 32)
 
 
@@ -90,12 +88,18 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _compiler_params():
-    sem = ("parallel", "parallel", "arbitrary")
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
+def default_tile_q(group: int, dtype) -> int:
+    """Flat-token tile height: the smallest multiple of 8 whose
+    ``tile_q * group`` score rows fill whole sublane tiles of ``dtype``
+    (8 rows of f32, 16 of bf16, 32 of int8) — the q block's second-minor
+    dim must be a tile multiple for Mosaic. 8 everywhere except narrow
+    dtypes on MHA models (``group == 1``), the no-waste floor for
+    decode-heavy mixes (each decode row contributes group-many rows)."""
+    sublane = 32 // jnp.dtype(dtype).itemsize
+    tile = 8
+    while (tile * group) % sublane:
+        tile += 8
+    return tile
 
 
 def rpa_max_steps(tile_q: int, max_blocks_per_seq: int,
@@ -181,17 +185,20 @@ def _rpa_kernel(ss_ref, sb_ref, bt_ref, cu_ref, ctx_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
-        # row r of the tile is (token j*tile_q + r//group, head r%group)
+        # row r of the tile is (token j*tile_q + r//group, head r%group).
+        # The three bounds below are the token-space tests
+        #   start <= tok < end   and   kpos <= ctx + tok - start
+        # multiplied through by ``group`` (r//g >= a  <=>  r >= a*g), so
+        # the kernel needs no vector integer division
         r = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0)
-        tok = j * tile_q + r // group
-        start = cu_ref[ss]
-        owned = (tok >= start) & (tok < cu_ref[ss + 1])
-        qpos = ctx_ref[ss] + tok - start
+        lo = cu_ref[ss] - j * tile_q        # sequence span, tile-relative
+        hi = cu_ref[ss + 1] - j * tile_q
         kpos = sb * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_size), 1)
         # one bound covers prior context, in-chunk causality, and (with
         # page enumeration stopping at ceil(kv_len/bs)) page raggedness
-        visible = owned & (kpos <= qpos)
+        visible = (r >= lo * group) & (r < hi * group) & \
+            (r >= (kpos - ctx_ref[ss] + lo) * group)
         s = jnp.maximum(jnp.where(visible, s, _MASK_VALUE), _MASK_VALUE)
         m_prev = m_sc[:, :1]                            # lane-replicated
         l_prev = l_sc[:, :1]
@@ -202,8 +209,9 @@ def _rpa_kernel(ss_ref, sb_ref, bt_ref, cu_ref, ctx_ref,
         # causally-dead decode rows) would contribute exp(MASK-MASK)=1
         # per column; zeroing them keeps their l at 0 so their m/l/acc
         # state rides through untouched (alpha re-scales acc by the same
-        # factor l absorbs)
-        p = jnp.where(jnp.any(visible, axis=-1, keepdims=True), p, 0.0)
+        # factor l absorbs). A row is live iff its max rose above the
+        # mask value — a float compare, not a boolean reduction
+        p = jnp.where(m_cur > _MASK_VALUE, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
@@ -225,7 +233,7 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, block_tables,
     """``q_heads`` [n_kv, T*group, hd] (token-major rows per kv head) →
     out in the same layout."""
     n_kv, tg, hd = q_heads.shape
-    block_size = k_pool.shape[1]
+    block_size = k_pool.shape[2]
     max_seqs = block_tables.shape[0] - 1
     num_tiles, max_steps = step_seq.shape
     rows = tile_q * group
@@ -242,15 +250,15 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, block_tables,
         # Dead steps resolve through the sentinel table row to the null
         # page 0; consecutive equal indices are not re-fetched, so a
         # padded work-list tail costs one DMA, not one per step.
-        return (bt[ss[j, i], sb[j, i]], 0, h, 0)
+        return (bt[ss[j, i], sb[j, i]], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(n_kv, num_tiles, max_steps),
         in_specs=[
             pl.BlockSpec((None, rows, hd), q_map),
-            pl.BlockSpec((None, block_size, None, hd), kv_map),
-            pl.BlockSpec((None, block_size, None, hd), kv_map),
+            pl.BlockSpec((None, None, block_size, hd), kv_map),
+            pl.BlockSpec((None, None, block_size, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((None, rows, hd), q_map),
         scratch_shapes=[
@@ -263,7 +271,8 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_kv, tg, hd), q_heads.dtype),
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(step_seq, step_blk, block_tables, cu_seqlens, context_lens,
       q_heads, k_pool, v_pool)
@@ -275,14 +284,14 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
     """GQA attention for a token-packed ragged batch over paged KV.
 
     ``q`` [total_tokens, n_heads, hd]; pools
-    ``[num_blocks + 1, block_size, n_kv, hd]`` (this step's new K/V
+    ``[num_blocks + 1, n_kv, block_size, hd]`` (this step's new K/V
     already scattered in — the kernel is a pure read); metadata as
     documented in the module docstring (``build_step_maps`` produces the
     step maps). Returns ``[total_tokens, n_heads, hd]``. Outputs at
     padding tokens (sentinel ``seq_id``) are exactly 0.
     """
     T, n_heads, hd = q.shape
-    n_kv = k_pool.shape[2]
+    n_kv = k_pool.shape[1]
     if n_heads % n_kv:
         raise ValueError(
             f"q heads {n_heads} must be a multiple of kv heads {n_kv}")
@@ -312,14 +321,14 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
 # =========================== tile autotune ===================================
 def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
                max_blocks_per_seq, pool_blocks, dtype="float32") -> int:
-    """The flat-token tile height for an engine at this signature — the
-    hand-picked :data:`DEFAULT_TILE_Q`, or (with ``FLAGS_use_autotune``
-    on chip) the winner of an on-device sweep over
-    ``_TILE_CANDIDATES`` measured once per signature and cached
+    """The flat-token tile height for an engine at this signature —
+    :func:`default_tile_q`, or (with ``FLAGS_use_autotune`` on chip) the
+    winner of an on-device sweep over the ``_TILE_CANDIDATES`` at least
+    that tall, measured once per signature and cached
     (``ops/pallas/autotune.py``, the flash-attention pattern). The
     engine rounds its token budget up to a multiple of the returned
     tile, so any candidate is legal."""
-    default = DEFAULT_TILE_Q
+    default = default_tile_q(n_heads // n_kv, dtype)
     if _interpret():
         return default  # interpret mode: timing a sweep is meaningless
     from paddle_tpu.core.flags import flag
@@ -362,12 +371,14 @@ def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
         with jax.ensure_compile_time_eval():
             dt = jnp.dtype(dtype)
             q0 = jnp.zeros((T, n_heads, head_dim), dt)
-            kp = jnp.zeros((pool_blocks + 1, block_size, n_kv, head_dim),
+            kp = jnp.zeros((pool_blocks + 1, n_kv, block_size, head_dim),
                            dt)
         return aot_runner(
             lambda qa, kpa, vpa: ragged_paged_attention(
                 qa, kpa, vpa, bt, cu, ctx_arr, ssq, sbk),
             q0, kp, kp)
 
-    return autotune("ragged_paged_attention", sig, _TILE_CANDIDATES,
-                    build, default)
+    # default first (a tie keeps it); shorter tiles would leave partial
+    # sublane tiles in the q block
+    cands = [default] + [t for t in _TILE_CANDIDATES if t > default]
+    return autotune("ragged_paged_attention", sig, cands, build, default)

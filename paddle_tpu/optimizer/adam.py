@@ -34,8 +34,10 @@ class Adam(Optimizer):
     def _create_state(self, p):
         dt = jnp.float32 if self._needs_master(p) else p.data.dtype
         return {
-            "moment1": jnp.zeros(p.data.shape, dt),
-            "moment2": jnp.zeros(p.data.shape, dt),
+            # zeros_like: the moments are born with the parameter's
+            # sharding instead of whole on the default device
+            "moment1": jnp.zeros_like(p.data, dtype=dt),
+            "moment2": jnp.zeros_like(p.data, dtype=dt),
             "beta1_pow": jnp.ones((), jnp.float32),
             "beta2_pow": jnp.ones((), jnp.float32),
         }

@@ -219,7 +219,7 @@ class ServingEngine:
         from paddle_tpu.models.generation import decode_surfaces
         from paddle_tpu.ops import paged_attention as pa
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
-            DEFAULT_TILE_Q, build_step_maps, rpa_max_steps, rpa_tile_q)
+            build_step_maps, default_tile_q, rpa_max_steps, rpa_tile_q)
         from paddle_tpu.quantization.weight_only import (
             WEIGHT_MODES, calibration_from_checkpoint, quantization_metrics,
             quantize_state)
@@ -357,8 +357,8 @@ class ServingEngine:
         # engine keeps the default tile — sweeping RPA kernel candidates
         # it will never execute would be pure startup cost
         n_heads = cfg.num_attention_heads
-        self._tile_q = DEFAULT_TILE_Q if self.attn_impl == "gather" \
-            else rpa_tile_q(
+        self._tile_q = default_tile_q(n_heads // n_kv, dtype) \
+            if self.attn_impl == "gather" else rpa_tile_q(
                 self.max_batch + self.prefill_chunk, n_heads, n_kv, hd,
                 block_size, self.cache.max_blocks_per_seq, max_blocks,
                 dtype=str(jnp.dtype(dtype)))

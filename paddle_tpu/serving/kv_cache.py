@@ -24,8 +24,9 @@ Three halves:
   so an index entry stays valid until the allocator evicts the block.
 
 * :class:`PagedKVCache` — the device state: one ``[num_blocks + 1,
-  block_size, n_kv, hd]`` K pool and V pool per layer (the +1 row is
-  the null block at physical index 0), threaded
+  n_kv, block_size, hd]`` K pool and V pool per layer (the +1 row is
+  the null block at physical index 0; the layout is
+  ``ops/paged_attention.py``'s), threaded
   functionally through the engine's compiled step (the jitted function
   takes the pools as inputs and returns the updated ones — nothing is
   mutated in place, so the executable never recompiles), plus the
@@ -371,7 +372,7 @@ class PagedKVCache:
         self.compute_dtype = jnp.dtype(dtype)
         self.kv_dtype = kv_dtype
         # +1: physical block 0 is the null block and backs no sequence
-        shape = (num_blocks + 1, block_size, num_kv_heads, head_dim)
+        shape = (num_blocks + 1, num_kv_heads, block_size, head_dim)
         store = jnp.int8 if kv_dtype == "int8" else dtype
         self.k_pools = tuple(jnp.zeros(shape, store)
                              for _ in range(num_layers))
@@ -380,7 +381,7 @@ class PagedKVCache:
         if kv_dtype == "int8":
             # per-token-slot, per-head dequant multipliers, paged like
             # the pools themselves so block tables address both
-            sshape = (num_blocks + 1, block_size, num_kv_heads)
+            sshape = (num_blocks + 1, num_kv_heads, block_size)
             self.k_scales = tuple(jnp.zeros(sshape, jnp.float32)
                                   for _ in range(num_layers))
             self.v_scales = tuple(jnp.zeros(sshape, jnp.float32)
@@ -415,11 +416,11 @@ class PagedKVCache:
         sharding through its functional threading."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        sh = NamedSharding(mesh, P(None, None, axis, None))
+        sh = NamedSharding(mesh, P(None, axis, None, None))
         self.k_pools = tuple(jax.device_put(p, sh) for p in self.k_pools)
         self.v_pools = tuple(jax.device_put(p, sh) for p in self.v_pools)
         if self.k_scales:
-            ssh = NamedSharding(mesh, P(None, None, axis))
+            ssh = NamedSharding(mesh, P(None, axis, None))
             self.k_scales = tuple(jax.device_put(p, ssh)
                                   for p in self.k_scales)
             self.v_scales = tuple(jax.device_put(p, ssh)
@@ -448,8 +449,8 @@ class PagedKVCache:
     # -- cross-replica block transfer (fleet disaggregation) ---------------
     def export_block(self, block_id: int) -> Tuple[np.ndarray, np.ndarray]:
         """Host-stage one physical block's KV rows across every layer:
-        returns ``(k, v)`` numpy arrays of shape ``[num_layers,
-        block_size, n_kv, hd]``. Device->host copy only — the caller
+        returns ``(k, v)`` numpy arrays of shape ``[num_layers, n_kv,
+        block_size, hd]``. Device->host copy only — the caller
         must hold a reference on ``block_id`` for the duration (the
         fleet handoff claims one via ``reuse_cached`` before calling)."""
         k = np.stack([np.asarray(p[block_id]) for p in self.k_pools])

@@ -229,7 +229,7 @@ class TestForward:
 
         def fake_bshd(*a, **kw):
             calls.append(kw)
-            raise RuntimeError("recorded")  # router falls back on error
+            return a[0]  # a kernel error would raise, not fall back
         monkeypatch.setattr(fa_mod, "flash_attention_bshd", fake_bshd)
 
         rng = np.random.RandomState(0)
@@ -563,3 +563,41 @@ class TestTrainableMask:
             attn_mask=pt.to_tensor(np.ones((1, 1, 8, 8), np.float32) * 0.3,
                                    stop_gradient=False))
         np.testing.assert_allclose(out2.numpy(), out3.numpy(), atol=1e-6)
+
+
+def test_kernel_shard_maps_itself_under_a_pinned_mesh():
+    """GSPMD cannot partition a Mosaic call (jax refuses to lower it), so
+    under ``spmd_mesh`` the kernel runs per (dp, mp) shard: same values
+    and gradients as the unpinned call, output sharded like the input
+    (ISSUE 21; interpret mode on the virtual mesh)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.ops.pallas.flash_attention import (
+        flash_attention_bshd, spmd_mesh)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+    rng = np.random.RandomState(0)
+    B, S, H, HK, D = 4, 64, 4, 2, 16
+    q, k, v = (jnp.asarray(rng.randn(B, S, h, D), jnp.float32)
+               for h in (H, HK, HK))
+    seg = jnp.asarray(np.repeat(np.arange(2), S // 2)[None].repeat(B, 0))
+
+    def run(pin):
+        def loss(q, k, v):
+            with spmd_mesh(mesh if pin else None):
+                o = flash_attention_bshd(
+                    pt.Tensor(q), pt.Tensor(k), pt.Tensor(v), causal=True,
+                    q_segment_ids=seg, kv_segment_ids=seg,
+                    block_q=32, block_k=32).data
+            return (o ** 2).sum(), o
+        args = (q, k, v)
+        if pin:
+            sh = NamedSharding(mesh, P("dp", None, "mp", None))
+            args = tuple(jax.device_put(a, sh) for a in args)
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(*args)
+        return o, g
+    o0, g0 = run(False)
+    o1, g1 = run(True)
+    assert o1.sharding.spec == P("dp", None, "mp", None)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o0), atol=1e-6)
+    for a, b in zip(g1, g0):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)

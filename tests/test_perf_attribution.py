@@ -118,23 +118,42 @@ class TestBenchReportGate:
     def bench(self):
         return _bench()
 
+    #: a driver-round record the tests write themselves (the gate reads
+    #: whatever BENCH_r*.json its --baseline-dir holds; no committed
+    #: record is needed to test it)
+    ROUND = {"rc": 0, "parsed": {
+        "llama_full_train_step_mfu_bf16": 60.0, "tokens_per_sec": 25000.0,
+        "step_ms": 300.0, "spread_pct_of_mean": 1.0,
+        "layer_mfu_pct": 65.0, "device": "TPU v5 lite"}}
+    MULTICHIP = {"n_devices": 8, "rc": 0, "ok": True, "tail":
+                 "dryrun_multichip(8): dpxmp(dp=4,mp=2) loss=5.5567 | "
+                 "dp-parity |5.55671-5.55671|<tol | pp(stages=4,v=2) "
+                 "loss=1.2698 | zero(p_g_os) |1.15677-1.15677|<tol | "
+                 "ep(experts=8) ok\n"}
+
     @pytest.fixture(scope="class")
-    def baseline(self, bench):
-        name, metrics = bench.report_baseline(REPO)
-        assert name and metrics, "committed trajectory must parse"
+    def rounds(self, tmp_path_factory):
+        d = tmp_path_factory.mktemp("rounds")
+        (d / "BENCH_r05.json").write_text(json.dumps(self.ROUND))
+        (d / "MULTICHIP_r05.json").write_text(json.dumps(self.MULTICHIP))
+        return str(d)
+
+    @pytest.fixture(scope="class")
+    def baseline(self, bench, rounds):
+        name, metrics = bench.report_baseline(rounds)
+        assert name == "BENCH_r05.json" and metrics
         return metrics
 
     def test_baseline_extraction(self, baseline):
-        # the committed r05 round: headline MFU + parsed details
         assert baseline["llama_full_train_step_mfu_bf16"] == \
-            pytest.approx(63.48)
-        assert baseline["step_ms"] == pytest.approx(287.88)
+            pytest.approx(60.0)
+        assert baseline["step_ms"] == pytest.approx(300.0)
 
-    def test_equal_run_passes(self, bench, baseline, tmp_path):
+    def test_equal_run_passes(self, bench, baseline, rounds, tmp_path):
         cur = tmp_path / "cur.json"
         cur.write_text(json.dumps({"parsed": baseline}))
         rc = bench.bench_report(["--report", "--current", str(cur),
-                                 "--baseline-dir", REPO])
+                                 "--baseline-dir", rounds])
         assert rc == 0
 
     @pytest.mark.parametrize("doctor", [
@@ -143,46 +162,46 @@ class TestBenchReportGate:
         {"tokens_per_sec": 0.8},
         {"spread_pct_of_mean": 4.0},                # stability blown
     ])
-    def test_doctored_regression_fails(self, bench, baseline, tmp_path,
-                                       doctor):
+    def test_doctored_regression_fails(self, bench, baseline, rounds,
+                                       tmp_path, doctor):
         bad = dict(baseline)
         for k, f in doctor.items():
             bad[k] = bad[k] * f
         cur = tmp_path / "bad.json"
         cur.write_text(json.dumps({"parsed": bad}))
         rc = bench.bench_report(["--report", "--current", str(cur),
-                                 "--baseline-dir", REPO])
+                                 "--baseline-dir", rounds])
         assert rc == 1
 
-    def test_improvement_passes(self, bench, baseline, tmp_path):
+    def test_improvement_passes(self, bench, baseline, rounds, tmp_path):
         good = dict(baseline)
         good["llama_full_train_step_mfu_bf16"] *= 1.1  # faster is fine
         good["step_ms"] *= 0.9
         cur = tmp_path / "good.json"
         cur.write_text(json.dumps({"parsed": good}))
         assert bench.bench_report(["--report", "--current", str(cur),
-                                   "--baseline-dir", REPO]) == 0
+                                   "--baseline-dir", rounds]) == 0
 
-    def test_tolerance_is_configurable(self, bench, baseline, tmp_path):
+    def test_tolerance_is_configurable(self, bench, baseline, rounds, tmp_path):
         near = dict(baseline)
         near["step_ms"] *= 1.04  # 4% slower
         cur = tmp_path / "near.json"
         cur.write_text(json.dumps({"parsed": near}))
         assert bench.bench_report(
-            ["--report", "--current", str(cur), "--baseline-dir", REPO,
+            ["--report", "--current", str(cur), "--baseline-dir", rounds,
              "--tolerance", "5"]) == 0
         assert bench.bench_report(
-            ["--report", "--current", str(cur), "--baseline-dir", REPO,
+            ["--report", "--current", str(cur), "--baseline-dir", rounds,
              "--tolerance", "2"]) == 1
 
     def test_crashed_current_run_fails_gate(self, bench, baseline,
-                                            tmp_path):
+                                            rounds, tmp_path):
         # a crashed bench's partial numbers are not proof of no
         # regression — rc != 0 fails regardless of the numbers
         cur = tmp_path / "crashed.json"
         cur.write_text(json.dumps({"rc": 1, "parsed": dict(baseline)}))
         rc = bench.bench_report(["--report", "--current", str(cur),
-                                 "--baseline-dir", REPO])
+                                 "--baseline-dir", rounds])
         assert rc == 1
 
     def test_baseline_skips_metricless_round(self, bench, tmp_path):
@@ -209,22 +228,22 @@ class TestBenchReportGate:
         assert name == "BENCH_r10.json"
         assert base["step_ms"] == 200.0
 
-    def test_missing_metrics_skip_unless_strict(self, bench, tmp_path):
+    def test_missing_metrics_skip_unless_strict(self, bench, rounds,
+                                                tmp_path):
         cur = tmp_path / "cpu.json"
         cur.write_text(json.dumps(
             {"parsed": {"tokens_per_sec_cpu_smoke": 123.0}}))
-        argv = ["--report", "--current", str(cur), "--baseline-dir", REPO]
+        argv = ["--report", "--current", str(cur), "--baseline-dir", rounds]
         assert bench.bench_report(argv) == 0            # visible but soft
         assert bench.bench_report(argv + ["--strict"]) == 1
 
-    def test_multichip_coverage_gate(self, bench, tmp_path):
-        with open(os.path.join(REPO, "MULTICHIP_r05.json")) as f:
-            mc = json.load(f)
-        ok = bench.report_multichip(REPO, mc)
+    def test_multichip_coverage_gate(self, bench, rounds):
+        mc = self.MULTICHIP
+        ok = bench.report_multichip(rounds, mc)
         assert ok["status"] == "ok"
         shrunk = dict(mc)
         shrunk["tail"] = mc["tail"].split("| zero")[0]
-        bad = bench.report_multichip(REPO, shrunk)
+        bad = bench.report_multichip(rounds, shrunk)
         assert bad["status"] == "fail"
         assert "zero" in bad["missing_segments"]
 

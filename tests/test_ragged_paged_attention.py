@@ -66,7 +66,7 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
         pos[off:off + n] = c + np.arange(n)
         off += n
 
-    kp = np.zeros((pool_blocks + 1, block_size, n_kv, hd), np.float32)
+    kp = np.zeros((pool_blocks + 1, n_kv, block_size, hd), np.float32)
     vp = np.zeros_like(kp)
     full_k, full_v = [], []
     for s, (n, c) in enumerate(seqs):
@@ -75,8 +75,8 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
         full_k.append(fk)
         full_v.append(fv)
         for t in range(c):            # prior context from earlier steps
-            kp[bt[s, t // block_size], t % block_size] = fk[t]
-            vp[bt[s, t // block_size], t % block_size] = fv[t]
+            kp[bt[s, t // block_size], :, t % block_size] = fk[t]
+            vp[bt[s, t // block_size], :, t % block_size] = fv[t]
     q = rng.randn(T, n_heads, hd).astype(np.float32)
     knew = np.zeros((T, n_kv, hd), np.float32)
     vnew = np.zeros((T, n_kv, hd), np.float32)

@@ -107,8 +107,8 @@ def test_paged_cache_matches_concat_cache():
     hd = m.cfg.hidden_size // m.cfg.num_attention_heads
     bs, nblk = 4, 3  # capacity 12 >= 8 cached tokens
     bt = pt.to_tensor(np.array([[1, 2, 3]], np.int32))  # blocks 1..3
-    pools = [[pt.to_tensor(jnp.zeros((nblk + 1, bs, n_kv, hd))),
-              pt.to_tensor(jnp.zeros((nblk + 1, bs, n_kv, hd)))]
+    pools = [[pt.to_tensor(jnp.zeros((nblk + 1, n_kv, bs, hd))),
+              pt.to_tensor(jnp.zeros((nblk + 1, n_kv, bs, hd)))]
              for _ in range(m.cfg.num_hidden_layers)]
 
     def run(x, ctx, n_new):

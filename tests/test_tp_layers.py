@@ -145,6 +145,32 @@ class TestTPTrainingParity:
                     for s in tp.up.weight.data.addressable_shards}) == 8
 
 
+class TestTrainStepPlacement:
+    def test_state_on_the_mesh_before_the_first_call(self, mesh_dp2mp4):
+        """Layers initialize on the default device and only stamp a spec.
+        TrainStep must place parameters and accumulators by that spec up
+        front: left off the mesh they park the whole training state on
+        the first chip, and — avals carry the mesh — retype the second
+        call, one full recompile (ISSUE 21: the parent compiled twice)."""
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        pt.seed(0)
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            num_hidden_layers=1, tie_word_embeddings=True,
+            tensor_parallel=True))
+        o = opt.AdamW(learning_rate=1e-3, parameters=model.parameters())
+        step = pt.jit.TrainStep(model, lambda m, x: m(x, labels=x)[1], o,
+                                mesh=mesh_dp2mp4, input_spec=P("dp"))
+        w = model.model.layers[0].self_attn.q_proj.weight
+        assert w.data.sharding.spec == P(None, "mp")
+        assert o._ensure_state(w)["moment1"].sharding.spec == P(None, "mp")
+        x = pt.to_tensor(np.random.RandomState(0).randint(
+            0, 256, (4, 16)).astype(np.int64))
+        for _ in range(3):
+            step(x)
+        (compiled,) = step._cache.values()
+        assert compiled._cache_size() == 1
+
+
 class TestFleetFacade:
     def test_init_and_wrap(self):
         strategy = fleet.DistributedStrategy()
