@@ -225,6 +225,13 @@ def comm_scope(op: str, axes: Sequence[str], payload=None,
     wd = _collective_watchdog
     token = None if wd is None else wd.arm(
         f"collective:{op}@{axes_label}", wd.collective_timeout)
+    # the same span in the JAX profiler's trace, when a session runs (the
+    # exposure split is known only at the end and stays with the sinks
+    # ``_emit`` feeds)
+    from paddle_tpu import profiler
+    ann = profiler.annotate(f"comm::{op}", {"bytes": nbytes,
+                                            "axes": axes_label,
+                                            **(extra or {})})
     t0 = time.perf_counter_ns()
     try:
         hook = _chaos_hook
@@ -232,6 +239,8 @@ def comm_scope(op: str, axes: Sequence[str], payload=None,
             hook(op, axes_label)
         yield
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         if wd is not None:
             wd.disarm(token)
         _emit(op, axes_label, nbytes, t0, time.perf_counter_ns(), extra)

@@ -274,6 +274,9 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, block_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        # the device op's name in a profiler trace (``rpa.N custom-call``;
+        # without it the op is named after the jitted caller)
+        name="rpa",
     )(step_seq, step_blk, block_tables, cu_seqlens, context_lens,
       q_heads, k_pool, v_pool)
 

@@ -16,6 +16,12 @@ with payload bytes + group axes by ``observability.comm``) render as a
 dedicated lane plus cumulative-bytes counter events in the chrome export;
 every span also feeds the crash flight recorder's ring when that is on,
 profiler active or not.
+
+Every ``RecordEvent`` is also a ``jax.profiler.TraceAnnotation``: while a JAX
+profiler session runs (``jax.profiler.start_trace``, a ``Profiler`` with a
+TPU target, ``POST /debug/profile``) the span lands in the ``.xplane.pb`` on
+the profiler's own clock, beside the device plane, its ``args`` as the
+event's stats. With no session it is a flag check.
 """
 from __future__ import annotations
 
@@ -165,6 +171,37 @@ class _Event:
         self.cat = cat
 
 
+#: a TraceMe's name carries its stats as ``name#k=v,k=v#``: the decoder
+#: splits on these three, so a value may not hold them
+_TRACEME_UNSAFE = str.maketrans({",": ";", "#": "_", "=": ":"})
+_trace_annotation = None
+
+
+def _trace_args(args) -> dict:
+    """``args`` as TraceMe stats: numbers as they are, anything else (the
+    ``comm`` spans' axes, a list) through ``str()``, cut to 256 characters,
+    the encoding's separators replaced."""
+    return {str(k): v if isinstance(v, (int, float))
+            else str(v).translate(_TRACEME_UNSAFE)[:256]
+            for k, v in args.items()}
+
+
+def annotate(name: str, args=None):
+    """An entered ``jax.profiler.TraceAnnotation`` for a span that begins
+    now, or None when no profiler session runs. The caller leaves it with
+    ``__exit__(None, None, None)``."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    if not _trace_annotation.is_enabled():
+        return None
+    ann = _trace_annotation(name, **_trace_args(args)) if args \
+        else _trace_annotation(name)
+    ann.__enter__()
+    return ann
+
+
 def _emit_event(name, start, end, tid=None, args=None, cat="op"):
     """Append one finished span to the active profiler (used by the comm
     tracer and any instrumentation that already has its timestamps).
@@ -180,21 +217,39 @@ def _emit_event(name, start, end, tid=None, args=None, cat="op"):
 
 class RecordEvent:
     """RAII host span (reference: ``paddle.profiler.RecordEvent``). Usable
-    as context manager or begin()/end() pair; no-op when no profiler runs
-    AND the flight recorder is off."""
+    as context manager or begin()/end() pair. Three sinks, each a no-op
+    when off: the active ``Profiler``'s store, the flight recorder's ring,
+    and the JAX profiler's trace (a ``TraceAnnotation``, so the span shares
+    the ``.xplane.pb`` and its clock with the device plane). ``args`` set
+    before ``begin()`` become the trace event's stats at once; keys added
+    to ``args`` between ``begin()`` and ``end()`` follow as late metadata.
+    A TraceMe is written whole by the thread that ends it, so the trace
+    files a pair that crosses threads (nothing in the program does) under
+    the thread of its ``end()``, with its true times."""
 
     def __init__(self, name: str, event_type=None, args=None, cat="op"):
         self.name = name
         self.args = args
         self.cat = cat
         self._t0 = None
+        self._ann = None
 
     def begin(self):
+        self._ann = annotate(self.name, self.args)
+        if self._ann is not None:
+            self._ann_sent = len(self.args) if self.args else 0
         fr = _flight_mod or _flight()
         if _state["active"] is not None or fr._active is not None:
             self._t0 = time.perf_counter_ns()
 
     def end(self):
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            if self.args and len(self.args) > self._ann_sent:
+                late = dict(list(self.args.items())[self._ann_sent:])
+                ann.set_metadata(**_trace_args(late))
+            ann.__exit__(None, None, None)
         if self._t0 is None:
             return
         t0, self._t0 = self._t0, None

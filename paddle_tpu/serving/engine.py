@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..profiler import RecordEvent
 from .kv_cache import PagedKVCache, chain_hash
 from .scheduler import Request, RequestState, Scheduler
 
@@ -917,35 +918,53 @@ class ServingEngine:
         return handle
 
     # -- one engine iteration ----------------------------------------------
+    def _leaf(self, name: str, step: int, **args) -> RecordEvent:
+        """One phase of a step as a ``RecordEvent`` (cat ``serving``). The
+        phases tile the step back to back on the engine's thread, and
+        nothing spans the step as a whole: an idle gap of the device then
+        falls to the phase the host was in (docs/SERVING.md). ``step`` is
+        the count of the step the call runs, shared by its leaves."""
+        return RecordEvent(name, args={"step": step, **args}, cat="serving")
+
     def step(self) -> bool:
         """Plan + run one unified token-packed step (all live decode
         slots + the packed prefill chunks). Returns whether any work
         happened."""
-        with self._lock:
-            plan = self.scheduler.schedule()
-            if self._ledger is not None:
-                # step-boundary occupancy sample: bill each slotted
-                # request's previous holding level for the elapsed
-                # interval (scheduler.preempt/finish tick pre-free, so
-                # no interval is lost when blocks go back)
-                self._ledger.note_occupancy_many(self.scheduler.slotted())
-            # belt-and-braces against plan staleness: never act on a
-            # sequence that lost its slot/blocks during planning (a
-            # later allocation in the same plan may have preempted it)
-            decode = [s for s in plan.decode
-                      if s.slot is not None
-                      and s.state is RequestState.RUNNING]
-            prefills = [(s, n) for (s, n) in plan.prefills
-                        if s.slot is not None
-                        and s.state is RequestState.PREFILL]
+        n = self._decode_steps + 1
+        with self._leaf("serving.lock", n):
+            self._lock.acquire()
+        try:
+            with self._leaf("serving.plan", n):
+                plan = self.scheduler.schedule()
+                if self._ledger is not None:
+                    # step-boundary occupancy sample: bill each slotted
+                    # request's previous holding level for the elapsed
+                    # interval (scheduler.preempt/finish tick pre-free,
+                    # so no interval is lost when blocks go back)
+                    self._ledger.note_occupancy_many(
+                        self.scheduler.slotted())
+                # belt-and-braces against plan staleness: never act on a
+                # sequence that lost its slot/blocks during planning (a
+                # later allocation in the same plan may have preempted
+                # it)
+                decode = [s for s in plan.decode
+                          if s.slot is not None
+                          and s.state is RequestState.RUNNING]
+                prefills = [(s, n_tok) for (s, n_tok) in plan.prefills
+                            if s.slot is not None
+                            and s.state is RequestState.PREFILL]
             if decode or prefills:
                 self._run_unified(decode, prefills)
-                # healthz liveness stamp: a wedged-but-listening server
-                # shows a growing last_step_age_seconds
-                from paddle_tpu.observability import fleet
-                fleet.note_step()
-            self._update_gauges()
+            with self._leaf("serving.gauges", n):
+                if decode or prefills:
+                    # healthz liveness stamp: a wedged-but-listening
+                    # server shows a growing last_step_age_seconds
+                    from paddle_tpu.observability import fleet
+                    fleet.note_step()
+                self._update_gauges()
             return bool(decode or prefills)
+        finally:
+            self._lock.release()
 
     def _run_unified(self, decode: List[Request],
                      prefills: List[tuple]):
@@ -955,6 +974,9 @@ class ServingEngine:
         step, and harvest per-sequence results."""
         from paddle_tpu.observability import trace
 
+        n_step = self._decode_steps + 1
+        leaf = self._leaf("serving.pack", n_step)
+        leaf.begin()
         for seq, _ in prefills:
             if seq.prefill_pos == 0 and seq.slot_time is not None \
                     and not getattr(seq, "_queue_wait_observed", False):
@@ -1040,6 +1062,16 @@ class ServingEngine:
                     self._build_step(instrument=True)
             step_fn = self._numerics_step
 
+        leaf.end()
+        leaf = self._leaf(
+            "serving.dispatch", n_step, decode_rows=len(decode),
+            prefill_rows=len(prefills),
+            prefill_tokens=sum(n for _, n in prefills),
+            # the rows as the step runs them, "<new>@<context>" in row
+            # order (";": a TraceMe cuts a value at a comma)
+            rows=";".join(f"{n}@{ctx[i]}"
+                          for i, (_, n, _) in enumerate(entries)))
+        leaf.begin()
         t0 = time.perf_counter_ns()
         compiles0 = self.step_traces
         try:
@@ -1066,7 +1098,11 @@ class ServingEngine:
         self._clear_model_side_effects()
         t1 = time.perf_counter_ns()
         compiled = self.step_traces - compiles0
+        leaf.args["compiled"] = compiled
+        leaf.end()
         self._m_steps.inc(kind="unified")
+        leaf = self._leaf("serving.fetch", n_step)
+        leaf.begin()
         arr = np.asarray(logits)
         if taps_out is not None:
             try:
@@ -1078,7 +1114,11 @@ class ServingEngine:
             except Exception:
                 warnings.warn("[numerics] decode sample publication "
                               "failed", RuntimeWarning)
+        leaf.end()
 
+        leaf = self._leaf("serving.commit", n_step)
+        leaf.begin()
+        tokens_out = 0
         for i, (seq, n, is_prefill) in enumerate(entries):
             if is_prefill:
                 if trace.active() is not None:
@@ -1107,11 +1147,15 @@ class ServingEngine:
                     tok = self._sample(arr[i], seq)
                     seq.state = RequestState.RUNNING
                     self._emit_token(seq, tok)
+                    tokens_out += 1
             else:
                 seq.num_cached += 1
                 self._commit_cached_blocks(seq)
                 tok = self._sample(arr[i], seq)
                 self._emit_token(seq, tok)
+                tokens_out += 1
+        leaf.args["tokens_out"] = tokens_out
+        leaf.end()
 
     def _commit_cached_blocks(self, seq: Request):
         """Register every newly-completed full block in the prefix
@@ -1302,7 +1346,8 @@ class ServingEngine:
                 if self._shutdown and not self.scheduler.has_work():
                     return
                 if not self.scheduler.has_work():
-                    self._cv.wait(timeout=0.1)
+                    with RecordEvent("serving.idle_wait", cat="serving"):
+                        self._cv.wait(timeout=0.1)
                     continue
             try:
                 self.step()
