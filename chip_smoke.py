@@ -196,12 +196,12 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     from paddle_tpu.ops.paged_attention import ragged_gather_attention
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
         build_step_maps, default_tile_q, ragged_paged_attention,
-        rpa_max_steps)
+        rpa_max_items)
 
     rng = np.random.RandomState(1)
     tile = default_tile_q(n_heads // n_kv, dtype)
     T = -(-(max_batch + prefill_chunk) // tile) * tile
-    max_steps = rpa_max_steps(tile, max_blocks_per_seq, max_blocks)
+    max_items = rpa_max_items(T // tile, max_batch, max_blocks_per_seq)
     bt = np.zeros((max_batch + 1, max_blocks_per_seq), np.int32)
     cu = np.zeros(max_batch + 2, np.int32)
     ctx = np.zeros(max_batch + 1, np.int32)
@@ -228,16 +228,20 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     k_pool = jnp.asarray(rng.randn(*shape), dtype)
     v_pool = jnp.asarray(rng.randn(*shape), dtype)
     q = jnp.asarray(rng.randn(T, n_heads, head_dim), dtype)
-    ssq, sbk = build_step_maps(cu[:len(seqs) + 1], kv_lens, total_tokens=T,
-                               tile_q=tile, block_size=block_size,
-                               max_steps=max_steps, max_seqs=max_batch)
+    maps = build_step_maps(cu[:len(seqs) + 1], kv_lens, total_tokens=T,
+                           tile_q=tile, block_size=block_size,
+                           max_items=max_items, max_seqs=max_batch)
     t0 = time.perf_counter()
     rpa = jax.jit(ragged_paged_attention)(
         q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(cu),
-        jnp.asarray(ctx), jnp.asarray(ssq), jnp.asarray(sbk))
+        jnp.asarray(ctx), jnp.asarray(maps.step_seq),
+        jnp.asarray(maps.step_blk), jnp.asarray(maps.step_tile))
     rpa = np.asarray(rpa.astype(jnp.float32))
     print(f"  rpa kernel compile+run {time.perf_counter() - t0:.1f}s "
-          f"(tile_q={tile}, tokens={T}, max_steps={max_steps}, pages "
+          f"(tile_q={tile}, tokens={T}, flat work list: {maps.walked} "
+          f"grid steps a kv head = {maps.live} live (tile, sequence, "
+          f"page) items + {maps.walked - maps.live} tiles without work, "
+          f"in arrays of {max_items}; pages "
           f"{[-(-kv // block_size) for kv in kv_lens]})")
     gather = jax.jit(ragged_gather_attention, static_argnames="scale")(
         q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(sid),

@@ -47,9 +47,10 @@ DEFAULT_LARGE_BYTES = 1 << 20
 TRAIN_STEP_ARGS = ("train", "frozen", "buffers", "states", "group_lrs",
                    "rng", "batch")
 SERVING_STEP_ARGS = ("state", "tokens", "k_pools", "v_pools",
-                     "block_tables", "cu_seqlens", "context_lens",
-                     "seq_ids", "positions", "step_seq_map",
-                     "step_block_map", "last_idx")
+                     "k_scales", "v_scales", "block_tables",
+                     "cu_seqlens", "context_lens", "seq_ids", "positions",
+                     "step_seq", "step_blk", "step_tile", "last_idx",
+                     "adapter_ids")
 
 
 @dataclass

@@ -415,7 +415,7 @@ class LlamaAttention(nn.Layer):
             # K/V per (token, head) and threads the scale pools
             # alongside the value pools
             def fq(qa, ka, va, kp, vp, ksc, vsc, bt, cu, ctx, sid, pos,
-                   ssq, sbk):
+                   ssq, sbk, stl):
                 pidx = jnp.clip(pos.astype(jnp.int32), 0, table_len - 1)
                 cos, sin = _gather_rope(pidx[None, :], hd, theta,
                                         str(qa.dtype), table_len)
@@ -423,22 +423,23 @@ class LlamaAttention(nn.Layer):
                 return pa.ragged_paged_attention_step(
                     _rot_interleaved(qa, cos, sin),
                     _rot_interleaved(ka, cos, sin), va, kp, vp,
-                    bt, cu, ctx, sid, pos, ssq, sbk, scale=scale,
+                    bt, cu, ctx, sid, pos, ssq, sbk, stl, scale=scale,
                     k_scale=ksc, v_scale=vsc)
 
             out, kp2, vp2, ks2, vs2 = apply_op(
                 fq, q, k, v, cache.k_pool, cache.v_pool, cache.k_scale,
                 cache.v_scale, cache.block_tables, cache.cu_seqlens,
                 cache.context_lens, cache.seq_ids, cache.positions,
-                cache.step_seq, cache.step_blk,
+                cache.step_seq, cache.step_blk, cache.step_tile,
                 op_name="ragged_paged_kv_attention_int8")
             return self.o_proj(ops.reshape(out, [1, T, -1])), \
                 pa.RaggedLayerCache(
                     kp2, vp2, cache.block_tables, cache.cu_seqlens,
                     cache.context_lens, cache.seq_ids, cache.positions,
-                    cache.step_seq, cache.step_blk, ks2, vs2)
+                    cache.step_seq, cache.step_blk, cache.step_tile,
+                    ks2, vs2)
 
-        def f(qa, ka, va, kp, vp, bt, cu, ctx, sid, pos, ssq, sbk):
+        def f(qa, ka, va, kp, vp, bt, cu, ctx, sid, pos, ssq, sbk, stl):
             pidx = jnp.clip(pos.astype(jnp.int32), 0, table_len - 1)
             cos, sin = _gather_rope(pidx[None, :], hd, theta,
                                     str(qa.dtype), table_len)
@@ -446,19 +447,19 @@ class LlamaAttention(nn.Layer):
             return pa.ragged_paged_attention_step(
                 _rot_interleaved(qa, cos, sin),
                 _rot_interleaved(ka, cos, sin), va, kp, vp,
-                bt, cu, ctx, sid, pos, ssq, sbk, scale=scale)
+                bt, cu, ctx, sid, pos, ssq, sbk, stl, scale=scale)
 
         out, kp2, vp2 = apply_op(
             f, q, k, v, cache.k_pool, cache.v_pool, cache.block_tables,
             cache.cu_seqlens, cache.context_lens, cache.seq_ids,
             cache.positions, cache.step_seq, cache.step_blk,
-            op_name="ragged_paged_kv_attention")
+            cache.step_tile, op_name="ragged_paged_kv_attention")
         # back to [1, T, hidden] for the backbone's residual stream
         return self.o_proj(ops.reshape(out, [1, T, -1])), \
             pa.RaggedLayerCache(
                 kp2, vp2, cache.block_tables, cache.cu_seqlens,
                 cache.context_lens, cache.seq_ids, cache.positions,
-                cache.step_seq, cache.step_blk)
+                cache.step_seq, cache.step_blk, cache.step_tile)
 
 
 class LlamaMLP(nn.Layer):

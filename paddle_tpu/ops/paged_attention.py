@@ -158,11 +158,13 @@ class RaggedLayerCache(NamedTuple):
     tokens back to back — prefill chunks (S>1) and decode rows (S=1)
     together. ``block_tables`` carries an extra all-null sentinel row
     (index ``max_seqs``) that padding tokens resolve through; metadata
-    rows beyond the live sequences point at it. The ``step_seq`` /
-    ``step_blk`` work maps are built host-side per step
-    (``ops.pallas.ragged_paged_attention.build_step_maps``) and are
-    traced INPUTS — shapes never change, so the engine's one executable
-    serves every batch mix."""
+    rows beyond the live sequences point at it. The RPA kernel's flat
+    work list (``step_seq`` / ``step_blk`` per item, ``step_tile`` its
+    CSR tile pointers) is built host-side per step
+    (``ops.pallas.ragged_paged_attention.build_step_maps``); all three
+    are traced INPUTS, and so is the kernel's trip count
+    (``step_tile[-1]``) — shapes never change, so the engine's one
+    executable serves every batch mix."""
     k_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
     v_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
     block_tables: object  # [max_seqs + 1, max_blocks_per_seq] int32
@@ -170,8 +172,9 @@ class RaggedLayerCache(NamedTuple):
     context_lens: object  # [max_seqs + 1] int32 cached tokens per seq
     seq_ids: object       # [T] int32 token -> sequence (max_seqs = pad)
     positions: object     # [T] int32 absolute position per token
-    step_seq: object      # [num_q_tiles, max_steps] int32 kernel work map
-    step_blk: object      # [num_q_tiles, max_steps] int32 kernel work map
+    step_seq: object      # [max_items] int32 work item -> sequence
+    step_blk: object      # [max_items] int32 work item -> kv page index
+    step_tile: object     # [num_q_tiles + 1] int32 tile -> first item
     # int8-KV quantization (ISSUE 20): per-token-slot, per-head dequant
     # multipliers paged like the pools; None on unquantized engines
     k_scale: object = None  # [num_blocks + 1, n_kv, block_size] f32
@@ -311,8 +314,9 @@ def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
 
 def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
                                 cu_seqlens, context_lens, seq_ids,
-                                positions, step_seq, step_blk, *,
-                                scale=None, k_scale=None, v_scale=None):
+                                positions, step_seq, step_blk, step_tile,
+                                *, scale=None, k_scale=None,
+                                v_scale=None):
     """One unified serving step over the token-packed ragged layout.
 
     ``q`` [T, n_heads, hd] and ``k``/``v`` [T, n_kv, hd] are the
@@ -364,7 +368,8 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
             # is opaque to GSPMD, so shard_map runs one kernel instance
             # per mp shard — q over n_heads, pools over n_kv (whole GQA
             # groups stay together because n_heads/n_kv shard by the
-            # same factor), metadata replicated. Attention is
+            # same factor), metadata replicated (every shard walks the
+            # same work list under the same traced bound). Attention is
             # embarrassingly parallel across heads: no collective is
             # introduced here (the o_proj psum stays GSPMD's).
             from jax.sharding import PartitionSpec as P
@@ -373,18 +378,20 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
             pools = P(None, ax, None, None)
             rep = P()
             out = jax.shard_map(
-                lambda qa, kp, vp, bt, cu, ctx, ssq, sbk:
+                lambda qa, kp, vp, bt, cu, ctx, ssq, sbk, stl:
                     ragged_paged_attention(qa, kp, vp, bt, cu, ctx,
-                                           ssq, sbk, sm_scale=scale),
+                                           ssq, sbk, stl, sm_scale=scale),
                 mesh=mesh,
-                in_specs=(heads, pools, pools, rep, rep, rep, rep, rep),
+                in_specs=(heads, pools, pools, rep, rep, rep, rep, rep,
+                          rep),
                 out_specs=heads, check_vma=False)(
                 q, k_pool, v_pool, block_tables, cu_seqlens,
-                context_lens, step_seq, step_blk)
+                context_lens, step_seq, step_blk, step_tile)
         else:
             out = ragged_paged_attention(
                 q, k_pool, v_pool, block_tables, cu_seqlens,
-                context_lens, step_seq, step_blk, sm_scale=scale)
+                context_lens, step_seq, step_blk, step_tile,
+                sm_scale=scale)
     else:
         out = ragged_gather_attention(
             q, k_pool, v_pool, block_tables, seq_ids, positions,

@@ -21,7 +21,7 @@ arxiv 2604.15464) this kernel takes the batch **token-packed**:
                      the last two dims is not)
     block_tables   : [max_seqs + 1, max_blocks_per_seq] int32 — row
                      ``max_seqs`` is the all-null sentinel row that
-                     padding tokens and dead grid steps resolve through
+                     padding tokens and sentinel work items resolve through
     cu_seqlens     : [max_seqs + 2] int32 — sequence s's new tokens
                      occupy flat positions [cu[s], cu[s+1])
     context_lens   : [max_seqs + 1] int32 — tokens already cached
@@ -33,26 +33,40 @@ f32 score tensor in HBM, online softmax in VMEM scratch.
 
 Grid design
 -----------
-``grid = (n_kv_heads, num_q_tiles, max_steps)``. The flat token axis is
-cut into fixed ``tile_q``-token tiles; a tile may span several ragged
-sequences, so the inner grid dimension walks a host-built work list
-(``build_step_maps``): step ``(j, i)`` names ``(sequence, kv page)`` in
-scalar-prefetched int32 maps, and the K/V BlockSpec index maps chase
-``block_tables[step_seq[j,i], step_blk[j,i]]`` straight from SMEM — the
-pipeline's revolving buffers double-buffer the page DMAs exactly like
-the classic paged kernel (boom_attention_tricks.md §9–11), with no
-manual descriptors. Rows of the score tile that don't belong to the
-step's sequence are masked dead (their online-softmax state is provably
-untouched: p = 0 rows with α folded to carry ``m``/``l`` through), so
-prefill chunks (in-chunk causal via ``kpos <= ctx + (t - cu[s])``) and
-decode rows coexist in one tile. Dead padding steps map to the null
-page; consecutive equal indices are not re-fetched, so the padded tail
-of a tile's work list costs one null-page DMA, not one per step.
+``grid = (n_kv_heads, n_items)`` with ``n_items`` a TRACED int32: the
+kernel's trip count follows the step's live work (the pattern of
+megablox ``gmm``'s ``num_active_tiles``). The flat token axis is cut into
+fixed ``tile_q``-token tiles; a tile may span several ragged sequences,
+so the inner grid dimension walks one host-built **flat work list** for
+the whole call (``build_step_maps``), sorted by q tile: item ``w`` names
+``(tile, sequence, kv page)`` in scalar-prefetched int32 arrays, the q
+and output BlockSpecs are indexed by the item's tile and the K/V
+BlockSpec index maps chase ``block_tables[step_seq[w], step_blk[w]]``
+straight from SMEM — the pipeline's revolving buffers double-buffer the
+page DMAs exactly like the classic paged kernel
+(boom_attention_tricks.md §9–11), with no manual descriptors, and a q
+tile is fetched once for its whole run of items. The list is in CSR
+form: tile ``j`` owns items ``[step_tile[j], step_tile[j + 1])`` and
+``n_items = step_tile[-1]``; the online-softmax scratch is initialised
+at a tile's first item and the output block written at its last. Every
+tile owns at least one item — a tile of only padding tokens gets one
+sentinel item (sequence ``max_seqs``, the null page, no compute) — so
+every output block is written and padding rows stay exactly 0. Rows of
+the score tile that don't belong to the item's sequence are masked dead
+(their online-softmax state is provably untouched: p = 0 rows with α
+folded to carry ``m``/``l`` through), so prefill chunks (in-chunk causal
+via ``kpos <= ctx + (t - cu[s])``) and decode rows coexist in one tile.
 
-``max_steps`` is static: ``min(tile_q * max_blocks_per_seq,
-pool_capacity)`` — at most ``tile_q`` sequences overlap one tile, each
-bounded by its table width, and all sequences in a tile together can't
-hold more pages than the pool has blocks.
+The arrays are sized by the static :func:`rpa_max_items` =
+``max_blocks_per_seq * (num_q_tiles + max_seqs)``: a sequence is
+re-walked once per tile it spans, and all sequences together span at
+most ``num_q_tiles + n_seqs - 1`` tiles. The bound never uses the pool's
+size (sequences that share prefix pages are distinct rows that name the
+same pages), and it sizes arrays only — nothing walks it. A caller that
+holds per-tile maps ``[num_q_tiles, k]`` padded with the sentinel
+(``rpa_max_steps`` wide) may hand those instead: the wrapper compacts
+them into the same flat list on the device and both reach the one
+``pallas_call``.
 
 Off-TPU the kernel runs in Pallas interpret mode, which is what tier-1
 parity tests exercise on the CPU mesh (`tests/test_ragged_paged_attention.py`);
@@ -64,6 +78,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import numpy as np
 
@@ -72,8 +87,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ragged_paged_attention", "build_step_maps", "rpa_tile_q",
-           "rpa_max_steps", "default_tile_q"]
+__all__ = ["ragged_paged_attention", "build_step_maps", "StepMaps",
+           "rpa_tile_q", "rpa_max_items", "rpa_max_steps",
+           "default_tile_q"]
 
 _LANES = 128
 # finite stand-in for -inf (same trick as flash_attention.py): keeps the
@@ -102,18 +118,45 @@ def default_tile_q(group: int, dtype) -> int:
     return tile
 
 
+def rpa_max_items(num_tiles: int, max_seqs: int,
+                  max_blocks_per_seq: int) -> int:
+    """Static length of the flat work list's arrays. Sequences are packed
+    back to back, so a sequence is walked once per q tile it spans and
+    all ``n`` sequences together span at most ``num_tiles + n - 1``
+    tiles; each walk streams at most ``max_blocks_per_seq`` pages, and a
+    tile without work adds one sentinel item where it adds no walk.
+    Sound when sequences share prefix pages (the pool's size is no part
+    of it). It sizes arrays only: the kernel walks the live length."""
+    return max_blocks_per_seq * (num_tiles + max_seqs)
+
+
 def rpa_max_steps(tile_q: int, max_blocks_per_seq: int,
-                  pool_blocks: int) -> int:
-    """Static bound on the per-tile work-list length. A tile of
-    ``tile_q`` tokens overlaps at most ``tile_q`` sequences; each streams
-    at most ``max_blocks_per_seq`` pages; and all sequences overlapping
-    one tile are distinct, so together they can't hold more pages than
-    the pool has allocatable blocks."""
-    return max(1, min(tile_q * max_blocks_per_seq, pool_blocks))
+                  pool_blocks: int | None = None) -> int:
+    """Width of per-tile ``[num_q_tiles, k]`` maps, for a caller that
+    hands :func:`ragged_paged_attention` those instead of the flat list:
+    a tile of ``tile_q`` tokens overlaps at most ``tile_q`` sequences and
+    each streams at most ``max_blocks_per_seq`` pages. ``pool_blocks`` is
+    accepted and ignored: sequences that share prefix pages walk the
+    same pages once each, so the pool's size bounds nothing."""
+    del pool_blocks
+    return max(1, tile_q * max_blocks_per_seq)
+
+
+class StepMaps(NamedTuple):
+    """The flat work list of one engine step (:func:`build_step_maps`)."""
+    step_seq: np.ndarray    # [max_items] int32 — item w's sequence
+    step_blk: np.ndarray    # [max_items] int32 — item w's kv page index
+    step_tile: np.ndarray   # [num_q_tiles + 1] int32 — CSR tile pointers
+    live: int               # items that name a real (sequence, page)
+
+    @property
+    def walked(self) -> int:
+        """The kernel's inner grid bound: ``live`` + tiles without work."""
+        return int(self.step_tile[-1])
 
 
 def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
-                    block_size, max_steps, max_seqs):
+                    block_size, max_items, max_seqs) -> StepMaps:
     """Host-side (numpy) kernel work list for one engine step.
 
     ``cu_seqlens``: int array ``[num_seqs + 1]`` — prefix sums of the
@@ -121,64 +164,92 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
     array ``[num_seqs]`` — each sequence's total KV length after this
     step's writes (``context_len + new_len``).
 
-    Returns ``(step_seq, step_blk)``, both ``[num_q_tiles, max_steps]``
-    int32: for q tile ``j``, the live steps enumerate every
+    Returns :class:`StepMaps`: the items sorted by q tile, tile ``j``'s
+    in ``[step_tile[j], step_tile[j + 1])``, enumerating every
     ``(sequence, kv page)`` pair the tile's tokens attend over — pages
     only up to ``ceil(kv_len / block_size)``, i.e. only the real
-    context. Dead steps carry the ``max_seqs`` sentinel (the all-null
-    block-table row).
+    context. A tile no sequence reaches owns one sentinel item
+    (sequence ``max_seqs``, the all-null block-table row); the arrays'
+    tail past ``step_tile[-1]`` is never walked and carries the same.
     """
-    cu = np.asarray(cu_seqlens, np.int64)
-    kv = np.asarray(kv_lens, np.int64)
-    num_seqs = len(kv)
+    cu = [int(c) for c in cu_seqlens]
+    pages = [-(-int(kv) // block_size) for kv in kv_lens]
+    num_seqs = len(pages)
     if total_tokens % tile_q:
         raise ValueError(
             f"total_tokens {total_tokens} not a multiple of tile_q "
             f"{tile_q}")
     num_tiles = total_tokens // tile_q
-    step_seq = np.full((num_tiles, max_steps), max_seqs, np.int32)
-    step_blk = np.zeros((num_tiles, max_steps), np.int32)
+    seqs, blks, step_tile = [], [], [0]
+    first = empty_tiles = 0
     for j in range(num_tiles):
         lo, hi = j * tile_q, (j + 1) * tile_q
-        used = 0
-        for s in range(num_seqs):
-            if cu[s] >= cu[s + 1] or cu[s + 1] <= lo or cu[s] >= hi:
-                # no tokens at all (a new_len == 0 padding slot) or none
-                # in this tile: contributes no work steps — the static
-                # max_steps bound counts only sequences with real tokens
-                continue
-            n_pages = -(-int(kv[s]) // block_size)
-            if used + n_pages > max_steps:
-                raise ValueError(
-                    f"tile {j} needs {used + n_pages} kv steps > "
-                    f"max_steps {max_steps} — the scheduler admitted "
-                    f"more pages than the static bound (bug)")
-            step_seq[j, used:used + n_pages] = s
-            step_blk[j, used:used + n_pages] = np.arange(n_pages)
-            used += n_pages
-    return step_seq, step_blk
+        # sequences are packed in order: the tile's are a contiguous run
+        while first < num_seqs and cu[first + 1] <= lo:
+            first += 1
+        s = first
+        while s < num_seqs and cu[s] < hi:
+            if cu[s] < cu[s + 1]:   # a new_len == 0 slot owns no tokens
+                seqs += [s] * pages[s]
+                blks += range(pages[s])
+            s += 1
+        if len(seqs) == step_tile[-1]:
+            seqs.append(max_seqs)
+            blks.append(0)
+            empty_tiles += 1
+        step_tile.append(len(seqs))
+    walked = len(seqs)
+    if walked > max_items:
+        raise ValueError(
+            f"the step needs {walked} work items > max_items {max_items} "
+            f"— the scheduler admitted more pages than the static bound "
+            f"(bug)")
+    step_seq = np.full((max_items,), max_seqs, np.int32)
+    step_blk = np.zeros((max_items,), np.int32)
+    step_seq[:walked] = seqs
+    step_blk[:walked] = blks
+    return StepMaps(step_seq, step_blk, np.asarray(step_tile, np.int32),
+                    walked - empty_tiles)
+
+
+def _flatten_maps(step_seq, step_blk, max_seqs):
+    """Per-tile maps ``[num_tiles, k]`` (dead steps carry the ``max_seqs``
+    sentinel) → the flat list ``(step_seq, step_blk, step_tile)`` on the
+    device: live entries compacted in row-major order, a tile with none
+    keeping its first (sentinel) entry."""
+    num_tiles, k = step_seq.shape
+    live = step_seq < max_seqs
+    keep = live | ((jnp.arange(k) == 0)[None, :]
+                   & ~jnp.any(live, axis=1, keepdims=True))
+    step_tile = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32),
+        jnp.cumsum(jnp.sum(keep, axis=1, dtype=jnp.int32))])
+    # a stable sort on "dropped" moves the kept entries to the front in
+    # their order; the tail past step_tile[-1] is never walked
+    order = jnp.argsort(~keep.reshape(-1), stable=True)
+    return (step_seq.reshape(-1)[order], step_blk.reshape(-1)[order],
+            step_tile)
 
 
 # =========================== kernel ==========================================
-def _rpa_kernel(ss_ref, sb_ref, bt_ref, cu_ref, ctx_ref,
+def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
                 q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
-                *, tile_q, group, block_size, max_steps, max_seqs,
-                sm_scale):
-    j = pl.program_id(1)
-    i = pl.program_id(2)
+                *, tile_q, group, block_size, max_seqs, sm_scale):
+    w = pl.program_id(1)
+    j = to_ref[w]
     rows = tile_q * group
 
-    @pl.when(i == 0)
+    @pl.when(w == tp_ref[j])
     def _init():
         m_sc[...] = jnp.full_like(m_sc[...], -jnp.inf)
         l_sc[...] = jnp.zeros_like(l_sc[...])
         acc_sc[...] = jnp.zeros_like(acc_sc[...])
 
-    ss = ss_ref[j, i]
+    ss = ss_ref[w]
 
     @pl.when(ss < max_seqs)
     def _compute():
-        sb = sb_ref[j, i]
+        sb = sb_ref[w]
         q = q_ref[...]                                  # [rows, hd]
         k = k_ref[...]                                  # [bs, hd]
         v = v_ref[...]
@@ -220,7 +291,7 @@ def _rpa_kernel(ss_ref, sb_ref, bt_ref, cu_ref, ctx_ref,
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
 
-    @pl.when(i == max_steps - 1)
+    @pl.when(w + 1 == tp_ref[j + 1])
     def _finish():
         # rows that saw no live step (padding tokens): exact 0 output
         l = l_sc[:, :1]
@@ -228,33 +299,39 @@ def _rpa_kernel(ss_ref, sb_ref, bt_ref, cu_ref, ctx_ref,
         o_ref[...] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
 
 
-def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, block_tables,
-              cu_seqlens, context_lens, *, tile_q, group, sm_scale):
+def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
+              block_tables, cu_seqlens, context_lens, *, tile_q, group,
+              sm_scale):
     """``q_heads`` [n_kv, T*group, hd] (token-major rows per kv head) →
     out in the same layout."""
     n_kv, tg, hd = q_heads.shape
     block_size = k_pool.shape[2]
     max_seqs = block_tables.shape[0] - 1
-    num_tiles, max_steps = step_seq.shape
     rows = tile_q * group
+    # item w's tile: the tile pointers at or below w, less the first
+    # (items past the live length read the last tile; none is walked)
+    tile_of = jnp.sum(
+        step_tile[None, 1:-1] <= jnp.arange(
+            step_seq.shape[0], dtype=jnp.int32)[:, None],
+        axis=1, dtype=jnp.int32)
 
     kernel = functools.partial(
         _rpa_kernel, tile_q=tile_q, group=group, block_size=block_size,
-        max_steps=max_steps, max_seqs=max_seqs, sm_scale=sm_scale)
+        max_seqs=max_seqs, sm_scale=sm_scale)
 
-    def q_map(h, j, i, ss, sb, bt, cu, ctx):
-        return (h, j, 0)
+    def q_map(h, w, to, ss, sb, tp, bt, cu, ctx):
+        return (h, to[w], 0)
 
-    def kv_map(h, j, i, ss, sb, bt, cu, ctx):
-        # scalar-prefetch chase: physical page of this step's (seq, blk).
-        # Dead steps resolve through the sentinel table row to the null
-        # page 0; consecutive equal indices are not re-fetched, so a
-        # padded work-list tail costs one DMA, not one per step.
-        return (bt[ss[j, i], sb[j, i]], h, 0, 0)
+    def kv_map(h, w, to, ss, sb, tp, bt, cu, ctx):
+        # scalar-prefetch chase: physical page of this item's (seq, blk).
+        # A sentinel item resolves through the sentinel table row to the
+        # null page 0
+        return (bt[ss[w], sb[w]], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(n_kv, num_tiles, max_steps),
+        num_scalar_prefetch=7,
+        # the inner bound is traced: the live length of the work list
+        grid=(n_kv, step_tile[-1]),
         in_specs=[
             pl.BlockSpec((None, rows, hd), q_map),
             pl.BlockSpec((None, None, block_size, hd), kv_map),
@@ -272,25 +349,29 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_kv, tg, hd), q_heads.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
         # the device op's name in a profiler trace (``rpa.N custom-call``;
         # without it the op is named after the jitted caller)
         name="rpa",
-    )(step_seq, step_blk, block_tables, cu_seqlens, context_lens,
-      q_heads, k_pool, v_pool)
+    )(tile_of, step_seq, step_blk, step_tile, block_tables, cu_seqlens,
+      context_lens, q_heads, k_pool, v_pool)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
-                           context_lens, step_seq, step_blk, *,
-                           sm_scale=None):
+                           context_lens, step_seq, step_blk,
+                           step_tile=None, *, sm_scale=None):
     """GQA attention for a token-packed ragged batch over paged KV.
 
     ``q`` [total_tokens, n_heads, hd]; pools
     ``[num_blocks + 1, n_kv, block_size, hd]`` (this step's new K/V
     already scattered in — the kernel is a pure read); metadata as
-    documented in the module docstring (``build_step_maps`` produces the
-    step maps). Returns ``[total_tokens, n_heads, hd]``. Outputs at
+    documented in the module docstring. The work list is either the
+    flat one ``build_step_maps`` produces (1-D ``step_seq`` / ``step_blk``
+    with the CSR ``step_tile`` ``[num_q_tiles + 1]``, in which every tile
+    owns at least one item) or, with ``step_tile`` None, per-tile maps
+    ``[num_q_tiles, k]`` padded with the ``max_seqs`` sentinel, which are
+    flattened here. Returns ``[total_tokens, n_heads, hd]``. Outputs at
     padding tokens (sentinel ``seq_id``) are exactly 0.
     """
     T, n_heads, hd = q.shape
@@ -299,10 +380,16 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
         raise ValueError(
             f"q heads {n_heads} must be a multiple of kv heads {n_kv}")
     group = n_heads // n_kv
-    num_tiles = step_seq.shape[0]
-    if num_tiles == 0 or T % num_tiles:
+    step_seq = jnp.asarray(step_seq, jnp.int32)
+    step_blk = jnp.asarray(step_blk, jnp.int32)
+    if step_tile is None:
+        step_seq, step_blk, step_tile = _flatten_maps(
+            step_seq, step_blk, block_tables.shape[0] - 1)
+    step_tile = jnp.asarray(step_tile, jnp.int32)
+    num_tiles = step_tile.shape[0] - 1
+    if num_tiles <= 0 or T % num_tiles:
         raise ValueError(
-            f"step maps have {num_tiles} tiles for {T} tokens")
+            f"the work list has {num_tiles} tiles for {T} tokens")
     tile_q = T // num_tiles
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
@@ -311,8 +398,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
     qh = q.reshape(T, n_kv, group, hd).transpose(1, 0, 2, 3) \
           .reshape(n_kv, T * group, hd)
     out = _rpa_call(
-        qh, k_pool, v_pool,
-        jnp.asarray(step_seq, jnp.int32), jnp.asarray(step_blk, jnp.int32),
+        qh, k_pool, v_pool, step_seq, step_blk, step_tile,
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(cu_seqlens, jnp.int32),
         jnp.asarray(context_lens, jnp.int32),
@@ -347,7 +433,6 @@ def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
         from .autotune import aot_runner
         T = -(-int(budget_tokens) // tile) * tile
         max_seqs = max(2, min(T, 8))
-        max_steps = rpa_max_steps(tile, max_blocks_per_seq, pool_blocks)
         # representative mix: one prefill chunk spanning half the budget
         # plus decode rows for the rest, each with a page of context
         n_dec = min(max_seqs - 1, max(1, T // 2))
@@ -367,9 +452,11 @@ def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
             nxt += n_pages
         if nxt - 1 > pool_blocks:
             raise ValueError("synthetic workload exceeds pool")
-        ssq, sbk = build_step_maps(
+        ssq, sbk, stl, _ = build_step_maps(
             cu[:len(new_lens) + 1], kv_lens, total_tokens=T,
-            tile_q=tile, block_size=block_size, max_steps=max_steps,
+            tile_q=tile, block_size=block_size,
+            max_items=rpa_max_items(T // tile, max_seqs,
+                                    max_blocks_per_seq),
             max_seqs=max_seqs)
         with jax.ensure_compile_time_eval():
             dt = jnp.dtype(dtype)
@@ -378,7 +465,7 @@ def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
                            dt)
         return aot_runner(
             lambda qa, kpa, vpa: ragged_paged_attention(
-                qa, kpa, vpa, bt, cu, ctx_arr, ssq, sbk),
+                qa, kpa, vpa, bt, cu, ctx_arr, ssq, sbk, stl),
             q0, kp, kp)
 
     # default first (a tie keeps it); shorter tiles would leave partial

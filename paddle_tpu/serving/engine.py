@@ -95,6 +95,11 @@ def _build_serving_metrics(reg) -> dict:
             "serving_preemptions_total", "sequences preempted (recompute)"),
         "steps": reg.counter(
             "serving_engine_steps_total", "compiled steps run, by kind"),
+        "rpa_steps": reg.counter(
+            "serving_rpa_steps_total",
+            "RPA kernel grid steps a kv head and layer, by kind: live "
+            "(work items that name a real page) / walked (the kernel's "
+            "grid bound: live + one step for each q tile without work)"),
         "rejections": reg.counter(
             "serving_rejections_total",
             "requests shed by graceful degradation, by reason "
@@ -220,7 +225,7 @@ class ServingEngine:
         from paddle_tpu.models.generation import decode_surfaces
         from paddle_tpu.ops import paged_attention as pa
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
-            build_step_maps, default_tile_q, rpa_max_steps, rpa_tile_q)
+            build_step_maps, default_tile_q, rpa_max_items, rpa_tile_q)
         from paddle_tpu.quantization.weight_only import (
             WEIGHT_MODES, calibration_from_checkpoint, quantization_metrics,
             quantize_state)
@@ -353,10 +358,12 @@ class ServingEngine:
             self.attn_impl = "gather"
         # unified-step geometry: the flat token budget covers every
         # decode slot plus one full prefill chunk, rounded up to the RPA
-        # kernel's q-tile height (autotunable on chip); max_steps is the
-        # kernel's static per-tile work-list bound. A gather-pinned
-        # engine keeps the default tile — sweeping RPA kernel candidates
-        # it will never execute would be pure startup cost
+        # kernel's q-tile height (autotunable on chip); max_items sizes
+        # the arrays of the kernel's flat work list (nothing walks it:
+        # the kernel's trip count is the list's live length). A
+        # gather-pinned engine keeps the default tile — sweeping RPA
+        # kernel candidates it will never execute would be pure startup
+        # cost
         n_heads = cfg.num_attention_heads
         self._tile_q = default_tile_q(n_heads // n_kv, dtype) \
             if self.attn_impl == "gather" else rpa_tile_q(
@@ -365,15 +372,16 @@ class ServingEngine:
                 dtype=str(jnp.dtype(dtype)))
         budget = self.max_batch + self.prefill_chunk
         self.step_tokens = -(-budget // self._tile_q) * self._tile_q
-        self._max_steps = rpa_max_steps(
-            self._tile_q, self.cache.max_blocks_per_seq, max_blocks)
-        # all-sentinel work lists for the gather path (same traced
-        # shapes, ignored by the gather read — built once, not per step)
-        self._null_step_maps = (
-            np.full((self.step_tokens // self._tile_q, self._max_steps),
-                    self.max_batch, np.int32),
-            np.zeros((self.step_tokens // self._tile_q, self._max_steps),
-                     np.int32))
+        num_tiles = self.step_tokens // self._tile_q
+        self._max_items = rpa_max_items(
+            num_tiles, self.max_batch, self.cache.max_blocks_per_seq)
+        # the work list of a step without work (one sentinel item a
+        # tile): what the gather path feeds (same traced shapes, ignored
+        # by the gather read — built once, not per step)
+        self._null_step_maps = build_step_maps(
+            [0], [], total_tokens=self.step_tokens, tile_q=self._tile_q,
+            block_size=block_size, max_items=self._max_items,
+            max_seqs=self.max_batch)
         self.scheduler = Scheduler(self.cache, self.max_batch,
                                    self.prefill_chunk,
                                    step_tokens=self.step_tokens)
@@ -549,7 +557,7 @@ class ServingEngine:
         tap_order = [] if instrument else None
 
         def step(stt, tokens, k_pools, v_pools, k_scales, v_scales,
-                 bt, cu, ctx, sid, pos, ssq, sbk, last_idx, aid):
+                 bt, cu, ctx, sid, pos, ssq, sbk, stl, last_idx, aid):
             # executes at trace time only — counting compiles is the
             # point (the compile-once guard tests read it)
             self.step_traces += 1  # analysis: allow(trace-attr-mutation)
@@ -563,13 +571,15 @@ class ServingEngine:
                 caches = [pa.RaggedLayerCache(
                     Tensor(k_pools[i]), Tensor(v_pools[i]), Tensor(bt),
                     Tensor(cu), Tensor(ctx), Tensor(sid), Tensor(pos),
-                    Tensor(ssq), Tensor(sbk), Tensor(k_scales[i]),
-                    Tensor(v_scales[i])) for i in range(nl)]
+                    Tensor(ssq), Tensor(sbk), Tensor(stl),
+                    Tensor(k_scales[i]), Tensor(v_scales[i]))
+                    for i in range(nl)]
             else:
                 caches = [pa.RaggedLayerCache(
                     Tensor(k_pools[i]), Tensor(v_pools[i]), Tensor(bt),
                     Tensor(cu), Tensor(ctx), Tensor(sid), Tensor(pos),
-                    Tensor(ssq), Tensor(sbk)) for i in range(nl)]
+                    Tensor(ssq), Tensor(sbk), Tensor(stl))
+                    for i in range(nl)]
             # per-row LoRA dispatch: pin this step's token->slot ids for
             # the adapter hooks traced inside the backbone call
             adapters = (lora.adapter_ids(aid) if n_slots
@@ -649,7 +659,7 @@ class ServingEngine:
         pos = np.zeros((T,), np.int32)
         last_idx = np.zeros((S,), np.int32)
         aid = np.zeros((T,), np.int32)
-        ssq, sbk = self._null_step_maps
+        maps = self._null_step_maps
         with self._lock:
             try:
                 return self._step.lower(
@@ -657,8 +667,9 @@ class ServingEngine:
                     self.cache.v_pools, self.cache.k_scales,
                     self.cache.v_scales, jnp.asarray(bt), jnp.asarray(cu),
                     jnp.asarray(ctx), jnp.asarray(sid), jnp.asarray(pos),
-                    jnp.asarray(ssq), jnp.asarray(sbk),
-                    jnp.asarray(last_idx), jnp.asarray(aid))
+                    jnp.asarray(maps.step_seq), jnp.asarray(maps.step_blk),
+                    jnp.asarray(maps.step_tile), jnp.asarray(last_idx),
+                    jnp.asarray(aid))
             finally:
                 self._clear_model_side_effects()
 
@@ -676,6 +687,7 @@ class ServingEngine:
         self._m_tokens = m["tokens"]
         self._m_preempt = m["preemptions"]
         self._m_steps = m["steps"]
+        self._m_rpa_steps = m["rpa_steps"]
         self._m_in_flight = m["in_flight"]
         self._m_kv_block_seconds = m["kv_block_seconds"]
         self._m_kv_headroom = m["kv_headroom"]
@@ -1037,14 +1049,14 @@ class ServingEngine:
             off += n
         cu[len(entries) + 1:] = off
         if self.attn_impl == "rpa":
-            ssq, sbk = self._build_step_maps(
+            maps = self._build_step_maps(
                 cu[:len(entries) + 1], kv_lens, total_tokens=T,
                 tile_q=self._tile_q, block_size=self.cache.block_size,
-                max_steps=self._max_steps, max_seqs=S)
+                max_items=self._max_items, max_seqs=S)
         else:
-            # the gather path ignores the kernel work lists; feed the
-            # cached all-sentinel maps instead of rebuilding per step
-            ssq, sbk = self._null_step_maps
+            # the gather path ignores the kernel work list; feed the
+            # cached all-sentinel one instead of rebuilding per step
+            maps = self._null_step_maps
 
         from paddle_tpu.observability import numerics
 
@@ -1080,7 +1092,8 @@ class ServingEngine:
                 self.cache.v_pools, self.cache.k_scales,
                 self.cache.v_scales, jnp.asarray(bt), jnp.asarray(cu),
                 jnp.asarray(ctx), jnp.asarray(sid), jnp.asarray(pos),
-                jnp.asarray(ssq), jnp.asarray(sbk), jnp.asarray(last_idx),
+                jnp.asarray(maps.step_seq), jnp.asarray(maps.step_blk),
+                jnp.asarray(maps.step_tile), jnp.asarray(last_idx),
                 jnp.asarray(aid))
             if step_fn is self._step:
                 logits, kps, vps, kss, vss = out
@@ -1099,6 +1112,12 @@ class ServingEngine:
         t1 = time.perf_counter_ns()
         compiled = self.step_traces - compiles0
         leaf.args["compiled"] = compiled
+        if self.attn_impl == "rpa":
+            # the RPA kernel's grid steps a kv head and layer: the work
+            # items that name a real page, and the bound it walked
+            leaf.args.update(rpa_live=maps.live, rpa_walked=maps.walked)
+            self._m_rpa_steps.inc(maps.live, kind="live")
+            self._m_rpa_steps.inc(maps.walked, kind="walked")
         leaf.end()
         self._m_steps.inc(kind="unified")
         leaf = self._leaf("serving.fetch", n_step)

@@ -10,9 +10,13 @@ shapes — the 870s tier-1 cutoff counts dots):
 * token-level equality through ``ServingEngine`` greedy decode under
   BOTH settings of the impl knob — the engine-level acceptance check
   (the preemption/resume variant rides the slow lane);
-* the host-side work-list builder's invariants (every (sequence, page)
-  pair exactly once per overlapping tile, only real pages, static
-  bound honored).
+* the host-side work-list builder's invariants (every (tile, sequence,
+  page) exactly once, only real pages, one sentinel item for a tile
+  without work, the static bound honored under adversarial packings);
+* the kernel's dynamic grid bound: parity where the work list is short,
+  long, absent for whole tiles or names shared pages, in both forms the
+  wrapper takes, and a Mosaic compile for a described v5e at the serving
+  cell's shapes.
 """
 import numpy as np
 import pytest
@@ -25,22 +29,26 @@ from paddle_tpu.ops.paged_attention import (
     impl_override, paged_attention_impl, ragged_gather_attention,
     write_tokens_to_pool)
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
-    build_step_maps, ragged_paged_attention, rpa_max_steps)
+    build_step_maps, default_tile_q, ragged_paged_attention, rpa_max_items,
+    rpa_max_steps)
 from paddle_tpu.serving import ServingEngine
 
 
 # ---------------- raw kernel parity ------------------------------------------
 def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
-                 mbps=6, pool_blocks=24):
+                 mbps=6, pool_blocks=24, pad_tiles=0, shared=()):
     """Build one token-packed ragged scenario: ``seqs`` is a list of
     ``(new_len, context_len)`` — new_len 0 models a padding slot whose
-    metadata row exists but owns no tokens. Returns everything the two
-    impls and the eager oracle need."""
+    metadata row exists but owns no tokens. ``pad_tiles`` appends q tiles
+    of only padding tokens; ``shared`` lists ``(s, s0, n_pages)``:
+    sequence ``s`` names the first ``n_pages`` pages of the earlier
+    ``s0`` as its own prefix (and holds the same keys there). Returns
+    everything the two impls and the eager oracle need."""
     n_heads = n_kv * grp
     max_seqs = len(seqs) + 1          # one extra never-used row
     total_new = sum(n for n, _ in seqs)
-    T = -(-max(total_new, 1) // tile_q) * tile_q
-    max_steps = rpa_max_steps(tile_q, mbps, pool_blocks)
+    T = (-(-max(total_new, 1) // tile_q) + pad_tiles) * tile_q
+    shared = {s: (s0, npg) for s, s0, npg in shared}
 
     bt = np.zeros((max_seqs + 1, mbps), np.int32)
     nxt = 1
@@ -49,8 +57,10 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
         kv = n + c
         kv_lens.append(kv)
         npg = -(-kv // block_size) if kv else 0
-        bt[s, :npg] = np.arange(nxt, nxt + npg)
-        nxt += npg
+        s0, pre = shared.get(s, (0, 0))
+        bt[s, :pre] = bt[s0, :pre]
+        bt[s, pre:npg] = np.arange(nxt, nxt + npg - pre)
+        nxt += npg - pre
     assert nxt - 1 <= pool_blocks
 
     cu = np.zeros(max_seqs + 2, np.int32)
@@ -72,6 +82,11 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
     for s, (n, c) in enumerate(seqs):
         fk = rng.randn(n + c, n_kv, hd).astype(np.float32)
         fv = rng.randn(n + c, n_kv, hd).astype(np.float32)
+        if s in shared:
+            s0, pre = shared[s]
+            assert pre * block_size <= min(c, seqs[s0][1])
+            fk[:pre * block_size] = full_k[s0][:pre * block_size]
+            fv[:pre * block_size] = full_v[s0][:pre * block_size]
         full_k.append(fk)
         full_v.append(fv)
         for t in range(c):            # prior context from earlier steps
@@ -92,13 +107,30 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
     vp2 = write_tokens_to_pool(jnp.asarray(vp), jnp.asarray(vnew),
                                jnp.asarray(bt), jnp.asarray(sid),
                                jnp.asarray(pos))
-    ssq, sbk = build_step_maps(cu[:len(seqs) + 1], kv_lens,
-                               total_tokens=T, tile_q=tile_q,
-                               block_size=block_size,
-                               max_steps=max_steps, max_seqs=max_seqs)
+    maps = build_step_maps(cu[:len(seqs) + 1], kv_lens,
+                           total_tokens=T, tile_q=tile_q,
+                           block_size=block_size,
+                           max_items=rpa_max_items(T // tile_q, max_seqs,
+                                                   mbps),
+                           max_seqs=max_seqs)
     return dict(q=q, kp=kp2, vp=vp2, bt=bt, cu=cu, ctx=ctx, sid=sid,
-                pos=pos, ssq=ssq, sbk=sbk, full_k=full_k, full_v=full_v,
-                seqs=seqs, max_seqs=max_seqs, grp=grp, hd=hd)
+                pos=pos, maps=maps, full_k=full_k, full_v=full_v,
+                seqs=seqs, max_seqs=max_seqs, grp=grp, hd=hd,
+                tile_q=tile_q, kv_lens=kv_lens, block_size=block_size)
+
+
+def _run_rpa(c, maps=None):
+    ssq, sbk, stl = (maps or c["maps"])[:3]
+    return np.asarray(ragged_paged_attention(
+        jnp.asarray(c["q"]), c["kp"], c["vp"], jnp.asarray(c["bt"]),
+        jnp.asarray(c["cu"]), jnp.asarray(c["ctx"]), ssq, sbk, stl))
+
+
+def _run_gather(c):
+    return np.asarray(ragged_gather_attention(
+        jnp.asarray(c["q"]), c["kp"], c["vp"], jnp.asarray(c["bt"]),
+        jnp.asarray(c["sid"]), jnp.asarray(c["pos"]),
+        scale=1.0 / np.sqrt(c["hd"])))
 
 
 def _eager_oracle(case):
@@ -134,13 +166,8 @@ def test_kernel_matches_gather_and_eager(block_size, grp):
     seqs = [(5, 0), (1, 2 * block_size + 3), (0, 0), (1, 3),
             (9, block_size)]
     c = _ragged_case(rng, seqs, block_size, n_kv=2, grp=grp)
-    out_rpa = np.asarray(ragged_paged_attention(
-        jnp.asarray(c["q"]), c["kp"], c["vp"], jnp.asarray(c["bt"]),
-        jnp.asarray(c["cu"]), jnp.asarray(c["ctx"]), c["ssq"], c["sbk"]))
-    out_g = np.asarray(ragged_gather_attention(
-        jnp.asarray(c["q"]), c["kp"], c["vp"], jnp.asarray(c["bt"]),
-        jnp.asarray(c["sid"]), jnp.asarray(c["pos"]),
-        scale=1.0 / np.sqrt(c["hd"])))
+    out_rpa = _run_rpa(c)
+    out_g = _run_gather(c)
     ref = _eager_oracle(c)
     valid = c["sid"] < c["max_seqs"]
     np.testing.assert_allclose(out_rpa[valid], ref[valid], atol=2e-5)
@@ -149,35 +176,231 @@ def test_kernel_matches_gather_and_eager(block_size, grp):
     assert np.all(out_rpa[~valid] == 0.0)
 
 
+def _per_tile_maps(c, width):
+    """The flat list of case ``c`` as per-tile maps ``[num_tiles, width]``
+    padded with the sentinel: the form a caller without the flat list
+    hands the kernel."""
+    ssq, sbk, stl, _ = c["maps"]
+    num_tiles = len(stl) - 1
+    seq2 = np.full((num_tiles, width), c["max_seqs"], np.int32)
+    blk2 = np.zeros((num_tiles, width), np.int32)
+    for j in range(num_tiles):
+        items = [w for w in range(stl[j], stl[j + 1])
+                 if ssq[w] < c["max_seqs"]]
+        seq2[j, :len(items)] = ssq[items]
+        blk2[j, :len(items)] = sbk[items]
+    return seq2, blk2, None
+
+
+#: name -> (rows as (new, context) with block_size 8, keywords of the case)
+_WALK_CASES = {
+    # 16 decode rows at varied depths fill two tiles; nothing else
+    "decode_only": ([(1, 3 + 5 * i) for i in range(16)],
+                    dict(mbps=11, pool_blocks=120)),
+    # a 21-token chunk with 16 cached tokens spans tiles 0-3 beside rows
+    "chunk_over_tiles_beside_decode": (
+        [(1, 9), (1, 30), (1, 1), (21, 16), (1, 17)],
+        dict(pool_blocks=40)),
+    # three trailing tiles hold only padding tokens: one sentinel item each
+    "trailing_padding_tiles": ([(3, 6), (1, 12)], dict(pad_tiles=3)),
+    "one_live_row": ([(1, 20)], dict(pad_tiles=1)),
+    # two rows name the same two prefix pages; 9 pages hold a step whose
+    # tile lists 6 + 6 + 1: the pool's size bounds no work list
+    "shared_prefix_small_pool": (
+        [(5, 40), (3, 40), (1, 4)],
+        dict(pool_blocks=9, shared=[(1, 0, 5)])),
+}
+
+
+@pytest.mark.parametrize("form", ["flat", "per_tile"])
+@pytest.mark.parametrize("name", sorted(_WALK_CASES))
+def test_kernel_walks_the_live_work(name, form):
+    """Parity with the gather reader where the work list is short, long,
+    absent for whole tiles, or names shared pages; padding rows read
+    exactly 0; and the walk is the live items plus one item for each
+    tile without work, whatever the static bounds are."""
+    seqs, kw = _WALK_CASES[name]
+    rng = np.random.RandomState(len(name))
+    c = _ragged_case(rng, seqs, 8, n_kv=2, grp=2, **kw)
+    maps = c["maps"]
+    tile_q, num_tiles = c["tile_q"], len(maps.step_tile) - 1
+    touched = set()
+    off = 0
+    for n, _ in seqs:
+        touched.update(range(off // tile_q, -(-(off + n) // tile_q)))
+        off += n
+    # each sequence walks its pages once for every tile it spans
+    assert maps.live == sum(
+        -(-kv // 8) * (-(-cu1 // tile_q) - cu0 // tile_q)
+        for kv, cu0, cu1 in zip(c["kv_lens"], c["cu"], c["cu"][1:])
+        if cu1 > cu0)
+    assert maps.walked == maps.live + num_tiles - len(touched)
+    if name == "shared_prefix_small_pool":
+        assert maps.walked > kw["pool_blocks"]
+        assert kw["pool_blocks"] < tile_q * 6       # < tile_q x mbps
+    out = _run_rpa(c, maps if form == "flat"
+                   else _per_tile_maps(c, rpa_max_steps(tile_q, 11)))
+    valid = c["sid"] < c["max_seqs"]
+    np.testing.assert_allclose(out[valid], _run_gather(c)[valid],
+                               atol=2e-5)
+    assert np.all(out[~valid] == 0.0)
+
+
+def _covered(maps, max_seqs):
+    """``{(tile, seq): [pages in walk order]}`` of a flat list, and the
+    tiles that hold only a sentinel item."""
+    got, sentinel_tiles = {}, []
+    stl = maps.step_tile
+    for j in range(len(stl) - 1):
+        items = range(stl[j], stl[j + 1])
+        assert len(items) >= 1              # every tile owns an item
+        seqs = [int(maps.step_seq[w]) for w in items]
+        if seqs == [max_seqs]:
+            sentinel_tiles.append(j)
+            continue
+        assert max_seqs not in seqs         # no sentinel beside real work
+        for w in items:
+            got.setdefault((j, int(maps.step_seq[w])), []).append(
+                int(maps.step_blk[w]))
+    return got, sentinel_tiles
+
+
 def test_step_maps_cover_each_page_exactly_once():
     """Work-list invariants: for every tile, each overlapping sequence
-    contributes exactly ceil(kv_len / block_size) steps (its REAL pages,
-    nothing more), empty sequences contribute none, and dead steps carry
-    the sentinel."""
+    contributes exactly ceil(kv_len / block_size) items (its REAL pages,
+    nothing more, in order), empty sequences contribute none, a tile
+    without work owns one sentinel item, and the tail past the live
+    length carries the sentinel."""
     cu = np.array([0, 5, 5, 6, 16])  # seq 1 is a new_len == 0 slot
     kv_lens = [5, 8, 9, 16]
     tile_q, bs, max_seqs = 8, 8, 6
-    ssq, sbk = build_step_maps(cu, kv_lens, total_tokens=16,
-                               tile_q=tile_q, block_size=bs,
-                               max_steps=rpa_max_steps(tile_q, 4, 32),
-                               max_seqs=max_seqs)
-    for j in range(2):
+    maps = build_step_maps(cu, kv_lens, total_tokens=32,
+                           tile_q=tile_q, block_size=bs,
+                           max_items=rpa_max_items(4, max_seqs, 4),
+                           max_seqs=max_seqs)
+    want = {}
+    for j in range(4):
         lo, hi = j * tile_q, (j + 1) * tile_q
-        want = {}
         for s in range(4):
             if cu[s] < cu[s + 1] and cu[s + 1] > lo and cu[s] < hi:
-                want[s] = -(-kv_lens[s] // bs)
-        got = {}
-        for s, b in zip(ssq[j], sbk[j]):
-            if s == max_seqs:
-                continue
-            got.setdefault(int(s), []).append(int(b))
-        assert {s: len(b) for s, b in got.items()} == want
-        for s, blocks in got.items():
-            assert blocks == list(range(want[s]))  # each page once, in order
-    with pytest.raises(ValueError, match="max_steps"):
-        build_step_maps(cu, kv_lens, total_tokens=16, tile_q=tile_q,
-                        block_size=bs, max_steps=1, max_seqs=max_seqs)
+                want[(j, s)] = list(range(-(-kv_lens[s] // bs)))
+    got, sentinel_tiles = _covered(maps, max_seqs)
+    assert got == want                      # each page once, in order
+    assert sentinel_tiles == [2, 3]
+    assert maps.live == sum(len(v) for v in want.values())
+    assert maps.walked == maps.live + len(sentinel_tiles)
+    assert np.all(maps.step_seq[maps.walked:] == max_seqs)
+    assert list(maps.step_tile) == sorted(maps.step_tile)
+    with pytest.raises(ValueError, match="max_items"):
+        build_step_maps(cu, kv_lens, total_tokens=32, tile_q=tile_q,
+                        block_size=bs, max_items=5, max_seqs=max_seqs)
+
+
+@pytest.mark.parametrize("packing", ["straddlers", "single_tokens",
+                                     "random"])
+def test_step_maps_stay_inside_the_static_bound(packing):
+    """The arrays' static length holds under the packings that make the
+    most (tile, sequence) pairs, every sequence at full table width: a
+    sequence across each tile boundary, one sequence a token, and random
+    cuts of the token axis."""
+    tile_q, bs, mbps, num_tiles = 8, 4, 5, 6
+    T = tile_q * num_tiles
+    rng = np.random.RandomState(3)
+    if packing == "straddlers":
+        # 6, then 4 across every boundary with 4 more between them
+        cuts = [[0, 6] + [t for b in range(8, T, 8) for t in (b + 2, b + 6)]
+                + [T]]
+    elif packing == "single_tokens":
+        cuts = [list(range(T + 1))]
+    else:
+        cuts = [[0] + sorted(rng.choice(np.arange(1, T), size=rng.randint(
+            1, T - 1), replace=False).tolist()) + [T] for _ in range(50)]
+    for cu in cuts:
+        n = len(cu) - 1
+        bound = rpa_max_items(num_tiles, n, mbps)
+        maps = build_step_maps(cu, [mbps * bs] * n, total_tokens=T,
+                               tile_q=tile_q, block_size=bs,
+                               max_items=bound, max_seqs=n)
+        got, sentinel_tiles = _covered(maps, n)
+        assert not sentinel_tiles
+        assert all(v == list(range(mbps)) for v in got.values())
+        spans = sum(-(-b // tile_q) - a // tile_q
+                    for a, b in zip(cu, cu[1:]))
+        assert len(got) == spans <= num_tiles + n - 1
+        assert maps.walked == maps.live == mbps * spans <= bound
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """A described TPU v5e to compile for (nothing runs). Inside a
+    fixture, never at import: only the worker that runs this file loads
+    the TPU's library."""
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler for the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
+        v5e_chip, monkeypatch):
+    """The dynamic grid bound lowers for the chip: the kernel at the
+    serving cell's shapes (``benchmark/configs``), fed the flat list the
+    engine builds, compiles with Mosaic into one ``rpa`` custom call. A
+    lowering error of the traced bound shows here, on a CPU."""
+    import importlib
+    import json
+    import os
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    # (the package exports the function under the module's name)
+    mod = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs",
+                           "mistral-7b-v0.3-serve-l16.json")) as f:
+        cfg = json.load(f)
+    eng = cfg["engine"]
+    heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = cfg.get("head_dim") or cfg["hidden_size"] // heads
+    tile = default_tile_q(heads // kv, jnp.bfloat16)
+    tokens = eng["max_batch"] + eng["prefill_chunk"]
+    assert tokens % tile == 0
+    seqs = eng["max_batch"] + 1
+    items = rpa_max_items(tokens // tile, eng["max_batch"],
+                          eng["max_blocks_per_seq"])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    pool = arr((eng["max_blocks"] + 1, kv, eng["block_size"], hd),
+               jnp.bfloat16)
+    # interpret mode off, and no persistent cache entry (it could not be
+    # read back): as tests/benchmark/test_kernel_trace_names.py
+    monkeypatch.setattr(mod, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(ragged_paged_attention).lower(
+            arr((tokens, heads, hd), jnp.bfloat16), pool, pool,
+            arr((seqs, eng["max_blocks_per_seq"]), jnp.int32),
+            arr((seqs + 1,), jnp.int32), arr((seqs,), jnp.int32),
+            arr((items,), jnp.int32), arr((items,), jnp.int32),
+            arr((tokens // tile + 1,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1, calls
+    assert "rpa" in calls[0].split("=")[0], calls[0]
 
 
 def test_impl_knob_resolution(monkeypatch):

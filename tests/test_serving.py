@@ -470,6 +470,67 @@ def test_metrics_families_exposed(served):
         assert family in text
 
 
+def test_rpa_walk_follows_live_work_under_one_executable():
+    """Steps of very different live counts (no work, 16 decode rows, a
+    long prompt's chunks over a growing context beside them) run the ONE
+    executable: the RPA kernel's trip count is a traced input. Streams
+    equal the gather reader's, and ``serving_rpa_steps_total`` grows by
+    each step's work list: live items, and walked = live + one item for
+    each q tile without work."""
+    from paddle_tpu.serving.engine import serving_metrics
+    model = _tiny(4)
+    rng = np.random.RandomState(12)
+    short = [rng.randint(1, 128, 2 + i % 3) for i in range(16)]
+    long_prompt = rng.randint(1, 128, 70)
+    counter = serving_metrics()["rpa_steps"]
+    streams, walks = {}, []
+    for impl in ("gather", "rpa"):
+        eng = ServingEngine(model, max_batch=16, max_blocks=96,
+                            block_size=4, prefill_chunk=16,
+                            attn_impl=impl)
+        build = eng._build_step_maps
+        built = []
+        eng._build_step_maps = lambda *a, **k: (
+            built.append(build(*a, **k)) or built[-1])
+        before = counter.value(kind="walked"), counter.value(kind="live")
+        assert eng.step() is False          # no work: nothing dispatched
+        handles = [eng.submit(p, max_new_tokens=8 + i % 5)
+                   for i, p in enumerate(short)]
+        for _ in range(5):          # 48 prompt tokens, 16 a step; 1 more
+            eng.step()
+        decode_rows = sum(r.state is RequestState.RUNNING
+                          for r in eng.scheduler.slotted())
+        handles.append(eng.submit(long_prompt, max_new_tokens=4))
+        while eng.step():
+            pass
+        streams[impl] = [h.result(30)["token_ids"] for h in handles]
+        assert eng.step_traces == 1
+        eng.cache.allocator.assert_no_leaks()
+        grown = (counter.value(kind="walked") - before[0],
+                 counter.value(kind="live") - before[1])
+        if impl == "gather":
+            assert not built and grown == (0, 0)
+            continue
+        assert decode_rows == 16
+        num_tiles = eng.step_tokens // eng._tile_q
+        for m in built:
+            sentinels = int(np.sum(m.step_seq[:m.walked] == eng.max_batch))
+            assert m.walked == m.live + sentinels
+            assert num_tiles <= m.walked <= eng._max_items
+            # a tile holds the sentinel item only where it has no work
+            assert sentinels == sum(
+                m.step_tile[j + 1] - m.step_tile[j] == 1
+                and m.step_seq[m.step_tile[j]] == eng.max_batch
+                for j in range(num_tiles))
+        walks = [m.walked for m in built]
+        assert grown == (sum(walks), sum(m.live for m in built))
+    assert streams["rpa"] == streams["gather"]
+    assert streams["rpa"][-1] == _eager_continuation(model, long_prompt, 4)
+    # the bound moved with the work: a lone decode tail walks a few
+    # items, the long prompt's last chunks a sequence's every page per tile
+    assert max(walks) >= 4 * min(walks), walks
+
+
 # ---------------- generate_loop early exit (satellite) -----------------------
 def test_generate_loop_breaks_on_all_eos():
     """The eager decode loop must stop as soon as every row has hit
