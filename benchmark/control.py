@@ -17,6 +17,14 @@ lower reading) and the control's (``control_gap_max``: the reference in
 int8 put in the program's place, read at the same prompts and tokens). With
 ``--program-quantize`` the program's own weight-only path is switched on
 instead, and its ``served_gap_max`` is a control reading.
+
+With ``--rates`` a serving cell's mix is offered at each rate in turn (one
+seed, no output check): the sweep that finds its capacity.
+
+Everything model-shaped (the engine, the trainer's reference, the control
+modes) comes from the module the cell's configuration names under
+``"model"`` (``harness.model_of``), so the limits and the rates of a later
+model's cells are set by these same commands.
 """
 import argparse
 import json
@@ -28,22 +36,23 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def train_readings(cfg, mix, seed, modes=("fp8", "half_batch")):
+    from benchmark import harness
     from benchmark.kinds import train_job
-    from benchmark.reference import mistral as reference
+    model = harness.model_of(cfg)
     n = int(mix["check_steps"])
     pool = train_job.batches(mix, seed, int(cfg["vocab_size"]), n)
     dtype = cfg.get("dtype", "bfloat16")
-    ref = reference.train_steps(seed, cfg, mix["optimizer"], pool,
-                                weight_dtype=dtype)
+    ref = model.train_steps(seed, cfg, mix["optimizer"], pool,
+                            weight_dtype=dtype)
     out = {}
     for mode in modes:
         if mode == "half_batch":
             half = [b[: max(1, len(b) // 2)] for b in pool]
-            got = reference.train_steps(seed, cfg, mix["optimizer"], half,
-                                        weight_dtype=dtype)
+            got = model.train_steps(seed, cfg, mix["optimizer"], half,
+                                    weight_dtype=dtype)
         else:
-            got = reference.train_steps(seed, cfg, mix["optimizer"], pool,
-                                        mode=mode, weight_dtype=dtype)
+            got = model.train_steps(seed, cfg, mix["optimizer"], pool,
+                                    mode=mode, weight_dtype=dtype)
         out[mode] = train_job.compare(got, ref)
     return out
 
@@ -60,7 +69,7 @@ def main(argv=None):
                          "window's backlog and end-to-end numbers")
     args = ap.parse_args(argv)
 
-    from benchmark import harness, sut
+    from benchmark import harness
     manifest = harness.load_manifest()
     wl, cfg, mix, _ = harness.load_cell(manifest, args.workload)
     try:
@@ -89,7 +98,8 @@ def main(argv=None):
         else:
             hooks = {}
             if args.program_quantize:
-                hooks["engine"] = lambda c, s: sut.build_engine(
+                build = harness.model_of(cfg).build_engine
+                hooks["engine"] = lambda c, s: build(
                     c, s, {"quantize": args.program_quantize})
             ctx = harness.Context(
                 cell=args.workload, cfg=cfg, mix=mix, seed=seed, seconds=args.seconds, traced=False, peaks=peaks,

@@ -2,9 +2,10 @@
 the traced sub-window, the per-layer readers, the comparison that decides
 ``correct`` and the result line.
 
-Nothing in here names a cell, a configuration or a metric: those are files
-found by the names ``BENCHMARK.json`` gives (``configs/``, ``traffic/``,
-``limits/``, ``layer_metrics/``, ``kinds/``).
+Nothing in here names a cell, a configuration, a model or a metric: those
+are files found by the names ``BENCHMARK.json`` gives (``configs/``,
+``traffic/``, ``limits/``, ``layer_metrics/``, ``kinds/``) and, for what is
+model-shaped, by the name the configuration's file gives (``models/``).
 """
 from __future__ import annotations
 
@@ -45,10 +46,30 @@ def load_cell(manifest: dict, name: str, root=ROOT):
                        f"{[w['name'] for w in manifest['workloads']]}")
     conf = next(c for c in manifest["configs"] if c["name"] == wl["config"])
     cfg = load_json(root, conf["file"])
-    mix = load_json(HERE, "traffic", wl["traffic"] + ".json")
-    lim_path = os.path.join(HERE, "limits", name + ".json")
+    here = os.path.join(root, os.path.basename(HERE))
+    mix = load_json(here, "traffic", wl["traffic"] + ".json")
+    lim_path = os.path.join(here, "limits", name + ".json")
     limits = load_json(lim_path) if os.path.exists(lim_path) else {}
     return wl, cfg, mix, limits
+
+
+def model_names(here=HERE):
+    """The model modules there are: ``models/<name>.py``."""
+    return sorted(f[:-3] for f in os.listdir(os.path.join(here, "models"))
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def model_of(cfg: dict):
+    """The module ``models/<cfg["model"]>.py``: the builder of the system
+    under test, the plain reference and the operation counts of the model
+    the configuration says it is. There is no default: a file that does not
+    name its model, or names one no module answers to, is an error."""
+    name, there = cfg.get("model"), model_names()
+    if name not in there:
+        raise LookupError(
+            f"the configuration names model {name!r} under \"model\"; "
+            f"benchmark/models/ has {there}")
+    return importlib.import_module("benchmark.models." + name)
 
 
 def metrics_of(manifest: dict, section: str, cell: str):

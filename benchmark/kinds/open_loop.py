@@ -336,14 +336,14 @@ def run(ctx):
     """Build the engine, warm its one executable with one request through
     the served entry, open the window, offer the schedule, then (window
     closed, peak read, engine gone) run the reference over a sample of what
-    was served."""
+    was served. Engine, reference and operation counts are those of the
+    configuration's model (``harness.model_of``)."""
     from benchmark import harness, sut
-    from benchmark.kernels import model as model_flops
-    from benchmark.reference import mistral as reference
 
     cfg, mix, seed = ctx.cfg, ctx.mix, ctx.seed
+    model = harness.model_of(cfg)
     vocab = int(cfg["vocab_size"])
-    engine = ctx.hooks.get("engine", sut.build_engine)(cfg, seed)
+    engine = ctx.hooks.get("engine", model.build_engine)(cfg, seed)
     engine.start()
     notes = []
     warm_rng = np.random.default_rng(int(seed) + 2)
@@ -411,7 +411,7 @@ def run(ctx):
     if sample:
         gaps = served_gaps(
             sample, seed, cfg, mix, ctx.reference_mode,
-            ctx.hooks.get("serve_logits", reference.serve_logits))
+            ctx.hooks.get("serve_logits", model.serve_logits))
         numbers["served_gap_max"] = float(gaps["served"].max())
         numbers["served_gap_mean"] = float(gaps["served"].mean())
         notes.append(
@@ -441,7 +441,7 @@ def run(ctx):
         "kind": "open_loop", "cfg": cfg, "mix": mix, "peaks": ctx.peaks,
         "window_s": ctx.seconds, "counters": window, "records": records,
         "e2e": e2e, "trace": trace, "traced": tr, "stats": stats,
-        "flops": model_flops,
+        "flops": model,
     }
     return harness.Outcome(
         attempted=len(records), failed=failed, end_to_end=e2e,

@@ -3,9 +3,14 @@ batches drawn from the seed, as a trainer's inner loop calls it.
 
 The mix's file gives the batch (rows x tokens), how many distinct batches
 the feed cycles through, how many steps may be in flight (the loop waits
-for step ``i - in_flight`` before it enqueues step ``i``, so the device
-always has the next step queued and the host never runs minutes ahead of
-the clock), and how many first steps the output check follows.
+for step ``i - in_flight`` before it enqueues step ``i``: some seconds of
+steps are queued ahead, so the device stays fed while the host stands
+still, and the host never runs minutes ahead of the clock), and how many
+first steps the output check follows. Losses are read ``in_flight`` steps
+late. When the window's time is up the loop sends nothing more, waits for
+all it sent and reads the clock after that wait: all of that work counts,
+over all of that time, so the window is longer than asked by what was
+queued.
 """
 from __future__ import annotations
 
@@ -56,16 +61,22 @@ def run(ctx):
     the seed through its first steps, through the window's own call and
     feed; the window then takes that same object on. The reference follows
     those first steps once the window has closed, the peak has been read
-    and the trainer's state is gone."""
+    and the trainer's state is gone. Trainer, reference and operation counts
+    are those of the configuration's model (``harness.model_of``)."""
     from benchmark import harness, sut
-    from benchmark.kernels import model as model_flops
-    from benchmark.reference import mistral as reference
 
     cfg, mix, seed = ctx.cfg, ctx.mix, ctx.seed
+    model = harness.model_of(cfg)
+    make_trainer = ctx.hooks.get("trainer", getattr(model, "Trainer", None))
+    if make_trainer is None:
+        raise ValueError(
+            f"model {cfg['model']!r} supplies no Trainer (it serves only): "
+            f"a train_job cell needs benchmark/models/{cfg['model']}.py to "
+            f"bring one")
     n_check = int(mix["check_steps"])
     pool = batches(mix, seed, int(cfg["vocab_size"]),
                    max(int(mix["batch_pool"]), n_check))
-    trainer = ctx.hooks.get("trainer", sut.Trainer)(cfg, mix["optimizer"], seed)
+    trainer = make_trainer(cfg, mix["optimizer"], seed)
     feeds = [trainer.feed(b) for b in pool]
     notes = []
 
@@ -103,7 +114,7 @@ def run(ctx):
     notes.append(f"window: {steps} steps of {tokens_per_step} tokens in "
                  f"{window_s:.3f} s; first losses {first['loss']}")
 
-    ref = ctx.hooks.get("train_reference", reference.train_steps)(
+    ref = ctx.hooks.get("train_reference", model.train_steps)(
         seed, cfg, mix["optimizer"], pool[:n_check], mode=ctx.reference_mode,
         weight_dtype=cfg.get("dtype", "bfloat16"))
     numbers = compare(first, ref)
@@ -123,7 +134,7 @@ def run(ctx):
         "kind": "train_job", "cfg": cfg, "mix": mix, "peaks": ctx.peaks,
         "window_s": window_s, "steps": steps, "step_done_s": done_s,
         "tokens_per_s": tokens_per_s,
-        "flops_per_token": model_flops.train_flops_per_token(
+        "flops_per_token": model.train_flops_per_token(
             cfg, int(mix["seq_len"])),
         "trace": trace,
         "trace_span_s": (tw.end_s - tw.begin_s) if tw else math.nan,

@@ -6,7 +6,8 @@ import time
 
 from benchmark import harness
 
-CFG = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+CFG = dict(model="mistral", hidden_size=64, intermediate_size=128,
+           num_hidden_layers=2,
            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
            vocab_size=256, rope_theta=1e6, rms_norm_eps=1e-5,
            max_position_embeddings=512, dtype="float32",
@@ -51,9 +52,10 @@ def flood_mix():
     return mix
 
 
-def context(cell, mix, seed=2 ** 31 + 5, seconds=1.5, **kw):
+def context(cell, mix, seed=2 ** 31 + 5, seconds=1.5, cfg=None, **kw):
     return harness.Context(
-        cell=cell, cfg=copy.deepcopy(CFG), mix=mix, seed=seed, seconds=seconds, traced=False, peaks=PEAKS,
+        cell=cell, cfg=copy.deepcopy(cfg or CFG), mix=mix, seed=seed,
+        seconds=seconds, traced=False, peaks=PEAKS,
         t_process_start=time.perf_counter(), trace_dir="", **kw)
 
 
