@@ -15,7 +15,8 @@ from ..topology import CommunicateTopology, HybridCommunicateGroup
 from . import mpu  # noqa: F401
 from . import pipeline  # noqa: F401
 from . import moe  # noqa: F401
-from .moe import MoELayer, NaiveGate, SwitchGate, GShardGate  # noqa: F401
+from .moe import (HeldExpertsLayer, MoELayer, NaiveGate,  # noqa: F401
+                  SwitchGate, GShardGate)
 from . import sequence_parallel  # noqa: F401
 from .sequence_parallel import (  # noqa: F401
     ring_attention, ulysses_attention, scatter_sequence, gather_sequence,
@@ -39,7 +40,7 @@ __all__ = ["init", "DistributedStrategy", "distributed_model",
            "distributed_optimizer", "get_hybrid_communicate_group",
            "worker_num", "worker_index", "mpu", "ColumnParallelLinear",
            "RowParallelLinear", "VocabParallelEmbedding",
-           "ParallelCrossEntropy", "LayerDesc", "SharedLayerDesc", "PipelineLayer", "PipelineParallel", "pipeline_spmd", "spmd_schedule_stats", "SpmdPipelineLayer", "SpmdPipelineParallel", "pipeline_spmd_hetero", "SpmdHeteroPipelineLayer", "MoELayer", "NaiveGate", "SwitchGate", "GShardGate", "ring_attention", "ulysses_attention", "scatter_sequence", "gather_sequence", "utils", "recompute"]
+           "ParallelCrossEntropy", "LayerDesc", "SharedLayerDesc", "PipelineLayer", "PipelineParallel", "pipeline_spmd", "spmd_schedule_stats", "SpmdPipelineLayer", "SpmdPipelineParallel", "pipeline_spmd_hetero", "SpmdHeteroPipelineLayer", "MoELayer", "HeldExpertsLayer", "NaiveGate", "SwitchGate", "GShardGate", "ring_attention", "ulysses_attention", "scatter_sequence", "gather_sequence", "utils", "recompute"]
 
 _state = {"hcg": None, "strategy": None}
 

@@ -30,6 +30,14 @@ Two dispatch formulations behind the same API (``dispatch_mode``):
 * ``"dense"`` — the original GShard one-hot einsum formulation
   ([T, E, C] dispatch/combine contractions); kept as the differential
   -testing oracle and for tiny shapes.
+
+:class:`HeldExpertsLayer` is the other expert layer: gated (SwiGLU)
+experts of which this chip is *told which it holds*, a router over all
+of them, and **no capacity**: every assignment to a held expert is
+computed (a ragged grouped product over the rows sorted by expert), so a
+token's output never depends on what else is in the batch. It is what
+expert parallelism asks of one chip without its exchange, and what a
+benchmark configuration that holds a chip's share of the experts runs.
 """
 from __future__ import annotations
 
@@ -46,7 +54,8 @@ from paddle_tpu.nn.layer_base import Layer
 from ..mesh import get_mesh
 from ..sharding_api import shard_tensor
 
-__all__ = ["MoELayer", "NaiveGate", "SwitchGate", "GShardGate"]
+__all__ = ["MoELayer", "HeldExpertsLayer", "NaiveGate", "SwitchGate",
+           "GShardGate"]
 
 
 def _ragged_moves(n_slots):
@@ -343,4 +352,111 @@ class MoELayer(Layer):
         out, aux = apply_op(f, x, self.gate.weight, self.w1, self.b1,
                             self.w2, self.b2, *extra, op_name="moe_layer")
         self.l_aux = aux
+        return out
+
+
+class HeldExpertsLayer(Layer):
+    """Dropless routed experts, a chip's share (the sigmoid-scored,
+    top-k-normalised router of the DeepSeek-V3 / openPangu-Ultra family).
+
+    ``num_experts`` experts exist, the router scores all of them and each
+    token chooses ``top_k``; ``held`` names the experts whose weights live
+    here (global ids; default: all). With ``h`` a token's input:
+
+        s = sigmoid(W_g h)                    over all experts, float32
+        w = s_top / (sum s_top + 1e-20) * routed_scaling_factor
+        y = sum over the chosen experts that are held of w_k E_k(h)
+        E(h) = W_down (silu(W_gate h) * W_up h)
+
+    ``w`` is normalised over all ``top_k`` chosen, held or not: the parts
+    the shares of a deployment compute add up to the whole layer's
+    routed output. What the absent experts would add is left out and
+    nothing stands in for them or their exchange. No capacity and no
+    dropped token: the ``T * top_k`` assignments are sorted by held
+    expert (those to absent experts last) and each held expert multiplies
+    exactly its rows, a ragged grouped product (``jax.lax.ragged_dot``,
+    XLA's own grouped kernel on a TPU: at 16 experts of 7680 x 2048 and
+    8,320 sorted rows it took 0.8-1.0 ms a product where
+    a column-tiled ``ops.pallas.grouped_matmul.gmm`` took 2.3 and 7.0 ms, PR 27).
+
+    ``forward`` leaves the rows each held expert took, ``[len(held)]``
+    int32, in ``self.last_rows`` (a traced value inside a compiled step:
+    the caller reads it in the same trace and clears it).
+    """
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k, held=None,
+                 routed_scaling_factor=1.0, norm_topk_prob=True,
+                 init_std=0.02):
+        super().__init__()
+        held = tuple(range(num_experts)) if held is None \
+            else tuple(int(e) for e in held)
+        if len(set(held)) != len(held) or not held \
+                or not all(0 <= e < num_experts for e in held):
+            raise ValueError(f"held experts {held} of {num_experts}")
+        self.d_model, self.d_hidden = d_model, d_hidden
+        self.num_experts, self.top_k, self.held = num_experts, top_k, held
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        n, init = len(held), I.Normal(std=init_std)
+        self.router = self.create_parameter(
+            shape=[d_model, num_experts], default_initializer=init)
+        self.w_gate = self.create_parameter(
+            shape=[n, d_model, d_hidden], default_initializer=init)
+        self.w_up = self.create_parameter(
+            shape=[n, d_model, d_hidden], default_initializer=init)
+        self.w_down = self.create_parameter(
+            shape=[n, d_hidden, d_model], default_initializer=init)
+        self.last_rows = None
+
+
+    def forward(self, x, token_mask=None):
+        """x: [..., d_model] -> the held experts' part of the routed
+        output, same shape. ``token_mask`` (broadcastable to x's leading
+        dims, True = real token): padding chooses no expert."""
+        import jax
+        import jax.numpy as jnp
+
+        E, K, n = self.num_experts, self.top_k, len(self.held)
+        scale, norm = self.routed_scaling_factor, self.norm_topk_prob
+        local_of = np.full((E,), n, np.int32)      # global id -> held slot
+        local_of[list(self.held)] = np.arange(n, dtype=np.int32)
+        product = jax.lax.ragged_dot
+
+        def f(xa, gw, wg, wu, wd, *rest):
+            lead = xa.shape[:-1]
+            xt = xa.reshape(-1, xa.shape[-1])
+            T = xt.shape[0]
+            s = jax.nn.sigmoid(jnp.dot(
+                xt.astype(jnp.float32), gw.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))        # [T, E]
+            top_s, top_i = jax.lax.top_k(s, K)
+            w = top_s * scale
+            if norm:
+                w = w / (top_s.sum(-1, keepdims=True) + 1e-20)
+            slot = jnp.asarray(local_of)[top_i]              # [T, K]
+            if rest:
+                vm = jnp.broadcast_to(rest[0].astype(bool), lead).reshape(T)
+                slot = jnp.where(vm[:, None], slot, n)
+            # the T*K assignments sorted by held expert, absent ones last
+            R = T * K
+            flat = slot.reshape(R)
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
+            rows = xt[order // K]                            # [R, d]
+            act = jax.nn.silu(product(rows, wg, sizes)) \
+                * product(rows, wu, sizes)
+            out = product(act.astype(xt.dtype), wd, sizes)   # [R, d]
+            # combine, gather-only: where assignment (t, k) sits in the
+            # sorted order; an absent expert's row weighs nothing
+            where = jnp.zeros((R,), jnp.int32).at[order].set(
+                jnp.arange(R, dtype=jnp.int32)).reshape(T, K)
+            w = jnp.where(slot < n, w, 0.0)
+            y = sum(out[where[:, k]].astype(jnp.float32) * w[:, k:k + 1]
+                    for k in range(K))
+            return y.astype(xa.dtype).reshape(xa.shape), sizes
+
+        extra = () if token_mask is None else (token_mask,)
+        out, rows = apply_op(f, x, self.router, self.w_gate, self.w_up,
+                             self.w_down, *extra, op_name="held_experts")
+        self.last_rows = rows
         return out

@@ -634,6 +634,14 @@ class LlamaForCausalLM(nn.Layer):
             ops.reshape(labels[:, 1:], [-1]))
         return logits, loss
 
+    def kv_cache_spec(self):
+        """What each layer keeps of a token in a paged cache (the serving
+        engine builds its pools from this): a K and a V row of
+        ``head_dim`` under each KV head."""
+        from paddle_tpu.ops.paged_attention import LayerCacheSpec
+        attn = self.model.layers[0].self_attn
+        return LayerCacheSpec.kv(attn.n_kv, attn.head_dim)
+
     def _logits(self, h):
         if self.lm_head is not None:
             return self.lm_head(h)

@@ -167,6 +167,13 @@ class MoeForCausalLM(nn.Layer):
     # slicing h BEFORE the head matmul (see forward)
     _FUSED_CE_MIN_VOCAB = 32768
 
+    def kv_cache_spec(self):
+        """One K and V row of ``head_dim`` under each KV head, a layer
+        (the serving engine builds its pools from this)."""
+        from paddle_tpu.ops.paged_attention import LayerCacheSpec
+        attn = self.layers[0].self_attn
+        return LayerCacheSpec.kv(attn.n_kv, attn.head_dim)
+
     def aux_loss(self):
         total = None
         for layer in self.layers:

@@ -54,11 +54,37 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["PagedLayerCache", "RaggedLayerCache", "write_to_pool",
+__all__ = ["LayerCacheSpec", "PagedLayerCache", "RaggedLayerCache",
+           "write_to_pool", "ragged_latent_attention_step",
            "write_tokens_to_pool", "gather_pool", "paged_attention_step",
            "ragged_gather_attention", "ragged_paged_attention_step",
            "paged_attention_impl", "impl_override", "mesh_override",
            "quantize_kv_slots"]
+
+
+class LayerCacheSpec(NamedTuple):
+    """What an attention layer keeps of a token in the paged cache; a
+    served model states it (``model.kv_cache_spec()``, the same for every
+    layer) and the pools are built from it
+    (``serving.kv_cache.PagedKVCache``).
+
+    ``value_dim`` None is a **latent** page (MLA): the layer writes one
+    row ``[c | k_rope]`` of ``key_dim`` numbers a token under its one
+    head, its values are the first ``value_cols`` columns of that same
+    row, and no value pool exists."""
+    kv_heads: int
+    key_dim: int
+    value_dim: object = None       # int, or None: no value pool
+    value_cols: int = 0            # latent page: columns that are values
+
+    @property
+    def latent(self) -> bool:
+        return self.value_dim is None
+
+    @classmethod
+    def kv(cls, kv_heads: int, head_dim: int) -> "LayerCacheSpec":
+        """A K and a V pool of one width (GQA/MHA layers)."""
+        return cls(int(kv_heads), int(head_dim), int(head_dim))
 
 
 class PagedLayerCache(NamedTuple):
@@ -166,7 +192,8 @@ class RaggedLayerCache(NamedTuple):
     (``step_tile[-1]``) — shapes never change, so the engine's one
     executable serves every batch mix."""
     k_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
-    v_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
+    v_pool: object        # the same; None where the layer's page is a
+    #                       latent one (``LayerCacheSpec.latent``)
     block_tables: object  # [max_seqs + 1, max_blocks_per_seq] int32
     cu_seqlens: object    # [max_seqs + 2] int32 token-span prefix sums
     context_lens: object  # [max_seqs + 1] int32 cached tokens per seq
@@ -397,3 +424,68 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
             q, k_pool, v_pool, block_tables, seq_ids, positions,
             scale=scale)
     return out.reshape(T, n_heads * hd), k_pool, v_pool
+
+
+# ===================== latent (MLA) pages ====================================
+def write_rows_to_latent_pool(pool, rows, block_tables, seq_ids, positions):
+    """Scatter ``rows`` [T, kd] into a one-head latent ``pool``
+    ``[num_blocks + 1, 1, block_size, kd]``. With one head a page is a
+    run of ``block_size`` rows, so the pool is written as the flat
+    ``[(num_blocks + 1) * block_size, kd]`` table it is (the reshape
+    moves nothing): one row scatter, padding tokens to the null block's
+    first row."""
+    nb1, _, bs, kd = pool.shape
+    nblk = block_tables.shape[1]
+    pos = positions.astype(jnp.int32)
+    phys = block_tables[seq_ids, jnp.clip(pos // bs, 0, nblk - 1)]
+    flat = jnp.where(phys == 0, 0, phys * bs + pos % bs)
+    return pool.reshape(nb1 * bs, kd).at[flat].set(
+        rows.astype(pool.dtype)).reshape(pool.shape)
+
+
+def ragged_latent_gather_attention(q, pool, block_tables, seq_ids,
+                                   positions, *, value_cols, scale):
+    """The gather fallback of the latent read (and the kernel's parity
+    oracle): every sequence's whole padded context gathered, each token's
+    row picked, dense masked softmax in float32. ``q`` [T, n_heads, kd]
+    absorbed queries; returns ``[T, n_heads, value_cols]``."""
+    rows = gather_pool(pool, block_tables)[seq_ids][:, :, 0]   # [T, L, kd]
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("thd,tld->thl", q.astype(jnp.float32), rows) * scale
+    visible = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] <= \
+        positions.astype(jnp.int32)[:, None]
+    s = jnp.where(visible[:, None, :], s, jnp.finfo(jnp.float32).min)
+    w = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("thl,tlc->thc", w, rows[..., :value_cols])
+
+
+def ragged_latent_attention_step(q, rows, pool, block_tables, cu_seqlens,
+                                 context_lens, seq_ids, positions, step_seq,
+                                 step_blk, step_tile, *, value_cols, scale):
+    """One unified serving step of a latent-attention (MLA) layer, read
+    **absorbed**: ``q`` [T, n_heads, kd] is ``[W_UK^T q_nope | q_rope]``,
+    ``rows`` [T, kd] the step's new cache rows ``[c | k_rope]``. Writes
+    the rows into the layer's one pool, then reads it with the RPA
+    kernel's latent form (``rpa_mla``: every query head shares the page,
+    values are its first ``value_cols`` columns) or the gather fallback,
+    by :func:`paged_attention_impl`. Returns ``(u [T, n_heads,
+    value_cols], pool')``; ``u`` at padding tokens is garbage (gather)
+    or 0 (rpa), as in :func:`ragged_paged_attention_step`."""
+    pool = write_rows_to_latent_pool(pool, rows, block_tables, seq_ids,
+                                     positions)
+    if paged_attention_impl() == "rpa":
+        if _tp_mesh() is not None:
+            raise NotImplementedError(
+                "a latent pool has one head: it is replicated, not "
+                "sharded over a model-parallel axis")
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention
+        u = ragged_paged_attention(
+            q, pool, None, block_tables, cu_seqlens, context_lens,
+            step_seq, step_blk, step_tile, sm_scale=scale,
+            value_cols=value_cols)
+    else:
+        u = ragged_latent_gather_attention(
+            q, pool, block_tables, seq_ids, positions,
+            value_cols=value_cols, scale=scale).astype(q.dtype)
+    return u, pool

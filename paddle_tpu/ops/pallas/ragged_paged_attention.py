@@ -233,8 +233,15 @@ def _flatten_maps(step_seq, step_blk, max_seqs):
 
 # =========================== kernel ==========================================
 def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
-                q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
-                *, tile_q, group, block_size, max_seqs, sm_scale):
+                q_ref, k_ref, *rest, tile_q, group, block_size, max_seqs,
+                sm_scale, value_cols=None):
+    # ``value_cols``: the page is a latent one and its values are the
+    # first ``value_cols`` columns of the key page itself (one pool, one
+    # DMA a page); there is no v ref then
+    if value_cols is None:
+        v_ref, o_ref, m_sc, l_sc, acc_sc = rest
+    else:
+        v_ref, (o_ref, m_sc, l_sc, acc_sc) = None, rest
     w = pl.program_id(1)
     j = to_ref[w]
     rows = tile_q * group
@@ -252,7 +259,7 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         sb = sb_ref[w]
         q = q_ref[...]                                  # [rows, hd]
         k = k_ref[...]                                  # [bs, hd]
-        v = v_ref[...]
+        v = v_ref[...] if v_ref is not None else k[:, :value_cols]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
@@ -301,10 +308,14 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
 
 def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
               block_tables, cu_seqlens, context_lens, *, tile_q, group,
-              sm_scale):
+              sm_scale, value_cols=None):
     """``q_heads`` [n_kv, T*group, hd] (token-major rows per kv head) →
-    out in the same layout."""
+    out in the same layout, ``vd`` wide: the value pool's width, or with
+    ``v_pool`` None (a latent pool) the ``value_cols`` first columns of
+    the key page."""
     n_kv, tg, hd = q_heads.shape
+    latent = v_pool is None
+    vd = int(value_cols) if latent else v_pool.shape[3]
     block_size = k_pool.shape[2]
     max_seqs = block_tables.shape[0] - 1
     rows = tile_q * group
@@ -317,7 +328,8 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
 
     kernel = functools.partial(
         _rpa_kernel, tile_q=tile_q, group=group, block_size=block_size,
-        max_seqs=max_seqs, sm_scale=sm_scale)
+        max_seqs=max_seqs, sm_scale=sm_scale,
+        value_cols=vd if latent else None)
 
     def q_map(h, w, to, ss, sb, tp, bt, cu, ctx):
         return (h, to[w], 0)
@@ -335,33 +347,44 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
         in_specs=[
             pl.BlockSpec((None, rows, hd), q_map),
             pl.BlockSpec((None, None, block_size, hd), kv_map),
-            pl.BlockSpec((None, None, block_size, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((None, rows, hd), q_map),
+        ] + ([] if latent else
+             [pl.BlockSpec((None, None, block_size, vd), kv_map)]),
+        out_specs=pl.BlockSpec((None, rows, vd), q_map),
         scratch_shapes=[
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((rows, vd), jnp.float32),
         ],
     )
+    pools = (k_pool,) if latent else (k_pool, v_pool)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_kv, tg, hd), q_heads.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_kv, tg, vd), q_heads.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-        # the device op's name in a profiler trace (``rpa.N custom-call``;
-        # without it the op is named after the jitted caller)
-        name="rpa",
+        # the device op's name in a profiler trace (``rpa.N custom-call``,
+        # ``rpa_mla.N custom-call`` over a latent pool; without it the op
+        # is named after the jitted caller)
+        name="rpa_mla" if latent else "rpa",
     )(tile_of, step_seq, step_blk, step_tile, block_tables, cu_seqlens,
-      context_lens, q_heads, k_pool, v_pool)
+      context_lens, q_heads, *pools)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
                            context_lens, step_seq, step_blk,
-                           step_tile=None, *, sm_scale=None):
+                           step_tile=None, *, sm_scale=None,
+                           value_cols=None):
     """GQA attention for a token-packed ragged batch over paged KV.
+
+    With ``v_pool`` None the pool is a **latent** one (MLA read absorbed:
+    ``k_pool`` ``[num_blocks + 1, 1, block_size, kd]`` holds a token's
+    ``[c | k_rope]`` row, ``q`` is ``[W_UK^T q_nope | q_rope]``): the
+    values are the first ``value_cols`` columns of the key page, every
+    query head shares the one page, and the output is
+    ``[total_tokens, n_heads, value_cols]``. Same work list, same body;
+    the kernel is then named ``rpa_mla`` in a trace.
 
     ``q`` [total_tokens, n_heads, hd]; pools
     ``[num_blocks + 1, n_kv, block_size, hd]`` (this step's new K/V
@@ -380,6 +403,9 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
         raise ValueError(
             f"q heads {n_heads} must be a multiple of kv heads {n_kv}")
     group = n_heads // n_kv
+    if (v_pool is None) != (value_cols is not None):
+        raise ValueError("value_cols goes with a latent pool (v_pool None)")
+    vd = int(value_cols) if v_pool is None else v_pool.shape[3]
     step_seq = jnp.asarray(step_seq, jnp.int32)
     step_blk = jnp.asarray(step_blk, jnp.int32)
     if step_tile is None:
@@ -402,9 +428,10 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(cu_seqlens, jnp.int32),
         jnp.asarray(context_lens, jnp.int32),
-        tile_q=tile_q, group=group, sm_scale=float(sm_scale))
-    return out.reshape(n_kv, T, group, hd).transpose(1, 0, 2, 3) \
-              .reshape(T, n_heads, hd)
+        tile_q=tile_q, group=group, sm_scale=float(sm_scale),
+        value_cols=value_cols)
+    return out.reshape(n_kv, T, group, vd).transpose(1, 0, 2, 3) \
+              .reshape(T, n_heads, vd)
 
 
 # =========================== tile autotune ===================================

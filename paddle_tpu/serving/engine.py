@@ -100,6 +100,10 @@ def _build_serving_metrics(reg) -> dict:
             "RPA kernel grid steps a kv head and layer, by kind: live "
             "(work items that name a real page) / walked (the kernel's "
             "grid bound: live + one step for each q tile without work)"),
+        "moe_rows": reg.counter(
+            "serving_moe_expert_rows_total",
+            "token rows the step's routed experts took, by layer and by "
+            "held expert (models with dropless held experts only)"),
         "rejections": reg.counter(
             "serving_rejections_total",
             "requests shed by graceful degradation, by reason "
@@ -116,6 +120,10 @@ def _build_serving_metrics(reg) -> dict:
         "kv_blocks": reg.gauge(
             "serving_kv_blocks_in_use",
             "KV-cache blocks currently held by live sequences"),
+        "kv_pool_bytes": reg.gauge(
+            "serving_kv_pool_bytes",
+            "bytes the paged cache's pools hold, by kind (latent: "
+            "one-pool latent pages; kv: K and V pools with their scales)"),
         # the two stats()-only fields promoted to real gauge families
         # (ISSUE 11): Prometheus scrapers and the bench --report gate
         # see pool pressure and compile churn without polling /healthz
@@ -208,8 +216,19 @@ class RequestHandle:
 
 class ServingEngine:
     """Continuous-batching inference over any zoo causal LM that speaks
-    the ``caches=`` protocol (Llama, MoE — the ``compiled_generate``
-    family seam)."""
+    the ``caches=`` protocol (Llama, MoE, the latent-attention MoE of
+    ``models/pangu_moe.py`` — the ``compiled_generate`` family seam).
+
+    What a model must state: ``cfg`` (``num_hidden_layers``,
+    ``num_attention_heads``, a position cap), a backbone that takes
+    ``caches=[RaggedLayerCache, ...]`` and returns ``(hidden, caches)``
+    (``models.generation.decode_surfaces``), and ``kv_cache_spec()``:
+    the ``ops.paged_attention.LayerCacheSpec`` of its layers, from which
+    the pools are built. Optional: ``moe_expert_rows()`` (the rows each held
+    expert took in the traced step, ``[layers, held]`` int32: returned by
+    the compiled step beside the logits and published by the commit
+    span and ``serving_moe_expert_rows_total``) and
+    ``clear_decode_side_effects()``."""
 
     def __init__(self, model, max_batch: int = 8, max_blocks: int = 64,
                  block_size: int = 16, prefill_chunk: int = 16,
@@ -277,8 +296,17 @@ class ServingEngine:
         self._adapter_gen = {}  # slot -> int
 
         nl = cfg.num_hidden_layers
-        n_kv = cfg.num_key_value_heads
-        hd = cfg.hidden_size // cfg.num_attention_heads
+        # what each layer keeps of a token is the model's to state
+        # (ops.paged_attention.LayerCacheSpec), never derived from cfg
+        spec_fn = getattr(model, "kv_cache_spec", None)
+        if spec_fn is None:
+            raise TypeError(
+                f"{type(model).__name__} states no kv_cache_spec(): a "
+                f"served model returns its layers' LayerCacheSpec")
+        spec = spec_fn()
+        # the read kernel's geometry (tile height, model-parallel split)
+        n_kv = spec.kv_heads
+        hd = spec.key_dim
         #: block-granular prefix-cache KV reuse (ISSUE 15) — on by
         #: default; PADDLE_TPU_PREFIX_CACHE=0 (or prefix_cache=False)
         #: restores the cache-off engine, the bit-parity oracle
@@ -329,8 +357,9 @@ class ServingEngine:
             max_pos = max_blocks * block_size
         if max_blocks_per_seq is None:
             max_blocks_per_seq = min(max_blocks, -(-max_pos // block_size))
-        self.cache = PagedKVCache(nl, max_blocks, block_size, n_kv, hd,
-                                  max_blocks_per_seq, dtype,
+        self.cache = PagedKVCache(nl, max_blocks, block_size, spec,
+                                  max_blocks_per_seq=max_blocks_per_seq,
+                                  dtype=dtype,
                                   prefix_cache=self.prefix_cache_enabled,
                                   kv_dtype=self.kv_dtype)
         if self.mesh is not None:
@@ -555,6 +584,13 @@ class ServingEngine:
         kv_quant = self.kv_dtype is not None
         n_slots = self.n_adapter_slots
         tap_order = [] if instrument else None
+        moe_rows = getattr(model, "moe_expert_rows", None)
+
+        def pool(p):     # a latent layer has no v pool
+            return None if p is None else Tensor(p)
+
+        def data(t):
+            return None if t is None else t.data
 
         def step(stt, tokens, k_pools, v_pools, k_scales, v_scales,
                  bt, cu, ctx, sid, pos, ssq, sbk, stl, last_idx, aid):
@@ -576,7 +612,7 @@ class ServingEngine:
                     for i in range(nl)]
             else:
                 caches = [pa.RaggedLayerCache(
-                    Tensor(k_pools[i]), Tensor(v_pools[i]), Tensor(bt),
+                    Tensor(k_pools[i]), pool(v_pools[i]), Tensor(bt),
                     Tensor(cu), Tensor(ctx), Tensor(sid), Tensor(pos),
                     Tensor(ssq), Tensor(sbk), Tensor(stl))
                     for i in range(nl)]
@@ -594,15 +630,18 @@ class ServingEngine:
                 # host-side harvest)
                 hsel = Tensor(h.data[0][last_idx][:, None, :])
                 logits = project(hsel)             # [max_batch, 1, V]
+                # the rows each held expert took, where the model has
+                # such layers (trace time: the plain step gains nothing)
+                rows = () if moe_rows is None else (moe_rows().data,)
             kps = tuple(c.k_pool.data for c in new_caches)
-            vps = tuple(c.v_pool.data for c in new_caches)
+            vps = tuple(data(c.v_pool) for c in new_caches)
             if kv_quant:
                 kss = tuple(c.k_scale.data for c in new_caches)
                 vss = tuple(c.v_scale.data for c in new_caches)
             else:
                 kss, vss = (), ()
             out = (logits.data[:, 0].astype(jnp.float32), kps, vps,
-                   kss, vss)
+                   kss, vss) + rows
             if not instrument:
                 return out
             # trace-time fill of the execution-order cell (jax pytrees
@@ -688,6 +727,7 @@ class ServingEngine:
         self._m_preempt = m["preemptions"]
         self._m_steps = m["steps"]
         self._m_rpa_steps = m["rpa_steps"]
+        self._m_moe_rows = m["moe_rows"]
         self._m_in_flight = m["in_flight"]
         self._m_kv_block_seconds = m["kv_block_seconds"]
         self._m_kv_headroom = m["kv_headroom"]
@@ -701,6 +741,8 @@ class ServingEngine:
         m["adapter_slots"].set(self.n_adapter_slots)
         m["adapter_slots_loaded"].set(len(self._adapters))
         self.cache.gauge_in_use()
+        for kind, n in self.cache.pool_bytes().items():
+            m["kv_pool_bytes"].set(n, kind=kind)     # fixed at construction
         self._register_memory_owners()
 
     def _register_memory_owners(self):
@@ -1095,10 +1137,9 @@ class ServingEngine:
                 jnp.asarray(maps.step_seq), jnp.asarray(maps.step_blk),
                 jnp.asarray(maps.step_tile), jnp.asarray(last_idx),
                 jnp.asarray(aid))
-            if step_fn is self._step:
-                logits, kps, vps, kss, vss = out
-            else:
-                logits, kps, vps, kss, vss, taps_out = out
+            if step_fn is not self._step:
+                out, taps_out = out[:-1], out[-1]
+            logits, kps, vps, kss, vss, *moe_rows = out
         except Exception as e:
             # RESOURCE_EXHAUSTED gets one postmortem (ledger owners +
             # the unified step's memory report) before re-raising into
@@ -1137,6 +1178,8 @@ class ServingEngine:
 
         leaf = self._leaf("serving.commit", n_step)
         leaf.begin()
+        if moe_rows:
+            self._publish_moe_rows(np.asarray(moe_rows[0]), leaf)
         tokens_out = 0
         for i, (seq, n, is_prefill) in enumerate(entries):
             if is_prefill:
@@ -1175,6 +1218,17 @@ class ServingEngine:
                 tokens_out += 1
         leaf.args["tokens_out"] = tokens_out
         leaf.end()
+
+    def _publish_moe_rows(self, rows: np.ndarray, leaf):
+        """``rows`` [layers, held experts]: the token rows the step's
+        routed experts took. On the commit span: their sum, the fullest
+        expert's and how many (layer, expert) pairs took any; in the
+        registry: every (layer, expert) count."""
+        leaf.args.update(moe_rows=int(rows.sum()), moe_max=int(rows.max()),
+                         moe_live=int(np.count_nonzero(rows)))
+        for layer, expert in zip(*np.nonzero(rows)):
+            self._m_moe_rows.inc(int(rows[layer, expert]),
+                                 layer=str(layer), expert=str(expert))
 
     def _commit_cached_blocks(self, seq: Request):
         """Register every newly-completed full block in the prefix

@@ -23,10 +23,13 @@ Three halves:
   token ran, and sequence writes land strictly beyond ``num_cached``,
   so an index entry stays valid until the allocator evicts the block.
 
-* :class:`PagedKVCache` — the device state: one ``[num_blocks + 1,
-  n_kv, block_size, hd]`` K pool and V pool per layer (the +1 row is
-  the null block at physical index 0; the layout is
-  ``ops/paged_attention.py``'s), threaded
+* :class:`PagedKVCache` — the device state, built from the layer spec
+  the served model states (``ops.paged_attention.LayerCacheSpec``:
+  heads, key width, value width, or "values are the first columns of
+  the key page"): per layer one ``[num_blocks + 1, kv_heads, block_size,
+  key_dim]`` K pool and, unless the layer's page is a latent one, a V
+  pool of ``value_dim`` (the +1 row is the null block at physical index
+  0; the layout is ``ops/paged_attention.py``'s), threaded
   functionally through the engine's compiled step (the jitted function
   takes the pools as inputs and returns the updated ones — nothing is
   mutated in place, so the executable never recompiles), plus the
@@ -349,17 +352,28 @@ class PrefixCache:
 
 
 class PagedKVCache:
-    """Per-layer block pools + the allocator + table-shaping helpers."""
+    """Per-layer block pools + the allocator + table-shaping helpers.
+
+    What a model must state: ``spec``, the
+    :class:`~paddle_tpu.ops.paged_attention.LayerCacheSpec` of its
+    attention layers (``model.kv_cache_spec()``): how many heads a page
+    holds, how wide a key row is, and either how wide a value row is or
+    that the values are the first ``value_cols`` columns of the key page
+    (a latent page: there is no V pool and ``v_pools`` holds None a
+    layer). Every layer keeps the same; layers of unequal pages are
+    ROADMAP D11."""
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
-                 num_kv_heads: int, head_dim: int,
-                 max_blocks_per_seq: Optional[int] = None,
+                 spec, max_blocks_per_seq: Optional[int] = None,
                  dtype=jnp.float32, prefix_cache: bool = False,
                  kv_dtype: Optional[str] = None):
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"kv_dtype={kv_dtype!r} (want None or 'int8')")
+        if kv_dtype is not None and spec.latent:
+            raise ValueError("int8 KV covers K/V pools, not latent pages")
+        self.spec = sp = spec
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -371,25 +385,35 @@ class PagedKVCache:
         #: storage dtype below may be narrower
         self.compute_dtype = jnp.dtype(dtype)
         self.kv_dtype = kv_dtype
-        # +1: physical block 0 is the null block and backs no sequence
-        shape = (num_blocks + 1, num_kv_heads, block_size, head_dim)
         store = jnp.int8 if kv_dtype == "int8" else dtype
-        self.k_pools = tuple(jnp.zeros(shape, store)
-                             for _ in range(num_layers))
-        self.v_pools = tuple(jnp.zeros(shape, store)
+
+        # +1: physical block 0 is the null block and backs no sequence
+        def pool(width):
+            return jnp.zeros((num_blocks + 1, sp.kv_heads, block_size,
+                              width), store)
+        self.k_pools = tuple(pool(sp.key_dim) for _ in range(num_layers))
+        self.v_pools = tuple(None if sp.latent else pool(sp.value_dim)
                              for _ in range(num_layers))
         if kv_dtype == "int8":
             # per-token-slot, per-head dequant multipliers, paged like
             # the pools themselves so block tables address both
-            sshape = (num_blocks + 1, num_kv_heads, block_size)
-            self.k_scales = tuple(jnp.zeros(sshape, jnp.float32)
-                                  for _ in range(num_layers))
-            self.v_scales = tuple(jnp.zeros(sshape, jnp.float32)
-                                  for _ in range(num_layers))
+            self.k_scales = tuple(
+                jnp.zeros((num_blocks + 1, sp.kv_heads, block_size),
+                          jnp.float32) for _ in range(num_layers))
+            self.v_scales = tuple(jnp.zeros_like(s) for s in self.k_scales)
         else:
             self.k_scales = ()
             self.v_scales = ()
         self._copy_fn = None  # lazily-jitted COW block copy
+
+    def pool_bytes(self) -> dict:
+        """Bytes the pools hold, by kind: ``latent`` (one-pool latent
+        pages) and ``kv`` (K and V pools, with their int8 scales)."""
+        out = {"latent": 0, "kv": 0}
+        out["latent" if self.spec.latent else "kv"] = sum(
+            int(p.nbytes) for p in self.k_pools + self.v_pools
+            + self.k_scales + self.v_scales if p is not None)
+        return out
 
     @property
     def max_seq_len(self) -> int:
@@ -418,7 +442,8 @@ class PagedKVCache:
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = NamedSharding(mesh, P(None, axis, None, None))
         self.k_pools = tuple(jax.device_put(p, sh) for p in self.k_pools)
-        self.v_pools = tuple(jax.device_put(p, sh) for p in self.v_pools)
+        self.v_pools = tuple(None if p is None else jax.device_put(p, sh)
+                             for p in self.v_pools)
         if self.k_scales:
             ssh = NamedSharding(mesh, P(None, axis, None))
             self.k_scales = tuple(jax.device_put(p, ssh)
@@ -435,10 +460,9 @@ class PagedKVCache:
 
         if self._copy_fn is None:
             def _copy(kps, vps, kss, vss, s, d):
-                return (tuple(p.at[d].set(p[s]) for p in kps),
-                        tuple(p.at[d].set(p[s]) for p in vps),
-                        tuple(p.at[d].set(p[s]) for p in kss),
-                        tuple(p.at[d].set(p[s]) for p in vss))
+                # a latent layer's v pool is None: tree_map passes it by
+                return jax.tree_util.tree_map(
+                    lambda p: p.at[d].set(p[s]), (kps, vps, kss, vss))
             donate = (0, 1, 2, 3) if jax.default_backend() == "tpu" else ()
             self._copy_fn = jax.jit(_copy, donate_argnums=donate)
         (self.k_pools, self.v_pools, self.k_scales,
@@ -450,10 +474,14 @@ class PagedKVCache:
     def export_block(self, block_id: int) -> Tuple[np.ndarray, np.ndarray]:
         """Host-stage one physical block's KV rows across every layer:
         returns ``(k, v)`` numpy arrays of shape ``[num_layers, n_kv,
-        block_size, hd]``. Device->host copy only — the caller
-        must hold a reference on ``block_id`` for the duration (the
-        fleet handoff claims one via ``reuse_cached`` before calling)."""
+        block_size, hd]``; latent pages give ``(rows, None)`` with rows
+        ``[num_layers, 1, block_size, key_dim]``. Device->host copy only
+        — the caller must hold a reference on ``block_id`` for the
+        duration (the fleet handoff claims one via ``reuse_cached``
+        before calling)."""
         k = np.stack([np.asarray(p[block_id]) for p in self.k_pools])
+        if self.spec.latent:
+            return k, None
         v = np.stack([np.asarray(p[block_id]) for p in self.v_pools])
         if self.kv_dtype == "int8":
             # wire format stays the compute dtype so handoffs work
@@ -465,9 +493,10 @@ class PagedKVCache:
             v = (v.astype(np.float32) * vs[..., None]).astype(cd)
         return k, v
 
-    def import_block(self, block_id: int, k: np.ndarray, v: np.ndarray):
+    def import_block(self, block_id: int, k: np.ndarray, v=None):
         """Write host-staged KV rows into physical ``block_id`` on this
-        replica (the inverse of :meth:`export_block`). One jitted
+        replica (the inverse of :meth:`export_block`; ``v`` None for
+        latent pages). One jitted
         row-set program for the cache's lifetime — destination id and
         rows are traced, so repeated handoffs reuse the executable.
         The caller owns ``block_id`` (freshly allocated) and registers
@@ -494,7 +523,7 @@ class PagedKVCache:
                 def _imp(kps, vps, kss, vss, kr, vr, d):
                     return (tuple(p.at[d].set(kr[i])
                                   for i, p in enumerate(kps)),
-                            tuple(p.at[d].set(vr[i])
+                            tuple(None if p is None else p.at[d].set(vr[i])
                                   for i, p in enumerate(vps)),
                             kss, vss)
             self._import_fn = jax.jit(_imp)
@@ -502,7 +531,8 @@ class PagedKVCache:
         (self.k_pools, self.v_pools, self.k_scales,
          self.v_scales) = self._import_fn(
             self.k_pools, self.v_pools, self.k_scales, self.v_scales,
-            jnp.asarray(k, dt), jnp.asarray(v, dt), jnp.int32(block_id))
+            jnp.asarray(k, dt), None if v is None else jnp.asarray(v, dt),
+            jnp.int32(block_id))
 
     def pad_block_table(self, block_ids: Sequence[int]) -> np.ndarray:
         """[max_blocks_per_seq] int32 row, null-padded."""

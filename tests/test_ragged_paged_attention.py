@@ -403,6 +403,61 @@ def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
     assert "rpa" in calls[0].split("=")[0], calls[0]
 
 
+def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
+    """The kernel's latent form (one 640-column pool, values its first 512
+    columns, 128 query heads on the one page) at the shapes of
+    ``openpangu-ultra-moe-718b-serve-ep16-l5`` compiles with Mosaic into one
+    ``rpa_mla`` custom call that reads the pool as it lies."""
+    import importlib
+    import json
+    import os
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    mod = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "openpangu-ultra-moe-718b-serve-ep16-l5.json")) as f:
+        cfg = json.load(f)
+    eng, heads = cfg["engine"], cfg["num_attention_heads"]
+    rank, row = cfg["kv_lora_rank"], \
+        cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    cols = -(-row // 128) * 128
+    tile = default_tile_q(heads, jnp.bfloat16)
+    tokens = -(-(eng["max_batch"] + eng["prefill_chunk"]) // tile) * tile
+    seqs = eng["max_batch"] + 1
+    items = rpa_max_items(tokens // tile, eng["max_batch"],
+                          eng["max_blocks_per_seq"])
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    monkeypatch.setattr(mod, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            lambda q, pool, bt, cu, ctx, ss, sb, st: ragged_paged_attention(
+                q, pool, None, bt, cu, ctx, ss, sb, st, sm_scale=0.07,
+                value_cols=rank)).lower(
+            arr((tokens, heads, cols), jnp.bfloat16),
+            arr((eng["max_blocks"] + 1, 1, eng["block_size"], cols),
+                jnp.bfloat16),
+            arr((seqs, eng["max_blocks_per_seq"]), jnp.int32),
+            arr((seqs + 1,), jnp.int32), arr((seqs,), jnp.int32),
+            arr((items,), jnp.int32), arr((items,), jnp.int32),
+            arr((tokens // tile + 1,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "rpa_mla" in calls[0]
+    # the 640-column pool reaches the kernel as it lies: no whole-pool copy
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f",1,{eng['block_size']},{cols}]" in ln]
+
+
 def test_impl_knob_resolution(monkeypatch):
     """auto = gather off-TPU; env and override win in that order."""
     monkeypatch.delenv("PADDLE_TPU_PAGED_ATTN_IMPL", raising=False)

@@ -20,6 +20,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.ops.paged_attention import LayerCacheSpec
 from paddle_tpu.serving import (BlockAllocator, Server, ServingEngine)
 from paddle_tpu.serving.scheduler import RequestState
 
@@ -137,7 +138,7 @@ def test_decode_outranks_prefill_for_the_last_block():
     from paddle_tpu.serving.scheduler import Request, Scheduler
 
     cache = PagedKVCache(num_layers=1, num_blocks=3, block_size=4,
-                         num_kv_heads=1, head_dim=4)
+                         spec=LayerCacheSpec.kv(1, 4))
     sch = Scheduler(cache, max_batch=2, prefill_chunk=4)
     a = Request(prompt_tokens=[1] * 8)   # older: running, block-boundary
     sch.add(a)
@@ -167,7 +168,7 @@ def test_multi_chunk_packing_and_budget():
     from paddle_tpu.serving.scheduler import Request, Scheduler
 
     cache = PagedKVCache(num_layers=1, num_blocks=32, block_size=4,
-                         num_kv_heads=1, head_dim=4)
+                         spec=LayerCacheSpec.kv(1, 4))
     sch = Scheduler(cache, max_batch=4, prefill_chunk=4, step_tokens=8)
     d = Request(prompt_tokens=[9] * 4)          # oldest: mid-decode
     sch.add(d)
@@ -216,7 +217,7 @@ def test_prefill_candidate_preempted_mid_loop_is_skipped():
     from paddle_tpu.serving.scheduler import Request, Scheduler
 
     cache = PagedKVCache(num_layers=1, num_blocks=3, block_size=4,
-                         num_kv_heads=1, head_dim=4)
+                         spec=LayerCacheSpec.kv(1, 4))
     sch = Scheduler(cache, max_batch=2, prefill_chunk=4, step_tokens=8)
     senior = Request(prompt_tokens=[1] * 4)
     sch.add(senior)
@@ -249,7 +250,7 @@ def test_evicted_plan_entry_goes_stale_not_corrupt():
     from paddle_tpu.serving.scheduler import Request, Scheduler
 
     cache = PagedKVCache(num_layers=1, num_blocks=2, block_size=4,
-                         num_kv_heads=1, head_dim=4)
+                         spec=LayerCacheSpec.kv(1, 4))
     sch = Scheduler(cache, max_batch=2, prefill_chunk=4, step_tokens=5)
     old = Request(prompt_tokens=[1] * 4)     # senior, needs 1 block
     sch.add(old)
