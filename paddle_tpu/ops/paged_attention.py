@@ -281,14 +281,53 @@ def write_tokens_to_pool(pool, new, block_tables, seq_ids, positions):
     """Scatter ``new`` [T, n_kv, hd] into ``pool`` at each token's
     ``positions`` through its sequence's block-table row. Padding tokens
     (sentinel ``seq_ids`` → the all-null table row) land in the null
-    block, exactly like the per-row form's invalid-token redirection.
-    Per-token dequant scales [T, n_kv] scatter into a scale pool
-    ``[num_blocks + 1, n_kv, block_size]`` through the same indices."""
-    bs, nblk = pool.shape[2], block_tables.shape[1]
-    blk = jnp.clip(positions.astype(jnp.int32) // bs, 0, nblk - 1)
-    phys = block_tables[seq_ids, blk]
-    slot = jnp.where(phys == 0, 0, positions.astype(jnp.int32) % bs)
-    return pool.at[phys, :, slot].set(new.astype(pool.dtype))
+    block's slot 0, so indices there repeat. Per-token dequant scales
+    [T, n_kv] scatter into a scale pool ``[num_blocks + 1, n_kv,
+    block_size]`` through the same indices, and a one-head latent pool
+    takes its rows as ``[T, 1, kd]``.
+
+    The pool is written as the flat table of rows it is — row
+    ``(block * n_kv + head) * block_size + slot`` — so the reshape moves
+    nothing and one row scatter updates it in place. Indexed as
+    ``pool.at[block, :, slot]`` XLA wants a token's ``[n_kv, hd]`` tile
+    contiguous and copies the whole pool to ``[block, slot, head, hd]``
+    and back around every write (docs/SERVING.md "Pool layout")."""
+    n_kv, bs = pool.shape[1:3]
+    nblk = block_tables.shape[1]
+    pos = positions.astype(jnp.int32)
+    phys = block_tables[seq_ids, jnp.clip(pos // bs, 0, nblk - 1)]
+    slot = jnp.where(phys == 0, 0, pos % bs)
+    rows = (phys[:, None] * n_kv + jnp.arange(n_kv, dtype=jnp.int32)) * bs \
+        + slot[:, None]
+    row = pool.shape[3:]              # (hd,), or () in a scale pool
+    return pool.reshape((-1,) + row).at[rows.reshape(-1)].set(
+        new.astype(pool.dtype).reshape((-1,) + row)).reshape(pool.shape)
+
+
+def _write_step_kv(pools, news, block_tables, seq_ids, positions):
+    """Write each of ``news`` into its pool (K and V, and with int8 pools
+    their scales). Under a model-parallel mesh the pools are sharded over
+    their head axis, which the flat view of a pool would merge away (GSPMD
+    would gather the pool to reshape it): there each shard flat-writes
+    its own heads into its own pool shard under ``shard_map``."""
+    def write(pools, news, bt, sid, pos):
+        return tuple(write_tokens_to_pool(p, n, bt, sid, pos)
+                     for p, n in zip(pools, news))
+
+    tp = _tp_mesh()
+    if tp is None:
+        return write(pools, news, block_tables, seq_ids, positions)
+    from jax.sharding import PartitionSpec as P
+    mesh, ax = tp
+
+    def over_heads(arrays):          # axis 1 of every pool and new is n_kv
+        return tuple(P(None, ax, *[None] * (a.ndim - 2)) for a in arrays)
+
+    return jax.shard_map(
+        write, mesh=mesh,
+        in_specs=(over_heads(pools), over_heads(news), P(), P(), P()),
+        out_specs=over_heads(pools), check_vma=False)(
+        pools, news, block_tables, seq_ids, positions)
 
 
 def quantize_kv_slots(x):
@@ -368,24 +407,17 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
     if k_scale is not None:
         kq, ks = quantize_kv_slots(k)
         vq, vs = quantize_kv_slots(v)
-        k_pool = write_tokens_to_pool(k_pool, kq, block_tables, seq_ids,
-                                      positions)
-        v_pool = write_tokens_to_pool(v_pool, vq, block_tables, seq_ids,
-                                      positions)
-        k_scale = write_tokens_to_pool(k_scale, ks, block_tables,
-                                       seq_ids, positions)
-        v_scale = write_tokens_to_pool(v_scale, vs, block_tables,
-                                       seq_ids, positions)
+        k_pool, v_pool, k_scale, v_scale = _write_step_kv(
+            (k_pool, v_pool, k_scale, v_scale), (kq, vq, ks, vs),
+            block_tables, seq_ids, positions)
         out = ragged_gather_attention(
             q, k_pool, v_pool, block_tables, seq_ids, positions,
             scale=scale, k_scale=k_scale, v_scale=v_scale)
         out = out.astype(q.dtype)
         return (out.reshape(T, n_heads * hd), k_pool, v_pool,
                 k_scale, v_scale)
-    k_pool = write_tokens_to_pool(k_pool, k, block_tables, seq_ids,
-                                  positions)
-    v_pool = write_tokens_to_pool(v_pool, v, block_tables, seq_ids,
-                                  positions)
+    k_pool, v_pool = _write_step_kv((k_pool, v_pool), (k, v), block_tables,
+                                    seq_ids, positions)
     if paged_attention_impl() == "rpa":
         from paddle_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention
@@ -427,22 +459,6 @@ def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
 
 
 # ===================== latent (MLA) pages ====================================
-def write_rows_to_latent_pool(pool, rows, block_tables, seq_ids, positions):
-    """Scatter ``rows`` [T, kd] into a one-head latent ``pool``
-    ``[num_blocks + 1, 1, block_size, kd]``. With one head a page is a
-    run of ``block_size`` rows, so the pool is written as the flat
-    ``[(num_blocks + 1) * block_size, kd]`` table it is (the reshape
-    moves nothing): one row scatter, padding tokens to the null block's
-    first row."""
-    nb1, _, bs, kd = pool.shape
-    nblk = block_tables.shape[1]
-    pos = positions.astype(jnp.int32)
-    phys = block_tables[seq_ids, jnp.clip(pos // bs, 0, nblk - 1)]
-    flat = jnp.where(phys == 0, 0, phys * bs + pos % bs)
-    return pool.reshape(nb1 * bs, kd).at[flat].set(
-        rows.astype(pool.dtype)).reshape(pool.shape)
-
-
 def ragged_latent_gather_attention(q, pool, block_tables, seq_ids,
                                    positions, *, value_cols, scale):
     """The gather fallback of the latent read (and the kernel's parity
@@ -471,8 +487,8 @@ def ragged_latent_attention_step(q, rows, pool, block_tables, cu_seqlens,
     by :func:`paged_attention_impl`. Returns ``(u [T, n_heads,
     value_cols], pool')``; ``u`` at padding tokens is garbage (gather)
     or 0 (rpa), as in :func:`ragged_paged_attention_step`."""
-    pool = write_rows_to_latent_pool(pool, rows, block_tables, seq_ids,
-                                     positions)
+    pool = write_tokens_to_pool(pool, rows[:, None, :], block_tables, seq_ids,
+                                positions)
     if paged_attention_impl() == "rpa":
         if _tp_mesh() is not None:
             raise NotImplementedError(

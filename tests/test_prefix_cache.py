@@ -287,3 +287,37 @@ def test_tensor_parallel_mp2_token_parity():
         eng.cache.allocator.assert_no_leaks()
     finally:
         set_mesh(prev)
+
+
+@pytest.mark.parametrize("engine_kw", [
+    {"attn_impl": "gather"}, {"attn_impl": "rpa"}, {"kv_dtype": "int8"}],
+    ids=["gather", "rpa", "int8_kv"])
+def test_tensor_parallel_step_gathers_no_pool(engine_kw):
+    """Under an mp mesh each shard writes the step's rows into its own
+    heads of its own pool shard: the compiled step holds no all-gather
+    whose result has a pool's (or a scale pool's) full shape. The flat
+    view of a pool merges the sharded head axis; written under GSPMD
+    alone, every pool would be gathered to be reshaped."""
+    import re
+
+    import jax
+
+    from paddle_tpu.distributed import get_mesh, init_mesh, set_mesh
+
+    if jax.device_count() < 2:
+        pytest.skip("needs >= 2 devices")
+    prev = get_mesh()
+    try:
+        mesh = init_mesh({"mp": 2}, devices=jax.devices()[:2])
+        eng = ServingEngine(_tiny(12, tensor_parallel=True), max_batch=2,
+                            max_blocks=16, block_size=BS, prefill_chunk=4,
+                            mesh=mesh, **engine_kw)
+        text = eng.compiled_hlo()
+    finally:
+        set_mesh(prev)
+    pools = eng.cache.k_pools + eng.cache.k_scales
+    assert all(p.sharding.spec[1] == "mp" for p in pools)
+    full = {",".join(map(str, p.shape)) for p in pools}
+    gathered = re.findall(r"= \w+\[([\d,]+)\]\S* all-gather(?:-start)?\(",
+                          text)
+    assert not full & set(gathered), sorted(full & set(gathered))

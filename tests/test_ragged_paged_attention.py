@@ -16,8 +16,16 @@ shapes — the 870s tier-1 cutoff counts dots):
 * the kernel's dynamic grid bound: parity where the work list is short,
   long, absent for whole tiles or names shared pages, in both forms the
   wrapper takes, and a Mosaic compile for a described v5e at the serving
-  cell's shapes.
+  cell's shapes;
+* the step's K/V write: the one writer against a numpy loop for every
+  pool form it serves, and the compiled step holds no instruction of a
+  pool's shape but parameter, write and bitcast (no whole-pool copy).
 """
+import contextlib
+import json
+import os
+import re
+
 import numpy as np
 import pytest
 
@@ -27,6 +35,7 @@ import paddle_tpu as pt
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.ops.paged_attention import (
     impl_override, paged_attention_impl, ragged_gather_attention,
+    ragged_latent_attention_step, ragged_paged_attention_step,
     write_tokens_to_pool)
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     build_step_maps, default_tile_q, ragged_paged_attention, rpa_max_items,
@@ -330,6 +339,81 @@ def test_step_maps_stay_inside_the_static_bound(packing):
         assert maps.walked == maps.live == mbps * spans <= bound
 
 
+# ---------------- the step's K/V write -----------------------------------------
+def _write_case(pool_shape, dtype, seqs, table, mbps=4, pad=0):
+    """A token-packed write: ``seqs`` lists ``(new_len, context_len)``,
+    ``table`` each sequence's physical pages; ``pad`` padding tokens
+    follow (sentinel sequence, the all-null table row)."""
+    bt = np.zeros((len(seqs) + 1, mbps), np.int32)
+    for s, pages in enumerate(table):
+        bt[s, :len(pages)] = pages
+    sid = np.concatenate([np.full(n, s, np.int32)
+                          for s, (n, _) in enumerate(seqs)]
+                         + [np.full(pad, len(seqs), np.int32)])
+    pos = np.concatenate([c + np.arange(n, dtype=np.int32)
+                          for n, c in seqs] + [np.zeros(pad, np.int32)])
+    return dict(pool_shape=pool_shape, dtype=dtype, bt=bt, sid=sid, pos=pos)
+
+
+_WRITE_CASES = {
+    # the serving cell's page: 8 kv heads, 128 slots, 128 wide, bf16
+    "bf16_kv_8x128x128": _write_case(
+        (6, 8, 128, 128), jnp.bfloat16, [(5, 120), (1, 3)],
+        [[2, 4], [1]], pad=2),
+    "int8_values": _write_case(
+        (9, 2, 8, 16), jnp.int8, [(6, 5), (1, 17)], [[3, 1], [5, 6, 7]]),
+    "f32_scale_pool_3d": _write_case(
+        (9, 2, 8), jnp.float32, [(6, 5), (1, 17)], [[3, 1], [5, 6, 7]]),
+    "latent_one_head_640": _write_case(
+        (7, 1, 8, 640), jnp.bfloat16, [(9, 4), (1, 0)], [[6, 2], [3]],
+        pad=6),
+    "padding_only": _write_case(
+        (5, 2, 8, 16), jnp.float32, [], [], pad=8),
+    # both name page 3 as their first page and write behind it
+    "shared_prefix_page": _write_case(
+        (9, 2, 8, 16), jnp.float32, [(3, 8), (2, 10)], [[3, 1], [3, 2]]),
+    "chunk_across_pages": _write_case(
+        (9, 2, 8, 16), jnp.float32, [(13, 6)], [[4, 8, 2]]),
+    "last_page_of_the_table": _write_case(
+        (9, 2, 8, 16), jnp.float32, [(1, 31), (2, 29)],
+        [[1, 2, 3, 4], [5, 6, 7, 8]]),
+}
+
+
+@pytest.mark.parametrize("name", list(_WRITE_CASES))
+def test_writer_matches_numpy_loop(name):
+    """``write_tokens_to_pool`` against a loop over tokens, for every
+    form of pool the step writes (K/V pages, int8 values and their 3-D
+    scale pools, a one-head latent page) and the index cases that can go
+    wrong in the flat row arithmetic. Outside the null block the pool is
+    the loop's, bit for bit; in it only slot 0 may change."""
+    c = _WRITE_CASES[name]
+    rng = np.random.RandomState(len(name))
+    shape = c["pool_shape"]
+    nb1, n_kv, bs = shape[:3]
+    T = len(c["sid"])
+
+    def draw(sh):
+        x = rng.randn(*sh) * 4
+        return np.asarray(jnp.asarray(x).astype(c["dtype"]))
+
+    pool, new = draw(shape), draw((T, n_kv) + shape[3:])
+    want = pool.copy()
+    for t in range(T):
+        if c["sid"][t] < len(c["bt"]) - 1:        # not a padding token
+            page = c["bt"][c["sid"][t], c["pos"][t] // bs]
+            assert page > 0
+            want[page, :, c["pos"][t] % bs] = new[t]
+    got = np.asarray(write_tokens_to_pool(
+        jnp.asarray(pool), jnp.asarray(new), jnp.asarray(c["bt"]),
+        jnp.asarray(c["sid"]), jnp.asarray(c["pos"])))
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    np.testing.assert_array_equal(got[1:], want[1:])
+    np.testing.assert_array_equal(got[0, :, 1:], pool[0, :, 1:])
+    if T and (c["sid"] == len(c["bt"]) - 1).all():
+        assert (got[0, :, 0] != pool[0, :, 0]).any()   # padding landed there
+
+
 @pytest.fixture(scope="module")
 def v5e_chip():
     """A described TPU v5e to compile for (nothing runs). Inside a
@@ -347,20 +431,33 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _compiling_for_the_chip(monkeypatch):
+    """Interpret mode off, and no persistent cache entry (it could not be
+    read back): as tests/benchmark/test_kernel_trace_names.py."""
+    import importlib
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    mod = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    monkeypatch.setattr(mod, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
         v5e_chip, monkeypatch):
     """The dynamic grid bound lowers for the chip: the kernel at the
     serving cell's shapes (``benchmark/configs``), fed the flat list the
     engine builds, compiles with Mosaic into one ``rpa`` custom call. A
     lowering error of the traced bound shows here, on a CPU."""
-    import importlib
-    import json
-    import os
     import jax
-    from jax.experimental.compilation_cache import compilation_cache
-    # (the package exports the function under the module's name)
-    mod = importlib.import_module(
-        "paddle_tpu.ops.pallas.ragged_paged_attention")
 
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
                            "configs",
@@ -381,22 +478,13 @@ def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
 
     pool = arr((eng["max_blocks"] + 1, kv, eng["block_size"], hd),
                jnp.bfloat16)
-    # interpret mode off, and no persistent cache entry (it could not be
-    # read back): as tests/benchmark/test_kernel_trace_names.py
-    monkeypatch.setattr(mod, "_interpret", lambda: False)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compiling_for_the_chip(monkeypatch):
         text = jax.jit(ragged_paged_attention).lower(
             arr((tokens, heads, hd), jnp.bfloat16), pool, pool,
             arr((seqs, eng["max_blocks_per_seq"]), jnp.int32),
             arr((seqs + 1,), jnp.int32), arr((seqs,), jnp.int32),
             arr((items,), jnp.int32), arr((items,), jnp.int32),
             arr((tokens // tile + 1,), jnp.int32)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
     calls = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1, calls
@@ -408,13 +496,8 @@ def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
     columns, 128 query heads on the one page) at the shapes of
     ``openpangu-ultra-moe-718b-serve-ep16-l5`` compiles with Mosaic into one
     ``rpa_mla`` custom call that reads the pool as it lies."""
-    import importlib
-    import json
-    import os
     import jax
-    from jax.experimental.compilation_cache import compilation_cache
-    mod = importlib.import_module(
-        "paddle_tpu.ops.pallas.ragged_paged_attention")
+
     with open(os.path.join(
             os.path.dirname(__file__), "..", "benchmark", "configs",
             "openpangu-ultra-moe-718b-serve-ep16-l5.json")) as f:
@@ -432,11 +515,7 @@ def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
-    monkeypatch.setattr(mod, "_interpret", lambda: False)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _compiling_for_the_chip(monkeypatch):
         text = jax.jit(
             lambda q, pool, bt, cu, ctx, ss, sb, st: ragged_paged_attention(
                 q, pool, None, bt, cu, ctx, ss, sb, st, sm_scale=0.07,
@@ -448,14 +527,99 @@ def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
             arr((seqs + 1,), jnp.int32), arr((seqs,), jnp.int32),
             arr((items,), jnp.int32), arr((items,), jnp.int32),
             arr((tokens // tile + 1,), jnp.int32)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 1 and "rpa_mla" in calls[0]
     # the 640-column pool reaches the kernel as it lies: no whole-pool copy
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and f",1,{eng['block_size']},{cols}]" in ln]
+
+
+def _pool_shaped(text, pool_shape):
+    """Opcode of every instruction of the compiled ``text`` whose result
+    is a pool, as it lies or as the flat table of rows the writer sees."""
+    flat = (int(np.prod(pool_shape[:3])), pool_shape[3])
+    shapes = tuple(f"bf16[{','.join(map(str, sh))}]"
+                   for sh in (pool_shape, flat))
+    ops = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = (\S+) ([\w\-]+)\(", ln)
+        if m and m.group(1).startswith(shapes):
+            ops.append(m.group(2))
+    return ops
+
+
+@pytest.mark.parametrize("cell", ["mistral-7b-v0.3-serve-l16",
+                                  "openpangu-ultra-moe-718b-serve-ep16-l5"])
+def test_step_writes_its_pools_in_place_at_serving_shapes(
+        cell, v5e_chip, monkeypatch):
+    """Two layers of the serving step's attention at a cell's shapes
+    (``benchmark/configs``), pools donated, compiled for the chip: each
+    pool is a parameter, bitcast to the flat table of rows, updated by one
+    row scatter in its write fusion and bitcast back for the kernel. No
+    ``copy`` nor any other instruction has a pool's shape: the write
+    moves the step's rows and nothing else."""
+    import jax
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", cell + ".json")) as f:
+        cfg = json.load(f)
+    eng, heads = cfg["engine"], cfg["num_attention_heads"]
+    latent = "kv_lora_rank" in cfg
+    if latent:
+        kv, grp = 1, heads
+        hd = -(-(cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]) // 128) * 128
+    else:
+        kv = cfg["num_key_value_heads"]
+        grp, hd = heads // kv, cfg["head_dim"]
+    tile = default_tile_q(grp, jnp.bfloat16)
+    tokens = -(-(eng["max_batch"] + eng["prefill_chunk"]) // tile) * tile
+    seqs = eng["max_batch"] + 1
+    items = rpa_max_items(tokens // tile, eng["max_batch"],
+                          eng["max_blocks_per_seq"])
+    pool_shape = (eng["max_blocks"] + 1, kv, eng["block_size"], hd)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    meta = (arr((seqs, eng["max_blocks_per_seq"])), arr((seqs + 1,)),
+            arr((seqs,)), arr((tokens,)), arr((tokens,)), arr((items,)),
+            arr((items,)), arr((tokens // tile + 1,)))
+    q = arr((tokens, heads, hd), jnp.bfloat16)
+    pool = arr(pool_shape, jnp.bfloat16)
+
+    # a fresh function a case: jax.jit reuses a trace by the function's
+    # identity, and the trace holds the impl it was made under
+    if latent:
+        new = (arr((tokens, hd), jnp.bfloat16),)
+        pools = (pool, pool)
+
+        def step(q, rows, p0, p1, *meta):
+            kw = dict(value_cols=cfg["kv_lora_rank"], scale=0.07)
+            u, p0 = ragged_latent_attention_step(q, rows, p0, *meta, **kw)
+            u = jnp.pad(u, ((0, 0), (0, 0), (0, hd - u.shape[-1])))
+            u, p1 = ragged_latent_attention_step(u, rows * 2, p1, *meta, **kw)
+            return u, p0, p1
+    else:
+        new = (arr((tokens, kv, hd), jnp.bfloat16),) * 2
+        pools = (pool,) * 4
+
+        def step(q, k, v, kp0, vp0, kp1, vp1, *meta):
+            o, kp0, vp0 = ragged_paged_attention_step(q, k, v, kp0, vp0, *meta)
+            o, kp1, vp1 = ragged_paged_attention_step(
+                o.reshape(q.shape), k * 2, v * 2, kp1, vp1, *meta)
+            return o, kp0, vp0, kp1, vp1
+
+    donate = tuple(range(1 + len(new), 1 + len(new) + len(pools)))
+    with _compiling_for_the_chip(monkeypatch), impl_override("rpa"):
+        text = jax.jit(step, donate_argnums=donate).lower(
+            q, *new, *pools, *meta).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2, calls
+    assert all(("rpa_mla" if latent else "rpa") in c.split("=")[0]
+               for c in calls), calls
+    ops = _pool_shaped(text, pool_shape)
+    assert set(ops) <= {"parameter", "bitcast", "fusion", "scatter"}, ops
+    assert ops.count("scatter") == ops.count("fusion") == len(pools), ops
 
 
 def test_impl_knob_resolution(monkeypatch):
