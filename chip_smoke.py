@@ -407,9 +407,6 @@ def multichip_leg(one_chip_first_loss):
 
 
 def run():
-    if os.environ.get("PADDLE_TPU_PAGED_ATTN_IMPL"):
-        raise SmokeFailure("unset PADDLE_TPU_PAGED_ATTN_IMPL: the smoke "
-                           "proves the reader the code picks")
     t_start = time.perf_counter()
     dev = describe_backend()
 

@@ -51,7 +51,8 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .findings import Finding, P0, P1
-from .hlo import COLLECTIVE_STEMS, _balanced_braces, shape_bytes
+from .hlo import (COLLECTIVE_STEMS, COMMENT_RE, _balanced_braces,
+                  shape_bytes)
 
 __all__ = ["Collective", "MeshInfo", "parse_collectives", "map_axes",
            "wire_bytes", "comm_ledger", "CommReport", "audit_comm",
@@ -116,6 +117,8 @@ class Collective:
     computation: str                # enclosing computation (% stripped)
     entry: bool                     # lives in the ENTRY computation
     payload_bytes: int              # see module doc (tuple handling)
+    payloads: int = 1               # buffers moved: >1 in a variadic
+    #                                 all-reduce (XLA's combiner)
     groups: Optional[List[List[int]]] = None   # decoded replica groups
     pairs: Optional[List[Tuple[int, int]]] = None  # source_target_pairs
     channel_id: Optional[int] = None
@@ -203,9 +206,11 @@ def _parse_pairs(line: str) -> Optional[List[Tuple[int, int]]]:
 
 
 def _result_bytes(result: str, kind: str, is_start: bool) -> int:
-    """Payload bytes from the result type. A plain tuple all-to-all
-    moves every element (sum); a ``-start`` tuple is (operand, dest,
-    context...) — the destination (largest element) is the payload."""
+    """Payload bytes from the result type. A plain tuple all-to-all and
+    a variadic all-reduce (sync or ``-start``: its result has its
+    operands' shapes) move every element (sum); any other ``-start``
+    tuple is (operand, dest, context...) — the destination (largest
+    element) is the payload."""
     shapes = [(d, c) for d, c in _SHAPE_TOK_RE.findall(result)]
     if not shapes:
         return 0
@@ -213,7 +218,7 @@ def _result_bytes(result: str, kind: str, is_start: bool) -> int:
         d, c = shapes[0]
         return shape_bytes(d, c)
     sizes = [shape_bytes(d, c) for d, c in shapes]
-    if kind == "all-to-all" and not is_start:
+    if kind == "all-reduce" or (kind == "all-to-all" and not is_start):
         return sum(sizes)
     return max(sizes)
 
@@ -229,6 +234,7 @@ def parse_collectives(hlo_text: str) -> List[Collective]:
             computation = cm.group(2).lstrip("%")
             entry = bool(cm.group(1))
             continue
+        raw = COMMENT_RE.sub("", raw)
         m = _COLL_RE.match(raw)
         if not m:
             continue
@@ -246,6 +252,8 @@ def parse_collectives(hlo_text: str) -> List[Collective]:
         out.append(Collective(
             kind=kind, name=name, computation=computation, entry=entry,
             payload_bytes=_result_bytes(result, kind, suffix == "-start"),
+            payloads=len(_SHAPE_TOK_RE.findall(result))
+            if kind == "all-reduce" else 1,
             groups=_parse_groups(raw), pairs=_parse_pairs(raw),
             channel_id=int(ch.group(1)) if ch else None,
             use_global_ids=bool(gl and gl.group(1) == "true"),
@@ -368,7 +376,10 @@ def comm_ledger(collectives: List[Collective],
                 mesh: Optional[MeshInfo]) -> Dict[str, dict]:
     """Aggregate per mesh-axis key (``"dp"``, ``"dp+mp"`` for a group
     varying on both, ``"none"`` for degenerate single-member groups):
-    op count, wire bytes/step, per-kind counts, hop class."""
+    op count, wire bytes/step, per-kind counts, hop class. A variadic
+    all-reduce counts once per buffer it reduces, as in
+    ``hlo.collective_census``: the pins hold whether or not XLA's
+    combiner packed the reductions into one instruction."""
     out: Dict[str, dict] = {}
     for c in collectives:
         axes, exact, crosses = map_axes(c, mesh)
@@ -376,9 +387,9 @@ def comm_ledger(collectives: List[Collective],
         slot = out.setdefault(key, {
             "ops": 0, "bytes": 0, "kinds": {}, "hops": "ici",
             "inexact_groups": 0})
-        slot["ops"] += 1
+        slot["ops"] += c.payloads
         slot["bytes"] += wire_bytes(c)
-        slot["kinds"][c.kind] = slot["kinds"].get(c.kind, 0) + 1
+        slot["kinds"][c.kind] = slot["kinds"].get(c.kind, 0) + c.payloads
         if crosses:
             slot["hops"] = "dcn"
         if not exact:

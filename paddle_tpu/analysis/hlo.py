@@ -45,6 +45,9 @@ _OP_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*"
     r"(?:\(.*?\)|([a-z][a-z0-9]*)\[([0-9,]*)\](?:\{[^}]*\})?)\s*"
     r"([a-z][a-z0-9\-]*)\(")
+#: XLA interleaves ``/*index=5*/`` position comments into long tuple
+#: types and layouts; their ``=`` ends a result type early for a pattern
+COMMENT_RE = re.compile(r"/\*.*?\*/")
 _ALIAS_ENTRY_RE = re.compile(r"\{[0-9,\s]*\}:\s*\((\d+)")
 _ENTRY_RE = re.compile(r"entry_computation_layout=\{\((.*?)\)->")
 _METADATA_RE = re.compile(
@@ -128,8 +131,7 @@ def parse_entry_params(hlo_text: str) -> List[Tuple[str, Tuple[int, ...],
     m = _ENTRY_RE.search(hlo_text)
     if not m:
         return []
-    # XLA interleaves /*index=N*/ position comments into long layouts
-    body = re.sub(r"/\*.*?\*/", "", m.group(1))
+    body = COMMENT_RE.sub("", m.group(1))
     out = []
     for tok in _split_top(body):
         sm = _SHAPE_RE.match(tok)
@@ -169,12 +171,20 @@ def donated_params(hlo_text: str) -> set:
 
 
 def collective_census(hlo_text: str) -> Dict[str, int]:
-    """Instruction count per collective stem. Async pairs count once
-    (``-start`` carries the payload; ``-done`` is just the wait)."""
+    """Count per collective stem. Async pairs count once (``-start``
+    carries the payload; ``-done`` is just the wait). An all-reduce
+    counts once per buffer it reduces: XLA's combiner packs independent
+    all-reduces into one variadic instruction with a tuple result, more
+    of them on a small program than a large one, and the contracts read
+    here (``buckets + 1`` payloads, a per-parameter storm) are about what
+    is reduced, not how the compiler grouped it."""
     census = {stem: 0 for stem in COLLECTIVE_STEMS}
+    hlo_text = COMMENT_RE.sub("", hlo_text)
     for stem in COLLECTIVE_STEMS:
-        census[stem] = len(re.findall(
-            rf"= [^=]*\b{stem}(?:-start)?\(", hlo_text))
+        for result in re.findall(
+                rf"= ([^=]*)\b{stem}(?:-start)?\(", hlo_text):
+            census[stem] += len(_SHAPE_RE.findall(result)) \
+                if stem == "all-reduce" else 1
     return census
 
 
@@ -185,15 +195,22 @@ def upcast_ops(hlo_text: str, min_bytes: int = 0,
     bandwidth for an intermediate it never asked for). ``ops`` reuses
     a prior :func:`iter_ops` parse (the text can be tens of MB on the
     chip geometry)."""
-    out = []
+    out, dtype_of = [], {}
     for op in (iter_ops(hlo_text) if ops is None else ops):
+        name = re.match(r"(?:ROOT\s+)?(%[\w.\-]+)", op.line)
+        if name:
+            dtype_of[name.group(1)] = op.dtype
         if op.opcode != "convert" or op.dtype not in ("f32", "f64"):
             continue
         if op.nbytes < min_bytes:
             continue
-        # operand dtype rides the line: convert(bf16[...] %x)
-        m = re.search(r"convert\(([a-z][a-z0-9]*)\[", op.line)
-        if not m or m.group(1) not in ("bf16", "f16", "f8e4m3fn", "f8e5m2"):
+        # the operand's dtype rides the line (``convert(bf16[...] %x)``)
+        # or, where XLA prints operands bare (``convert(%x)``), is that
+        # of the operand's own definition earlier in its computation
+        m = re.search(r"convert\((?:([a-z][a-z0-9]*)\[\S* )?(%[\w.\-]+)",
+                      op.line)
+        if not m or (m.group(1) or dtype_of.get(m.group(2))) not in (
+                "bf16", "f16", "f8e4m3fn", "f8e5m2"):
             continue
         out.append(op)
     return out

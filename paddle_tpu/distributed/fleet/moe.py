@@ -376,8 +376,8 @@ class HeldExpertsLayer(Layer):
     expert (those to absent experts last) and each held expert multiplies
     exactly its rows, a ragged grouped product (``jax.lax.ragged_dot``,
     XLA's own grouped kernel on a TPU: at 16 experts of 7680 x 2048 and
-    8,320 sorted rows it took 0.8-1.0 ms a product where
-    a column-tiled ``ops.pallas.grouped_matmul.gmm`` took 2.3 and 7.0 ms, PR 27).
+    8,320 sorted rows it took 0.8-1.0 ms a product where a column-tiled
+    Pallas grouped matmul took 2.3 and 7.0 ms, PR 27).
 
     ``forward`` leaves the rows each held expert took, ``[len(held)]``
     int32, in ``self.last_rows`` (a traced value inside a compiled step:

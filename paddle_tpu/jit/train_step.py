@@ -464,6 +464,16 @@ class TrainStep:
                     tap_order[:] = list(col.taps)
                 return val, (out_bufs, col.taps)
 
+            # the replicated parameters are cast to varying over dp
+            # BEFORE the grad: differentiating through the implicit cast
+            # would have autodiff psum each parameter's gradient over dp
+            # (the cast's transpose), and the buckets' pmean below would
+            # reduce a second time what is already the SUM over shards:
+            # per-parameter all-reduces back in the program, and
+            # gradients dp times too large. check_vma stays on, so what
+            # leaves under out_specs=P() is proven replicated.
+            train = jax.tree_util.tree_map(
+                lambda a: jax.lax.pcast(a, "dp", to="varying"), train)
             (loss_val, (new_bufs, taps)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train)
             if instrument:

@@ -29,8 +29,7 @@ from paddle_tpu import ops
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
 from paddle_tpu.observability import numerics
-from paddle_tpu.ops.paged_attention import (PagedLayerCache,
-                                            RaggedLayerCache)
+from paddle_tpu.ops.paged_attention import RaggedLayerCache
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM"]
 
@@ -202,17 +201,12 @@ class LlamaAttention(nn.Layer):
         equal ids, which is exactly the 1/0 padding form generalized) and
         ``position_ids`` restarts at 0 inside each document.
 
-        A :class:`~paddle_tpu.ops.paged_attention.PagedLayerCache` takes
-        the BLOCK-PAGED path (the continuous-batching serving engine's
-        cache form): per-row positions from ``context_lens``, scatter into
-        the shared block pools, gather-based attention over each row's
-        block table. A
-        :class:`~paddle_tpu.ops.paged_attention.RaggedLayerCache` is the
-        TOKEN-PACKED form of the same pools (the engine's one unified
-        prefill+decode step): ``x`` is ``[1, total_tokens, hidden]``,
-        per-token RoPE positions come from the cache, and the read path
-        is the Ragged-Paged-Attention Pallas kernel (or its gather
-        fallback — ``ops/paged_attention.py``'s impl knob)."""
+        A :class:`~paddle_tpu.ops.paged_attention.RaggedLayerCache` is the
+        block-paged cache in its TOKEN-PACKED form (the serving engine's
+        one unified prefill+decode step): ``x`` is ``[1, total_tokens,
+        hidden]``, per-token RoPE positions come from the cache, and the
+        cache writes the step's K/V and reads its pages itself
+        (``ops/paged_attention.attend``)."""
         if isinstance(cache, RaggedLayerCache):
             if attention_mask is not None or pos_offsets is not None \
                     or position_ids is not None:
@@ -220,13 +214,6 @@ class LlamaAttention(nn.Layer):
                     "the ragged paged path derives per-token positions "
                     "and key liveness from the cache itself")
             return self._ragged_paged_forward(x, cache)
-        if isinstance(cache, PagedLayerCache):
-            if attention_mask is not None or pos_offsets is not None:
-                raise NotImplementedError(
-                    "the paged path derives per-row positions and key "
-                    "liveness from the cache itself; attention_mask/"
-                    "pos_offsets do not apply")
-            return self._paged_forward(x, cache)
         if cache is not None and position_ids is not None:
             raise NotImplementedError(
                 "position_ids is a cacheless (packed training) argument")
@@ -351,52 +338,12 @@ class LlamaAttention(nn.Layer):
                                  op_name="static_kv_attention")
         return self.o_proj(out), (kb2, vb2, pos + S)
 
-    def _paged_forward(self, x, cache):
-        """Block-paged KV attention (the ``serving.ServingEngine`` path):
-        RoPE at per-row traced positions (``context_lens``), scatter the
-        new K/V into the shared block pools, masked gather-attention over
-        each row's block table (ops/paged_attention.py). Shapes are
-        independent of any sequence's length, so one executable serves
-        every mix of requests. Cache position is HOST-managed: the
-        returned cache carries the same ``context_lens`` — the engine
-        advances them after harvesting valid tokens."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.ops import paged_attention as pa
-
-        B, S = x.shape[0], x.shape[1]
-        q = ops.reshape(self.q_proj(x), [B, S, self.n_heads, self.head_dim])
-        k = ops.reshape(self.k_proj(x), [B, S, self.n_kv, self.head_dim])
-        v = ops.reshape(self.v_proj(x), [B, S, self.n_kv, self.head_dim])
-        hd = self.head_dim
-        theta = self.cfg.rope_theta
-        table_len = self.cfg.max_position_embeddings
-        scale = 1.0 / math.sqrt(hd)
-
-        def f(qa, ka, va, kp, vp, bt, ctx, nlen):
-            pos = ctx[:, None].astype(jnp.int32) + \
-                jnp.arange(S, dtype=jnp.int32)[None, :]
-            cos, sin = _gather_rope(jnp.clip(pos, 0, table_len - 1), hd,
-                                    theta, str(qa.dtype), table_len)
-            return pa.paged_attention_step(
-                _rot_interleaved(qa, cos, sin),
-                _rot_interleaved(ka, cos, sin), va, kp, vp,
-                bt, ctx, nlen, scale=scale)
-
-        out, kp2, vp2 = apply_op(
-            f, q, k, v, cache.k_pool, cache.v_pool, cache.block_tables,
-            cache.context_lens, cache.new_lens, op_name="paged_kv_attention")
-        return self.o_proj(out), pa.PagedLayerCache(
-            kp2, vp2, cache.block_tables, cache.context_lens, cache.new_lens)
-
     def _ragged_paged_forward(self, x, cache):
         """Token-packed block-paged attention (the unified serving
         step): ``x`` [1, T, hidden] carries every scheduled sequence's
         new tokens back to back; RoPE at the cache's per-token absolute
-        positions; scatter the new K/V into the shared pools; then the
-        RPA Pallas kernel (or gather fallback) streams each sequence's
-        real pages (ops/paged_attention.py dispatches on the impl knob
-        at trace time)."""
+        positions; the cache then writes the new K/V into its pools and
+        reads each sequence's pages (``ops/paged_attention.attend``)."""
         import jax.numpy as jnp
 
         from paddle_tpu.ops import paged_attention as pa
@@ -410,56 +357,21 @@ class LlamaAttention(nn.Layer):
         table_len = self.cfg.max_position_embeddings
         scale = 1.0 / math.sqrt(hd)
 
-        if cache.k_scale is not None:
-            # int8-KV pools (ISSUE 20): the step quantizes the fresh
-            # K/V per (token, head) and threads the scale pools
-            # alongside the value pools
-            def fq(qa, ka, va, kp, vp, ksc, vsc, bt, cu, ctx, sid, pos,
-                   ssq, sbk, stl):
-                pidx = jnp.clip(pos.astype(jnp.int32), 0, table_len - 1)
-                cos, sin = _gather_rope(pidx[None, :], hd, theta,
-                                        str(qa.dtype), table_len)
-                cos, sin = cos[0], sin[0]
-                return pa.ragged_paged_attention_step(
-                    _rot_interleaved(qa, cos, sin),
-                    _rot_interleaved(ka, cos, sin), va, kp, vp,
-                    bt, cu, ctx, sid, pos, ssq, sbk, stl, scale=scale,
-                    k_scale=ksc, v_scale=vsc)
-
-            out, kp2, vp2, ks2, vs2 = apply_op(
-                fq, q, k, v, cache.k_pool, cache.v_pool, cache.k_scale,
-                cache.v_scale, cache.block_tables, cache.cu_seqlens,
-                cache.context_lens, cache.seq_ids, cache.positions,
-                cache.step_seq, cache.step_blk, cache.step_tile,
-                op_name="ragged_paged_kv_attention_int8")
-            return self.o_proj(ops.reshape(out, [1, T, -1])), \
-                pa.RaggedLayerCache(
-                    kp2, vp2, cache.block_tables, cache.cu_seqlens,
-                    cache.context_lens, cache.seq_ids, cache.positions,
-                    cache.step_seq, cache.step_blk, cache.step_tile,
-                    ks2, vs2)
-
-        def f(qa, ka, va, kp, vp, bt, cu, ctx, sid, pos, ssq, sbk, stl):
-            pidx = jnp.clip(pos.astype(jnp.int32), 0, table_len - 1)
+        def f(qa, ka, va, c):
+            pidx = jnp.clip(c.positions.astype(jnp.int32), 0, table_len - 1)
             cos, sin = _gather_rope(pidx[None, :], hd, theta,
                                     str(qa.dtype), table_len)
             cos, sin = cos[0], sin[0]          # [T, 1, hd/2]
-            return pa.ragged_paged_attention_step(
-                _rot_interleaved(qa, cos, sin),
-                _rot_interleaved(ka, cos, sin), va, kp, vp,
-                bt, cu, ctx, sid, pos, ssq, sbk, stl, scale=scale)
+            out, c = pa.attend(c, _rot_interleaved(qa, cos, sin),
+                               _rot_interleaved(ka, cos, sin), va,
+                               scale=scale)
+            return (out.reshape(T, -1),) + c.pools()
 
-        out, kp2, vp2 = apply_op(
-            f, q, k, v, cache.k_pool, cache.v_pool, cache.block_tables,
-            cache.cu_seqlens, cache.context_lens, cache.seq_ids,
-            cache.positions, cache.step_seq, cache.step_blk,
-            cache.step_tile, op_name="ragged_paged_kv_attention")
+        out, *pools = apply_op(f, q, k, v, cache,
+                               op_name="ragged_paged_kv_attention")
         # back to [1, T, hidden] for the backbone's residual stream
         return self.o_proj(ops.reshape(out, [1, T, -1])), \
-            pa.RaggedLayerCache(
-                kp2, vp2, cache.block_tables, cache.cu_seqlens,
-                cache.context_lens, cache.seq_ids, cache.positions,
-                cache.step_seq, cache.step_blk, cache.step_tile)
+            cache.with_pools(pools)
 
 
 class LlamaMLP(nn.Layer):

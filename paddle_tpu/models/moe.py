@@ -19,8 +19,7 @@ from dataclasses import dataclass
 from paddle_tpu import ops
 from paddle_tpu import nn
 from paddle_tpu.nn import functional as F
-from paddle_tpu.ops.paged_attention import (PagedLayerCache,
-                                            RaggedLayerCache)
+from paddle_tpu.ops.paged_attention import RaggedLayerCache
 from .llama import LlamaAttention, LlamaConfig, LlamaMLP
 
 __all__ = ["MoeConfig", "MoeDecoderLayer", "MoeForCausalLM"]
@@ -123,22 +122,11 @@ class MoeDecoderLayer(nn.Layer):
         if self.is_dense:
             out = ops.add(x, self.mlp(h))
         else:
-            # paged serving: padded prefill tails and inactive decode
-            # slots must not steal expert capacity from real tokens —
-            # derive a token-validity mask from the cache's new_lens
-            # (per-row form) or seq_ids (token-packed form: the sentinel
-            # id marks budget padding)
+            # paged serving: the step's budget padding must not steal
+            # expert capacity from real tokens
             kw = {}
-            if isinstance(cache, PagedLayerCache):
-                S = x.shape[1]
-                kw["token_mask"] = ops.less_than(
-                    ops.reshape(ops.arange(0, S, 1, "int32"), [1, S]),
-                    ops.reshape(cache.new_lens, [-1, 1]))
-            elif isinstance(cache, RaggedLayerCache):
-                sentinel = cache.block_tables.shape[0] - 1
-                kw["token_mask"] = ops.less_than(
-                    ops.reshape(cache.seq_ids, [1, -1]),
-                    ops.full([1, 1], sentinel, "int32"))
+            if isinstance(cache, RaggedLayerCache):
+                kw["token_mask"] = cache.live_mask()
             routed = self.mlp(h, **kw)
             if self.shared_expert is not None:
                 routed = ops.add(routed, self.shared_expert(h))

@@ -43,7 +43,7 @@ from paddle_tpu import nn, ops
 from paddle_tpu.core.autograd import apply_op
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.observability import numerics
-from paddle_tpu.ops.paged_attention import LayerCacheSpec, RaggedLayerCache
+from paddle_tpu.ops.paged_attention import LayerCacheSpec
 from .llama import LlamaConfig, LlamaMLP, _gather_rope, _rot_interleaved
 
 __all__ = ["PanguMoeConfig", "PanguMoeModel", "PanguMoeForCausalLM"]
@@ -148,17 +148,10 @@ class PanguMLA(nn.Layer):
             out = apply_op(self._expanded, q, c, k_r, self.kv_b_proj.weight,
                            op_name="mla_expanded_attention")
             return self.o_proj(out)
-        if not isinstance(cache, RaggedLayerCache) \
-                or cache.v_pool is not None:
-            raise NotImplementedError(
-                "latent attention is served through the token-packed paged "
-                "cache over a latent pool (RaggedLayerCache, v_pool None)")
-        out, pool = apply_op(
-            self._absorbed, q, c, k_r, self.kv_b_proj.weight, cache.k_pool,
-            cache.block_tables, cache.cu_seqlens, cache.context_lens,
-            cache.seq_ids, cache.positions, cache.step_seq, cache.step_blk,
-            cache.step_tile, op_name="ragged_latent_attention")
-        return self.o_proj(out), cache._replace(k_pool=pool)
+        out, *pools = apply_op(
+            self._absorbed, q, c, k_r, self.kv_b_proj.weight, cache,
+            op_name="ragged_latent_attention")
+        return self.o_proj(out), cache.with_pools(pools)
 
     def _rope(self, pos, dtype):
         cfg = self.cfg
@@ -197,29 +190,27 @@ class PanguMLA(nn.Layer):
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         return jnp.einsum("bhqk,bkhv->bqhv", p, v).reshape(B, S, -1)
 
-    def _absorbed(self, qa, ca, kra, wkvb, pool, bt, cu, ctx, sid, pos,
-                  ssq, sbk, stl):
+    def _absorbed(self, qa, ca, kra, wkvb, cache):
         from paddle_tpu.ops import paged_attention as pa
         cfg = self.cfg
         nope = cfg.qk_nope_head_dim
         qa, ca, kra = qa[0], ca[0], kra[0]               # the packed axis
         T = qa.shape[0]
-        cos, sin = self._rope(pos, qa.dtype)
+        cos, sin = self._rope(cache.positions, qa.dtype)
         q_rope = _rot_interleaved(qa[..., nope:], cos, sin)
         k_rope = _rot_interleaved(kra[:, None, :], cos, sin)[:, 0]
         w_uk, w_uv = self._split_kvb(wkvb)
         q_abs = jnp.einsum("thn,chn->thc", qa[..., :nope], w_uk)
         pad = cfg.latent_cols - cfg.latent_row       # zeros: score nothing
-        u, pool = pa.ragged_latent_attention_step(
+        u, cache = pa.attend(
+            cache,
             jnp.concatenate(
                 [q_abs, q_rope, jnp.zeros((T, q_abs.shape[1], pad),
                                           q_abs.dtype)], -1),
             jnp.concatenate([ca, k_rope, jnp.zeros((T, pad), ca.dtype)], -1),
-            pool, bt, cu, ctx, sid, pos,
-            ssq, sbk, stl, value_cols=cfg.kv_lora_rank,
-            scale=1.0 / math.sqrt(self.qk_dim))
+            value_cols=cfg.kv_lora_rank, scale=1.0 / math.sqrt(self.qk_dim))
         out = jnp.einsum("thc,chv->thv", u, w_uv)
-        return out.reshape(1, T, -1), pool
+        return (out.reshape(1, T, -1),) + cache.pools()
 
 
 class PanguDecoderLayer(nn.Layer):
@@ -259,14 +250,9 @@ class PanguDecoderLayer(nn.Layer):
         if self.is_dense:
             m = self.mlp(h)
         else:
-            kw = {}
-            if cache is not None:
-                # the step's budget padding (sentinel sequence id) chooses
-                # no expert and counts in no expert's rows
-                sentinel = cache.block_tables.shape[0] - 1
-                kw["token_mask"] = ops.less_than(
-                    ops.reshape(cache.seq_ids, [1, -1]),
-                    ops.full([1, 1], sentinel, "int32"))
+            # the step's budget padding chooses no expert and counts in
+            # no expert's rows
+            kw = {} if cache is None else {"token_mask": cache.live_mask()}
             m = self.mlp(h, **kw)
             if self.shared_experts is not None:
                 m = ops.add(m, self.shared_experts(h))

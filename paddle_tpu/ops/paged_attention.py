@@ -9,15 +9,15 @@ as a pool of fixed-size token blocks instead of one contiguous
                       block ids run 1..num_blocks; head-major inside a
                       block so one head's page is a whole
                       ``[block_size, hd]`` tile for the Pallas reader)
-    block_tables    : [B, max_blocks_per_seq] int32 — logical block i of
-                      row b lives in physical block ``block_tables[b, i]``
-    context_lens    : [B] int32 — tokens already cached per row
-    new_lens        : [B] int32 — valid tokens in this call's input
-                      (rows may carry right-padding: a partial prefill
-                      chunk, or an inactive decode slot with new_len 0)
+    block_tables    : [max_seqs + 1, max_blocks_per_seq] int32 — logical
+                      block i of sequence s lives in physical block
+                      ``block_tables[s, i]``; the last row is all null
+    seq_ids         : [T] int32 — the sequence of each of the step's
+                      packed tokens (``max_seqs`` = budget padding)
+    positions       : [T] int32 — each token's absolute position
 
 Physical **block 0 is reserved as the null block**: padded block-table
-entries point at it and every invalid token's write is redirected into
+entries point at it and every padding token's write is redirected into
 it, so padding can never clobber a live sequence's cache. The allocator
 (``serving.kv_cache``) never hands block 0 out.
 
@@ -34,31 +34,24 @@ This mirrors the vLLM / Ragged-Paged-Attention layout (see
   streams each sequence's KV page by page with online softmax, only
   the real ``context_len`` worth of pages, no dense score tensor.
 
-``PADDLE_TPU_PAGED_ATTN_IMPL={rpa,gather,auto}`` picks the path
-(``auto``, the default: rpa on TPU, gather elsewhere — off-TPU the
-kernel only runs in Pallas interpret mode, a test vehicle);
-:func:`impl_override` pins it programmatically (the engine's
-``attn_impl=`` knob, and how parity tests compare both). The serving
-engine feeds the ragged token-packed form (:class:`RaggedLayerCache`);
-the per-row ``[B, S]`` form (:class:`PagedLayerCache`) remains for
-non-engine callers.
+The engine hands each layer a :class:`RaggedLayerCache`; the layer
+projects and rotates the step's rows and calls :func:`attend`, the one
+place where a step's rows are written and the pages read. Who reads is
+decided there from what the cache holds and says (:func:`paged_attention_impl`):
+rpa on TPU, gather elsewhere — off-TPU the kernel only runs in Pallas
+interpret mode, a test vehicle — and gather over int8 pools.
 """
 from __future__ import annotations
 
-import contextlib
 import math
-import os
-import threading
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["LayerCacheSpec", "PagedLayerCache", "RaggedLayerCache",
-           "write_to_pool", "ragged_latent_attention_step",
-           "write_tokens_to_pool", "gather_pool", "paged_attention_step",
-           "ragged_gather_attention", "ragged_paged_attention_step",
-           "paged_attention_impl", "impl_override", "mesh_override",
+__all__ = ["LayerCacheSpec", "RaggedLayerCache", "attend",
+           "write_tokens_to_pool", "gather_pool", "ragged_gather_attention",
+           "ragged_latent_gather_attention", "paged_attention_impl",
            "quantize_kv_slots"]
 
 
@@ -87,43 +80,6 @@ class LayerCacheSpec(NamedTuple):
         return cls(int(kv_heads), int(head_dim), int(head_dim))
 
 
-class PagedLayerCache(NamedTuple):
-    """One layer's view of the paged KV state.
-
-    Threaded through ``LlamaModel.forward(caches=[...])`` exactly like
-    the ``(k, v)`` / ``(k_buf, v_buf, pos)`` cache forms; the attention
-    layer dispatches on this type. ``block_tables`` / ``context_lens`` /
-    ``new_lens`` are shared across layers (one table per sequence), the
-    pools are per-layer.
-    """
-    k_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
-    v_pool: object        # [num_blocks + 1, n_kv, block_size, hd]
-    block_tables: object  # [B, max_blocks_per_seq] int32
-    context_lens: object  # [B] int32
-    new_lens: object      # [B] int32
-
-
-def _scatter_indices(block_tables, positions, valid, block_size):
-    """(phys_block [B,S], slot [B,S]) for logical ``positions`` [B,S];
-    invalid tokens are redirected to (null block 0, slot 0)."""
-    nblk = block_tables.shape[1]
-    blk = jnp.clip(positions // block_size, 0, nblk - 1)
-    phys = jnp.take_along_axis(block_tables, blk, axis=1)
-    slot = positions % block_size
-    phys = jnp.where(valid, phys, 0)
-    slot = jnp.where(valid, slot, 0)
-    return phys, slot
-
-
-def write_to_pool(pool, new, block_tables, positions, valid):
-    """Scatter ``new`` [B, S, n_kv, hd] into ``pool`` at logical
-    ``positions`` [B, S] through ``block_tables``; tokens with
-    ``valid == False`` land in the null block."""
-    phys, slot = _scatter_indices(block_tables, positions, valid,
-                                  pool.shape[2])
-    return pool.at[phys, :, slot].set(new.astype(pool.dtype))
-
-
 def gather_pool(pool, block_tables):
     """[B, max_blocks_per_seq * block_size, n_kv, hd] contiguous view of
     each row's paged context (the XLA-gather read path); a scale pool
@@ -131,49 +87,6 @@ def gather_pool(pool, block_tables):
     g = jnp.swapaxes(pool[block_tables], 2, 3)  # [B, nblk, bs, n_kv, hd]
     B, nblk, bs = g.shape[0], g.shape[1], g.shape[2]
     return g.reshape(B, nblk * bs, *g.shape[3:])
-
-
-def paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
-                         context_lens, new_lens, *, scale=None):
-    """One attention step over a block-paged cache.
-
-    ``q`` [B, S, n_heads, hd] and ``k``/``v`` [B, S, n_kv, hd] are the
-    (already position-encoded) projections of this call's ``S`` input
-    tokens per row — ``S`` is the prefill chunk length, or 1 in decode.
-    Writes the new K/V into the pools (invalid tokens to the null
-    block), gathers each row's whole paged context, and runs masked
-    GQA attention: key at logical position ``l`` is visible to row
-    ``b``'s query ``i`` iff ``l <= context_lens[b] + i`` — that one
-    bound covers prior context, in-chunk causality, and (together with
-    null-block redirection) keeps padding invisible.
-
-    Returns ``(out [B, S, n_heads*hd], k_pool', v_pool')``. Outputs at
-    padded query positions (``i >= new_lens[b]``) are garbage by
-    construction and must be discarded by the caller.
-    """
-    B, S, n_kv, hd = k.shape
-    n_heads = q.shape[2]
-    grp = n_heads // n_kv
-    if scale is None:
-        scale = 1.0 / math.sqrt(hd)
-    pos = context_lens[:, None].astype(jnp.int32) + \
-        jnp.arange(S, dtype=jnp.int32)[None, :]                 # [B, S]
-    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < \
-        new_lens[:, None].astype(jnp.int32)
-    k_pool = write_to_pool(k_pool, k, block_tables, pos, valid)
-    v_pool = write_to_pool(v_pool, v, block_tables, pos, valid)
-    keys = gather_pool(k_pool, block_tables)                    # [B, L, ...]
-    vals = gather_pool(v_pool, block_tables)
-    L = keys.shape[1]
-    qg = q.reshape(B, S, n_kv, grp, hd)
-    s = jnp.einsum("bskgh,blkh->bskgl", qg.astype(jnp.float32),
-                   keys.astype(jnp.float32)) * scale
-    visible = jnp.arange(L)[None, None, :] <= pos[:, :, None]   # [B, S, L]
-    s = jnp.where(visible[:, :, None, None, :], s,
-                  jnp.finfo(jnp.float32).min)
-    w = jax.nn.softmax(s, axis=-1).astype(vals.dtype)
-    out = jnp.einsum("bskgl,blkh->bskgh", w, vals)
-    return out.reshape(B, S, n_heads * hd), k_pool, v_pool
 
 
 # ===================== ragged token-packed form ==============================
@@ -206,69 +119,56 @@ class RaggedLayerCache(NamedTuple):
     # multipliers paged like the pools; None on unquantized engines
     k_scale: object = None  # [num_blocks + 1, n_kv, block_size] f32
     v_scale: object = None  # [num_blocks + 1, n_kv, block_size] f32
+    # not arrays: who reads the pages (``"rpa"`` | ``"gather"``; None =
+    # by platform, :func:`paged_attention_impl`), and the tensor-parallel
+    # mesh the pools are sharded over, if any. The engine resolves both
+    # at construction and states them on the caches it builds in its
+    # trace, so two engines tracing at once cannot see each other's.
+    impl: object = None
+    mesh: object = None
+
+    def live_mask(self):
+        """``[1, T]`` bool Tensor over the step's packed tokens: False at
+        budget padding (the sentinel sequence id), which must choose no
+        expert and count in no expert's rows."""
+        from paddle_tpu import ops
+        sentinel = self.block_tables.shape[0] - 1
+        return ops.less_than(ops.reshape(self.seq_ids, [1, -1]),
+                             ops.full([1, 1], sentinel, "int32"))
+
+    def pools(self):
+        """The arrays a step writes (pools, then scale pools; those this
+        cache holds), as a layer's ``apply_op`` closure returns them."""
+        return tuple(getattr(self, n) for n in self._written())
+
+    def with_pools(self, pools):
+        """This cache over the written ``pools`` (the order of
+        :meth:`pools`)."""
+        return self._replace(**dict(zip(self._written(), pools)))
+
+    def _written(self):
+        return [n for n in ("k_pool", "v_pool", "k_scale", "v_scale")
+                if getattr(self, n) is not None]
 
 
-# thread-local: two engines may trace their unified steps concurrently
-# on their background threads, each under its own attn_impl pin — a
-# process-global would let one trace leak its impl into the other
-_impl_local = threading.local()
-
-
-def paged_attention_impl() -> str:
-    """Resolve the paged read-path implementation: an
-    :func:`impl_override` in effect on THIS thread, else
-    ``PADDLE_TPU_PAGED_ATTN_IMPL`` (``rpa`` | ``gather`` | ``auto``),
-    else auto — rpa on TPU, gather elsewhere. Read at TRACE time: a
-    compiled serving step keeps whatever was resolved when it traced."""
-    override = getattr(_impl_local, "value", None)
-    if override is not None:
-        return override
-    v = os.environ.get("PADDLE_TPU_PAGED_ATTN_IMPL", "auto").lower()
-    if v in ("rpa", "gather"):
-        return v
-    if v != "auto":
-        raise ValueError(
-            f"PADDLE_TPU_PAGED_ATTN_IMPL={v!r} (want rpa|gather|auto)")
+def paged_attention_impl(impl=None, *, quantized: bool = False) -> str:
+    """Who reads the pages: ``impl`` where one is given (``"rpa"`` |
+    ``"gather"``), else rpa on TPU and gather elsewhere; always gather
+    over int8 pools (``quantized``), since the kernel streams raw pages
+    and knows nothing of the scale pools. Read at TRACE time: a compiled
+    serving step keeps what was resolved when it traced."""
+    if impl not in (None, "rpa", "gather"):
+        raise ValueError(f"attn impl {impl!r} (want rpa|gather|None)")
+    if quantized:
+        return "gather"
+    if impl is not None:
+        return impl
     return "rpa" if jax.default_backend() == "tpu" else "gather"
 
 
-@contextlib.contextmanager
-def impl_override(value):
-    """Pin the read-path impl for the calls traced inside the block on
-    the current thread (``None`` = no-op). The engine wraps its unified
-    step's trace in this so ``ServingEngine(attn_impl=...)`` wins over
-    the env."""
-    if value is not None and value not in ("rpa", "gather"):
-        raise ValueError(f"attn impl {value!r} (want rpa|gather|None)")
-    prev = getattr(_impl_local, "value", None)
-    _impl_local.value = value
-    try:
-        yield
-    finally:
-        _impl_local.value = prev
-
-
-@contextlib.contextmanager
-def mesh_override(mesh):
-    """Pin a tensor-parallel mesh for the ragged calls traced inside
-    the block on this thread (``None`` = single-device, a no-op). The
-    serving engine wraps its unified step's trace in this; the rpa
-    branch of :func:`ragged_paged_attention_step` reads it to shard_map
-    the Pallas kernel over the model-parallel axis (the kernel is
-    opaque to GSPMD — the gather fallback needs nothing, XLA partitions
-    it from the pool/projection shardings alone)."""
-    prev = getattr(_impl_local, "mesh", None)
-    _impl_local.mesh = mesh
-    try:
-        yield
-    finally:
-        _impl_local.mesh = prev
-
-
-def _tp_mesh():
-    """(mesh, mp_axis_name) when a tensor-parallel mesh with a >1
-    model axis is pinned on this thread, else None."""
-    mesh = getattr(_impl_local, "mesh", None)
+def _tp_mesh(mesh):
+    """(mesh, mp_axis_name) when ``mesh`` has a >1 model axis, else
+    None."""
     if mesh is None:
         return None
     for cand in ("mp", "model", "tp"):
@@ -304,17 +204,17 @@ def write_tokens_to_pool(pool, new, block_tables, seq_ids, positions):
         new.astype(pool.dtype).reshape((-1,) + row)).reshape(pool.shape)
 
 
-def _write_step_kv(pools, news, block_tables, seq_ids, positions):
+def _write_step_kv(pools, news, block_tables, seq_ids, positions, tp):
     """Write each of ``news`` into its pool (K and V, and with int8 pools
-    their scales). Under a model-parallel mesh the pools are sharded over
-    their head axis, which the flat view of a pool would merge away (GSPMD
-    would gather the pool to reshape it): there each shard flat-writes
-    its own heads into its own pool shard under ``shard_map``."""
+    their scales). Under a model-parallel mesh (``tp``, of
+    :func:`_tp_mesh`) the pools are sharded over their head axis, which
+    the flat view of a pool would merge away (GSPMD would gather the pool
+    to reshape it): there each shard flat-writes its own heads into its
+    own pool shard under ``shard_map``."""
     def write(pools, news, bt, sid, pos):
         return tuple(write_tokens_to_pool(p, n, bt, sid, pos)
                      for p, n in zip(pools, news))
 
-    tp = _tp_mesh()
     if tp is None:
         return write(pools, news, block_tables, seq_ids, positions)
     from jax.sharding import PartitionSpec as P
@@ -378,87 +278,6 @@ def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
     return out.reshape(T, n_heads, hd)
 
 
-def ragged_paged_attention_step(q, k, v, k_pool, v_pool, block_tables,
-                                cu_seqlens, context_lens, seq_ids,
-                                positions, step_seq, step_blk, step_tile,
-                                *, scale=None, k_scale=None,
-                                v_scale=None):
-    """One unified serving step over the token-packed ragged layout.
-
-    ``q`` [T, n_heads, hd] and ``k``/``v`` [T, n_kv, hd] are the
-    (already position-encoded) projections of the step's flat tokens.
-    Writes the new K/V into the pools (padding to the null block), then
-    dispatches the read path on :func:`paged_attention_impl`: the
-    Pallas RPA kernel (page-streamed, online softmax) or the gather
-    fallback. Returns ``(out [T, n_heads*hd], k_pool', v_pool')``;
-    outputs at padding tokens are garbage (gather) or 0 (rpa) and must
-    be discarded by the caller either way.
-
-    With int8-KV pools (``k_scale``/``v_scale`` scale pools given), the
-    new K/V are quantized per (token, head) before the scatter and the
-    read path dequantizes on the fly; the return grows to
-    ``(out, k_pool', v_pool', k_scale', v_scale')``. Only the gather
-    path reads quantized pools (the Pallas kernel streams raw pages —
-    the engine forces ``gather`` for int8 KV).
-    """
-    T, n_heads, hd = q.shape
-    if scale is None:
-        scale = 1.0 / math.sqrt(hd)
-    if k_scale is not None:
-        kq, ks = quantize_kv_slots(k)
-        vq, vs = quantize_kv_slots(v)
-        k_pool, v_pool, k_scale, v_scale = _write_step_kv(
-            (k_pool, v_pool, k_scale, v_scale), (kq, vq, ks, vs),
-            block_tables, seq_ids, positions)
-        out = ragged_gather_attention(
-            q, k_pool, v_pool, block_tables, seq_ids, positions,
-            scale=scale, k_scale=k_scale, v_scale=v_scale)
-        out = out.astype(q.dtype)
-        return (out.reshape(T, n_heads * hd), k_pool, v_pool,
-                k_scale, v_scale)
-    k_pool, v_pool = _write_step_kv((k_pool, v_pool), (k, v), block_tables,
-                                    seq_ids, positions)
-    if paged_attention_impl() == "rpa":
-        from paddle_tpu.ops.pallas.ragged_paged_attention import \
-            ragged_paged_attention
-        tp = _tp_mesh()
-        if tp is not None:
-            # SPMD over the kernel's head dimension (ISSUE 15): Pallas
-            # is opaque to GSPMD, so shard_map runs one kernel instance
-            # per mp shard — q over n_heads, pools over n_kv (whole GQA
-            # groups stay together because n_heads/n_kv shard by the
-            # same factor), metadata replicated (every shard walks the
-            # same work list under the same traced bound). Attention is
-            # embarrassingly parallel across heads: no collective is
-            # introduced here (the o_proj psum stays GSPMD's).
-            from jax.sharding import PartitionSpec as P
-            mesh, ax = tp
-            heads = P(None, ax, None)
-            pools = P(None, ax, None, None)
-            rep = P()
-            out = jax.shard_map(
-                lambda qa, kp, vp, bt, cu, ctx, ssq, sbk, stl:
-                    ragged_paged_attention(qa, kp, vp, bt, cu, ctx,
-                                           ssq, sbk, stl, sm_scale=scale),
-                mesh=mesh,
-                in_specs=(heads, pools, pools, rep, rep, rep, rep, rep,
-                          rep),
-                out_specs=heads, check_vma=False)(
-                q, k_pool, v_pool, block_tables, cu_seqlens,
-                context_lens, step_seq, step_blk, step_tile)
-        else:
-            out = ragged_paged_attention(
-                q, k_pool, v_pool, block_tables, cu_seqlens,
-                context_lens, step_seq, step_blk, step_tile,
-                sm_scale=scale)
-    else:
-        out = ragged_gather_attention(
-            q, k_pool, v_pool, block_tables, seq_ids, positions,
-            scale=scale)
-    return out.reshape(T, n_heads * hd), k_pool, v_pool
-
-
-# ===================== latent (MLA) pages ====================================
 def ragged_latent_gather_attention(q, pool, block_tables, seq_ids,
                                    positions, *, value_cols, scale):
     """The gather fallback of the latent read (and the kernel's parity
@@ -475,33 +294,92 @@ def ragged_latent_gather_attention(q, pool, block_tables, seq_ids,
     return jnp.einsum("thl,tlc->thc", w, rows[..., :value_cols])
 
 
-def ragged_latent_attention_step(q, rows, pool, block_tables, cu_seqlens,
-                                 context_lens, seq_ids, positions, step_seq,
-                                 step_blk, step_tile, *, value_cols, scale):
-    """One unified serving step of a latent-attention (MLA) layer, read
-    **absorbed**: ``q`` [T, n_heads, kd] is ``[W_UK^T q_nope | q_rope]``,
-    ``rows`` [T, kd] the step's new cache rows ``[c | k_rope]``. Writes
-    the rows into the layer's one pool, then reads it with the RPA
-    kernel's latent form (``rpa_mla``: every query head shares the page,
-    values are its first ``value_cols`` columns) or the gather fallback,
-    by :func:`paged_attention_impl`. Returns ``(u [T, n_heads,
-    value_cols], pool')``; ``u`` at padding tokens is garbage (gather)
-    or 0 (rpa), as in :func:`ragged_paged_attention_step`."""
-    pool = write_tokens_to_pool(pool, rows[:, None, :], block_tables, seq_ids,
-                                positions)
-    if paged_attention_impl() == "rpa":
-        if _tp_mesh() is not None:
+def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
+    """One layer's share of the unified serving step: write the step's
+    rows into ``cache``'s pools, read the pages, return ``(out, cache')``.
+    The one place that knows the cache's fields; it branches on what the
+    cache holds, never on who calls.
+
+    * **K/V pools**: ``q`` [T, n_heads, hd], ``k``/``v`` [T, n_kv, hd],
+      already position-encoded; ``out`` [T, n_heads, hd]. Where the cache
+      carries scale pools (int8 KV) the new rows are quantized per
+      (token, head) before the scatter and the read dequantizes.
+    * **a latent pool** (``v_pool`` None, MLA read **absorbed**): ``q``
+      [T, n_heads, kd] is ``[W_UK^T q_nope | q_rope]``, ``k`` [T, kd] the
+      step's rows ``[c | k_rope]``, whose first ``value_cols`` columns
+      are the values; ``out`` [T, n_heads, value_cols].
+
+    The reader is :func:`paged_attention_impl` of ``cache.impl`` and the
+    scales. ``out`` at padding tokens is garbage (gather) or 0 (rpa) and
+    the caller discards it either way."""
+    bt, sid, pos = cache.block_tables, cache.seq_ids, cache.positions
+    work = (bt, cache.cu_seqlens, cache.context_lens, cache.step_seq,
+            cache.step_blk, cache.step_tile)
+    latent = cache.v_pool is None
+    if latent != (v is None):
+        raise NotImplementedError(
+            "latent attention is served over a latent pool (v_pool None) "
+            "and K/V attention over a K and a V pool")
+    quantized = cache.k_scale is not None
+    rpa = paged_attention_impl(cache.impl, quantized=quantized) == "rpa"
+    tp = _tp_mesh(cache.mesh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if rpa:
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention
+
+    if latent:
+        pool = write_tokens_to_pool(cache.k_pool, k[:, None, :], bt, sid, pos)
+        if not rpa:
+            out = ragged_latent_gather_attention(
+                q, pool, bt, sid, pos, value_cols=value_cols,
+                scale=scale).astype(q.dtype)
+        elif tp is not None:
             raise NotImplementedError(
                 "a latent pool has one head: it is replicated, not "
                 "sharded over a model-parallel axis")
-        from paddle_tpu.ops.pallas.ragged_paged_attention import \
-            ragged_paged_attention
-        u = ragged_paged_attention(
-            q, pool, None, block_tables, cu_seqlens, context_lens,
-            step_seq, step_blk, step_tile, sm_scale=scale,
-            value_cols=value_cols)
+        else:
+            out = ragged_paged_attention(q, pool, None, *work, sm_scale=scale,
+                                         value_cols=value_cols)
+        return out, cache._replace(k_pool=pool)
+
+    if quantized:
+        kq, ks = quantize_kv_slots(k)
+        vq, vs = quantize_kv_slots(v)
+        k_pool, v_pool, k_scale, v_scale = _write_step_kv(
+            (cache.k_pool, cache.v_pool, cache.k_scale, cache.v_scale),
+            (kq, vq, ks, vs), bt, sid, pos, tp)
+        out = ragged_gather_attention(
+            q, k_pool, v_pool, bt, sid, pos, scale=scale, k_scale=k_scale,
+            v_scale=v_scale).astype(q.dtype)
+        return out, cache._replace(k_pool=k_pool, v_pool=v_pool,
+                                   k_scale=k_scale, v_scale=v_scale)
+
+    k_pool, v_pool = _write_step_kv((cache.k_pool, cache.v_pool), (k, v),
+                                    bt, sid, pos, tp)
+    if not rpa:
+        out = ragged_gather_attention(q, k_pool, v_pool, bt, sid, pos,
+                                      scale=scale)
+    elif tp is None:
+        out = ragged_paged_attention(q, k_pool, v_pool, *work, sm_scale=scale)
     else:
-        u = ragged_latent_gather_attention(
-            q, pool, block_tables, seq_ids, positions,
-            value_cols=value_cols, scale=scale).astype(q.dtype)
-    return u, pool
+        # SPMD over the kernel's head dimension (ISSUE 15): Pallas is
+        # opaque to GSPMD, so shard_map runs one kernel instance per mp
+        # shard — q over n_heads, pools over n_kv (whole GQA groups stay
+        # together because n_heads/n_kv shard by the same factor),
+        # metadata replicated (every shard walks the same work list
+        # under the same traced bound). Attention is embarrassingly
+        # parallel across heads: no collective is introduced here (the
+        # o_proj psum stays GSPMD's). The gather reader needs nothing:
+        # XLA partitions it from the pool/projection shardings alone.
+        from jax.sharding import PartitionSpec as P
+        mesh, ax = tp
+        heads = P(None, ax, None)
+        pools = P(None, ax, None, None)
+        out = jax.shard_map(
+            lambda qa, kp, vp, *w: ragged_paged_attention(
+                qa, kp, vp, *w, sm_scale=scale),
+            mesh=mesh, in_specs=(heads, pools, pools) + (P(),) * len(work),
+            out_specs=heads, check_vma=False)(q, k_pool, v_pool, *work)
+    return out, cache._replace(k_pool=k_pool, v_pool=v_pool)

@@ -887,9 +887,10 @@ def spmd_mesh(mesh):
     wrap the call in a shard_map"). ``TrainStep`` wraps its GSPMD trace
     in this and :func:`flash_attention_bshd` reads it to ``shard_map``
     the kernel over the batch (``dp``) and head (``mp``) axes, so each
-    chip runs the kernel on its own ``[B/dp, S, H/mp, D]`` shard. The
-    serving route pins its mesh the same way
-    (``ops/paged_attention.mesh_override``)."""
+    chip runs the kernel on its own ``[B/dp, S, H/mp, D]`` shard. A
+    thread-local because ``TrainStep`` traces a user's ``forward``, which
+    calls ``F.flash_attention`` with no object to carry the mesh (the
+    serving route's rides on its cache)."""
     prev = getattr(_spmd_local, "mesh", None)
     _spmd_local.mesh = mesh
     try:

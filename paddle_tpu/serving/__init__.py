@@ -25,7 +25,7 @@ The attention read path is the Ragged-Paged-Attention Pallas kernel
 (``ops/pallas/ragged_paged_attention.py``, the RPA paper — PAPERS.md,
 arxiv 2604.15464) on TPU, with the gather-based fallback in
 ``ops/paged_attention.py`` as the backend-portable parity oracle
-(``PADDLE_TPU_PAGED_ATTN_IMPL`` / ``ServingEngine(attn_impl=...)``).
+(``ServingEngine(attn_impl=...)`` pins either, for tests).
 """
 from . import engine, fleet, kv_cache, scheduler, server  # noqa: F401
 from .engine import RequestHandle, ServingEngine  # noqa: F401

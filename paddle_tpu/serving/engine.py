@@ -14,7 +14,7 @@ runs a **unified step** over a token-packed ragged batch — a flat
 each) plus as many prefill chunks as the budget covers, back to back.
 Attention reads go through the Ragged-Paged-Attention Pallas kernel on
 TPU (``ops/pallas/ragged_paged_attention.py``; the XLA-gather fallback
-elsewhere or via ``attn_impl=``/``PADDLE_TPU_PAGED_ATTN_IMPL``), which
+elsewhere, or where ``attn_impl=`` pins it), which
 streams each sequence's real pages instead of materializing padded
 contexts — and because one kernel covers every prefill/decode mix,
 chunked prefill no longer needs its own compiled executable.
@@ -371,20 +371,16 @@ class ServingEngine:
         self.max_model_len = min(self.cache.max_seq_len, max_pos)
         self.max_batch = int(max_batch)
         self.prefill_chunk = int(prefill_chunk)
-        #: attention read path, pinned at construction (None = env/auto:
-        #: rpa on TPU, gather elsewhere — docs/SERVING.md)
-        self.attn_impl = attn_impl if attn_impl is not None \
-            else pa.paged_attention_impl()
-        if self.attn_impl not in ("rpa", "gather"):
-            raise ValueError(
-                f"attn_impl {self.attn_impl!r} (want rpa|gather)")
-        if self.kv_dtype is not None and self.attn_impl == "rpa":
-            # the Pallas kernel streams raw pages and knows nothing of
-            # the scale pools; int8 KV rides the gather read path
+        #: attention read path, resolved at construction (None = rpa on
+        #: TPU, gather elsewhere; gather over int8 pools) and stated on
+        #: the caches the step builds: here it sizes the q tile, decides
+        #: whether a step builds the kernel's work list, and is reported
+        if self.kv_dtype is not None and attn_impl == "rpa":
             warnings.warn(
                 "kv_dtype='int8' forces attn_impl='gather' (the RPA "
                 "kernel reads unquantized pools)", RuntimeWarning)
-            self.attn_impl = "gather"
+        self.attn_impl = pa.paged_attention_impl(
+            attn_impl, quantized=self.kv_dtype is not None)
         # unified-step geometry: the flat token budget covers every
         # decode slot plus one full prefill chunk, rounded up to the RPA
         # kernel's q-tile height (autotunable on chip); max_items sizes
@@ -586,8 +582,10 @@ class ServingEngine:
         tap_order = [] if instrument else None
         moe_rows = getattr(model, "moe_expert_rows", None)
 
-        def pool(p):     # a latent layer has no v pool
-            return None if p is None else Tensor(p)
+        def pool(pools, i):  # a latent layer has no v pool, and an
+            # unquantized engine no scale pools
+            return Tensor(pools[i]) if pools and pools[i] is not None \
+                else None
 
         def data(t):
             return None if t is None else t.data
@@ -603,26 +601,18 @@ class ServingEngine:
             # arrays of the model's dtype
             stt = {k: (v.dequantize() if isinstance(v, QuantizedLeaf)
                        else v) for k, v in stt.items()}
-            if kv_quant:
-                caches = [pa.RaggedLayerCache(
-                    Tensor(k_pools[i]), Tensor(v_pools[i]), Tensor(bt),
-                    Tensor(cu), Tensor(ctx), Tensor(sid), Tensor(pos),
-                    Tensor(ssq), Tensor(sbk), Tensor(stl),
-                    Tensor(k_scales[i]), Tensor(v_scales[i]))
-                    for i in range(nl)]
-            else:
-                caches = [pa.RaggedLayerCache(
-                    Tensor(k_pools[i]), pool(v_pools[i]), Tensor(bt),
-                    Tensor(cu), Tensor(ctx), Tensor(sid), Tensor(pos),
-                    Tensor(ssq), Tensor(sbk), Tensor(stl))
-                    for i in range(nl)]
+            meta = [Tensor(a) for a in (bt, cu, ctx, sid, pos, ssq, sbk,
+                                        stl)]
+            caches = [pa.RaggedLayerCache(
+                pool(k_pools, i), pool(v_pools, i), *meta,
+                pool(k_scales, i), pool(v_scales, i),
+                impl=impl, mesh=self.mesh) for i in range(nl)]
             # per-row LoRA dispatch: pin this step's token->slot ids for
             # the adapter hooks traced inside the backbone call
             adapters = (lora.adapter_ids(aid) if n_slots
                         else contextlib.nullcontext())
             with numerics.collect(instrument) as col, no_grad(), \
                     swap_state(model, stt, collect_buffers=False), \
-                    pa.impl_override(impl), pa.mesh_override(self.mesh), \
                     adapters:
                 h, new_caches = backbone(Tensor(tokens), caches=caches)
                 # logits at each sequence's LAST packed token (rows of

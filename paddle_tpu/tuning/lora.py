@@ -63,8 +63,8 @@ class LoRAConfig:
 
 
 # thread-local: the serving engine pins the step's traced per-token
-# slot ids here while tracing/running its unified step (same pattern
-# as ops.paged_attention.impl_override)
+# slot ids here while tracing/running its unified step (the adapter
+# hooks of a layer have no argument to receive them through)
 _ids_local = threading.local()
 
 

@@ -45,24 +45,21 @@ def test_kernel_check_tiny_interpret():
                                 dtype="float32", **TINY_ENGINE)
 
 
-def test_server_leg_tiny_interpret(monkeypatch):
-    """The HTTP leg end to end with the kernel reader in interpret mode
-    (the chip run leaves the choice to the engine; the script refuses the
-    variable)."""
-    monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN_IMPL", "rpa")
+def test_server_leg_tiny_interpret():
+    """The HTTP leg end to end with the kernel reader in interpret mode,
+    pinned through the engine's arguments (the chip run leaves the choice
+    to the engine)."""
     _, health = chip_smoke.server_leg(
-        TINY, TINY_ENGINE, [(6, False), (20, True), (24, True)],
+        TINY, dict(TINY_ENGINE, attn_impl="rpa"),
+        [(6, False), (20, True), (24, True)],
         prefix_len=8, new_tokens=4, dtype="float32")
     assert health["step_compiles"] == 1 and health["kv_blocks_in_use"] == 0
     assert health["prefix_cache"]["hits"] >= 2
-    with pytest.raises(chip_smoke.SmokeFailure, match="PAGED_ATTN_IMPL"):
-        chip_smoke.run()
 
 
 def test_main_refuses_a_cpu(monkeypatch, capsys):
     """No accelerator: non-zero exit code, the platform named, no result
     line, and no compile cache placed."""
-    monkeypatch.delenv("PADDLE_TPU_PAGED_ATTN_IMPL", raising=False)
     before = jax.config.jax_compilation_cache_dir
     assert chip_smoke.main() != 0
     io = capsys.readouterr()

@@ -88,6 +88,27 @@ def test_async_start_counted_once_done_excluded():
     assert c.payload_bytes == 256
 
 
+def test_combined_all_reduce_counts_every_buffer():
+    """XLA's combiner packs independent all-reduces into one variadic
+    instruction (a tuple result, with ``/*index=5*/`` comments once it is
+    long): the ledger and the census count every buffer it reduces and
+    sum their bytes, so a pin holds whether or not the combiner ran."""
+    shapes = ["f32[]", "f32[4]{0}", "f32[2,3]{1,0}", "f32[4]{0}",
+              "f32[4]{0}", "/*index=5*/f32[4]{0}"]
+    line = (f"  %all-reduce.44 = ({', '.join(shapes)}) all-reduce(%a, %b, "
+            "%c, %d, %e, /*index=5*/%f), channel_id=3, "
+            "replica_groups=[1,8]<=[8], use_global_device_ids=true, "
+            "to_apply=%add")
+    text = "ENTRY %e (x: f32[4]) -> f32[4] {\n" + line + "\n}\n"
+    (c,) = CP.parse_collectives(text)
+    assert c.kind == "all-reduce" and c.payloads == 6
+    assert c.payload_bytes == 4 + 4 * 16 + 24
+    (slot,) = CP.comm_ledger([c], None).values()
+    assert slot["ops"] == 6 and slot["kinds"] == {"all-reduce": 6}
+    from paddle_tpu.analysis.hlo import collective_census
+    assert collective_census(text)["all-reduce"] == 6
+
+
 def test_iota_transpose_decode():
     # [4,2]<=[2,4]T(1,0): arange(8).reshape(2,4).T.reshape(4,2)
     line = ("  %all-reduce.2 = f32[4]{0} all-reduce(f32[4]{0} %x), "
@@ -311,6 +332,9 @@ def test_matrix_covers_segments_and_maps_every_collective(commplan_run):
 
 
 def test_ledgers_match_pinned_baseline(commplan_run):
+    """The pins are what this installation's XLA emits (PR 29): counted
+    per reduced buffer, the dp8 and dpxmp/dp pins held as they had been
+    pinned; the other geometries were pinned again."""
     pinned = load_baseline().commplan
     assert pinned, "commplan section missing from committed baseline"
     for label, ledger in commplan_run["ledgers"].items():

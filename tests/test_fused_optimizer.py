@@ -9,7 +9,6 @@ guards, HLO-verified bucketed (not per-param, not monolithic) dp gradient
 reductions with the env-tunable bucket size, the flat-state flush protocol,
 the XLA tuning flag gate, and the bench report-gate wiring.
 """
-import re
 
 import numpy as np
 import pytest
@@ -507,16 +506,22 @@ def dp8():
 
 
 def _count_all_reduce(hlo_text):
-    return len(re.findall(r"all-reduce(?:-start)?\(", hlo_text))
+    """Buffers all-reduced in the compiled module: counted per payload
+    (``analysis.hlo.collective_census``), because XLA's combiner packs
+    the independent all-reduces of a program this small into one variadic
+    instruction, and the contract is about what travels."""
+    from paddle_tpu.analysis.hlo import collective_census
+    return collective_census(hlo_text)["all-reduce"]
 
 
 class TestBucketedCollectives:
-    def _dp_step(self, mesh, fused=True, bucketed=None, seed=3):
+    def _dp_step(self, mesh, fused=True, bucketed=None, seed=3,
+                 optimizer=opt.AdamW):
         import paddle_tpu.distributed as dist
         pt.seed(seed)
         net = nn.Sequential(nn.Linear(16, 32), nn.ReLU(), nn.Linear(32, 4))
         m = dist.DataParallel(net, mesh=mesh)
-        o = opt.AdamW(learning_rate=0.01, parameters=m.parameters())
+        o = optimizer(learning_rate=0.01, parameters=m.parameters())
         return m, o, pt.jit.TrainStep(m, _loss, o, fused=fused,
                                       bucketed=bucketed)
 
@@ -555,14 +560,18 @@ class TestBucketedCollectives:
         # bucketed step's 2
         assert _count_all_reduce(hlo) > 2
 
-    def test_bucketed_matches_single_device(self, dp8):
+    @pytest.mark.parametrize("optimizer", [opt.AdamW, opt.SGD])
+    def test_bucketed_matches_single_device(self, dp8, optimizer):
+        """SGD beside AdamW: Adam divides the gradient's scale out, so
+        only a plain update shows a gradient summed over the shards where
+        it should be their mean."""
         X, Y = self._batch()
         pt.seed(3)
         m1 = nn.Sequential(nn.Linear(16, 32), nn.ReLU(), nn.Linear(32, 4))
-        o1 = opt.AdamW(learning_rate=0.01, parameters=m1.parameters())
+        o1 = optimizer(learning_rate=0.01, parameters=m1.parameters())
         s1 = pt.jit.TrainStep(m1, _loss, o1)
         base = [float(s1(t(X), t(Y)).numpy()) for _ in range(6)]
-        m2, o2, s2 = self._dp_step(dp8)
+        m2, o2, s2 = self._dp_step(dp8, optimizer=optimizer)
         got = [float(s2(t(X), t(Y)).numpy()) for _ in range(6)]
         assert s2._bucketed_reason is None
         np.testing.assert_allclose(got, base, rtol=2e-4, atol=1e-6)

@@ -224,8 +224,9 @@ def test_rpa_mla_interpret_matches_expanded_attention(seqs):
 
 
 def test_latent_step_writes_rows_then_reads_them():
-    """``ragged_latent_attention_step``: the step's own rows land in the
-    pool at their pages (padding in the null block) before the read."""
+    """``attend`` over a latent pool: the step's own rows land in the
+    pool at their pages (padding in the null block) before the read; a
+    K/V cache, or values beside a latent pool, are refused."""
     rng = np.random.default_rng(4)
     case = _latent_case(rng, [(5, 3), (1, 9)])
     new = np.zeros((case["q"].shape[0], 24), np.float32)
@@ -236,20 +237,25 @@ def test_latent_step_writes_rows_then_reads_them():
         for p in range(c, c + n):                # not yet in the pool
             pool[case["bt"][s, p // 8], 0, p % 8] = 0
         off += n
+    meta = [jnp.asarray(case[k]) for k in ("bt", "cu", "ctx", "sid", "pos")] \
+        + list(case["maps"][:3])
     for impl in ("rpa", "gather"):
-        with pa.impl_override(impl):
-            u, pool2 = pa.ragged_latent_attention_step(
-                jnp.asarray(case["q"]), jnp.asarray(new), jnp.asarray(pool),
-                *[jnp.asarray(case[k]) for k in ("bt", "cu", "ctx", "sid",
-                                                 "pos")],
-                case["maps"].step_seq, case["maps"].step_blk,
-                case["maps"].step_tile, value_cols=16, scale=0.3)
+        cache = pa.RaggedLayerCache(jnp.asarray(pool), None, *meta, impl=impl)
+        u, cache2 = pa.attend(cache, jnp.asarray(case["q"]), jnp.asarray(new),
+                              value_cols=16, scale=0.3)
+        (pool2,) = cache2.pools()
         np.testing.assert_array_equal(np.asarray(pool2)[1:],
                                       case["pool"][1:])
         want = _expanded_oracle(case, np.eye(16)[:, None, :]
                                 .repeat(4, 1).astype(np.float32), 0.3)
         np.testing.assert_allclose(np.asarray(u)[:6], want, atol=1e-4,
                                    rtol=1e-4)
+    rows = jnp.asarray(new)
+    with pytest.raises(NotImplementedError, match="latent pool"):
+        pa.attend(cache, jnp.asarray(case["q"]), rows, rows, value_cols=16)
+    with pytest.raises(NotImplementedError, match="latent pool"):
+        pa.attend(cache._replace(v_pool=cache.k_pool),
+                  jnp.asarray(case["q"]), rows, value_cols=16)
 
 
 # ---------------------------------------------------- the held experts --

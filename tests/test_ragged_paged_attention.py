@@ -34,8 +34,7 @@ import jax.numpy as jnp
 import paddle_tpu as pt
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.ops.paged_attention import (
-    impl_override, paged_attention_impl, ragged_gather_attention,
-    ragged_latent_attention_step, ragged_paged_attention_step,
+    RaggedLayerCache, attend, paged_attention_impl, ragged_gather_attention,
     write_tokens_to_pool)
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     build_step_maps, default_tile_q, ragged_paged_attention, rpa_max_items,
@@ -534,16 +533,18 @@ def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
                 if " copy(" in ln and f",1,{eng['block_size']},{cols}]" in ln]
 
 
-def _pool_shaped(text, pool_shape):
+def _pool_shaped(text, pool_shape, dtype="bf16", ignore=()):
     """Opcode of every instruction of the compiled ``text`` whose result
-    is a pool, as it lies or as the flat table of rows the writer sees."""
-    flat = (int(np.prod(pool_shape[:3])), pool_shape[3])
-    shapes = tuple(f"bf16[{','.join(map(str, sh))}]"
+    is a pool, as it lies or as the flat table of rows the writer sees.
+    ``ignore`` names opcodes to leave out; the guard at a deployment's
+    shapes passes none."""
+    flat = (int(np.prod(pool_shape[:3])),) + tuple(pool_shape[3:])
+    shapes = tuple(f"{dtype}[{','.join(map(str, sh))}]"
                    for sh in (pool_shape, flat))
     ops = []
     for ln in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?\S+ = (\S+) ([\w\-]+)\(", ln)
-        if m and m.group(1).startswith(shapes):
+        if m and m.group(1).startswith(shapes) and m.group(2) not in ignore:
             ops.append(m.group(2))
     return ops
 
@@ -587,30 +588,31 @@ def test_step_writes_its_pools_in_place_at_serving_shapes(
     q = arr((tokens, heads, hd), jnp.bfloat16)
     pool = arr(pool_shape, jnp.bfloat16)
 
-    # a fresh function a case: jax.jit reuses a trace by the function's
-    # identity, and the trace holds the impl it was made under
     if latent:
         new = (arr((tokens, hd), jnp.bfloat16),)
         pools = (pool, pool)
 
         def step(q, rows, p0, p1, *meta):
             kw = dict(value_cols=cfg["kv_lora_rank"], scale=0.07)
-            u, p0 = ragged_latent_attention_step(q, rows, p0, *meta, **kw)
+            u, c0 = attend(RaggedLayerCache(p0, None, *meta, impl="rpa"),
+                           q, rows, **kw)
             u = jnp.pad(u, ((0, 0), (0, 0), (0, hd - u.shape[-1])))
-            u, p1 = ragged_latent_attention_step(u, rows * 2, p1, *meta, **kw)
-            return u, p0, p1
+            u, c1 = attend(RaggedLayerCache(p1, None, *meta, impl="rpa"),
+                           u, rows * 2, **kw)
+            return (u,) + c0.pools() + c1.pools()
     else:
         new = (arr((tokens, kv, hd), jnp.bfloat16),) * 2
         pools = (pool,) * 4
 
         def step(q, k, v, kp0, vp0, kp1, vp1, *meta):
-            o, kp0, vp0 = ragged_paged_attention_step(q, k, v, kp0, vp0, *meta)
-            o, kp1, vp1 = ragged_paged_attention_step(
-                o.reshape(q.shape), k * 2, v * 2, kp1, vp1, *meta)
-            return o, kp0, vp0, kp1, vp1
+            o, c0 = attend(RaggedLayerCache(kp0, vp0, *meta, impl="rpa"),
+                           q, k, v)
+            o, c1 = attend(RaggedLayerCache(kp1, vp1, *meta, impl="rpa"),
+                           o, k * 2, v * 2)
+            return (o,) + c0.pools() + c1.pools()
 
     donate = tuple(range(1 + len(new), 1 + len(new) + len(pools)))
-    with _compiling_for_the_chip(monkeypatch), impl_override("rpa"):
+    with _compiling_for_the_chip(monkeypatch):
         text = jax.jit(step, donate_argnums=donate).lower(
             q, *new, *pools, *meta).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
@@ -622,18 +624,123 @@ def test_step_writes_its_pools_in_place_at_serving_shapes(
     assert ops.count("scatter") == ops.count("fusion") == len(pools), ops
 
 
-def test_impl_knob_resolution(monkeypatch):
-    """auto = gather off-TPU; env and override win in that order."""
-    monkeypatch.delenv("PADDLE_TPU_PAGED_ATTN_IMPL", raising=False)
-    assert paged_attention_impl() == "gather"  # CPU mesh
-    monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN_IMPL", "rpa")
+def _guard_llama():
+    pt.seed(0)
+    m = LlamaForCausalLM(LlamaConfig(
+        vocab_size=128, hidden_size=512, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=256))
+    return m
+
+
+def _guard_latent():
+    from paddle_tpu.models.pangu_moe import (PanguMoeConfig,
+                                             PanguMoeForCausalLM)
+    pt.seed(0)
+    return PanguMoeForCausalLM(PanguMoeConfig.tiny(
+        held_experts=(0, 1, 2, 3), kv_lora_rank=96, qk_rope_head_dim=32,
+        num_hidden_layers=2))
+
+
+@pytest.mark.parametrize("build,engine_kw", [
+    (_guard_llama, {"attn_impl": "rpa"}),
+    (_guard_llama, {"attn_impl": "gather"}),
+    (_guard_llama, {"kv_dtype": "int8"}),
+    (_guard_latent, {"attn_impl": "rpa"}),
+], ids=["rpa", "gather", "int8", "latent"])
+def test_engine_step_writes_each_pool_once_in_place(
+        build, engine_kw, v5e_chip, monkeypatch):
+    """The whole step of a small engine, as ``ServingEngine`` builds it
+    through the model's attention layers and ``attend``, compiled for the
+    chip with the pools donated as on a TPU: under either reader, with
+    int8 pools and with a latent pool, every pool is written by exactly
+    one row scatter, and nothing else of a pool's shape is computed (no
+    ``copy``, no transpose). A layer that writes its pool any other way
+    than through the one writer fails here. (An int8 engine's scale
+    pools are held to the one scatter only: their flat view is no bitcast
+    in the chip's tiled layout.)"""
+    import jax
+
+    model = build()
+    model.eval()
+    model.bfloat16()
+    eng = ServingEngine(model, max_batch=4, max_blocks=1024,
+                        max_blocks_per_seq=8, block_size=16,
+                        prefill_chunk=16, **engine_kw)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        eng._lowered_step().args_info[0])
+    step = eng._step.__wrapped__
+    with _compiling_for_the_chip(monkeypatch):
+        # a fresh function: jax.jit reuses a trace by the function's
+        # identity, and the engine's own was made for this CPU
+        text = jax.jit(lambda *a: step(*a), donate_argnums=(2, 3, 4, 5)) \
+            .lower(*args).compile().as_text()
+    latent = eng.cache.v_pools[0] is None
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and ("%rpa_mla." if latent else "%rpa.") in ln.split("=")[0]]
+    assert len(calls) == (2 if eng.attn_impl == "rpa" else 0), calls
+    pool = eng.cache.k_pools[0]
+    n_pools = 2 if latent else 4
+    # a pool of this test's size fits the chip's fast memory and XLA
+    # prefetches it there (copy-start/copy-done between memory spaces),
+    # which a pool of a deployment's size cannot be: only here are those
+    # two left out, the guard at serving shapes above counts them
+    prefetch = ("copy-start", "copy-done")
+    ops = _pool_shaped(text, pool.shape,
+                       {"bfloat16": "bf16", "int8": "s8"}[str(pool.dtype)],
+                       ignore=prefetch)
+    assert set(ops) <= {"parameter", "bitcast", "fusion", "scatter"}, ops
+    assert ops.count("scatter") == n_pools, ops
+    if eng.kv_dtype is not None:
+        scales = _pool_shaped(text, eng.cache.k_scales[0].shape, "f32",
+                              ignore=prefetch)
+        assert scales.count("scatter") == 4, scales
+
+
+def test_hand_built_cache_picks_its_reader(monkeypatch):
+    """A cache built by hand with no reader named reads through gather
+    off a TPU (and would through the kernel on one); a reader it is given
+    is the one that runs; int8 pools read through gather whoever asks;
+    anything else is refused."""
+    import importlib
+    import jax
+    assert paged_attention_impl() == "gather"            # this CPU
+    assert paged_attention_impl("rpa") == "rpa"
+    assert paged_attention_impl("rpa", quantized=True) == "gather"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert paged_attention_impl() == "rpa"
-    with impl_override("gather"):
-        assert paged_attention_impl() == "gather"
-    assert paged_attention_impl() == "rpa"
-    monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN_IMPL", "bogus")
+    assert paged_attention_impl("gather") == "gather"
+    monkeypatch.undo()
     with pytest.raises(ValueError, match="bogus"):
-        paged_attention_impl()
+        paged_attention_impl("bogus")
+
+    mod = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    kernel_calls = []
+    real = mod.ragged_paged_attention
+    monkeypatch.setattr(mod, "ragged_paged_attention",
+                        lambda *a, **kw: kernel_calls.append(1)
+                        or real(*a, **kw))
+    c = _ragged_case(np.random.RandomState(2), [(3, 5), (1, 9)], 4, 2, 2)
+    meta = [jnp.asarray(c[k]) for k in ("bt", "cu", "ctx", "sid", "pos")] \
+        + [c["maps"].step_seq, c["maps"].step_blk, c["maps"].step_tile]
+    n_kv, hd = c["kp"].shape[1], c["kp"].shape[3]
+    new = jnp.ones((c["q"].shape[0], n_kv, hd), jnp.float32)
+    outs = {}
+    for impl, kernel in ((None, 0), ("gather", 0), ("rpa", 1)):
+        kernel_calls.clear()
+        cache = RaggedLayerCache(c["kp"], c["vp"], *meta, impl=impl)
+        outs[impl], cache2 = attend(cache, jnp.asarray(c["q"]), new, new)
+        assert len(kernel_calls) == kernel, impl
+        assert cache2.impl == impl and len(cache2.pools()) == 2
+    n = sum(n for n, _ in c["seqs"])
+    np.testing.assert_array_equal(np.asarray(outs[None]),
+                                  np.asarray(outs["gather"]))
+    np.testing.assert_allclose(np.asarray(outs["rpa"])[:n],
+                               np.asarray(outs["gather"])[:n],
+                               atol=1e-5, rtol=1e-5)
 
 
 # ---------------- engine-level acceptance ------------------------------------

@@ -88,46 +88,6 @@ def test_allocator_refcount_shared_block():
         a.incref(b)
 
 
-# ---------------- paged attention numerics -----------------------------------
-def test_paged_cache_matches_concat_cache():
-    """Prefill + decode through PagedLayerCache must reproduce the
-    legacy growing-concat path's hidden states."""
-    import jax.numpy as jnp
-    from paddle_tpu.ops.paged_attention import PagedLayerCache
-
-    m = _tiny(4)
-    rng = np.random.RandomState(5)
-    ids = pt.to_tensor(rng.randint(0, 128, (1, 7)).astype(np.int64))
-    tok = pt.to_tensor(rng.randint(0, 128, (1, 1)).astype(np.int64))
-
-    caches = [(None, None)] * m.cfg.num_hidden_layers
-    h1, caches = m.model(ids, caches=caches)
-    h2, caches = m.model(tok, caches=caches)
-
-    n_kv = m.cfg.num_key_value_heads
-    hd = m.cfg.hidden_size // m.cfg.num_attention_heads
-    bs, nblk = 4, 3  # capacity 12 >= 8 cached tokens
-    bt = pt.to_tensor(np.array([[1, 2, 3]], np.int32))  # blocks 1..3
-    pools = [[pt.to_tensor(jnp.zeros((nblk + 1, n_kv, bs, hd))),
-              pt.to_tensor(jnp.zeros((nblk + 1, n_kv, bs, hd)))]
-             for _ in range(m.cfg.num_hidden_layers)]
-
-    def run(x, ctx, n_new):
-        nonlocal pools
-        pc = [PagedLayerCache(k, v, bt,
-                              pt.to_tensor(np.array([ctx], np.int32)),
-                              pt.to_tensor(np.array([n_new], np.int32)))
-              for k, v in pools]
-        h, new_c = m.model(x, caches=pc)
-        pools = [[c.k_pool, c.v_pool] for c in new_c]
-        return h
-
-    g1 = run(ids, 0, 7)
-    g2 = run(tok, 7, 1)
-    np.testing.assert_allclose(g1.numpy(), h1.numpy(), atol=2e-5)
-    np.testing.assert_allclose(g2.numpy(), h2.numpy(), atol=2e-5)
-
-
 def test_decode_outranks_prefill_for_the_last_block():
     """Unified-step planning order (ISSUE 8): decode plans FIRST, so an
     OLDER running request takes the pool's last block ahead of a younger
