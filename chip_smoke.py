@@ -196,12 +196,13 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     from paddle_tpu.ops.paged_attention import ragged_gather_attention
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
         build_step_maps, default_tile_q, ragged_paged_attention,
-        rpa_max_items)
+        rpa_max_items, rpa_run_pages)
 
     rng = np.random.RandomState(1)
     tile = default_tile_q(n_heads // n_kv, dtype)
     T = -(-(max_batch + prefill_chunk) // tile) * tile
-    max_items = rpa_max_items(T // tile, max_batch, max_blocks_per_seq)
+    run = rpa_run_pages(head_dim, block_size)
+    max_items = rpa_max_items(T // tile, max_batch, max_blocks_per_seq, run)
     bt = np.zeros((max_batch + 1, max_blocks_per_seq), np.int32)
     cu = np.zeros(max_batch + 2, np.int32)
     ctx = np.zeros(max_batch + 1, np.int32)
@@ -230,7 +231,8 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     q = jnp.asarray(rng.randn(T, n_heads, head_dim), dtype)
     maps = build_step_maps(cu[:len(seqs) + 1], kv_lens, total_tokens=T,
                            tile_q=tile, block_size=block_size,
-                           max_items=max_items, max_seqs=max_batch)
+                           max_items=max_items, max_seqs=max_batch,
+                           run_pages=run)
     t0 = time.perf_counter()
     rpa = jax.jit(ragged_paged_attention)(
         q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(cu),
@@ -240,7 +242,8 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     print(f"  rpa kernel compile+run {time.perf_counter() - t0:.1f}s "
           f"(tile_q={tile}, tokens={T}, flat work list: {maps.walked} "
           f"grid steps a kv head = {maps.live} live (tile, sequence, "
-          f"page) items + {maps.walked - maps.live} tiles without work, "
+          f"run of {run} pages) items naming {maps.pages} pages + "
+          f"{maps.walked - maps.live} tiles without work, "
           f"in arrays of {max_items}; pages "
           f"{[-(-kv // block_size) for kv in kv_lens]})")
     gather = jax.jit(ragged_gather_attention, static_argnames="scale")(

@@ -27,9 +27,9 @@ arxiv 2604.15464) this kernel takes the batch **token-packed**:
     context_lens   : [max_seqs + 1] int32 — tokens already cached
                      BEFORE this step's writes, per sequence
 
-and streams each sequence's KV **page by page with only its real
-``context_len`` worth of pages** — no ``[B, L_max]`` materialization, no
-f32 score tensor in HBM, online softmax in VMEM scratch.
+and streams each sequence's KV **page by page with only the pages its
+tokens can see** — no ``[B, L_max]`` materialization, no f32 score
+tensor in HBM, online softmax in VMEM scratch.
 
 Grid design
 -----------
@@ -38,35 +38,53 @@ kernel's trip count follows the step's live work (the pattern of
 megablox ``gmm``'s ``num_active_tiles``). The flat token axis is cut into
 fixed ``tile_q``-token tiles; a tile may span several ragged sequences,
 so the inner grid dimension walks one host-built **flat work list** for
-the whole call (``build_step_maps``), sorted by q tile: item ``w`` names
-``(tile, sequence, kv page)`` in scalar-prefetched int32 arrays, the q
-and output BlockSpecs are indexed by the item's tile and the K/V
-BlockSpec index maps chase ``block_tables[step_seq[w], step_blk[w]]``
-straight from SMEM — the pipeline's revolving buffers double-buffer the
+the whole call (``build_step_maps``), sorted by q tile. **A work item is
+``(tile, sequence, run)``: a run of up to ``P`` consecutive pages of the
+sequence's block-table row that the tile's tokens can see**, named in
+scalar-prefetched int32 arrays (``step_blk[w]`` is the run: pages
+``P * step_blk[w] ... + P - 1``). The kernel makes its online-softmax
+update once an item: ``P`` pages of keys under one max / exp / sum pass
+and one rescale of the ``[rows, value_width]`` f32 accumulator, which is
+what a page-sized item spent most of its time on where the values are
+wide (a latent pool: 512 columns against 128 keys). ``P`` is read off
+the pool's shape (:func:`rpa_run_pages`: keys an item at least the value
+width), never set by a caller: 4 for a latent pool of 512 value columns in
+pages of 128, 1 for a K/V pool of head width 128 in the same pages.
+
+The q and output BlockSpecs are indexed by the item's tile, and the pool
+is handed to ``pallas_call`` under ``P`` BlockSpecs of one page each,
+whose index maps chase ``block_tables[step_seq[w], P * step_blk[w] + i]``
+straight from SMEM — the pipeline's revolving buffers double-buffer plain
 page DMAs exactly like the classic paged kernel
 (boom_attention_tricks.md §9–11), with no manual descriptors, and a q
-tile is fetched once for its whole run of items. The list is in CSR
-form: tile ``j`` owns items ``[step_tile[j], step_tile[j + 1])`` and
+tile is fetched once for its whole run of items. A run's pages past the
+sequence's last resolve to the null page through the table's null padding
+(past the table's width the index is clamped to it) and the causal mask
+kills their keys: ``kpos`` counts from the run's index. The list is in
+CSR form: tile ``j`` owns items ``[step_tile[j], step_tile[j + 1])`` and
 ``n_items = step_tile[-1]``; the online-softmax scratch is initialised
-at a tile's first item and the output block written at its last. Every
-tile owns at least one item — a tile of only padding tokens gets one
-sentinel item (sequence ``max_seqs``, the null page, no compute) — so
-every output block is written and padding rows stay exactly 0. Rows of
-the score tile that don't belong to the item's sequence are masked dead
-(their online-softmax state is provably untouched: p = 0 rows with α
-folded to carry ``m``/``l`` through), so prefill chunks (in-chunk causal
-via ``kpos <= ctx + (t - cu[s])``) and decode rows coexist in one tile.
+at a tile's first item and the output block written at its last. A tile
+lists a sequence's runs only up to its **causal horizon** there (the
+pages that hold a key its last token of that sequence may see), not the
+pages the step's later tiles write. Every tile owns at least one item — a
+tile of only padding tokens gets one sentinel item (sequence
+``max_seqs``, the null page, no compute) — so every output block is
+written and padding rows stay exactly 0. Rows of the score tile that
+don't belong to the item's sequence are masked dead (their
+online-softmax state is provably untouched: p = 0 rows with α folded to
+carry ``m``/``l`` through), so prefill chunks (in-chunk causal via
+``kpos <= ctx + (t - cu[s])``) and decode rows coexist in one tile.
 
 The arrays are sized by the static :func:`rpa_max_items` =
-``max_blocks_per_seq * (num_q_tiles + max_seqs)``: a sequence is
-re-walked once per tile it spans, and all sequences together span at
+``ceil(max_blocks_per_seq / P) * (num_q_tiles + max_seqs)``: a sequence
+is re-walked once per tile it spans, and all sequences together span at
 most ``num_q_tiles + n_seqs - 1`` tiles. The bound never uses the pool's
 size (sequences that share prefix pages are distinct rows that name the
 same pages), and it sizes arrays only — nothing walks it. A caller that
 holds per-tile maps ``[num_q_tiles, k]`` padded with the sentinel
-(``rpa_max_steps`` wide) may hand those instead: the wrapper compacts
-them into the same flat list on the device and both reach the one
-``pallas_call``.
+(``rpa_max_steps`` wide, naming runs the same way) may hand those
+instead: the wrapper compacts them into the same flat list on the device
+and both reach the one ``pallas_call``.
 
 Off-TPU the kernel runs in Pallas interpret mode, which is what tier-1
 parity tests exercise on the CPU mesh (`tests/test_ragged_paged_attention.py`);
@@ -88,7 +106,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ragged_paged_attention", "build_step_maps", "StepMaps",
-           "rpa_tile_q", "rpa_max_items", "rpa_max_steps",
+           "rpa_tile_q", "rpa_max_items", "rpa_max_steps", "rpa_run_pages",
            "default_tile_q"]
 
 _LANES = 128
@@ -118,36 +136,52 @@ def default_tile_q(group: int, dtype) -> int:
     return tile
 
 
-def rpa_max_items(num_tiles: int, max_seqs: int,
-                  max_blocks_per_seq: int) -> int:
-    """Static length of the flat work list's arrays. Sequences are packed
-    back to back, so a sequence is walked once per q tile it spans and
-    all ``n`` sequences together span at most ``num_tiles + n - 1``
-    tiles; each walk streams at most ``max_blocks_per_seq`` pages, and a
-    tile without work adds one sentinel item where it adds no walk.
-    Sound when sequences share prefix pages (the pool's size is no part
-    of it). It sizes arrays only: the kernel walks the live length."""
-    return max_blocks_per_seq * (num_tiles + max_seqs)
+def rpa_run_pages(value_width: int, block_size: int) -> int:
+    """Pages a work item names (``P``), read off the pool's shape: an
+    item's keys number at least the value width,
+    ``max(1, value_width // block_size)``. What a run amortises is the
+    kernel's ``[rows, value_width]`` f32 accumulator, rescaled once an
+    item: a run of ``P`` pages keeps that traffic at or under one
+    accumulator element a score element, the ratio of a flash kernel. 4
+    for a latent pool of 512 value columns in pages of 128 tokens, 1 for
+    a K/V pool of head width 128 in the same pages (an item is a page)."""
+    return max(1, int(value_width) // int(block_size))
+
+
+def rpa_max_items(num_tiles: int, max_seqs: int, max_blocks_per_seq: int,
+                  run_pages: int = 1) -> int:
+    """Static length of the flat work list's arrays. An item is a run of
+    up to ``run_pages`` consecutive pages of one sequence for one q tile.
+    Sequences are packed back to back, so a sequence is walked once per q
+    tile it spans and all ``n`` sequences together span at most
+    ``num_tiles + n - 1`` tiles; each walk streams at most
+    ``ceil(max_blocks_per_seq / run_pages)`` runs, and a tile without
+    work adds one sentinel item where it adds no walk. Sound when
+    sequences share prefix pages (the pool's size is no part of it). It
+    sizes arrays only: the kernel walks the live length."""
+    return -(-max_blocks_per_seq // run_pages) * (num_tiles + max_seqs)
 
 
 def rpa_max_steps(tile_q: int, max_blocks_per_seq: int,
-                  pool_blocks: int | None = None) -> int:
+                  pool_blocks: int | None = None, run_pages: int = 1) -> int:
     """Width of per-tile ``[num_q_tiles, k]`` maps, for a caller that
     hands :func:`ragged_paged_attention` those instead of the flat list:
     a tile of ``tile_q`` tokens overlaps at most ``tile_q`` sequences and
-    each streams at most ``max_blocks_per_seq`` pages. ``pool_blocks`` is
-    accepted and ignored: sequences that share prefix pages walk the
-    same pages once each, so the pool's size bounds nothing."""
+    each streams at most ``ceil(max_blocks_per_seq / run_pages)`` runs.
+    ``pool_blocks`` is accepted and ignored: sequences that share prefix
+    pages walk the same pages once each, so the pool's size bounds
+    nothing."""
     del pool_blocks
-    return max(1, tile_q * max_blocks_per_seq)
+    return max(1, tile_q * -(-max_blocks_per_seq // run_pages))
 
 
 class StepMaps(NamedTuple):
     """The flat work list of one engine step (:func:`build_step_maps`)."""
     step_seq: np.ndarray    # [max_items] int32 — item w's sequence
-    step_blk: np.ndarray    # [max_items] int32 — item w's kv page index
+    step_blk: np.ndarray    # [max_items] int32 — item w's run of kv pages
     step_tile: np.ndarray   # [num_q_tiles + 1] int32 — CSR tile pointers
-    live: int               # items that name a real (sequence, page)
+    live: int               # items that name a real (sequence, run)
+    pages: int              # real pages the live items name (<= P a run)
 
     @property
     def walked(self) -> int:
@@ -156,32 +190,41 @@ class StepMaps(NamedTuple):
 
 
 def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
-                    block_size, max_items, max_seqs) -> StepMaps:
+                    block_size, max_items, max_seqs,
+                    run_pages=1) -> StepMaps:
     """Host-side (numpy) kernel work list for one engine step.
 
     ``cu_seqlens``: int array ``[num_seqs + 1]`` — prefix sums of the
     LIVE sequences' new-token counts (packed order). ``kv_lens``: int
     array ``[num_seqs]`` — each sequence's total KV length after this
-    step's writes (``context_len + new_len``).
+    step's writes (``context_len + new_len``). ``run_pages``: the pages an
+    item names (:func:`rpa_run_pages` of the pool the kernel will read).
 
     Returns :class:`StepMaps`: the items sorted by q tile, tile ``j``'s
-    in ``[step_tile[j], step_tile[j + 1])``, enumerating every
-    ``(sequence, kv page)`` pair the tile's tokens attend over — pages
-    only up to ``ceil(kv_len / block_size)``, i.e. only the real
-    context. A tile no sequence reaches owns one sentinel item
-    (sequence ``max_seqs``, the all-null block-table row); the arrays'
-    tail past ``step_tile[-1]`` is never walked and carries the same.
+    in ``[step_tile[j], step_tile[j + 1])``. An item ``(sequence, r)``
+    names the run of pages ``[r * run_pages, (r + 1) * run_pages)`` of
+    the sequence's block-table row, and a tile lists for each of its
+    sequences the runs up to the tile's **causal horizon** there: the
+    pages that hold a key the tile's last token of that sequence may see,
+    ``ceil((context + tokens of the sequence up to the tile's end) /
+    block_size)`` — not the pages the step's later tiles write. A run's
+    pages past that count are masked inside the kernel. A tile no
+    sequence reaches owns one sentinel item (sequence ``max_seqs``, the
+    all-null block-table row); the arrays' tail past ``step_tile[-1]``
+    is never walked and carries the same.
     """
     cu = [int(c) for c in cu_seqlens]
-    pages = [-(-int(kv) // block_size) for kv in kv_lens]
-    num_seqs = len(pages)
+    # context + the sequence's own tokens before flat position t is
+    # base[s] + t: keys a token at t - 1 may see
+    base = [int(kv) - cu[s + 1] for s, kv in enumerate(kv_lens)]
+    num_seqs = len(base)
     if total_tokens % tile_q:
         raise ValueError(
             f"total_tokens {total_tokens} not a multiple of tile_q "
             f"{tile_q}")
     num_tiles = total_tokens // tile_q
     seqs, blks, step_tile = [], [], [0]
-    first = empty_tiles = 0
+    first = empty_tiles = pages = 0
     for j in range(num_tiles):
         lo, hi = j * tile_q, (j + 1) * tile_q
         # sequences are packed in order: the tile's are a contiguous run
@@ -190,8 +233,11 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
         s = first
         while s < num_seqs and cu[s] < hi:
             if cu[s] < cu[s + 1]:   # a new_len == 0 slot owns no tokens
-                seqs += [s] * pages[s]
-                blks += range(pages[s])
+                seen = -(-(base[s] + min(hi, cu[s + 1])) // block_size)
+                runs = -(-seen // run_pages)
+                seqs += [s] * runs
+                blks += range(runs)
+                pages += seen
             s += 1
         if len(seqs) == step_tile[-1]:
             seqs.append(max_seqs)
@@ -209,7 +255,7 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
     step_seq[:walked] = seqs
     step_blk[:walked] = blks
     return StepMaps(step_seq, step_blk, np.asarray(step_tile, np.int32),
-                    walked - empty_tiles)
+                    walked - empty_tiles, pages)
 
 
 def _flatten_maps(step_seq, step_blk, max_seqs):
@@ -233,18 +279,21 @@ def _flatten_maps(step_seq, step_blk, max_seqs):
 
 # =========================== kernel ==========================================
 def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
-                q_ref, k_ref, *rest, tile_q, group, block_size, max_seqs,
-                sm_scale, value_cols=None):
-    # ``value_cols``: the page is a latent one and its values are the
+                q_ref, *rest, tile_q, group, block_size, max_seqs,
+                sm_scale, run_pages, value_cols=None):
+    # ``rest``: the run's ``run_pages`` key pages (one ref a page), then
+    # as many value pages, then the output and the scratch. With
+    # ``value_cols`` the page is a latent one and its values are the
     # first ``value_cols`` columns of the key page itself (one pool, one
-    # DMA a page); there is no v ref then
+    # DMA a page); there are no value refs then
+    k_refs, rest = rest[:run_pages], rest[run_pages:]
     if value_cols is None:
-        v_ref, o_ref, m_sc, l_sc, acc_sc = rest
-    else:
-        v_ref, (o_ref, m_sc, l_sc, acc_sc) = None, rest
+        v_refs, rest = rest[:run_pages], rest[run_pages:]
+    o_ref, m_sc, l_sc, acc_sc = rest
     w = pl.program_id(1)
     j = to_ref[w]
     rows = tile_q * group
+    keys = run_pages * block_size
 
     @pl.when(w == tp_ref[j])
     def _init():
@@ -258,8 +307,14 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
     def _compute():
         sb = sb_ref[w]
         q = q_ref[...]                                  # [rows, hd]
-        k = k_ref[...]                                  # [bs, hd]
-        v = v_ref[...] if v_ref is not None else k[:, :value_cols]
+
+        def run_of(refs):       # the run's pages back to back: [keys, width]
+            pages = [r[...] for r in refs]
+            return pages[0] if run_pages == 1 else \
+                jnp.concatenate(pages, axis=0)
+
+        k = run_of(k_refs)
+        v = run_of(v_refs) if value_cols is None else k[:, :value_cols]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
@@ -268,13 +323,15 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         #   start <= tok < end   and   kpos <= ctx + tok - start
         # multiplied through by ``group`` (r//g >= a  <=>  r >= a*g), so
         # the kernel needs no vector integer division
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, block_size), 0)
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
         lo = cu_ref[ss] - j * tile_q        # sequence span, tile-relative
         hi = cu_ref[ss + 1] - j * tile_q
-        kpos = sb * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1)
-        # one bound covers prior context, in-chunk causality, and (with
-        # page enumeration stopping at ceil(kv_len/bs)) page raggedness
+        kpos = sb * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        # one bound covers prior context, in-chunk causality, page
+        # raggedness and the run's pages past the sequence's last (null
+        # pages, or under the clamp the table's last: their ``kpos`` lies
+        # beyond every token's position)
         visible = (r >= lo * group) & (r < hi * group) & \
             (r >= (kpos - ctx_ref[ss] + lo) * group)
         s = jnp.maximum(jnp.where(visible, s, _MASK_VALUE), _MASK_VALUE)
@@ -283,7 +340,7 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
-        # rows with no live key in THIS step (another sequence's rows, or
+        # rows with no live key in THIS item (another sequence's rows, or
         # causally-dead decode rows) would contribute exp(MASK-MASK)=1
         # per column; zeroing them keeps their l at 0 so their m/l/acc
         # state rides through untouched (alpha re-scales acc by the same
@@ -292,6 +349,8 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         p = jnp.where(m_cur > _MASK_VALUE, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        # the one rescale of the accumulator an item: ``run_pages`` pages
+        # of keys for one pass over its [rows, vd] f32
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -317,7 +376,9 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
     latent = v_pool is None
     vd = int(value_cols) if latent else v_pool.shape[3]
     block_size = k_pool.shape[2]
+    run_pages = rpa_run_pages(vd, block_size)
     max_seqs = block_tables.shape[0] - 1
+    table_width = block_tables.shape[1]
     rows = tile_q * group
     # item w's tile: the tile pointers at or below w, less the first
     # (items past the live length read the last tile; none is walked)
@@ -328,27 +389,37 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
 
     kernel = functools.partial(
         _rpa_kernel, tile_q=tile_q, group=group, block_size=block_size,
-        max_seqs=max_seqs, sm_scale=sm_scale,
+        max_seqs=max_seqs, sm_scale=sm_scale, run_pages=run_pages,
         value_cols=vd if latent else None)
 
     def q_map(h, w, to, ss, sb, tp, bt, cu, ctx):
         return (h, to[w], 0)
 
-    def kv_map(h, w, to, ss, sb, tp, bt, cu, ctx):
-        # scalar-prefetch chase: physical page of this item's (seq, blk).
-        # A sentinel item resolves through the sentinel table row to the
-        # null page 0
-        return (bt[ss[w], sb[w]], h, 0, 0)
+    def page_map(i):
+        def kv_map(h, w, to, ss, sb, tp, bt, cu, ctx):
+            # scalar-prefetch chase: physical page i of this item's run.
+            # Past the sequence's pages the table's null padding gives
+            # the null page 0 (where its width is no multiple of the run
+            # the index is clamped to it: a page the mask kills); a
+            # sentinel item resolves through the sentinel table row
+            page = run_pages * sb[w] + i
+            if table_width % run_pages:
+                page = jnp.minimum(page, table_width - 1)
+            return (bt[ss[w], page], h, 0, 0)
+        return kv_map
+
+    def page_specs(width):
+        # one page a BlockSpec: the pipeline double-buffers plain page
+        # DMAs, a run is ``run_pages`` of them side by side
+        return [pl.BlockSpec((None, None, block_size, width), page_map(i))
+                for i in range(run_pages)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         # the inner bound is traced: the live length of the work list
         grid=(n_kv, step_tile[-1]),
-        in_specs=[
-            pl.BlockSpec((None, rows, hd), q_map),
-            pl.BlockSpec((None, None, block_size, hd), kv_map),
-        ] + ([] if latent else
-             [pl.BlockSpec((None, None, block_size, vd), kv_map)]),
+        in_specs=[pl.BlockSpec((None, rows, hd), q_map)] + page_specs(hd)
+        + ([] if latent else page_specs(vd)),
         out_specs=pl.BlockSpec((None, rows, vd), q_map),
         scratch_shapes=[
             pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -356,7 +427,7 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
             pltpu.VMEM((rows, vd), jnp.float32),
         ],
     )
-    pools = (k_pool,) if latent else (k_pool, v_pool)
+    pools = (k_pool,) * run_pages + (() if latent else (v_pool,) * run_pages)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -479,12 +550,13 @@ def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
             nxt += n_pages
         if nxt - 1 > pool_blocks:
             raise ValueError("synthetic workload exceeds pool")
-        ssq, sbk, stl, _ = build_step_maps(
+        run = rpa_run_pages(head_dim, block_size)
+        ssq, sbk, stl = build_step_maps(
             cu[:len(new_lens) + 1], kv_lens, total_tokens=T,
             tile_q=tile, block_size=block_size,
             max_items=rpa_max_items(T // tile, max_seqs,
-                                    max_blocks_per_seq),
-            max_seqs=max_seqs)
+                                    max_blocks_per_seq, run),
+            max_seqs=max_seqs, run_pages=run)[:3]
         with jax.ensure_compile_time_eval():
             dt = jnp.dtype(dtype)
             q0 = jnp.zeros((T, n_heads, head_dim), dt)

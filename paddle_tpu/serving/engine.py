@@ -98,8 +98,9 @@ def _build_serving_metrics(reg) -> dict:
         "rpa_steps": reg.counter(
             "serving_rpa_steps_total",
             "RPA kernel grid steps a kv head and layer, by kind: live "
-            "(work items that name a real page) / walked (the kernel's "
-            "grid bound: live + one step for each q tile without work)"),
+            "(work items that name a real run of pages) / walked (the "
+            "kernel's grid bound: live + one step for each q tile without "
+            "work) / pages (the pages the live items name)"),
         "moe_rows": reg.counter(
             "serving_moe_expert_rows_total",
             "token rows the step's routed experts took, by layer and by "
@@ -244,7 +245,8 @@ class ServingEngine:
         from paddle_tpu.models.generation import decode_surfaces
         from paddle_tpu.ops import paged_attention as pa
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
-            build_step_maps, default_tile_q, rpa_max_items, rpa_tile_q)
+            build_step_maps, default_tile_q, rpa_max_items, rpa_run_pages,
+            rpa_tile_q)
         from paddle_tpu.quantization.weight_only import (
             WEIGHT_MODES, calibration_from_checkpoint, quantization_metrics,
             quantize_state)
@@ -398,15 +400,20 @@ class ServingEngine:
         budget = self.max_batch + self.prefill_chunk
         self.step_tokens = -(-budget // self._tile_q) * self._tile_q
         num_tiles = self.step_tokens // self._tile_q
+        # pages a work item names: the kernel reads it off the pool it is
+        # handed, the list's builder is told the same
+        self._run_pages = rpa_run_pages(
+            spec.value_cols if spec.latent else spec.value_dim, block_size)
         self._max_items = rpa_max_items(
-            num_tiles, self.max_batch, self.cache.max_blocks_per_seq)
+            num_tiles, self.max_batch, self.cache.max_blocks_per_seq,
+            self._run_pages)
         # the work list of a step without work (one sentinel item a
         # tile): what the gather path feeds (same traced shapes, ignored
         # by the gather read — built once, not per step)
         self._null_step_maps = build_step_maps(
             [0], [], total_tokens=self.step_tokens, tile_q=self._tile_q,
             block_size=block_size, max_items=self._max_items,
-            max_seqs=self.max_batch)
+            max_seqs=self.max_batch, run_pages=self._run_pages)
         self.scheduler = Scheduler(self.cache, self.max_batch,
                                    self.prefill_chunk,
                                    step_tokens=self.step_tokens)
@@ -1084,7 +1091,8 @@ class ServingEngine:
             maps = self._build_step_maps(
                 cu[:len(entries) + 1], kv_lens, total_tokens=T,
                 tile_q=self._tile_q, block_size=self.cache.block_size,
-                max_items=self._max_items, max_seqs=S)
+                max_items=self._max_items, max_seqs=S,
+                run_pages=self._run_pages)
         else:
             # the gather path ignores the kernel work list; feed the
             # cached all-sentinel one instead of rebuilding per step
@@ -1145,10 +1153,13 @@ class ServingEngine:
         leaf.args["compiled"] = compiled
         if self.attn_impl == "rpa":
             # the RPA kernel's grid steps a kv head and layer: the work
-            # items that name a real page, and the bound it walked
-            leaf.args.update(rpa_live=maps.live, rpa_walked=maps.walked)
+            # items that name a real run of pages, the bound it walked,
+            # and the pages those runs name (over live: the runs' fill)
+            leaf.args.update(rpa_live=maps.live, rpa_walked=maps.walked,
+                             rpa_pages=maps.pages)
             self._m_rpa_steps.inc(maps.live, kind="live")
             self._m_rpa_steps.inc(maps.walked, kind="walked")
+            self._m_rpa_steps.inc(maps.pages, kind="pages")
         leaf.end()
         self._m_steps.inc(kind="unified")
         leaf = self._leaf("serving.fetch", n_step)

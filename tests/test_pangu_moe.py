@@ -20,7 +20,7 @@ from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.fleet import HeldExpertsLayer
 from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
-    build_step_maps, ragged_paged_attention, rpa_max_items)
+    build_step_maps, ragged_paged_attention, rpa_max_items, rpa_run_pages)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.kv_cache import PagedKVCache
 
@@ -165,10 +165,11 @@ def _latent_case(rng, seqs, block_size=8, heads=4, kd=24, vd=16, tile_q=8,
         pos[off:off + n] = c + np.arange(n)
         off += n
     q = rng.standard_normal((T, heads, kd)).astype(np.float32)
+    run = rpa_run_pages(vd, block_size)     # 2: an item is a run of pages
     maps = build_step_maps(
         cu[:len(seqs) + 1], kv_lens, total_tokens=T, tile_q=tile_q,
-        block_size=block_size, max_seqs=max_seqs,
-        max_items=rpa_max_items(T // tile_q, max_seqs, mbps))
+        block_size=block_size, max_seqs=max_seqs, run_pages=run,
+        max_items=rpa_max_items(T // tile_q, max_seqs, mbps, run))
     return dict(q=q, pool=pool, bt=bt, cu=cu, ctx=ctx, sid=sid, pos=pos,
                 maps=maps, lat=lat, total=total, vd=vd, seqs=seqs)
 

@@ -10,9 +10,12 @@ shapes — the 870s tier-1 cutoff counts dots):
 * token-level equality through ``ServingEngine`` greedy decode under
   BOTH settings of the impl knob — the engine-level acceptance check
   (the preemption/resume variant rides the slow lane);
-* the host-side work-list builder's invariants (every (tile, sequence,
-  page) exactly once, only real pages, one sentinel item for a tile
-  without work, the static bound honored under adversarial packings);
+* the host-side work-list builder's invariants (an item is a run of P
+  pages a tile can see: every visible (token, key) pair in exactly one
+  item of its tile, no item beyond the tile's causal horizon, one
+  sentinel item for a tile without work, the static bound honored under
+  adversarial packings), and both pool forms against their gather reader
+  and the eager reference at P in {1, 2, 4};
 * the kernel's dynamic grid bound: parity where the work list is short,
   long, absent for whole tiles or names shared pages, in both forms the
   wrapper takes, and a Mosaic compile for a described v5e at the serving
@@ -35,23 +38,28 @@ import paddle_tpu as pt
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.ops.paged_attention import (
     RaggedLayerCache, attend, paged_attention_impl, ragged_gather_attention,
-    write_tokens_to_pool)
+    ragged_latent_gather_attention, write_tokens_to_pool)
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     build_step_maps, default_tile_q, ragged_paged_attention, rpa_max_items,
-    rpa_max_steps)
+    rpa_max_steps, rpa_run_pages)
 from paddle_tpu.serving import ServingEngine
 
 
 # ---------------- raw kernel parity ------------------------------------------
 def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
-                 mbps=6, pool_blocks=24, pad_tiles=0, shared=()):
+                 mbps=6, pool_blocks=24, pad_tiles=0, shared=(),
+                 value_cols=None):
     """Build one token-packed ragged scenario: ``seqs`` is a list of
     ``(new_len, context_len)`` — new_len 0 models a padding slot whose
     metadata row exists but owns no tokens. ``pad_tiles`` appends q tiles
     of only padding tokens; ``shared`` lists ``(s, s0, n_pages)``:
     sequence ``s`` names the first ``n_pages`` pages of the earlier
-    ``s0`` as its own prefix (and holds the same keys there). Returns
-    everything the two impls and the eager oracle need."""
+    ``s0`` as its own prefix (and holds the same keys there). With
+    ``value_cols`` the K pool is read as a latent one (``n_kv`` 1): the
+    values are a key row's first ``value_cols`` columns. The work list's
+    items are runs of the pages the kernel will read off those shapes
+    (``rpa_run_pages``). Returns everything the two impls and the eager
+    oracle need."""
     n_heads = n_kv * grp
     max_seqs = len(seqs) + 1          # one extra never-used row
     total_new = sum(n for n, _ in seqs)
@@ -115,30 +123,41 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
     vp2 = write_tokens_to_pool(jnp.asarray(vp), jnp.asarray(vnew),
                                jnp.asarray(bt), jnp.asarray(sid),
                                jnp.asarray(pos))
+    if value_cols is not None:
+        assert n_kv == 1
+        full_v = [fk[..., :value_cols] for fk in full_k]
+    run = rpa_run_pages(value_cols or hd, block_size)
     maps = build_step_maps(cu[:len(seqs) + 1], kv_lens,
                            total_tokens=T, tile_q=tile_q,
                            block_size=block_size,
                            max_items=rpa_max_items(T // tile_q, max_seqs,
-                                                   mbps),
-                           max_seqs=max_seqs)
+                                                   mbps, run),
+                           max_seqs=max_seqs, run_pages=run)
     return dict(q=q, kp=kp2, vp=vp2, bt=bt, cu=cu, ctx=ctx, sid=sid,
                 pos=pos, maps=maps, full_k=full_k, full_v=full_v,
                 seqs=seqs, max_seqs=max_seqs, grp=grp, hd=hd,
-                tile_q=tile_q, kv_lens=kv_lens, block_size=block_size)
+                tile_q=tile_q, kv_lens=kv_lens, block_size=block_size,
+                run=run, mbps=mbps, value_cols=value_cols)
 
 
 def _run_rpa(c, maps=None):
     ssq, sbk, stl = (maps or c["maps"])[:3]
+    latent = c["value_cols"] is not None
     return np.asarray(ragged_paged_attention(
-        jnp.asarray(c["q"]), c["kp"], c["vp"], jnp.asarray(c["bt"]),
-        jnp.asarray(c["cu"]), jnp.asarray(c["ctx"]), ssq, sbk, stl))
+        jnp.asarray(c["q"]), c["kp"], None if latent else c["vp"],
+        jnp.asarray(c["bt"]), jnp.asarray(c["cu"]), jnp.asarray(c["ctx"]),
+        ssq, sbk, stl, value_cols=c["value_cols"]))
 
 
 def _run_gather(c):
+    args = [jnp.asarray(c[k]) for k in ("bt", "sid", "pos")]
+    scale = 1.0 / np.sqrt(c["hd"])
+    if c["value_cols"] is not None:
+        return np.asarray(ragged_latent_gather_attention(
+            jnp.asarray(c["q"]), c["kp"], *args,
+            value_cols=c["value_cols"], scale=scale))
     return np.asarray(ragged_gather_attention(
-        jnp.asarray(c["q"]), c["kp"], c["vp"], jnp.asarray(c["bt"]),
-        jnp.asarray(c["sid"]), jnp.asarray(c["pos"]),
-        scale=1.0 / np.sqrt(c["hd"])))
+        jnp.asarray(c["q"]), c["kp"], c["vp"], *args, scale=scale))
 
 
 def _eager_oracle(case):
@@ -147,7 +166,8 @@ def _eager_oracle(case):
     q, seqs = case["q"], case["seqs"]
     grp, hd = case["grp"], case["hd"]
     scale = 1.0 / np.sqrt(hd)
-    ref = np.zeros((q.shape[0], q.shape[1], hd), np.float32)
+    ref = np.zeros((q.shape[0], q.shape[1], case["value_cols"] or hd),
+                   np.float32)
     off = 0
     for s, (n, c) in enumerate(seqs):
         K, V = case["full_k"][s], case["full_v"][s]
@@ -184,11 +204,50 @@ def test_kernel_matches_gather_and_eager(block_size, grp):
     assert np.all(out_rpa[~valid] == 0.0)
 
 
+#: rows (new, context) in pages of 8 under tiles of 8: a 13-token chunk
+#: over 43 cached tokens straddles pages 5 and 6 and ends in a tile it
+#: shares with three decode rows; the first of them names the chunk's
+#: first five pages as its own prefix; a slot without tokens; a row with
+#: no context; a 6-token chunk across pages 0 and 1; then an empty tile.
+#: 7 and 6 pages a sequence and a table 7 wide: multiples of no run
+_RUN_MIX = [(13, 43), (1, 41), (1, 7), (0, 0), (1, 0), (6, 5)]
+
+
+@pytest.mark.parametrize("run", [1, 2, 4])
+@pytest.mark.parametrize("form", ["kv", "latent"])
+def test_runs_of_pages_match_gather_and_eager(form, run):
+    """An item is a run of ``run`` pages (the kernel reads the count off
+    the pool's value width): both pool forms against their gather reader
+    and the eager reference, over page counts and a table width that are
+    no multiple of the run (its last pages resolve to the null page, or
+    under the clamp to the table's last, and are masked), shared prefix
+    pages, a chunk that straddles pages, decode rows beside a chunk in
+    one tile, an empty tile."""
+    rng = np.random.RandomState(17 * run + len(form))
+    kw = dict(n_kv=2, grp=2, hd=8 * run) if form == "kv" else \
+        dict(n_kv=1, grp=4, hd=8 * run + 8, value_cols=8 * run)
+    c = _ragged_case(rng, _RUN_MIX, 8, mbps=7, pad_tiles=1,
+                     shared=[(1, 0, 5)], **kw)
+    assert c["run"] == run and bool(c["bt"].shape[1] % run) == (run > 1)
+    maps = c["maps"]
+    assert maps.walked == maps.live + 1             # the empty tile
+    assert maps.live <= maps.pages <= run * maps.live
+    out, ref = _run_rpa(c), _eager_oracle(c)
+    valid = c["sid"] < c["max_seqs"]
+    np.testing.assert_allclose(out[valid], ref[valid], atol=2e-5)
+    np.testing.assert_allclose(out[valid], _run_gather(c)[valid], atol=2e-5)
+    assert np.all(out[~valid] == 0.0)
+    # the per-tile form names the same runs
+    per_tile = _run_rpa(c, _per_tile_maps(
+        c, rpa_max_steps(c["tile_q"], c["mbps"], run_pages=run)))
+    np.testing.assert_array_equal(per_tile, out)
+
+
 def _per_tile_maps(c, width):
     """The flat list of case ``c`` as per-tile maps ``[num_tiles, width]``
     padded with the sentinel: the form a caller without the flat list
     hands the kernel."""
-    ssq, sbk, stl, _ = c["maps"]
+    ssq, sbk, stl = c["maps"][:3]
     num_tiles = len(stl) - 1
     seq2 = np.full((num_tiles, width), c["max_seqs"], np.int32)
     blk2 = np.zeros((num_tiles, width), np.int32)
@@ -212,8 +271,8 @@ _WALK_CASES = {
     # three trailing tiles hold only padding tokens: one sentinel item each
     "trailing_padding_tiles": ([(3, 6), (1, 12)], dict(pad_tiles=3)),
     "one_live_row": ([(1, 20)], dict(pad_tiles=1)),
-    # two rows name the same two prefix pages; 9 pages hold a step whose
-    # tile lists 6 + 6 + 1: the pool's size bounds no work list
+    # two rows name the same five prefix pages; 9 pages hold a step whose
+    # tile names 6 + 6 + 1: the pool's size bounds no work list
     "shared_prefix_small_pool": (
         [(5, 40), (3, 40), (1, 4)],
         dict(pool_blocks=9, shared=[(1, 0, 5)])),
@@ -237,17 +296,19 @@ def test_kernel_walks_the_live_work(name, form):
     for n, _ in seqs:
         touched.update(range(off // tile_q, -(-(off + n) // tile_q)))
         off += n
-    # each sequence walks its pages once for every tile it spans
-    assert maps.live == sum(
-        -(-kv // 8) * (-(-cu1 // tile_q) - cu0 // tile_q)
-        for kv, cu0, cu1 in zip(c["kv_lens"], c["cu"], c["cu"][1:])
-        if cu1 > cu0)
+    # each sequence walks, for every tile it spans, the runs of pages up
+    # to that tile's causal horizon
+    seen = [-(-(ctx + min((j + 1) * tile_q, cu1) - cu0) // 8)
+            for (_, ctx), cu0, cu1 in zip(seqs, c["cu"], c["cu"][1:])
+            for j in range(cu0 // tile_q, -(-cu1 // tile_q))]
+    assert maps.live == sum(-(-n // c["run"]) for n in seen)
+    assert maps.pages == sum(seen)
     assert maps.walked == maps.live + num_tiles - len(touched)
     if name == "shared_prefix_small_pool":
-        assert maps.walked > kw["pool_blocks"]
+        assert maps.pages > kw["pool_blocks"]
         assert kw["pool_blocks"] < tile_q * 6       # < tile_q x mbps
-    out = _run_rpa(c, maps if form == "flat"
-                   else _per_tile_maps(c, rpa_max_steps(tile_q, 11)))
+    out = _run_rpa(c, maps if form == "flat" else _per_tile_maps(
+        c, rpa_max_steps(tile_q, 11, run_pages=c["run"])))
     valid = c["sid"] < c["max_seqs"]
     np.testing.assert_allclose(out[valid], _run_gather(c)[valid],
                                atol=2e-5)
@@ -273,44 +334,57 @@ def _covered(maps, max_seqs):
     return got, sentinel_tiles
 
 
-def test_step_maps_cover_each_page_exactly_once():
+@pytest.mark.parametrize("run", [1, 2, 4])
+def test_step_maps_cover_each_page_exactly_once(run):
     """Work-list invariants: for every tile, each overlapping sequence
-    contributes exactly ceil(kv_len / block_size) items (its REAL pages,
-    nothing more, in order), empty sequences contribute none, a tile
-    without work owns one sentinel item, and the tail past the live
-    length carries the sentinel."""
+    contributes the runs of ``run`` pages up to the tile's causal horizon
+    in it (``ceil((context + its tokens up to the tile's end) /
+    block_size)`` pages: nothing a later tile writes, nothing more, in
+    order), empty sequences contribute none, a tile without work owns one
+    sentinel item, and the tail past the live length carries the
+    sentinel."""
     cu = np.array([0, 5, 5, 6, 16])  # seq 1 is a new_len == 0 slot
-    kv_lens = [5, 8, 9, 16]
+    kv_lens = [5, 8, 29, 47]         # seq 3: 10 tokens over tiles 0-1
     tile_q, bs, max_seqs = 8, 8, 6
     maps = build_step_maps(cu, kv_lens, total_tokens=32,
                            tile_q=tile_q, block_size=bs,
-                           max_items=rpa_max_items(4, max_seqs, 4),
-                           max_seqs=max_seqs)
-    want = {}
+                           max_items=rpa_max_items(4, max_seqs, 6, run),
+                           max_seqs=max_seqs, run_pages=run)
+    want, pages = {}, 0
     for j in range(4):
         lo, hi = j * tile_q, (j + 1) * tile_q
         for s in range(4):
             if cu[s] < cu[s + 1] and cu[s + 1] > lo and cu[s] < hi:
-                want[(j, s)] = list(range(-(-kv_lens[s] // bs)))
+                ctx = kv_lens[s] - (cu[s + 1] - cu[s])
+                seen = -(-(ctx + min(hi, cu[s + 1]) - cu[s]) // bs)
+                want[(j, s)] = list(range(-(-seen // run)))
+                pages += seen
+    # the 10-token chunk: tile 0 sees 37 + 2 keys (5 pages), tile 1 all 6
+    assert len(want[(0, 3)]) == -(-5 // run)
+    assert len(want[(1, 3)]) == -(-6 // run)
     got, sentinel_tiles = _covered(maps, max_seqs)
-    assert got == want                      # each page once, in order
+    assert got == want                      # each run once, in order
     assert sentinel_tiles == [2, 3]
     assert maps.live == sum(len(v) for v in want.values())
+    assert maps.pages == pages
     assert maps.walked == maps.live + len(sentinel_tiles)
     assert np.all(maps.step_seq[maps.walked:] == max_seqs)
     assert list(maps.step_tile) == sorted(maps.step_tile)
     with pytest.raises(ValueError, match="max_items"):
         build_step_maps(cu, kv_lens, total_tokens=32, tile_q=tile_q,
-                        block_size=bs, max_items=5, max_seqs=max_seqs)
+                        block_size=bs, max_items=5, max_seqs=max_seqs,
+                        run_pages=run)
 
 
+@pytest.mark.parametrize("run", [1, 2, 4])
 @pytest.mark.parametrize("packing", ["straddlers", "single_tokens",
                                      "random"])
-def test_step_maps_stay_inside_the_static_bound(packing):
+def test_step_maps_stay_inside_the_static_bound(packing, run):
     """The arrays' static length holds under the packings that make the
     most (tile, sequence) pairs, every sequence at full table width: a
     sequence across each tile boundary, one sequence a token, and random
-    cuts of the token axis."""
+    cuts of the token axis. A sequence's last tile walks the whole table,
+    an earlier one the runs up to its horizon."""
     tile_q, bs, mbps, num_tiles = 8, 4, 5, 6
     T = tile_q * num_tiles
     rng = np.random.RandomState(3)
@@ -322,20 +396,65 @@ def test_step_maps_stay_inside_the_static_bound(packing):
         cuts = [list(range(T + 1))]
     else:
         cuts = [[0] + sorted(rng.choice(np.arange(1, T), size=rng.randint(
-            1, T - 1), replace=False).tolist()) + [T] for _ in range(50)]
+            1, T - 1), replace=False).tolist()) + [T] for _ in range(80)]
+        # a sequence's new tokens fit its table
+        cuts = [cu for cu in cuts if max(np.diff(cu)) <= mbps * bs]
+        assert len(cuts) >= 40
+    full = list(range(-(-mbps // run)))
     for cu in cuts:
         n = len(cu) - 1
-        bound = rpa_max_items(num_tiles, n, mbps)
+        bound = rpa_max_items(num_tiles, n, mbps, run)
         maps = build_step_maps(cu, [mbps * bs] * n, total_tokens=T,
                                tile_q=tile_q, block_size=bs,
-                               max_items=bound, max_seqs=n)
+                               max_items=bound, max_seqs=n, run_pages=run)
         got, sentinel_tiles = _covered(maps, n)
         assert not sentinel_tiles
-        assert all(v == list(range(mbps)) for v in got.values())
+        last = {s: -(-b // tile_q) - 1 for s, b in enumerate(cu[1:])}
+        assert all(v == full[:len(v)] and (j < last[s] or v == full)
+                   for (j, s), v in got.items())
         spans = sum(-(-b // tile_q) - a // tile_q
                     for a, b in zip(cu, cu[1:]))
         assert len(got) == spans <= num_tiles + n - 1
-        assert maps.walked == maps.live == mbps * spans <= bound
+        assert len(full) * n <= maps.walked == maps.live \
+            <= len(full) * spans <= bound
+
+
+@pytest.mark.parametrize("run", [1, 2, 4])
+def test_every_visible_pair_lies_in_one_item_of_its_tile(run):
+    """The list's contract with the kernel's mask, on random steps: every
+    (token, key) pair the token may see lies in exactly one item of the
+    token's tile; no item lies wholly beyond its tile's horizon (its
+    first key is one some token of the tile sees); the walk is inside the
+    static bound for this run."""
+    tile_q, bs, mbps, max_seqs = 8, 4, 9, 7
+    rng = np.random.RandomState(run)
+    for _ in range(40):
+        n = rng.randint(1, max_seqs + 1)
+        new = rng.choice([0, 1, 1, 2, 5, 11, 19], size=n)
+        ctx = np.array([rng.randint(0, mbps * bs - m + 1) for m in new])
+        cu = np.concatenate([[0], np.cumsum(new)])
+        T = -(-max(int(cu[-1]), 1) // tile_q) * tile_q + tile_q
+        bound = rpa_max_items(T // tile_q, max_seqs, mbps, run)
+        maps = build_step_maps(cu, ctx + new, total_tokens=T, tile_q=tile_q,
+                               block_size=bs, max_items=bound,
+                               max_seqs=max_seqs, run_pages=run)
+        assert maps.walked <= bound
+        got, _ = _covered(maps, max_seqs)
+        keys = run * bs
+        for s in range(n):
+            for t in range(cu[s], cu[s + 1]):
+                runs = got[(t // tile_q, s)]
+                assert len(set(runs)) == len(runs)
+                # the token sees keys 0 .. ctx + (t - cu[s]): all inside
+                # the tile's runs, which start at 0 and are consecutive
+                assert runs == list(range(len(runs)))
+                assert ctx[s] + t - cu[s] < len(runs) * keys
+        for (j, s), runs in got.items():
+            last_tok = min((j + 1) * tile_q, cu[s + 1]) - 1
+            assert runs[-1] * keys <= ctx[s] + last_tok - cu[s]
+        assert maps.pages == sum(
+            -(-(ctx[s] + min((j + 1) * tile_q, cu[s + 1]) - cu[s]) // bs)
+            for j, s in got)
 
 
 # ---------------- the step's K/V write -----------------------------------------
@@ -469,6 +588,7 @@ def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
     tokens = eng["max_batch"] + eng["prefill_chunk"]
     assert tokens % tile == 0
     seqs = eng["max_batch"] + 1
+    assert rpa_run_pages(hd, eng["block_size"]) == 1    # an item is a page
     items = rpa_max_items(tokens // tile, eng["max_batch"],
                           eng["max_blocks_per_seq"])
 
@@ -508,8 +628,12 @@ def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
     tile = default_tile_q(heads, jnp.bfloat16)
     tokens = -(-(eng["max_batch"] + eng["prefill_chunk"]) // tile) * tile
     seqs = eng["max_batch"] + 1
+    # the run the rule gives at these shapes: 512 value columns in pages
+    # of 128 tokens
+    run = rpa_run_pages(rank, eng["block_size"])
+    assert run == 4
     items = rpa_max_items(tokens // tile, eng["max_batch"],
-                          eng["max_blocks_per_seq"])
+                          eng["max_blocks_per_seq"], run)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -575,8 +699,10 @@ def test_step_writes_its_pools_in_place_at_serving_shapes(
     tile = default_tile_q(grp, jnp.bfloat16)
     tokens = -(-(eng["max_batch"] + eng["prefill_chunk"]) // tile) * tile
     seqs = eng["max_batch"] + 1
-    items = rpa_max_items(tokens // tile, eng["max_batch"],
-                          eng["max_blocks_per_seq"])
+    items = rpa_max_items(
+        tokens // tile, eng["max_batch"], eng["max_blocks_per_seq"],
+        rpa_run_pages(cfg["kv_lora_rank"] if latent else hd,
+                      eng["block_size"]))
     pool_shape = (eng["max_blocks"] + 1, kv, eng["block_size"], hd)
 
     def arr(shape, dtype=jnp.int32):
@@ -785,6 +911,52 @@ def test_engine_token_streams_identical_across_impls():
     assert streams["rpa"] == streams["gather"]
     assert streams["rpa"] == [
         _eager_continuation(model, p, 5) for p in prompts]
+
+
+def test_engine_counts_the_pages_its_items_name():
+    """Each step's ``serving.dispatch`` span carries ``rpa_pages`` beside
+    ``rpa_live`` / ``rpa_walked`` and ``serving_rpa_steps_total`` grows by
+    the same under ``kind="pages"``: the pages the step's live items name,
+    between one and P an item (P read off the pool: 16 value columns in
+    pages of 4 tokens), and what the step's own list says."""
+    from paddle_tpu.serving.engine import serving_metrics
+    model = _tiny(3)
+    eng = ServingEngine(model, max_batch=4, max_blocks=48, block_size=4,
+                        prefill_chunk=16, attn_impl="rpa")
+    assert eng._run_pages == 4
+    assert eng._max_items == rpa_max_items(
+        eng.step_tokens // eng._tile_q, 4, eng.cache.max_blocks_per_seq, 4)
+    build, leaf = eng._build_step_maps, eng._leaf
+    built, dispatched = [], []
+    eng._build_step_maps = lambda *a, **k: (
+        built.append(build(*a, **k)) or built[-1])
+
+    def record(name, step, **args):
+        ev = leaf(name, step, **args)
+        if name == "serving.dispatch":
+            dispatched.append(ev)
+        return ev
+    eng._leaf = record
+    counter = serving_metrics()["rpa_steps"]
+    before = {k: counter.value(kind=k) for k in ("live", "walked", "pages")}
+    rng = np.random.RandomState(5)
+    handles = [eng.submit(rng.randint(1, 128, n), max_new_tokens=4)
+               for n in (50, 3, 9)]
+    eng.run_until_idle()
+    assert all(len(h.result(30)["token_ids"]) == 4 for h in handles)
+    assert len(built) == len(dispatched) >= 6
+    for m, ev in zip(built, dispatched):
+        assert ev.args["rpa_live"] == m.live
+        assert ev.args["rpa_walked"] == m.walked
+        assert ev.args["rpa_pages"] == m.pages
+        assert 0 < m.live <= m.pages <= 4 * m.live
+    grown = {k: counter.value(kind=k) - before[k] for k in before}
+    assert grown == {"live": sum(m.live for m in built),
+                     "walked": sum(m.walked for m in built),
+                     "pages": sum(m.pages for m in built)}
+    # the 50-token prompt's later chunks see 5 to 13 pages a tile: some
+    # runs are full, a walk's last one is not
+    assert 1.0 < grown["pages"] / grown["live"] < 4.0
 
 
 @pytest.mark.slow

@@ -453,7 +453,8 @@ def test_rpa_walk_follows_live_work_under_one_executable():
         built = []
         eng._build_step_maps = lambda *a, **k: (
             built.append(build(*a, **k)) or built[-1])
-        before = counter.value(kind="walked"), counter.value(kind="live")
+        before = (counter.value(kind="walked"), counter.value(kind="live"),
+                  counter.value(kind="pages"))
         assert eng.step() is False          # no work: nothing dispatched
         handles = [eng.submit(p, max_new_tokens=8 + i % 5)
                    for i, p in enumerate(short)]
@@ -484,12 +485,17 @@ def test_rpa_walk_follows_live_work_under_one_executable():
                 and m.step_seq[m.step_tile[j]] == eng.max_batch
                 for j in range(num_tiles))
         walks = [m.walked for m in built]
+        pages = [m.pages for m in built]
+        assert all(m.live <= m.pages <= 4 * m.live for m in built)
         assert grown == (sum(walks), sum(m.live for m in built))
+        assert counter.value(kind="pages") - before[2] == sum(pages)
     assert streams["rpa"] == streams["gather"]
     assert streams["rpa"][-1] == _eager_continuation(model, long_prompt, 4)
     # the bound moved with the work: a lone decode tail walks a few
-    # items, the long prompt's last chunks a sequence's every page per tile
-    assert max(walks) >= 4 * min(walks), walks
+    # items, the long prompt's last chunks the runs of pages each tile can
+    # see (an item names up to 4 pages here: 16-wide values in pages of 4)
+    assert max(walks) >= 2 * min(walks), walks
+    assert max(pages) >= 4 * min(pages), pages
 
 
 # ---------------- generate_loop early exit (satellite) -----------------------
