@@ -186,11 +186,13 @@ def trainer_leg(model_kw, batch, seq, steps=5, mesh=None, dtype="bfloat16"):
 # ------------------------------------------------------------- kernel check --
 def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
                  block_size, prefill_chunk, max_blocks_per_seq,
-                 dtype="bfloat16"):
+                 dtype="bfloat16", window=None):
     """``ragged_paged_attention`` against ``ragged_gather_attention`` on
     the same pools, shaped as ``ServingEngine`` shapes its step. ``seqs``
     is ``[(new_tokens, context_tokens), ...]``. Outputs, not sampled
-    tokens, are the oracle. Returns the max abs difference."""
+    tokens, are the oracle. Under a ``window`` (a window layer's group)
+    the pages wholly behind a sequence's window are released: null in its
+    table, and never walked. Returns the max abs difference."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.paged_attention import ragged_gather_attention
@@ -202,7 +204,9 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     tile = default_tile_q(n_heads // n_kv, dtype)
     T = -(-(max_batch + prefill_chunk) // tile) * tile
     run = rpa_run_pages(head_dim, block_size)
-    max_items = rpa_max_items(T // tile, max_batch, max_blocks_per_seq, run)
+    max_items = rpa_max_items(T // tile, max_batch, max_blocks_per_seq, run,
+                              window=window, tile_q=tile,
+                              block_size=block_size)
     bt = np.zeros((max_batch + 1, max_blocks_per_seq), np.int32)
     cu = np.zeros(max_batch + 2, np.int32)
     ctx = np.zeros(max_batch + 1, np.int32)
@@ -218,6 +222,8 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
                 f"pool or the token budget")
         bt[s, :pages] = np.arange(next_block, next_block + pages)
         next_block += pages
+        if window is not None:       # a released-page sequence
+            bt[s, :max(0, c - window + 1) // block_size] = 0
         ctx[s] = c
         sid[off:off + n] = s
         pos[off:off + n] = c + np.arange(n)
@@ -232,12 +238,13 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     maps = build_step_maps(cu[:len(seqs) + 1], kv_lens, total_tokens=T,
                            tile_q=tile, block_size=block_size,
                            max_items=max_items, max_seqs=max_batch,
-                           run_pages=run)
+                           run_pages=run, window=window)
     t0 = time.perf_counter()
-    rpa = jax.jit(ragged_paged_attention)(
+    rpa = jax.jit(ragged_paged_attention, static_argnames="window")(
         q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(cu),
         jnp.asarray(ctx), jnp.asarray(maps.step_seq),
-        jnp.asarray(maps.step_blk), jnp.asarray(maps.step_tile))
+        jnp.asarray(maps.step_blk), jnp.asarray(maps.step_tile),
+        window=window)
     rpa = np.asarray(rpa.astype(jnp.float32))
     print(f"  rpa kernel compile+run {time.perf_counter() - t0:.1f}s "
           f"(tile_q={tile}, tokens={T}, flat work list: {maps.walked} "
@@ -245,10 +252,14 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
           f"run of {run} pages) items naming {maps.pages} pages + "
           f"{maps.walked - maps.live} tiles without work, "
           f"in arrays of {max_items}; pages "
-          f"{[-(-kv // block_size) for kv in kv_lens]})")
-    gather = jax.jit(ragged_gather_attention, static_argnames="scale")(
+          f"{[-(-kv // block_size) for kv in kv_lens]}"
+          + (f"; window {window}: {maps.pages} of {maps.pages_causal} "
+             f"pages" if window else "") + ")")
+    gather = jax.jit(ragged_gather_attention,
+                     static_argnames=("scale", "window"))(
         q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(sid),
-        jnp.asarray(pos), scale=1.0 / float(np.sqrt(head_dim)))
+        jnp.asarray(pos), scale=1.0 / float(np.sqrt(head_dim)),
+        window=window)
     gather = np.asarray(gather.astype(jnp.float32))
     live = sid < max_batch
     err = float(np.max(np.abs(rpa[live] - gather[live])))
@@ -433,6 +444,11 @@ def run():
     kernel_check(MODEL["num_attention_heads"], MODEL["num_attention_heads"],
                  hd, [(40, 300), (1, 15), (1, 2000)],
                  max_blocks_per_seq=table, **ENGINE)
+    # 7 query heads a KV head under a window of 512: a chunk that straddles
+    # the window's edge and decode rows whose early pages were released
+    kernel_check(28, 4, 128, [(80, 1100), (1, 15), (1, 600), (1, 3199),
+                              (40, 480)],
+                 max_blocks_per_seq=table, window=512, **ENGINE)
 
     print("[server leg]")
     engine, _ = server_leg(MODEL, ENGINE, PROMPTS, PREFIX_LEN, NEW_TOKENS)

@@ -356,8 +356,9 @@ class MoELayer(Layer):
 
 
 class HeldExpertsLayer(Layer):
-    """Dropless routed experts, a chip's share (the sigmoid-scored,
-    top-k-normalised router of the DeepSeek-V3 / openPangu-Ultra family).
+    """Dropless routed experts, a chip's share (by default the
+    sigmoid-scored, top-k-normalised router of the DeepSeek-V3 /
+    openPangu-Ultra family).
 
     ``num_experts`` experts exist, the router scores all of them and each
     token chooses ``top_k``; ``held`` names the experts whose weights live
@@ -367,6 +368,13 @@ class HeldExpertsLayer(Layer):
         w = s_top / (sum s_top + 1e-20) * routed_scaling_factor
         y = sum over the chosen experts that are held of w_k E_k(h)
         E(h) = W_down (silu(W_gate h) * W_up h)
+
+    With ``score="softmax"`` the ``top_k`` largest router logits are
+    chosen and ``w = softmax`` over those logits (float32; the softmax over
+    all experts renormalised over the chosen, no scaling factor); with
+    ``activation="relu"`` the gate is ``relu`` (ReGLU). ``router_input``
+    (``forward``) is what the router reads where that is not the experts'
+    input ``h`` (a router placed before the block's attention).
 
     ``w`` is normalised over all ``top_k`` chosen, held or not: the parts
     the shares of a deployment compute add up to the whole layer's
@@ -386,8 +394,13 @@ class HeldExpertsLayer(Layer):
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, held=None,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
-                 init_std=0.02):
+                 init_std=0.02, score="sigmoid", activation="silu"):
         super().__init__()
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score {score!r} (want sigmoid|softmax)")
+        if activation not in ("silu", "relu"):
+            raise ValueError(f"activation {activation!r} (want silu|relu)")
+        self.score, self.activation = score, activation
         held = tuple(range(num_experts)) if held is None \
             else tuple(int(e) for e in held)
         if len(set(held)) != len(held) or not held \
@@ -409,10 +422,11 @@ class HeldExpertsLayer(Layer):
         self.last_rows = None
 
 
-    def forward(self, x, token_mask=None):
+    def forward(self, x, token_mask=None, router_input=None):
         """x: [..., d_model] -> the held experts' part of the routed
         output, same shape. ``token_mask`` (broadcastable to x's leading
-        dims, True = real token): padding chooses no expert."""
+        dims, True = real token): padding chooses no expert.
+        ``router_input`` (x's shape): what the router scores, default x."""
         import jax
         import jax.numpy as jnp
 
@@ -421,21 +435,29 @@ class HeldExpertsLayer(Layer):
         local_of = np.full((E,), n, np.int32)      # global id -> held slot
         local_of[list(self.held)] = np.arange(n, dtype=np.int32)
         product = jax.lax.ragged_dot
+        softmax = self.score == "softmax"
+        gate = jax.nn.relu if self.activation == "relu" else jax.nn.silu
+        routed, masked = router_input is not None, token_mask is not None
 
         def f(xa, gw, wg, wu, wd, *rest):
             lead = xa.shape[:-1]
             xt = xa.reshape(-1, xa.shape[-1])
             T = xt.shape[0]
-            s = jax.nn.sigmoid(jnp.dot(
-                xt.astype(jnp.float32), gw.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))        # [T, E]
-            top_s, top_i = jax.lax.top_k(s, K)
-            w = top_s * scale
-            if norm:
-                w = w / (top_s.sum(-1, keepdims=True) + 1e-20)
+            rt = rest[0].reshape(T, -1) if routed else xt
+            logits = jnp.dot(
+                rt.astype(jnp.float32), gw.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)         # [T, E]
+            if softmax:
+                top_s, top_i = jax.lax.top_k(logits, K)
+                w = jax.nn.softmax(top_s, axis=-1)
+            else:
+                top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), K)
+                w = top_s * scale
+                if norm:
+                    w = w / (top_s.sum(-1, keepdims=True) + 1e-20)
             slot = jnp.asarray(local_of)[top_i]              # [T, K]
-            if rest:
-                vm = jnp.broadcast_to(rest[0].astype(bool), lead).reshape(T)
+            if masked:
+                vm = jnp.broadcast_to(rest[-1].astype(bool), lead).reshape(T)
                 slot = jnp.where(vm[:, None], slot, n)
             # the T*K assignments sorted by held expert, absent ones last
             R = T * K
@@ -443,7 +465,7 @@ class HeldExpertsLayer(Layer):
             order = jnp.argsort(flat, stable=True)
             sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
             rows = xt[order // K]                            # [R, d]
-            act = jax.nn.silu(product(rows, wg, sizes)) \
+            act = gate(product(rows, wg, sizes)) \
                 * product(rows, wu, sizes)
             out = product(act.astype(xt.dtype), wd, sizes)   # [R, d]
             # combine, gather-only: where assignment (t, k) sits in the
@@ -455,7 +477,8 @@ class HeldExpertsLayer(Layer):
                     for k in range(K))
             return y.astype(xa.dtype).reshape(xa.shape), sizes
 
-        extra = () if token_mask is None else (token_mask,)
+        extra = (() if router_input is None else (router_input,)) \
+            + (() if token_mask is None else (token_mask,))
         out, rows = apply_op(f, x, self.router, self.w_gate, self.w_up,
                              self.w_down, *extra, op_name="held_experts")
         self.last_rows = rows

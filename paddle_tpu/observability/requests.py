@@ -299,7 +299,7 @@ class RequestLedger:
         rec = self._inflight.get(seq.req_id)
         if rec is None:
             return
-        blocks = len(seq.block_ids) + (1 if seq.cow_src is not None else 0)
+        blocks = seq.blocks_held() + (1 if seq.cow_src is not None else 0)
         with self._lock:
             if rec._occ_t is not None and rec._occ_blocks > 0:
                 d = rec._occ_blocks * max(now - rec._occ_t, 0.0)

@@ -57,27 +57,36 @@ __all__ = ["LayerCacheSpec", "RaggedLayerCache", "attend",
 
 class LayerCacheSpec(NamedTuple):
     """What an attention layer keeps of a token in the paged cache; a
-    served model states it (``model.kv_cache_spec()``, the same for every
-    layer) and the pools are built from it
-    (``serving.kv_cache.PagedKVCache``).
+    served model states it (``model.kv_cache_spec()``: one spec for every
+    layer, or a list of one a layer) and the pools are built from it
+    (``serving.kv_cache.PagedKVCache``). Layers of equal spec form a
+    **group**: one allocator, one block table a sequence and one kernel
+    work list a step (docs/SERVING.md "Layer groups").
 
     ``value_dim`` None is a **latent** page (MLA): the layer writes one
     row ``[c | k_rope]`` of ``key_dim`` numbers a token under its one
     head, its values are the first ``value_cols`` columns of that same
-    row, and no value pool exists."""
+    row, and no value pool exists.
+
+    ``window`` None: a token sees every earlier key. An int: key ``j`` is
+    visible to query ``i`` iff ``0 <= i - j < window`` (the last ``window``
+    positions, the query's own among them); pages wholly behind the
+    window of a sequence's next token are released by the cache manager."""
     kv_heads: int
     key_dim: int
     value_dim: object = None       # int, or None: no value pool
     value_cols: int = 0            # latent page: columns that are values
+    window: object = None          # int, or None: every earlier key
 
     @property
     def latent(self) -> bool:
         return self.value_dim is None
 
     @classmethod
-    def kv(cls, kv_heads: int, head_dim: int) -> "LayerCacheSpec":
+    def kv(cls, kv_heads: int, head_dim: int, window=None) -> "LayerCacheSpec":
         """A K and a V pool of one width (GQA/MHA layers)."""
-        return cls(int(kv_heads), int(head_dim), int(head_dim))
+        return cls(int(kv_heads), int(head_dim), int(head_dim),
+                   window=None if window is None else int(window))
 
 
 def gather_pool(pool, block_tables):
@@ -126,6 +135,11 @@ class RaggedLayerCache(NamedTuple):
     # trace, so two engines tracing at once cannot see each other's.
     impl: object = None
     mesh: object = None
+    # not an array either: the layer's attention window
+    # (``LayerCacheSpec.window``), None where it sees every earlier key.
+    # ``block_tables`` and the work list are then its group's: released
+    # pages are null in the table and the list never names them
+    window: object = None
 
     def live_mask(self):
         """``[1, T]`` bool Tensor over the step's packed tokens: False at
@@ -244,9 +258,20 @@ def quantize_kv_slots(x):
     return q.astype(jnp.int8), scale
 
 
+def _visible(positions, L, window):
+    """``[T, L]`` bool: key ``j`` visible to the token at ``positions[t]``
+    (causal, and under a ``window`` only its last ``window`` positions)."""
+    kpos = jnp.arange(L, dtype=jnp.int32)[None, :]
+    pos = positions.astype(jnp.int32)[:, None]
+    visible = kpos <= pos
+    if window is not None:
+        visible &= kpos > pos - int(window)
+    return visible
+
+
 def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
                             positions, *, scale, k_scale=None,
-                            v_scale=None):
+                            v_scale=None, window=None):
     """Token-packed GQA attention via the XLA-gather fallback: gather
     every sequence's whole padded context, pick each token's row, dense
     masked softmax. Semantically identical to the rpa kernel (the parity
@@ -269,8 +294,7 @@ def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
     qg = q.reshape(T, n_kv, grp, hd)
     s = jnp.einsum("tkgh,tlkh->tkgl", qg.astype(jnp.float32),
                    kt.astype(jnp.float32)) * scale
-    visible = jnp.arange(L, dtype=jnp.int32)[None, :] <= \
-        positions.astype(jnp.int32)[:, None]            # [T, L]
+    visible = _visible(positions, L, window)            # [T, L]
     s = jnp.where(visible[:, None, None, :], s,
                   jnp.finfo(jnp.float32).min)
     w = jax.nn.softmax(s, axis=-1).astype(vt.dtype)
@@ -287,8 +311,7 @@ def ragged_latent_gather_attention(q, pool, block_tables, seq_ids,
     rows = gather_pool(pool, block_tables)[seq_ids][:, :, 0]   # [T, L, kd]
     rows = rows.astype(jnp.float32)
     s = jnp.einsum("thd,tld->thl", q.astype(jnp.float32), rows) * scale
-    visible = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] <= \
-        positions.astype(jnp.int32)[:, None]
+    visible = _visible(positions, rows.shape[1], None)
     s = jnp.where(visible[:, None, :], s, jnp.finfo(jnp.float32).min)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("thl,tlc->thc", w, rows[..., :value_cols])
@@ -310,8 +333,8 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
       are the values; ``out`` [T, n_heads, value_cols].
 
     The reader is :func:`paged_attention_impl` of ``cache.impl`` and the
-    scales. ``out`` at padding tokens is garbage (gather) or 0 (rpa) and
-    the caller discards it either way."""
+    scales, and is told the cache's ``window``. ``out`` at padding tokens
+    is garbage (gather) or 0 (rpa) and the caller discards it either way."""
     bt, sid, pos = cache.block_tables, cache.seq_ids, cache.positions
     work = (bt, cache.cu_seqlens, cache.context_lens, cache.step_seq,
             cache.step_blk, cache.step_tile)
@@ -321,6 +344,10 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
             "latent attention is served over a latent pool (v_pool None) "
             "and K/V attention over a K and a V pool")
     quantized = cache.k_scale is not None
+    window = cache.window
+    if latent and window is not None:
+        raise NotImplementedError("latent pages are read whole: no model "
+                                  "here keeps them under a window")
     rpa = paged_attention_impl(cache.impl, quantized=quantized) == "rpa"
     tp = _tp_mesh(cache.mesh)
     if scale is None:
@@ -352,7 +379,7 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
             (kq, vq, ks, vs), bt, sid, pos, tp)
         out = ragged_gather_attention(
             q, k_pool, v_pool, bt, sid, pos, scale=scale, k_scale=k_scale,
-            v_scale=v_scale).astype(q.dtype)
+            v_scale=v_scale, window=window).astype(q.dtype)
         return out, cache._replace(k_pool=k_pool, v_pool=v_pool,
                                    k_scale=k_scale, v_scale=v_scale)
 
@@ -360,9 +387,10 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
                                     bt, sid, pos, tp)
     if not rpa:
         out = ragged_gather_attention(q, k_pool, v_pool, bt, sid, pos,
-                                      scale=scale)
+                                      scale=scale, window=window)
     elif tp is None:
-        out = ragged_paged_attention(q, k_pool, v_pool, *work, sm_scale=scale)
+        out = ragged_paged_attention(q, k_pool, v_pool, *work, sm_scale=scale,
+                                     window=window)
     else:
         # SPMD over the kernel's head dimension (ISSUE 15): Pallas is
         # opaque to GSPMD, so shard_map runs one kernel instance per mp
@@ -379,7 +407,7 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
         pools = P(None, ax, None, None)
         out = jax.shard_map(
             lambda qa, kp, vp, *w: ragged_paged_attention(
-                qa, kp, vp, *w, sm_scale=scale),
+                qa, kp, vp, *w, sm_scale=scale, window=window),
             mesh=mesh, in_specs=(heads, pools, pools) + (P(),) * len(work),
             out_specs=heads, check_vma=False)(q, k_pool, v_pool, *work)
     return out, cache._replace(k_pool=k_pool, v_pool=v_pool)

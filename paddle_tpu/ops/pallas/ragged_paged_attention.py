@@ -66,7 +66,13 @@ CSR form: tile ``j`` owns items ``[step_tile[j], step_tile[j + 1])`` and
 at a tile's first item and the output block written at its last. A tile
 lists a sequence's runs only up to its **causal horizon** there (the
 pages that hold a key its last token of that sequence may see), not the
-pages the step's later tiles write. Every tile owns at least one item — a
+pages the step's later tiles write. Under a layer's attention **window** (key
+``j`` visible to query ``i`` iff ``0 <= i - j < window``) the walk also
+starts late: at the run that holds the first key the tile's first token
+of that sequence can see, so a tile walks ``O(window + tile_q)`` keys
+however long the sequence is, and the pages the cache manager released
+behind the window (null in the table) are never named. The windowed call
+is ``rpa_win`` in a trace. Every tile owns at least one item — a
 tile of only padding tokens gets one sentinel item (sequence
 ``max_seqs``, the null page, no compute) — so every output block is
 written and padding rows stay exactly 0. Rows of the score tile that
@@ -149,7 +155,8 @@ def rpa_run_pages(value_width: int, block_size: int) -> int:
 
 
 def rpa_max_items(num_tiles: int, max_seqs: int, max_blocks_per_seq: int,
-                  run_pages: int = 1) -> int:
+                  run_pages: int = 1, *, window=None, tile_q: int = 0,
+                  block_size: int = 0) -> int:
     """Static length of the flat work list's arrays. An item is a run of
     up to ``run_pages`` consecutive pages of one sequence for one q tile.
     Sequences are packed back to back, so a sequence is walked once per q
@@ -158,8 +165,21 @@ def rpa_max_items(num_tiles: int, max_seqs: int, max_blocks_per_seq: int,
     ``ceil(max_blocks_per_seq / run_pages)`` runs, and a tile without
     work adds one sentinel item where it adds no walk. Sound when
     sequences share prefix pages (the pool's size is no part of it). It
-    sizes arrays only: the kernel walks the live length."""
-    return -(-max_blocks_per_seq // run_pages) * (num_tiles + max_seqs)
+    sizes arrays only: the kernel walks the live length. Under a
+    ``window`` (with the list's ``tile_q`` and ``block_size``) a walk
+    spans the keys from the first its first token sees to its last
+    token's own, ``window + tile_q - 1`` of them: at most
+    ``ceil((window + tile_q) / block_size) + 1`` pages, where that is
+    fewer than the table's width."""
+    pages = max_blocks_per_seq
+    if window is not None:
+        pages = min(pages, -(-(int(window) + tile_q) // block_size) + 1)
+    # a walk of ``pages`` pages that starts anywhere in a run touches one
+    # run more than ``ceil(pages / run_pages)`` unless a run is a page
+    runs = -(-pages // run_pages) + (1 if window is not None
+                                     and run_pages > 1 else 0)
+    return min(runs, -(-max_blocks_per_seq // run_pages)) \
+        * (num_tiles + max_seqs)
 
 
 def rpa_max_steps(tile_q: int, max_blocks_per_seq: int,
@@ -182,6 +202,7 @@ class StepMaps(NamedTuple):
     step_tile: np.ndarray   # [num_q_tiles + 1] int32 — CSR tile pointers
     live: int               # items that name a real (sequence, run)
     pages: int              # real pages the live items name (<= P a run)
+    pages_causal: int = 0   # pages the same walks name with no window
 
     @property
     def walked(self) -> int:
@@ -191,7 +212,7 @@ class StepMaps(NamedTuple):
 
 def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
                     block_size, max_items, max_seqs,
-                    run_pages=1) -> StepMaps:
+                    run_pages=1, window=None) -> StepMaps:
     """Host-side (numpy) kernel work list for one engine step.
 
     ``cu_seqlens``: int array ``[num_seqs + 1]`` — prefix sums of the
@@ -208,7 +229,13 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
     pages that hold a key the tile's last token of that sequence may see,
     ``ceil((context + tokens of the sequence up to the tile's end) /
     block_size)`` — not the pages the step's later tiles write. A run's
-    pages past that count are masked inside the kernel. A tile no
+    pages past that count are masked inside the kernel. With ``window``
+    the list starts, for each (tile, sequence), at the run that holds the
+    first key the tile's **first** token of that sequence can see (key
+    ``max(0, context + tokens of the sequence before the tile's first -
+    window + 1)``); ``step_blk`` stays the run's logical index, and
+    ``pages_causal`` counts what the same walks would name without the
+    window. A tile no
     sequence reaches owns one sentinel item (sequence ``max_seqs``, the
     all-null block-table row); the arrays' tail past ``step_tile[-1]``
     is never walked and carries the same.
@@ -224,7 +251,7 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
             f"{tile_q}")
     num_tiles = total_tokens // tile_q
     seqs, blks, step_tile = [], [], [0]
-    first = empty_tiles = pages = 0
+    first = empty_tiles = pages = pages_causal = 0
     for j in range(num_tiles):
         lo, hi = j * tile_q, (j + 1) * tile_q
         # sequences are packed in order: the tile's are a contiguous run
@@ -235,9 +262,16 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
             if cu[s] < cu[s + 1]:   # a new_len == 0 slot owns no tokens
                 seen = -(-(base[s] + min(hi, cu[s + 1])) // block_size)
                 runs = -(-seen // run_pages)
-                seqs += [s] * runs
-                blks += range(runs)
-                pages += seen
+                # the first page that holds a key the tile's first token
+                # of the sequence (position base + max(lo, cu)) can see
+                page0 = 0 if window is None else \
+                    max(0, base[s] + max(lo, cu[s]) - window + 1) \
+                    // block_size
+                run0 = page0 // run_pages
+                seqs += [s] * (runs - run0)
+                blks += range(run0, runs)
+                pages += seen - page0
+                pages_causal += seen
             s += 1
         if len(seqs) == step_tile[-1]:
             seqs.append(max_seqs)
@@ -255,7 +289,7 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
     step_seq[:walked] = seqs
     step_blk[:walked] = blks
     return StepMaps(step_seq, step_blk, np.asarray(step_tile, np.int32),
-                    walked - empty_tiles, pages)
+                    walked - empty_tiles, pages, pages_causal)
 
 
 def _flatten_maps(step_seq, step_blk, max_seqs):
@@ -280,7 +314,7 @@ def _flatten_maps(step_seq, step_blk, max_seqs):
 # =========================== kernel ==========================================
 def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
                 q_ref, *rest, tile_q, group, block_size, max_seqs,
-                sm_scale, run_pages, value_cols=None):
+                sm_scale, run_pages, value_cols=None, window=None):
     # ``rest``: the run's ``run_pages`` key pages (one ref a page), then
     # as many value pages, then the output and the scratch. With
     # ``value_cols`` the page is a latent one and its values are the
@@ -334,6 +368,9 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         # beyond every token's position)
         visible = (r >= lo * group) & (r < hi * group) & \
             (r >= (kpos - ctx_ref[ss] + lo) * group)
+        if window is not None:
+            # kpos > ctx + tok - start - window, through ``group`` too
+            visible &= r < (kpos - ctx_ref[ss] + lo + window) * group
         s = jnp.maximum(jnp.where(visible, s, _MASK_VALUE), _MASK_VALUE)
         m_prev = m_sc[:, :1]                            # lane-replicated
         l_prev = l_sc[:, :1]
@@ -367,7 +404,7 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
 
 def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
               block_tables, cu_seqlens, context_lens, *, tile_q, group,
-              sm_scale, value_cols=None):
+              sm_scale, value_cols=None, window=None):
     """``q_heads`` [n_kv, T*group, hd] (token-major rows per kv head) →
     out in the same layout, ``vd`` wide: the value pool's width, or with
     ``v_pool`` None (a latent pool) the ``value_cols`` first columns of
@@ -390,7 +427,7 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
     kernel = functools.partial(
         _rpa_kernel, tile_q=tile_q, group=group, block_size=block_size,
         max_seqs=max_seqs, sm_scale=sm_scale, run_pages=run_pages,
-        value_cols=vd if latent else None)
+        value_cols=vd if latent else None, window=window)
 
     def q_map(h, w, to, ss, sb, tp, bt, cu, ctx):
         return (h, to[w], 0)
@@ -436,9 +473,9 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
         # the device op's name in a profiler trace (``rpa.N custom-call``,
-        # ``rpa_mla.N custom-call`` over a latent pool; without it the op
-        # is named after the jitted caller)
-        name="rpa_mla" if latent else "rpa",
+        # ``rpa_mla.N custom-call`` over a latent pool, ``rpa_win.N`` under
+        # a window; without it the op is named after the jitted caller)
+        name="rpa_mla" if latent else "rpa" if window is None else "rpa_win",
     )(tile_of, step_seq, step_blk, step_tile, block_tables, cu_seqlens,
       context_lens, q_heads, *pools)
 
@@ -446,7 +483,7 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
                            context_lens, step_seq, step_blk,
                            step_tile=None, *, sm_scale=None,
-                           value_cols=None):
+                           value_cols=None, window=None):
     """GQA attention for a token-packed ragged batch over paged KV.
 
     With ``v_pool`` None the pool is a **latent** one (MLA read absorbed:
@@ -456,6 +493,11 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
     query head shares the one page, and the output is
     ``[total_tokens, n_heads, value_cols]``. Same work list, same body;
     the kernel is then named ``rpa_mla`` in a trace.
+
+    ``window`` (static): key ``j`` is visible to query ``i`` iff ``0 <= i
+    - j < window``; the work list should then come from
+    ``build_step_maps(window=...)`` (a causal list is correct too, and
+    walks pages the mask kills). Named ``rpa_win`` in a trace.
 
     ``q`` [total_tokens, n_heads, hd]; pools
     ``[num_blocks + 1, n_kv, block_size, hd]`` (this step's new K/V
@@ -500,7 +542,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
         jnp.asarray(cu_seqlens, jnp.int32),
         jnp.asarray(context_lens, jnp.int32),
         tile_q=tile_q, group=group, sm_scale=float(sm_scale),
-        value_cols=value_cols)
+        value_cols=value_cols,
+        window=None if window is None else int(window))
     return out.reshape(n_kv, T, group, vd).transpose(1, 0, 2, 3) \
               .reshape(T, n_heads, vd)
 

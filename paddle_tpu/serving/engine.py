@@ -100,7 +100,12 @@ def _build_serving_metrics(reg) -> dict:
             "RPA kernel grid steps a kv head and layer, by kind: live "
             "(work items that name a real run of pages) / walked (the "
             "kernel's grid bound: live + one step for each q tile without "
-            "work) / pages (the pages the live items name)"),
+            "work) / pages (the pages the live items name); where the "
+            "cache has several layer groups also by group"),
+        "kv_released": reg.counter(
+            "serving_kv_pages_released_total",
+            "pages a window layer group gave back behind its sequences' "
+            "windows, by group"),
         "moe_rows": reg.counter(
             "serving_moe_expert_rows_total",
             "token rows the step's routed experts took, by layer and by "
@@ -172,6 +177,27 @@ def _build_serving_metrics(reg) -> dict:
     }
 
 
+def _row_args(rows, limit: int = 250) -> dict:
+    """The rows as the step runs them, ``"<new>@<context>"`` in row order
+    joined by ``;`` (a TraceMe cuts a value at a comma), as span arguments
+    ``rows``, ``rows_1``, ``rows_2``, ...: whole rows each, a value under
+    the 256 characters a trace keeps of one. Up to some thirty rows
+    ``rows`` holds them all."""
+    out, key, n = {}, "rows", 0
+    for row in rows:
+        if key in out and len(out[key]) + 1 + len(row) > limit:
+            n += 1
+            key = f"rows_{n}"
+        out[key] = f"{out[key]};{row}" if key in out else row
+    return out or {"rows": ""}
+
+
+def _a_group(arrays):
+    """One device array a layer group: how the compiled step takes the
+    block tables and the work lists."""
+    return tuple(jnp.asarray(a) for a in arrays)
+
+
 class RequestHandle:
     """Caller-side view of a submitted request (thread-safe wait)."""
 
@@ -224,14 +250,17 @@ class ServingEngine:
     ``num_attention_heads``, a position cap), a backbone that takes
     ``caches=[RaggedLayerCache, ...]`` and returns ``(hidden, caches)``
     (``models.generation.decode_surfaces``), and ``kv_cache_spec()``:
-    the ``ops.paged_attention.LayerCacheSpec`` of its layers, from which
-    the pools are built. Optional: ``moe_expert_rows()`` (the rows each held
-    expert took in the traced step, ``[layers, held]`` int32: returned by
-    the compiled step beside the logits and published by the commit
-    span and ``serving_moe_expert_rows_total``) and
+    the ``ops.paged_attention.LayerCacheSpec`` of its layers (one, or a
+    list of one a layer: layers of equal spec form a group with its own
+    pools, block tables and kernel work list, and ``max_blocks`` may then
+    be a mapping by group name), from which the pools are built.
+    Optional: ``moe_expert_rows()`` (the rows each held expert took in the
+    traced step, ``[layers, held]`` int32: returned by the compiled step
+    beside the logits and published by the commit span and
+    ``serving_moe_expert_rows_total``) and
     ``clear_decode_side_effects()``."""
 
-    def __init__(self, model, max_batch: int = 8, max_blocks: int = 64,
+    def __init__(self, model, max_batch: int = 8, max_blocks=64,
                  block_size: int = 16, prefill_chunk: int = 16,
                  max_blocks_per_seq: Optional[int] = None,
                  warm_start_from: Optional[str] = None,
@@ -305,8 +334,10 @@ class ServingEngine:
             raise TypeError(
                 f"{type(model).__name__} states no kv_cache_spec(): a "
                 f"served model returns its layers' LayerCacheSpec")
-        spec = spec_fn()
-        # the read kernel's geometry (tile height, model-parallel split)
+        specs = spec_fn()
+        # one spec for every layer, or one a layer; the read kernel's
+        # geometry (tile height, model-parallel split) is the first's
+        spec = specs if hasattr(specs, "kv_heads") else specs[0]
         n_kv = spec.kv_heads
         hd = spec.key_dim
         #: block-granular prefix-cache KV reuse (ISSUE 15) — on by
@@ -355,11 +386,13 @@ class ServingEngine:
         max_pos = getattr(cfg, "max_position_embeddings", None)
         if max_pos is None and hasattr(cfg, "_attn_cfg"):
             max_pos = cfg._attn_cfg().max_position_embeddings
+        most_blocks = max(max_blocks.values()) \
+            if isinstance(max_blocks, dict) else max_blocks
         if max_pos is None:
-            max_pos = max_blocks * block_size
+            max_pos = most_blocks * block_size
         if max_blocks_per_seq is None:
-            max_blocks_per_seq = min(max_blocks, -(-max_pos // block_size))
-        self.cache = PagedKVCache(nl, max_blocks, block_size, spec,
+            max_blocks_per_seq = min(most_blocks, -(-max_pos // block_size))
+        self.cache = PagedKVCache(nl, max_blocks, block_size, specs,
                                   max_blocks_per_seq=max_blocks_per_seq,
                                   dtype=dtype,
                                   prefix_cache=self.prefix_cache_enabled,
@@ -392,28 +425,44 @@ class ServingEngine:
         # kernel candidates it will never execute would be pure startup
         # cost
         n_heads = cfg.num_attention_heads
+        groups = self.cache.groups
         self._tile_q = default_tile_q(n_heads // n_kv, dtype) \
             if self.attn_impl == "gather" else rpa_tile_q(
                 self.max_batch + self.prefill_chunk, n_heads, n_kv, hd,
-                block_size, self.cache.max_blocks_per_seq, max_blocks,
-                dtype=str(jnp.dtype(dtype)))
+                block_size, self.cache.max_blocks_per_seq,
+                groups[0].num_blocks, dtype=str(jnp.dtype(dtype)))
+        # one tile height for every group's list (the tiles cut the one
+        # packed token axis): the tallest any group's head grouping asks
+        self._tile_q = max([self._tile_q] + [
+            default_tile_q(n_heads // g.spec.kv_heads, dtype)
+            for g in groups[1:]])
         budget = self.max_batch + self.prefill_chunk
         self.step_tokens = -(-budget // self._tile_q) * self._tile_q
         num_tiles = self.step_tokens // self._tile_q
-        # pages a work item names: the kernel reads it off the pool it is
-        # handed, the list's builder is told the same
-        self._run_pages = rpa_run_pages(
-            spec.value_cols if spec.latent else spec.value_dim, block_size)
-        self._max_items = rpa_max_items(
-            num_tiles, self.max_batch, self.cache.max_blocks_per_seq,
-            self._run_pages)
-        # the work list of a step without work (one sentinel item a
+
+        # a group's work list: the pages an item names (the kernel reads
+        # it off the pool it is handed, the list's builder is told the
+        # same), the window where its layers have one, and the static
+        # length of its arrays
+        def maps_kw(sp):
+            run = rpa_run_pages(
+                sp.value_cols if sp.latent else sp.value_dim, block_size)
+            return dict(
+                total_tokens=self.step_tokens, tile_q=self._tile_q,
+                block_size=block_size, max_seqs=self.max_batch,
+                run_pages=run, window=sp.window,
+                max_items=rpa_max_items(
+                    num_tiles, self.max_batch, self.cache.max_blocks_per_seq,
+                    run, window=sp.window, tile_q=self._tile_q,
+                    block_size=block_size))
+        self._maps_kw = [maps_kw(g.spec) for g in groups]
+        # read by the accepted benchmark's test of its pages-per-item reader
+        self._run_pages = self._maps_kw[0]["run_pages"]
+        # the work lists of a step without work (one sentinel item a
         # tile): what the gather path feeds (same traced shapes, ignored
         # by the gather read — built once, not per step)
-        self._null_step_maps = build_step_maps(
-            [0], [], total_tokens=self.step_tokens, tile_q=self._tile_q,
-            block_size=block_size, max_items=self._max_items,
-            max_seqs=self.max_batch, run_pages=self._run_pages)
+        self._null_step_maps = [build_step_maps([0], [], **kw)
+                                for kw in self._maps_kw]
         self.scheduler = Scheduler(self.cache, self.max_batch,
                                    self.prefill_chunk,
                                    step_tokens=self.step_tokens)
@@ -597,8 +646,11 @@ class ServingEngine:
         def data(t):
             return None if t is None else t.data
 
+        group_of = self.cache.group_of_layer
+        windows = [g.window for g in self.cache.groups]
+
         def step(stt, tokens, k_pools, v_pools, k_scales, v_scales,
-                 bt, cu, ctx, sid, pos, ssq, sbk, stl, last_idx, aid):
+                 bts, cu, ctx, sid, pos, ssqs, sbks, stls, last_idx, aid):
             # executes at trace time only — counting compiles is the
             # point (the compile-once guard tests read it)
             self.step_traces += 1  # analysis: allow(trace-attr-mutation)
@@ -608,12 +660,17 @@ class ServingEngine:
             # arrays of the model's dtype
             stt = {k: (v.dequantize() if isinstance(v, QuantizedLeaf)
                        else v) for k, v in stt.items()}
-            meta = [Tensor(a) for a in (bt, cu, ctx, sid, pos, ssq, sbk,
-                                        stl)]
+            # a set of step metadata a layer group (``bts``, ``ssqs``,
+            # ``sbks``, ``stls``: one array a group); the token axis'
+            # own (cu, ctx, sid, pos) is the step's
+            shared = [Tensor(a) for a in (cu, ctx, sid, pos)]
+            metas = [[Tensor(bt)] + shared + [Tensor(a) for a in work]
+                     for bt, *work in zip(bts, ssqs, sbks, stls)]
             caches = [pa.RaggedLayerCache(
-                pool(k_pools, i), pool(v_pools, i), *meta,
+                pool(k_pools, i), pool(v_pools, i), *metas[group_of[i]],
                 pool(k_scales, i), pool(v_scales, i),
-                impl=impl, mesh=self.mesh) for i in range(nl)]
+                impl=impl, mesh=self.mesh, window=windows[group_of[i]])
+                for i in range(nl)]
             # per-row LoRA dispatch: pin this step's token->slot ids for
             # the adapter hooks traced inside the backbone call
             adapters = (lora.adapter_ids(aid) if n_slots
@@ -701,10 +758,11 @@ class ServingEngine:
                 return self._step.lower(
                     self._st, jnp.asarray(tokens), self.cache.k_pools,
                     self.cache.v_pools, self.cache.k_scales,
-                    self.cache.v_scales, jnp.asarray(bt), jnp.asarray(cu),
-                    jnp.asarray(ctx), jnp.asarray(sid), jnp.asarray(pos),
-                    jnp.asarray(maps.step_seq), jnp.asarray(maps.step_blk),
-                    jnp.asarray(maps.step_tile), jnp.asarray(last_idx),
+                    self.cache.v_scales, _a_group(bt for _ in maps),
+                    jnp.asarray(cu), jnp.asarray(ctx), jnp.asarray(sid),
+                    jnp.asarray(pos), _a_group(m.step_seq for m in maps),
+                    _a_group(m.step_blk for m in maps),
+                    _a_group(m.step_tile for m in maps), jnp.asarray(last_idx),
                     jnp.asarray(aid))
             finally:
                 self._clear_model_side_effects()
@@ -724,6 +782,7 @@ class ServingEngine:
         self._m_preempt = m["preemptions"]
         self._m_steps = m["steps"]
         self._m_rpa_steps = m["rpa_steps"]
+        self._m_kv_released = m["kv_released"]
         self._m_moe_rows = m["moe_rows"]
         self._m_in_flight = m["in_flight"]
         self._m_kv_block_seconds = m["kv_block_seconds"]
@@ -793,12 +852,11 @@ class ServingEngine:
         # refcount-0 blocks are evictable capacity, not pressure — the
         # headroom gauge counts both so load shedding doesn't misread a
         # warm cache as a full pool
-        alloc = self.cache.allocator
-        cap = max(alloc.capacity, 1)
-        reclaim = alloc.num_reclaimable()
-        self._m_kv_headroom.set((alloc.num_free() + reclaim) / cap)
-        self._m_kv_reclaimable.set(reclaim / cap)
-        pc = self.cache.prefix_cache
+        # (the tightest group's: a sequence grows in every group or none)
+        free, reclaim = self.cache.tightest().fractions()
+        self._m_kv_headroom.set(free + reclaim)
+        self._m_kv_reclaimable.set(reclaim)
+        pc = self.cache.groups[0].prefix_cache
         if pc is not None:
             for key, counter in (("lookups", self._m_prefix_lookups),
                                  ("hits", self._m_prefix_hits),
@@ -814,7 +872,7 @@ class ServingEngine:
         # pool-occupancy cost: the allocator's exact integral, published
         # as a counter delta against a per-engine cursor (same pattern
         # as preemptions — the registry counter is process-global)
-        bs_total = alloc.block_seconds_total()
+        bs_total = self.cache.block_seconds_total()
         d = bs_total - self._published_block_seconds
         if d > 0:
             self._m_kv_block_seconds.inc(d)
@@ -928,13 +986,15 @@ class ServingEngine:
                 f"prompt+max_new_tokens = {total} exceeds the engine's "
                 f"max sequence length {self.max_model_len}")
         need = self.cache.blocks_for(total)
-        if need > min(self.cache.allocator.capacity,
-                      self.cache.max_blocks_per_seq):
-            raise ValueError(
-                f"request needs {need} KV blocks but the engine has "
-                f"{self.cache.allocator.capacity} (table width "
-                f"{self.cache.max_blocks_per_seq}) — raise max_blocks or "
-                "shorten the request")
+        width = self.cache.max_blocks_per_seq
+        for g in self.cache.groups:
+            # under a window a sequence holds a bounded number of pages
+            held = min(need, g.max_pages_held(self.prefill_chunk, width))
+            if need > width or held > g.allocator.capacity:
+                raise ValueError(
+                    f"request needs {held} KV blocks but the engine has "
+                    f"{g.allocator.capacity} (table width {width}) — "
+                    f"raise max_blocks or shorten the request")
         # non-base tenants hash their KV blocks under an adapter-specific
         # chain seed (slot + load generation): identical prompts under
         # different adapters produce different KV, so they must never
@@ -1049,9 +1109,9 @@ class ServingEngine:
         # once the copy ran (back to the cache's refcount).
         for seq, _ in prefills:
             if seq.cow_src is not None and seq.cow_index is not None \
-                    and seq.cow_index < len(seq.block_ids):
+                    and seq.cow_index < len(seq.tables[0]):
                 self.cache.copy_block(seq.cow_src,
-                                      seq.block_ids[seq.cow_index])
+                                      seq.tables[0][seq.cow_index])
                 self.scheduler._release_cow(seq)
 
         entries = [(seq, 1, False) for seq in decode] + \
@@ -1060,7 +1120,9 @@ class ServingEngine:
         assert len(entries) <= S and \
             sum(n for _, n, _ in entries) <= T, "scheduler over-packed"
         tokens = np.zeros((1, T), np.int32)
-        bt = np.zeros((S + 1, self.cache.max_blocks_per_seq), np.int32)
+        groups = self.cache.groups
+        bts = [np.zeros((S + 1, self.cache.max_blocks_per_seq), np.int32)
+               for _ in groups]
         cu = np.zeros((S + 2,), np.int32)
         ctx = np.zeros((S + 1,), np.int32)
         sid = np.full((T,), S, np.int32)   # sentinel = padding
@@ -1077,7 +1139,8 @@ class ServingEngine:
             else:
                 tokens[0, off] = seq.last_token()
                 c = seq.num_cached
-            bt[i] = self.cache.pad_block_table(seq.block_ids)
+            for bt, table in zip(bts, seq.tables):
+                bt[i] = self.cache.pad_block_table(table)
             ctx[i] = c
             sid[off:off + n] = i
             pos[off:off + n] = c + np.arange(n)
@@ -1088,11 +1151,8 @@ class ServingEngine:
             off += n
         cu[len(entries) + 1:] = off
         if self.attn_impl == "rpa":
-            maps = self._build_step_maps(
-                cu[:len(entries) + 1], kv_lens, total_tokens=T,
-                tile_q=self._tile_q, block_size=self.cache.block_size,
-                max_items=self._max_items, max_seqs=S,
-                run_pages=self._run_pages)
+            maps = [self._build_step_maps(cu[:len(entries) + 1], kv_lens,
+                                          **kw) for kw in self._maps_kw]
         else:
             # the gather path ignores the kernel work list; feed the
             # cached all-sentinel one instead of rebuilding per step
@@ -1119,10 +1179,8 @@ class ServingEngine:
             "serving.dispatch", n_step, decode_rows=len(decode),
             prefill_rows=len(prefills),
             prefill_tokens=sum(n for _, n in prefills),
-            # the rows as the step runs them, "<new>@<context>" in row
-            # order (";": a TraceMe cuts a value at a comma)
-            rows=";".join(f"{n}@{ctx[i]}"
-                          for i, (_, n, _) in enumerate(entries)))
+            **_row_args(f"{n}@{ctx[i]}"
+                        for i, (_, n, _) in enumerate(entries)))
         leaf.begin()
         t0 = time.perf_counter_ns()
         compiles0 = self.step_traces
@@ -1130,10 +1188,11 @@ class ServingEngine:
             out = step_fn(
                 self._st, jnp.asarray(tokens), self.cache.k_pools,
                 self.cache.v_pools, self.cache.k_scales,
-                self.cache.v_scales, jnp.asarray(bt), jnp.asarray(cu),
+                self.cache.v_scales, _a_group(bts), jnp.asarray(cu),
                 jnp.asarray(ctx), jnp.asarray(sid), jnp.asarray(pos),
-                jnp.asarray(maps.step_seq), jnp.asarray(maps.step_blk),
-                jnp.asarray(maps.step_tile), jnp.asarray(last_idx),
+                _a_group(m.step_seq for m in maps),
+                _a_group(m.step_blk for m in maps),
+                _a_group(m.step_tile for m in maps), jnp.asarray(last_idx),
                 jnp.asarray(aid))
             if step_fn is not self._step:
                 out, taps_out = out[:-1], out[-1]
@@ -1154,12 +1213,23 @@ class ServingEngine:
         if self.attn_impl == "rpa":
             # the RPA kernel's grid steps a kv head and layer: the work
             # items that name a real run of pages, the bound it walked,
-            # and the pages those runs name (over live: the runs' fill)
-            leaf.args.update(rpa_live=maps.live, rpa_walked=maps.walked,
-                             rpa_pages=maps.pages)
-            self._m_rpa_steps.inc(maps.live, kind="live")
-            self._m_rpa_steps.inc(maps.walked, kind="walked")
-            self._m_rpa_steps.inc(maps.pages, kind="pages")
+            # and the pages those runs name (over live: the runs' fill).
+            # The bare names are the first group's; a cache of several
+            # groups also writes each group's under ``<name>_<group>``
+            # (and what a window group's walks would name without the
+            # window), and labels the counter by group
+            lead = maps[0]
+            leaf.args.update(rpa_live=lead.live, rpa_walked=lead.walked,
+                             rpa_pages=lead.pages)
+            for g, m in zip(groups, maps):
+                label = {"group": g.name} if len(groups) > 1 else {}
+                for kind in ("live", "walked", "pages"):
+                    self._m_rpa_steps.inc(getattr(m, kind), kind=kind,
+                                          **label)
+                    if label:
+                        leaf.args[f"rpa_{kind}_{g.name}"] = getattr(m, kind)
+                if g.window is not None:
+                    leaf.args[f"rpa_pages_causal_{g.name}"] = m.pages_causal
         leaf.end()
         self._m_steps.inc(kind="unified")
         leaf = self._leaf("serving.fetch", n_step)
@@ -1217,6 +1287,12 @@ class ServingEngine:
                 tok = self._sample(arr[i], seq)
                 self._emit_token(seq, tok)
                 tokens_out += 1
+        if any(g.window is not None for g in groups):
+            for seq, _, _ in entries:
+                if seq.slot is not None:     # still holds its pages
+                    for name, n in self.scheduler.release_behind_window(
+                            seq).items():
+                        self._m_kv_released.inc(n, group=name)
         leaf.args["tokens_out"] = tokens_out
         leaf.end()
 
@@ -1239,8 +1315,7 @@ class ServingEngine:
         as reclaimable when ``finish`` drops the refcounts). Committed
         blocks are never written again (sequence writes land at
         ``num_cached`` and beyond), so the index entry is immutable."""
-        pc = self.cache.prefix_cache
-        if pc is None:
+        if not self.prefix_cache_enabled:
             return
         bs = self.cache.block_size
         full = seq.num_cached // bs
@@ -1252,7 +1327,10 @@ class ServingEngine:
         for i in range(seq.committed_blocks, full):
             d = chain_hash(seq.committed_hash,
                            stream[i * bs:(i + 1) * bs])
-            pc.register(d, seq.block_ids[i])
+            # in every group: the step that wrote the block's last token
+            # wrote it in each (a window group releases only afterwards)
+            for g, table in zip(self.cache.groups, seq.tables):
+                g.prefix_cache.register(d, table[i])
             seq.committed_hash = d
         seq.committed_blocks = full
 
@@ -1475,24 +1553,24 @@ class ServingEngine:
         """Lock-free snapshot (every field below is individually
         synchronized): /healthz must answer even while a step holds the
         engine lock through a first-time XLA compile."""
-        alloc = self.cache.allocator
-        cap = max(alloc.capacity, 1)
-        free = alloc.num_free()
-        reclaim = alloc.num_reclaimable()
-        pc = self.cache.prefix_cache
+        lead = self.cache.groups[0]
+        alloc, pc = lead.allocator, lead.prefix_cache
+        # the tightest group's shares: a sequence grows in every group or
+        # in none, so the router and load shedding read that one
+        free, reclaim = self.cache.tightest().fractions()
         out = {
             "running": self.scheduler.num_running,
             "waiting": self.scheduler.num_waiting,
             "kv_blocks_in_use": alloc.blocks_in_use(),
-            "kv_blocks_free": free,
-            "kv_blocks_reclaimable": reclaim,
+            "kv_blocks_free": alloc.num_free(),
+            "kv_blocks_reclaimable": alloc.num_reclaimable(),
             "preemptions": self.scheduler.num_preemptions,
             # ledger headline numbers (ISSUE 16): scrapeable without
             # /statusz — in-flight counts accepted-but-unfinished, and
             # the block-seconds integral is the allocator's exact one
             "requests_in_flight": len(self._handles),
             "kv_block_seconds_total": round(
-                alloc.block_seconds_total(), 4),
+                self.cache.block_seconds_total(), 4),
             "step_compiles": self.step_traces,
             "attn_impl": self.attn_impl,
             "step_tokens": self.step_tokens,
@@ -1501,9 +1579,9 @@ class ServingEngine:
             # prefix-cached blocks (the /healthz field operators watch),
             # split below so the HBM ledger and load shedding don't
             # misread a warm cache as pressure
-            "kv_headroom": round((free + reclaim) / cap, 4),
-            "kv_free_fraction": round(free / cap, 4),
-            "kv_reclaimable_fraction": round(reclaim / cap, 4),
+            "kv_headroom": round(free + reclaim, 4),
+            "kv_free_fraction": round(free, 4),
+            "kv_reclaimable_fraction": round(reclaim, 4),
             "max_batch": self.max_batch,
             "max_model_len": self.max_model_len,
             "block_size": self.cache.block_size,
@@ -1523,6 +1601,11 @@ class ServingEngine:
                               sorted(self._adapters.items())},
             },
         }
+        if len(self.cache.groups) > 1:
+            # a layer group each: pool size, blocks held by live sequences,
+            # free, and parked in the prefix cache (the kv_blocks_* keys
+            # above read the first group, the fractions the tightest)
+            out["kv_groups"] = {g.name: g.usage() for g in self.cache.groups}
         if pc is not None:
             s = pc.stats()
             s["hit_rate"] = round(s["hits"] / max(s["lookups"], 1), 4)
@@ -1542,18 +1625,18 @@ class ServingEngine:
         returning. Stops at the first miss (a chained digest after a
         miss could never be admitted anyway). Returns ``[(digest, k, v),
         ...]`` records for :meth:`import_kv_blocks` on a peer replica."""
-        pc = self.cache.prefix_cache
-        if pc is None:
+        if not self.prefix_cache_enabled:
             return []
+        g = self.cache.sole_group("export_kv_blocks (fleet KV handoff)")
         out: List[tuple] = []
         for d in digests:
-            b = pc.lookup(d)
-            if b is None or not self.cache.allocator.reuse_cached(b):
+            b = g.prefix_cache.claim(d)
+            if b is None:
                 break
             try:
                 k, v = self.cache.export_block(b)
             finally:
-                self.cache.allocator.free([b])
+                g.allocator.free([b])
             out.append((d, k, v))
         return out
 
@@ -1565,9 +1648,10 @@ class ServingEngine:
         cache hit (tail-only prefill). Already-known digests are
         skipped (first writer wins, same as ``register``); a full pool
         stops the import early. Returns the number of blocks adopted."""
-        pc = self.cache.prefix_cache
-        if pc is None:
+        if not self.prefix_cache_enabled:
             return 0
+        g = self.cache.sole_group("import_kv_blocks (fleet KV handoff)")
+        pc = g.prefix_cache
         n = 0
         with self._lock:
             for d, k, v in records:
@@ -1575,11 +1659,11 @@ class ServingEngine:
                     n += 1  # prefix already resident here
                     continue
                 try:
-                    (b,) = self.cache.allocator.allocate(1)
+                    (b,) = g.allocator.allocate(1)
                 except MemoryError:
                     break
                 self.cache.import_block(b, k, v)
                 pc.register(d, b)
-                self.cache.allocator.free([b])  # parks reclaimable
+                g.allocator.free([b])  # parks reclaimable
                 n += 1
         return n

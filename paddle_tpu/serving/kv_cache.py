@@ -1,6 +1,6 @@
 """Block-paged KV-cache manager for the serving engine.
 
-Three halves:
+Three parts:
 
 * :class:`BlockAllocator` — host-side accounting over a fixed pool of
   ``num_blocks`` token blocks: a free list, per-block refcounts
@@ -36,6 +36,9 @@ Three halves:
   allocator, the block-table padding helper, the copy-on-write block
   copy (one jitted program, physical src/dst are traced scalars) and
   the optional ``mp``-axis pool sharding for tensor-parallel serving.
+  A model may state a spec a layer: layers of equal spec form a
+  :class:`CacheGroup` with its own allocator, prefix index and pool size
+  (window layers beside full ones, docs/SERVING.md "Layer groups").
 
 Sizing math (docs/SERVING.md): a request of total length ``T`` (prompt +
 generated) holds ``ceil(T / block_size)`` blocks, so worst-case pool
@@ -58,7 +61,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "chain_hash"]
+__all__ = ["BlockAllocator", "CacheGroup", "PagedKVCache", "PrefixCache",
+           "chain_hash"]
 
 #: physical block id reserved as the write-off target for padding
 NULL_BLOCK = 0
@@ -254,9 +258,9 @@ class BlockAllocator:
 class PrefixCache:
     """Hash index over committed full KV blocks (ISSUE 15).
 
-    ``match`` walks the chain hashes of a prompt's full blocks and
-    CLAIMS every hit (incref / resurrect through the allocator) so a
-    concurrent eviction can't invalidate an earlier link mid-walk;
+    :meth:`PagedKVCache.match` walks the chain hashes of a prompt's full
+    blocks and CLAIMS every hit (``claim``: incref / resurrect through
+    the allocator);
     ``register`` is called by the engine's post-step commit pass — only
     for blocks whose final token the executed step wrote, so an indexed
     block is always immutable. Counters are cumulative; the engine
@@ -285,35 +289,17 @@ class PrefixCache:
     def lookup(self, digest: bytes) -> Optional[int]:
         return self._index.get(digest)
 
-    def match(self, tokens: Sequence[int],
-              seed: Optional[bytes] = None) -> Tuple[List[int], List[bytes]]:
-        """Longest registered full-block prefix of ``tokens``: returns
-        the CLAIMED physical blocks (one reference each, caller owns)
-        and their digests. The caller applies the at-least-one-token
-        prefill cap (scheduler admission) — this walk is pure content
-        matching at block granularity. ``seed`` roots the chain in a
-        namespace (the engine passes the LoRA adapter slot's digest so
-        KV computed under one adapter never matches another tenant's
-        identical prompt); ``None`` is the base-model namespace."""
-        self.lookups += 1
-        bs = self.block_size
-        blocks: List[int] = []
-        digests: List[bytes] = []
-        parent = seed
-        for i in range(len(tokens) // bs):
-            d = chain_hash(parent, tokens[i * bs:(i + 1) * bs])
-            b = self._index.get(d)
-            if b is None or not self.allocator.reuse_cached(b):
-                if b is not None:
-                    # index raced an eviction path — drop the stale entry
-                    self._index.pop(d, None)
-                break
-            blocks.append(b)
-            digests.append(d)
-            parent = d
-        if blocks:
-            self.hits += 1
-        return blocks, digests
+    def claim(self, digest: bytes) -> Optional[int]:
+        """The block registered under ``digest`` with one reference taken
+        on it (incref, or a parked block resurrected), the caller's to
+        free; None where nothing is registered or the block is gone."""
+        b = self._index.get(digest)
+        if b is None or not self.allocator.reuse_cached(b):
+            if b is not None:
+                # index raced an eviction path — drop the stale entry
+                self._index.pop(digest, None)
+            return None
+        return b
 
     def register(self, digest: bytes, block_id: int):
         """Index a completed full block. First writer wins: duplicate
@@ -351,8 +337,78 @@ class PrefixCache:
         return [d[:n].hex() for d in keys]
 
 
+class CacheGroup:
+    """The layers of one :class:`~paddle_tpu.ops.paged_attention.LayerCacheSpec`:
+    their pools share a size, an allocator, a prefix index and, a
+    sequence, one block-table row (docs/SERVING.md "Layer groups")."""
+
+    def __init__(self, name: str, spec, layers: Sequence[int],
+                 num_blocks: int, block_size: int, prefix_cache: bool):
+        self.name, self.spec, self.layers = name, spec, tuple(layers)
+        self.num_blocks = int(num_blocks)
+        self.block_size = block_size
+        self.allocator = BlockAllocator(self.num_blocks)
+        self.prefix_cache = (PrefixCache(self.allocator, block_size)
+                             if prefix_cache else None)
+
+    @property
+    def window(self):
+        return self.spec.window
+
+    def first_visible_page(self, kv_len: int) -> int:
+        """The first page that holds a key the token at position
+        ``kv_len`` (the next to come once ``kv_len`` are cached) can see:
+        0 without a window. Every page before it is dead to the sequence."""
+        if self.window is None:
+            return 0
+        return max(0, kv_len - self.window + 1) // self.block_size
+
+    def max_pages_held(self, new_tokens: int, table_width: int) -> int:
+        """Most pages one sequence holds in this group while a step
+        writes ``new_tokens`` of it: the table's width, or under a window
+        ``ceil((window + new_tokens) / block_size) + 1``."""
+        if self.window is None:
+            return table_width
+        return min(table_width,
+                   -(-(self.window + new_tokens) // self.block_size) + 1)
+
+    def usage(self) -> dict:
+        a = self.allocator
+        return {"blocks": self.num_blocks, "in_use": a.blocks_in_use(),
+                "free": a.num_free(), "reclaimable": a.num_reclaimable(),
+                "layers": len(self.layers), "window": self.window}
+
+    def fractions(self) -> Tuple[float, float]:
+        """``(free, reclaimable)`` as shares of the group's pool."""
+        a = self.allocator
+        cap = max(a.capacity, 1)
+        return a.num_free() / cap, a.num_reclaimable() / cap
+
+
+def _group_layers(specs):
+    """Layers of equal spec, in order of first appearance:
+    ``[(name, spec, [layer, ...])]``. A group is named for what sets it
+    apart: ``window``, ``latent``, else ``full``."""
+    groups = []
+    for i, sp in enumerate(specs):
+        for g in groups:
+            if g[1] == sp:
+                g[2].append(i)
+                break
+        else:
+            name = "window" if sp.window is not None else \
+                "latent" if sp.latent else "full"
+            if any(g[0] == name for g in groups):
+                raise NotImplementedError(
+                    f"two layer groups named {name!r}: layer {i} states "
+                    f"{sp}, an earlier one "
+                    f"{next(g[1] for g in groups if g[0] == name)}")
+            groups.append((name, sp, [i]))
+    return groups
+
+
 class PagedKVCache:
-    """Per-layer block pools + the allocator + table-shaping helpers.
+    """Per-layer block pools + the allocators + table-shaping helpers.
 
     What a model must state: ``spec``, the
     :class:`~paddle_tpu.ops.paged_attention.LayerCacheSpec` of its
@@ -360,10 +416,14 @@ class PagedKVCache:
     holds, how wide a key row is, and either how wide a value row is or
     that the values are the first ``value_cols`` columns of the key page
     (a latent page: there is no V pool and ``v_pools`` holds None a
-    layer). Every layer keeps the same; layers of unequal pages are
-    ROADMAP D11."""
+    layer). One spec stands for every layer; a list states one a layer,
+    and layers of equal spec form a :class:`CacheGroup` (``groups``, in
+    order of first appearance): its own pools' size, allocator and prefix
+    index. ``num_blocks`` is a number (every group's) or a mapping by
+    group name. Allocators, prefix indexes and specs are the groups': a
+    model of one spec has a list of one."""
 
-    def __init__(self, num_layers: int, num_blocks: int, block_size: int,
+    def __init__(self, num_layers: int, num_blocks, block_size: int,
                  spec, max_blocks_per_seq: Optional[int] = None,
                  dtype=jnp.float32, prefix_cache: bool = False,
                  kv_dtype: Optional[str] = None):
@@ -371,16 +431,34 @@ class PagedKVCache:
             raise ValueError("block_size must be >= 1")
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"kv_dtype={kv_dtype!r} (want None or 'int8')")
-        if kv_dtype is not None and spec.latent:
-            raise ValueError("int8 KV covers K/V pools, not latent pages")
-        self.spec = sp = spec
+        specs = list(spec) if isinstance(spec, (list, tuple)) \
+            and not hasattr(spec, "kv_heads") else [spec] * num_layers
+        if len(specs) != num_layers:
+            raise ValueError(f"{len(specs)} layer specs for {num_layers} "
+                             f"layers")
+        grouped = _group_layers(specs)
+        if isinstance(num_blocks, dict):
+            names = [g[0] for g in grouped]
+            if sorted(num_blocks) != sorted(names):
+                raise ValueError(f"max_blocks names groups "
+                                 f"{sorted(num_blocks)}; the model's layers "
+                                 f"form {names}")
+            sizes = [int(num_blocks[n]) for n in names]
+        else:
+            sizes = [int(num_blocks)] * len(grouped)
+        if kv_dtype is not None and (len(grouped) > 1
+                                     or grouped[0][1].latent):
+            raise ValueError("int8 KV covers K/V pools of one layer group, "
+                             "not latent pages nor several groups")
+        self.groups = [CacheGroup(n, sp, layers, nb, block_size, prefix_cache)
+                       for (n, sp, layers), nb in zip(grouped, sizes)]
+        self.group_of_layer = [0] * num_layers
+        for gi, g in enumerate(self.groups):
+            for i in g.layers:
+                self.group_of_layer[i] = gi
         self.num_layers = num_layers
-        self.num_blocks = num_blocks
         self.block_size = block_size
-        self.max_blocks_per_seq = max_blocks_per_seq or num_blocks
-        self.allocator = BlockAllocator(num_blocks)
-        self.prefix_cache = (PrefixCache(self.allocator, block_size)
-                             if prefix_cache else None)
+        self.max_blocks_per_seq = max_blocks_per_seq or max(sizes)
         #: compute dtype of the attention math / block transfers; the
         #: storage dtype below may be narrower
         self.compute_dtype = jnp.dtype(dtype)
@@ -388,31 +466,59 @@ class PagedKVCache:
         store = jnp.int8 if kv_dtype == "int8" else dtype
 
         # +1: physical block 0 is the null block and backs no sequence
-        def pool(width):
-            return jnp.zeros((num_blocks + 1, sp.kv_heads, block_size,
+        def pool(g, width):
+            return jnp.zeros((g.num_blocks + 1, g.spec.kv_heads, block_size,
                               width), store)
-        self.k_pools = tuple(pool(sp.key_dim) for _ in range(num_layers))
-        self.v_pools = tuple(None if sp.latent else pool(sp.value_dim)
-                             for _ in range(num_layers))
+        by_layer = [self.groups[gi] for gi in self.group_of_layer]
+        self.k_pools = tuple(pool(g, g.spec.key_dim) for g in by_layer)
+        self.v_pools = tuple(None if g.spec.latent
+                             else pool(g, g.spec.value_dim) for g in by_layer)
         if kv_dtype == "int8":
             # per-token-slot, per-head dequant multipliers, paged like
             # the pools themselves so block tables address both
             self.k_scales = tuple(
-                jnp.zeros((num_blocks + 1, sp.kv_heads, block_size),
-                          jnp.float32) for _ in range(num_layers))
+                jnp.zeros((g.num_blocks + 1, g.spec.kv_heads, block_size),
+                          jnp.float32) for g in by_layer)
             self.v_scales = tuple(jnp.zeros_like(s) for s in self.k_scales)
         else:
             self.k_scales = ()
             self.v_scales = ()
         self._copy_fn = None  # lazily-jitted COW block copy
 
+    def sole_group(self, what: str) -> CacheGroup:
+        """The one group of a one-group cache; ``what`` refuses others."""
+        if len(self.groups) > 1:
+            raise NotImplementedError(
+                f"{what} addresses one block id across every layer; this "
+                f"cache has {len(self.groups)} layer groups "
+                f"({[g.name for g in self.groups]}) with an allocator each "
+                f"(docs/SERVING.md \"Layer groups\")")
+        return self.groups[0]
+
+    def assert_no_leaks(self):
+        for g in self.groups:
+            g.allocator.assert_no_leaks()
+
+    def tightest(self) -> CacheGroup:
+        """The group with the least allocatable share of its pool (free
+        plus reclaimable): a sequence grows in every group or in none, so
+        this one's headroom is the cache's."""
+        return min(self.groups, key=lambda g: sum(g.fractions()))
+
+    def block_seconds_total(self) -> float:
+        """Pages held x seconds held, over every group's allocator."""
+        return sum(g.allocator.block_seconds_total() for g in self.groups)
+
     def pool_bytes(self) -> dict:
         """Bytes the pools hold, by kind: ``latent`` (one-pool latent
         pages) and ``kv`` (K and V pools, with their int8 scales)."""
         out = {"latent": 0, "kv": 0}
-        out["latent" if self.spec.latent else "kv"] = sum(
-            int(p.nbytes) for p in self.k_pools + self.v_pools
-            + self.k_scales + self.v_scales if p is not None)
+        for i, gi in enumerate(self.group_of_layer):
+            kind = "latent" if self.groups[gi].spec.latent else "kv"
+            out[kind] += sum(
+                int(p[i].nbytes) for p in (self.k_pools, self.v_pools,
+                                           self.k_scales, self.v_scales)
+                if p and p[i] is not None)
         return out
 
     @property
@@ -458,6 +564,7 @@ class PagedKVCache:
         divergence compiles it and every later COW reuses it."""
         import jax
 
+        self.sole_group("copy_block (copy-on-write)")
         if self._copy_fn is None:
             def _copy(kps, vps, kss, vss, s, d):
                 # a latent layer's v pool is None: tree_map passes it by
@@ -479,8 +586,9 @@ class PagedKVCache:
         — the caller must hold a reference on ``block_id`` for the
         duration (the fleet handoff claims one via ``reuse_cached``
         before calling)."""
+        g = self.sole_group("export_block (fleet KV handoff)")
         k = np.stack([np.asarray(p[block_id]) for p in self.k_pools])
-        if self.spec.latent:
+        if g.spec.latent:
             return k, None
         v = np.stack([np.asarray(p[block_id]) for p in self.v_pools])
         if self.kv_dtype == "int8":
@@ -503,6 +611,7 @@ class PagedKVCache:
         it with the prefix index afterwards."""
         import jax
 
+        self.sole_group("import_block (fleet KV handoff)")
         if getattr(self, "_import_fn", None) is None:
             if self.kv_dtype == "int8":
                 from paddle_tpu.ops.paged_attention import \
@@ -534,6 +643,53 @@ class PagedKVCache:
             jnp.asarray(k, dt), None if v is None else jnp.asarray(v, dt),
             jnp.int32(block_id))
 
+    def match(self, tokens: Sequence[int], seed: Optional[bytes] = None):
+        """Prefix admission: the longest prefix of ``n`` full blocks of
+        ``tokens`` for which every group still holds what the next token
+        can see there: blocks ``0..n-1`` without a window, under a window
+        only those from :meth:`CacheGroup.first_visible_page`. Returns
+        ``(tables, digests)``: a CLAIMED block-table prefix a group (one
+        reference a block, the caller owns; null before a window group's
+        first visible page) and the ``n`` chain digests. ``seed`` roots
+        the chain in a namespace (the engine passes the LoRA adapter
+        slot's digest so KV computed under one adapter never matches
+        another tenant's identical prompt); ``None`` is the base model's.
+        A one-group cache may match the whole prompt (the scheduler caps
+        it by copy-on-write); several groups have no block copy and leave
+        at least one token to prefill. Lookups and hits count on the first
+        group's index."""
+        lead = self.groups[0].prefix_cache
+        lead.lookups += 1
+        bs = self.block_size
+        digests: List[bytes] = []
+        parent = seed
+        whole = [g.prefix_cache for g in self.groups if g.window is None]
+        for i in range((len(tokens) - (len(self.groups) > 1)) // bs):
+            d = chain_hash(parent, tokens[i * bs:(i + 1) * bs])
+            if any(pc.lookup(d) is None for pc in whole):
+                break
+            digests.append(d)
+            parent = d
+        n = len(digests)
+        while n > 0 and not all(
+                g.prefix_cache.lookup(digests[i]) is not None
+                for g in self.groups if g.window is not None
+                for i in range(g.first_visible_page(n * bs), n)):
+            n -= 1
+        tables = []
+        for g in self.groups:
+            first = g.first_visible_page(n * bs)
+            claimed = [g.prefix_cache.claim(d) for d in digests[first:n]]
+            tables.append([NULL_BLOCK] * first + claimed)
+        if any(b is None for t in tables for b in t):
+            # an index entry outlived its block: give back, count a miss
+            for g, t in zip(self.groups, tables):
+                g.allocator.free([b for b in t if b])
+            return [[] for _ in self.groups], []
+        if n:
+            lead.hits += 1
+        return tables, digests[:n]
+
     def pad_block_table(self, block_ids: Sequence[int]) -> np.ndarray:
         """[max_blocks_per_seq] int32 row, null-padded."""
         if len(block_ids) > self.max_blocks_per_seq:
@@ -550,5 +706,5 @@ class PagedKVCache:
         g = get_registry().gauge(
             "serving_kv_blocks_in_use",
             "KV-cache blocks currently held by live sequences")
-        g.set(self.allocator.blocks_in_use())
+        g.set(self.groups[0].allocator.blocks_in_use())
         return g

@@ -54,7 +54,7 @@ from dataclasses import dataclass, field
 from enum import Enum
 from typing import Callable, List, Optional, Sequence, Tuple
 
-from .kv_cache import PagedKVCache
+from .kv_cache import NULL_BLOCK, PagedKVCache
 
 __all__ = ["RequestState", "Request", "StepPlan", "Scheduler"]
 
@@ -97,7 +97,10 @@ class Request:
     slot: Optional[int] = None
     #: first admission into a batch slot — the end of the queue-wait span
     slot_time: Optional[float] = None
-    block_ids: List[int] = field(default_factory=list)
+    #: the block-table rows, one list a layer group of the cache (sized
+    #: by ``Scheduler.add``); a page a window group released is the null
+    #: block there
+    tables: List[List[int]] = field(default_factory=list)
     #: tokens to (re)prefill — the prompt, or prompt+generated after a
     #: preemption (recompute)
     pending_tokens: List[int] = field(default=None)
@@ -143,6 +146,13 @@ class Request:
     @property
     def done(self) -> bool:
         return self.state in (RequestState.FINISHED, RequestState.FAILED)
+
+    def holds_blocks(self) -> bool:
+        return any(self.tables)
+
+    def blocks_held(self) -> int:
+        """Pages held over every group (a released page is null)."""
+        return sum(len(t) - t.count(NULL_BLOCK) for t in self.tables)
 
     def last_token(self) -> int:
         """The decode-step input: the newest sampled, not-yet-cached
@@ -219,6 +229,8 @@ class Scheduler:
     def add(self, req: Request):
         """FCFS enqueue (kept sorted by arrival so a preempted earlier
         request resumes ahead of later arrivals)."""
+        if len(req.tables) != len(self.cache.groups):
+            req.tables = [[] for _ in self.cache.groups]
         bisect.insort(self.waiting, req, key=lambda r: r.arrival_time)
 
     # -- planning ----------------------------------------------------------
@@ -258,31 +270,32 @@ class Scheduler:
         prefill to produce sampling logits); the cap lands mid-block, so
         the final matched block turns into a held COW source instead of
         a table entry."""
-        pc = self.cache.prefix_cache
-        if pc is None or seq.block_ids:
+        pc = self.cache.groups[0].prefix_cache
+        if pc is None or seq.holds_blocks():
             return
         tokens = seq.pending_tokens
         if len(tokens) <= self.cache.block_size:
             return  # no full block can match under the one-token cap
-        blocks, digests = pc.match(tokens, seed=seq.cache_seed)
-        if not blocks:
+        tables, digests = self.cache.match(tokens, seed=seq.cache_seed)
+        if not digests:
             return
-        matched = len(blocks) * self.cache.block_size
+        matched = len(digests) * self.cache.block_size
         if matched >= len(tokens):
-            # fully-cached aligned prompt: the last matched block is the
+            # fully-cached aligned prompt (a one-group cache's: several
+            # groups' match leaves a token): the last matched block is the
             # COW source (we hold its claimed reference until the engine
             # copies it); usable cache shrinks to len - 1 tokens
-            seq.cow_src = blocks.pop()
-            seq.cow_index = len(blocks)
+            seq.cow_src = tables[0].pop()
+            seq.cow_index = len(tables[0])
+            digests.pop()
             matched = len(tokens) - 1
-        seq.block_ids = blocks
+        seq.tables = tables
         seq.prefill_pos = matched
         seq.num_cached = matched
         seq.cached_prompt_tokens = matched
         seq.cached_tokens_total += matched
-        seq.committed_blocks = len(blocks)
-        seq.committed_hash = (digests[len(blocks) - 1] if blocks
-                              else seq.cache_seed)
+        seq.committed_blocks = len(digests)
+        seq.committed_hash = digests[-1] if digests else seq.cache_seed
         pc.hit_tokens += matched
 
     def _release_cow(self, seq: Request):
@@ -290,7 +303,7 @@ class Scheduler:
         the engine performed the copy — or after: the engine clears
         ``cow_src`` once the copy ran)."""
         if seq.cow_src is not None:
-            self.cache.allocator.free([seq.cow_src])
+            self.cache.groups[0].allocator.free([seq.cow_src])
             seq.cow_src = None
         seq.cow_index = None
 
@@ -336,24 +349,54 @@ class Scheduler:
         return batch
 
     # -- block management --------------------------------------------------
+    def _free_blocks(self, seq: Request):
+        """Every page ``seq`` holds goes back to its group's allocator."""
+        for g, t in zip(self.cache.groups, seq.tables):
+            g.allocator.free([b for b in t if b != NULL_BLOCK])
+        seq.tables = [[] for _ in self.cache.groups]
+
+    def release_behind_window(self, seq: Request) -> dict:
+        """After a step committed: a window group gives back every page
+        of ``seq`` whose last key no token still to come can see (it lies
+        at or before ``num_cached - window``). A page the prefix cache has
+        registered parks reclaimable and keeps its contents; the table
+        entry becomes the null block. Returns ``{group name: pages}``."""
+        out = {}
+        for g, t in zip(self.cache.groups, seq.tables):
+            if g.window is None:
+                continue
+            i = min(g.first_visible_page(seq.num_cached), len(t)) - 1
+            gone = []
+            while i >= 0 and t[i] != NULL_BLOCK:   # released: a prefix
+                gone.append(t[i])
+                t[i] = NULL_BLOCK
+                i -= 1
+            if gone:
+                g.allocator.free(gone)
+                out[g.name] = len(gone)
+        return out
+
     def _ensure_blocks(self, seq: Request, total_tokens: int) -> bool:
         """Grow ``seq``'s block table to cover ``total_tokens`` cached
-        positions, preempting latest-arrival sequences as needed.
+        positions in every layer group or in none, preempting
+        latest-arrival sequences as needed.
         Victims are always strictly younger than ``seq`` (FCFS-senior
         requests are never evicted for junior ones). A victim that was
         already planned this step is knocked to WAITING with its slot
         released, which is exactly what the engine's stale-entry filter
         checks — it can never be executed against freed blocks."""
-        alloc = self.cache.allocator
-        need = self.cache.blocks_for(total_tokens) - len(seq.block_ids)
-        if need <= 0:
+        groups, tables = self.cache.groups, seq.tables
+        want = self.cache.blocks_for(total_tokens)
+        need = [max(0, want - len(t)) for t in tables]
+        if not any(need):
             return True
-        while not alloc.can_allocate(need):
+        while not all(g.allocator.can_allocate(n)
+                      for g, n in zip(groups, need)):
             victim = self._pick_victim(after=seq)
             if victim is None:
                 holders = [s for s in self.slotted()
-                           if s is not seq and s.block_ids]
-                if (holders and seq.slot is not None and seq.block_ids
+                           if s is not seq and s.holds_blocks()]
+                if (holders and seq.slot is not None and seq.holds_blocks()
                         and all(h.arrival_time < seq.arrival_time
                                 for h in holders)):
                     # only FCFS-senior sequences hold the pool: hand our
@@ -363,7 +406,9 @@ class Scheduler:
                 # evictable/finish on a later step — just wait
                 return False
             self.preempt(victim)
-        seq.block_ids.extend(alloc.allocate(need))
+        for g, t, n in zip(groups, tables, need):
+            if n:
+                t.extend(g.allocator.allocate(n))
         return True
 
     def _pick_victim(self, after: Request) -> Optional[Request]:
@@ -371,7 +416,7 @@ class Scheduler:
         ``after`` — preemption never evicts an earlier (FCFS-senior)
         request."""
         cands = [s for s in self.slotted()
-                 if s is not after and s.block_ids
+                 if s is not after and s.holds_blocks()
                  and s.arrival_time > after.arrival_time]
         if not cands:
             return None
@@ -390,8 +435,7 @@ class Scheduler:
             # the request holds zero blocks until readmission
             led.note_occupancy(seq, time.monotonic())
         self._release_cow(seq)
-        self.cache.allocator.free(seq.block_ids)
-        seq.block_ids = []
+        self._free_blocks(seq)
         self.release_slot(seq)
         seq.pending_tokens = list(seq.prompt_tokens) + list(seq.generated)
         seq.prefill_pos = 0
@@ -425,8 +469,7 @@ class Scheduler:
             # bill the final holding interval before the blocks go back
             led.note_occupancy(seq, time.monotonic())
         self._release_cow(seq)
-        self.cache.allocator.free(seq.block_ids)
-        seq.block_ids = []
+        self._free_blocks(seq)
         self.release_slot(seq)
         seq.state = state
         seq.finish_reason = reason

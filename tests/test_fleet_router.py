@@ -77,7 +77,7 @@ def fleet2(oracle):
     router.shutdown(drain=True)
     for r in reps:
         if r.alive:
-            r.engine.cache.allocator.assert_no_leaks()
+            r.engine.cache.assert_no_leaks()
 
 
 class TestPlacement:
@@ -235,8 +235,8 @@ class TestDisaggregation:
             assert router.decisions["disagg_prefill"] == 1
         finally:
             router.shutdown(drain=True)
-        pre.engine.cache.allocator.assert_no_leaks()
-        dec.engine.cache.allocator.assert_no_leaks()
+        pre.engine.cache.assert_no_leaks()
+        dec.engine.cache.assert_no_leaks()
 
 
 class TestRouterServer:

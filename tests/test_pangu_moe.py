@@ -337,15 +337,15 @@ def test_latent_pages_copy_export_import_and_are_reused():
     assert a.pool_bytes() == {"latent": 2 * 7 * 4 * 24 * 4, "kv": 0}
     rows = np.random.default_rng(7).standard_normal((2, 1, 4, 24)) \
         .astype(np.float32)
-    (b1,) = a.allocator.allocate(1)
+    (b1,) = a.groups[0].allocator.allocate(1)
     a.import_block(b1, rows)
-    (b2,) = a.allocator.allocate(1)
+    (b2,) = a.groups[0].allocator.allocate(1)
     a.copy_block(b1, b2)
     k, v = a.export_block(b2)
     assert v is None
     np.testing.assert_array_equal(k, rows)
     other = PagedKVCache(2, 6, 4, spec)
-    (b3,) = other.allocator.allocate(1)
+    (b3,) = other.groups[0].allocator.allocate(1)
     other.import_block(b3, k, v)
     np.testing.assert_array_equal(np.asarray(other.k_pools[1][b3]), rows[1])
 
@@ -385,7 +385,7 @@ def test_llama_and_moe_engines_build_their_pools_from_the_spec():
         assert spec == pa.LayerCacheSpec.kv(n_kv, hd)
         eng = ServingEngine(m, max_batch=2, max_blocks=8, block_size=4,
                             prefill_chunk=4)
-        assert eng.cache.spec == spec
+        assert [g.spec for g in eng.cache.groups] == [spec]
         assert all(p.shape == (9, n_kv, 4, hd)
                    for p in eng.cache.k_pools + eng.cache.v_pools)
         assert eng.cache.pool_bytes()["latent"] == 0

@@ -18,7 +18,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import ServingEngine
-from paddle_tpu.serving.kv_cache import (BlockAllocator, PrefixCache,
+from paddle_tpu.serving.kv_cache import (BlockAllocator,
                                          chain_hash)
 
 
@@ -92,8 +92,12 @@ def test_reclaimable_lru_eviction_order_and_callback():
 
 # ---------------- PrefixCache unit -------------------------------------------
 def test_prefix_cache_match_register_and_cow_cap():
-    a = BlockAllocator(8)
-    pc = PrefixCache(a, block_size=4)
+    from paddle_tpu.ops.paged_attention import LayerCacheSpec
+    from paddle_tpu.serving import PagedKVCache
+    cache = PagedKVCache(num_layers=1, num_blocks=8, block_size=4,
+                         spec=LayerCacheSpec.kv(1, 4), prefix_cache=True)
+    (g,) = cache.groups
+    a, pc = g.allocator, g.prefix_cache
     blocks = a.allocate(2)
     d0 = chain_hash(None, [1, 2, 3, 4])
     d1 = chain_hash(d0, [5, 6, 7, 8])
@@ -101,12 +105,12 @@ def test_prefix_cache_match_register_and_cow_cap():
     pc.register(d1, blocks[1])
     a.free(blocks)                     # registered → both park
     # partial tail: only full, chain-linked blocks match
-    got, digests = pc.match([1, 2, 3, 4, 5, 6, 7, 8, 9])
-    assert got == blocks and digests == [d0, d1]
+    got, digests = cache.match([1, 2, 3, 4, 5, 6, 7, 8, 9])
+    assert got == [blocks] and digests == [d0, d1]
     assert a.refcount(blocks[0]) == 1  # match CLAIMS the blocks
     a.free(blocks)
     # divergence in the second block stops the walk after the first
-    got2, _ = pc.match([1, 2, 3, 4, 9, 9, 9, 9, 1])
+    (got2,), _ = cache.match([1, 2, 3, 4, 9, 9, 9, 9, 1])
     assert got2 == [blocks[0]]
     a.free(got2)
     assert pc.stats()["lookups"] == 2 and pc.stats()["hits"] == 2
@@ -151,7 +155,7 @@ def test_shared_prefix_bit_parity_cache_on_vs_off(model, eng_on):
         eng.run_until_idle()
         streams[name] = [h.result(30)["token_ids"]
                          for h in [h0] + handles]
-        eng.cache.allocator.assert_no_leaks()
+        eng.cache.assert_no_leaks()
     assert streams["on"] == streams["off"]
     pc = eng_on.stats()["prefix_cache"]
     assert pc["hits"] >= 2 and pc["hit_tokens"] >= 2 * 12
@@ -180,7 +184,7 @@ def test_fully_cached_prompt_cow_lifecycle(model, eng_on):
     assert r.prefilled_tokens == \
         r.admitted_pending_total - r.cached_tokens_total
     assert r.cow_src is None                          # copy released
-    eng_on.cache.allocator.assert_no_leaks()
+    eng_on.cache.assert_no_leaks()
 
 
 def test_mid_block_divergence_matches_cold_runs(model, eng_on):
@@ -200,7 +204,7 @@ def test_mid_block_divergence_matches_cold_runs(model, eng_on):
     assert hb.result(30)["token_ids"] == _eager_continuation(model, pb, 4)
     # b matched exactly the shared full blocks, recomputed its own tail
     assert hb._req.cached_tokens_total == 2 * BS
-    eng_on.cache.allocator.assert_no_leaks()
+    eng_on.cache.assert_no_leaks()
 
 
 def test_abort_while_cached_block_shared(model, eng_on):
@@ -216,9 +220,9 @@ def test_abort_while_cached_block_shared(model, eng_on):
     hc = eng_on.submit(pfx + [7, 8], max_new_tokens=4)
     # admit both (no model step yet): they claim the same cached blocks
     eng_on.scheduler._admit()
-    shared = hb._req.block_ids[:3]
-    assert shared and shared == hc._req.block_ids[:3]
-    alloc = eng_on.cache.allocator
+    shared = hb._req.tables[0][:3]
+    assert shared and shared == hc._req.tables[0][:3]
+    alloc = eng_on.cache.groups[0].allocator
     assert all(alloc.refcount(b) == 2 for b in shared)
     assert eng_on.abort(hb.req_id, reason="test")
     assert all(alloc.refcount(b) == 1 for b in shared)  # survivor holds
@@ -244,7 +248,7 @@ def test_preemption_readmits_through_cache(model):
     committed = h._req.committed_blocks
     assert committed >= 3                    # 12+ tokens committed
     eng.scheduler.preempt(h._req)
-    assert eng.cache.allocator.num_reclaimable() >= committed
+    assert eng.cache.groups[0].allocator.num_reclaimable() >= committed
     eng.run_until_idle()
     assert h.result(30)["token_ids"] == \
         _eager_continuation(model, prompt, 8)
@@ -253,7 +257,7 @@ def test_preemption_readmits_through_cache(model):
     assert r.cached_tokens_total == committed * BS   # tail-only recompute
     assert r.prefilled_tokens == \
         r.admitted_pending_total - r.cached_tokens_total
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
 
 
 def test_tensor_parallel_mp2_token_parity():
@@ -284,7 +288,7 @@ def test_tensor_parallel_mp2_token_parity():
             assert h.result(60)["token_ids"] == \
                 _eager_continuation(model, p, 6)
         assert eng.step_traces == 1
-        eng.cache.allocator.assert_no_leaks()
+        eng.cache.assert_no_leaks()
     finally:
         set_mesh(prev)
 

@@ -610,6 +610,65 @@ def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
     assert "rpa" in calls[0].split("=")[0], calls[0]
 
 
+def test_window_and_full_kernels_compile_at_the_two_group_cell(
+        v5e_chip, monkeypatch):
+    """7 query heads a KV head (a ``[112, 128]`` q block in bf16) at the
+    shapes of ``smallthinker-21b-a3b-serve-l12``: one program with a full
+    layer's call and a window layer's compiles with Mosaic into an ``rpa``
+    and an ``rpa_win`` custom call, the names the benchmark's two
+    rooflines tell the groups' device time apart by."""
+    import re
+    import jax
+    from benchmark import xplane
+    from benchmark.kernels import rpa_win
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "smallthinker-21b-a3b-serve-l12.json")) as f:
+        cfg = json.load(f)
+    eng, heads, kv, hd = (cfg["engine"], cfg["num_attention_heads"],
+                          cfg["num_key_value_heads"], cfg["head_dim"])
+    window, bs = cfg["sliding_window_size"], eng["block_size"]
+    tile = default_tile_q(heads // kv, jnp.bfloat16)
+    assert (heads // kv, tile) == (7, 16)
+    tokens = -(-(eng["max_batch"] + eng["prefill_chunk"]) // tile) * tile
+    seqs, width = eng["max_batch"] + 1, eng["max_blocks_per_seq"]
+    items = rpa_max_items(tokens // tile, eng["max_batch"], width)
+    win_items = rpa_max_items(tokens // tile, eng["max_batch"], width,
+                              window=window, tile_q=tile, block_size=bs)
+    assert win_items * 128 == items * 34       # 34 pages a walk, not 128
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def pool(name):
+        return arr((eng["max_blocks"][name] + 1, kv, bs, hd), jnp.bfloat16)
+
+    def two_layers(q, kf, vf, kw, vw, btf, btw, cu, ctx, sf, bf, tf, sw, bw,
+                   tw):
+        return (ragged_paged_attention(q, kf, vf, btf, cu, ctx, sf, bf, tf),
+                ragged_paged_attention(q, kw, vw, btw, cu, ctx, sw, bw, tw,
+                                       window=window))
+
+    with _compiling_for_the_chip(monkeypatch):
+        text = jax.jit(two_layers).lower(
+            arr((tokens, heads, hd), jnp.bfloat16), pool("full"),
+            pool("full"), pool("window"), pool("window"),
+            arr((seqs, width)), arr((seqs, width)), arr((seqs + 1,)),
+            arr((seqs,)), arr((items,)), arr((items,)),
+            arr((tokens // tile + 1,)), arr((win_items,)),
+            arr((win_items,)), arr((tokens // tile + 1,))
+        ).compile().as_text()
+    names = sorted(
+        xplane.short_name(re.sub(r"^(ROOT )?", "", ln.strip()))
+        for ln in text.splitlines() if "tpu_custom_call" in ln)
+    assert len(names) == 2, names
+    assert re.search(rpa_win.FULL_TRACE_PATTERN, names[0]), names
+    assert not re.search(rpa_win.TRACE_PATTERN, names[0]), names
+    assert re.search(rpa_win.TRACE_PATTERN, names[1]), names
+    assert not re.search(rpa_win.FULL_TRACE_PATTERN, names[1]), names
+
+
 def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
     """The kernel's latent form (one 640-column pool, values its first 512
     columns, 128 query heads on the one page) at the shapes of
@@ -907,7 +966,7 @@ def test_engine_token_streams_identical_across_impls():
         # prompt 11 >> chunk 4: three chunks rode the SAME executable
         assert eng.step_traces == 1
         assert eng.stats()["attn_impl"] == impl
-        eng.cache.allocator.assert_no_leaks()
+        eng.cache.assert_no_leaks()
     assert streams["rpa"] == streams["gather"]
     assert streams["rpa"] == [
         _eager_continuation(model, p, 5) for p in prompts]
@@ -924,7 +983,7 @@ def test_engine_counts_the_pages_its_items_name():
     eng = ServingEngine(model, max_batch=4, max_blocks=48, block_size=4,
                         prefill_chunk=16, attn_impl="rpa")
     assert eng._run_pages == 4
-    assert eng._max_items == rpa_max_items(
+    assert eng._maps_kw[0]["max_items"] == rpa_max_items(
         eng.step_tokens // eng._tile_q, 4, eng.cache.max_blocks_per_seq, 4)
     build, leaf = eng._build_step_maps, eng._leaf
     built, dispatched = [], []
@@ -978,5 +1037,5 @@ def test_engine_impl_parity_under_preemption():
         assert eng.scheduler.num_preemptions >= 1
         assert streams[impl] == [
             _eager_continuation(model, p, 8) for p in prompts]
-        eng.cache.allocator.assert_no_leaks()
+        eng.cache.assert_no_leaks()
     assert streams["rpa"] == streams["gather"]

@@ -105,7 +105,7 @@ def test_decode_outranks_prefill_for_the_last_block():
     b = Request(prompt_tokens=[2] * 8)   # younger: about to prefill
     sch.add(b)
     sch._admit()
-    a.block_ids = cache.allocator.allocate(2)
+    a.tables[0] = cache.groups[0].allocator.allocate(2)
     a.prefill_pos = a.num_cached = 8     # next decode needs a 3rd block
     a.state = RequestState.RUNNING
     a.generated = [5]
@@ -113,10 +113,10 @@ def test_decode_outranks_prefill_for_the_last_block():
     # A's decode takes the last free block; B's chunk finds the pool
     # empty and must WAIT (evicting would require a victim younger than
     # B — there is none) — never run through an all-null block table
-    assert a in plan.decode and len(a.block_ids) == 3
+    assert a in plan.decode and len(a.tables[0]) == 3
     assert plan.prefills == []
     assert b.slot is not None and b.state is RequestState.PREFILL
-    assert b.block_ids == []             # waiting, not corrupted
+    assert b.tables == [[]]             # waiting, not corrupted
 
 
 def test_multi_chunk_packing_and_budget():
@@ -138,7 +138,7 @@ def test_multi_chunk_packing_and_budget():
     for r in (p1, p2, p3):
         sch.add(r)
     sch._admit()
-    d.block_ids = cache.allocator.allocate(1)
+    d.tables[0] = cache.groups[0].allocator.allocate(1)
     d.prefill_pos = d.num_cached = 4
     d.state = RequestState.RUNNING
     d.generated = [7]
@@ -185,16 +185,16 @@ def test_prefill_candidate_preempted_mid_loop_is_skipped():
     junior = Request(prompt_tokens=[2] * 12)  # mid-prefill, holds blocks
     sch.add(junior)
     sch._admit()
-    junior.block_ids = cache.allocator.allocate(2)
+    junior.tables[0] = cache.groups[0].allocator.allocate(2)
     junior.prefill_pos = junior.num_cached = 8
-    cache.allocator.allocate(1)               # drain the last free block
+    cache.groups[0].allocator.allocate(1)               # drain the last free block
     plan = sch.schedule()
     # senior's chunk evicts junior (frees 2, takes 1, 1 left); the loop
     # then reaches junior — now WAITING/slotless — and must skip it
     assert [r for r, _ in plan.prefills] == [senior]
     assert junior.state is RequestState.WAITING and junior.slot is None
-    assert junior.block_ids == []             # no blocks parked on it
-    assert cache.allocator.num_free() == 1
+    assert junior.tables == [[]]             # no blocks parked on it
+    assert cache.groups[0].allocator.num_free() == 1
 
 
 def test_evicted_plan_entry_goes_stale_not_corrupt():
@@ -218,18 +218,18 @@ def test_evicted_plan_entry_goes_stale_not_corrupt():
     young = Request(prompt_tokens=[2] * 4)   # junior: running on 1 block
     sch.add(young)
     sch._admit()
-    young.block_ids = cache.allocator.allocate(1)
+    young.tables[0] = cache.groups[0].allocator.allocate(1)
     young.prefill_pos = young.num_cached = 3  # 4th token fits block 1
     young.state = RequestState.RUNNING
     young.generated = [5]
-    cache.allocator.allocate(1)               # drain the rest of the pool
+    cache.groups[0].allocator.allocate(1)               # drain the rest of the pool
     plan = sch.schedule()
     # young decodes within its block -> planned; old's 4-token chunk
     # then needs a block -> evicts young (the only junior victim)
     assert young in plan.decode
     assert sch.num_preemptions == 1
     assert young.slot is None and young.state is RequestState.WAITING
-    assert young.block_ids == []              # returned, not dangling
+    assert young.tables == [[]]              # returned, not dangling
     # the engine-side stale filter must drop it
     live = [s for s in plan.decode
             if s.slot is not None and s.state is RequestState.RUNNING]
@@ -237,7 +237,7 @@ def test_evicted_plan_entry_goes_stale_not_corrupt():
     # and the senior prefill got real blocks for its planned chunk
     assert plan.prefills and plan.prefills[0][0] is old
     seq, n = plan.prefills[0]
-    assert cache.blocks_for(seq.prefill_pos + n) <= len(seq.block_ids)
+    assert cache.blocks_for(seq.prefill_pos + n) <= len(seq.tables[0])
 
 
 # ---------------- engine: tier-1 smoke ---------------------------------------
@@ -251,7 +251,7 @@ def test_engine_single_request_matches_eager(served):
     assert res["token_ids"] == _eager_continuation(model, prompt, 8)
     assert res["finish_reason"] == "length"
     assert res["ttft_s"] > 0 and res["latency_s"] >= res["ttft_s"]
-    assert eng.cache.allocator.blocks_in_use() == 0
+    assert eng.cache.groups[0].allocator.blocks_in_use() == 0
     assert eng.step_traces == 1  # ONE unified executable, traced once
 
 
@@ -268,7 +268,7 @@ def test_engine_streaming_and_eos(served):
     # greedy first token IS the eos: one streamed token, eos finish
     assert res["token_ids"] == [first] == got
     assert res["finish_reason"] == "eos"
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
 
 
 def test_short_request_joins_mid_decode(served):
@@ -309,7 +309,7 @@ def test_preemption_recompute_no_leak():
         assert hd.result(30)["token_ids"] == \
             _eager_continuation(model, p, 8)
     assert eng.scheduler.num_preemptions >= 1
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
     assert eng.step_traces == 1
     # recompute-tail invariant (ISSUE 15): across every admission, a
     # request prefills AT MOST its pending demand minus what the prefix
@@ -350,7 +350,7 @@ def test_abort_releases_queued_request():
     assert h2._req in eng.scheduler.waiting or h2._req.slot is not None
     assert h1._req not in eng.scheduler.waiting and h1._req.slot is None
     assert eng.stats()["waiting"] + eng.stats()["running"] == 1
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
 
 
 # ---------------- HTTP front-end ---------------------------------------------
@@ -405,7 +405,7 @@ def test_http_generate_roundtrip(served):
     finally:
         # engine outlives the listener (later tests may reuse it)
         srv.close(stop_engine=False)
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
 
 
 def test_metrics_families_exposed(served):
@@ -467,7 +467,7 @@ def test_rpa_walk_follows_live_work_under_one_executable():
             pass
         streams[impl] = [h.result(30)["token_ids"] for h in handles]
         assert eng.step_traces == 1
-        eng.cache.allocator.assert_no_leaks()
+        eng.cache.assert_no_leaks()
         grown = (counter.value(kind="walked") - before[0],
                  counter.value(kind="live") - before[1])
         if impl == "gather":
@@ -478,7 +478,7 @@ def test_rpa_walk_follows_live_work_under_one_executable():
         for m in built:
             sentinels = int(np.sum(m.step_seq[:m.walked] == eng.max_batch))
             assert m.walked == m.live + sentinels
-            assert num_tiles <= m.walked <= eng._max_items
+            assert num_tiles <= m.walked <= eng._maps_kw[0]["max_items"]
             # a tile holds the sentinel item only where it has no work
             assert sentinels == sum(
                 m.step_tile[j + 1] - m.step_tile[j] == 1
@@ -555,7 +555,7 @@ def test_moe_served_independent_of_inactive_slots():
         h = eng.submit(p, max_new_tokens=6)
         eng.run_until_idle()
         outs.append(h.result(30)["token_ids"])
-        eng.cache.allocator.assert_no_leaks()
+        eng.cache.assert_no_leaks()
     assert outs[0] == outs[1], \
         "occupancy changed an MoE request's routing/output"
     assert m.aux_loss() is None  # decode tracers cleared via the hook
@@ -584,7 +584,7 @@ def test_serving_acceptance_concurrent_mixed():
         assert hd.result(30)["token_ids"] == \
             _eager_continuation(model, p, mn)
     assert eng.step_traces == 1
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
     eng.shutdown()
 
     from paddle_tpu.observability import get_registry
@@ -623,7 +623,7 @@ def test_http_concurrent_clients():
             t.join(timeout=120)
     for i, p in enumerate(prompts):
         assert results[i]["token_ids"] == _eager_continuation(model, p, 6)
-    eng.cache.allocator.assert_no_leaks()
+    eng.cache.assert_no_leaks()
 
 
 def test_request_span_chain_in_trace(served, tmp_path):
