@@ -50,7 +50,8 @@ SERVING_STEP_ARGS = ("state", "tokens", "k_pools", "v_pools",
                      "k_scales", "v_scales", "block_tables",
                      "cu_seqlens", "context_lens", "seq_ids", "positions",
                      "step_seq", "step_blk", "step_tile", "last_idx",
-                     "adapter_ids")
+                     "adapter_ids", "prev_tokens", "src_rows", "sampling",
+                     "key_base", "key_counts")
 
 
 @dataclass

@@ -15,7 +15,7 @@ from paddle_tpu.core import generator as G
 from paddle_tpu.core.autograd import no_grad
 from paddle_tpu.core.tensor import Tensor
 
-__all__ = ["sample_token", "generate_loop", "compiled_generate",
+__all__ = ["sample_token", "sample_rows", "generate_loop", "compiled_generate",
            "decode_surfaces"]
 
 
@@ -58,6 +58,40 @@ def sample_token(step_logits, temperature: float, top_k: int,
         cutoff = jnp.take_along_axis(srt, cutoff_idx[:, None], -1)
         sl = jnp.where(sl < cutoff, -jnp.inf, sl)
     return jax.random.categorical(G.next_key() if key is None else key, sl)
+
+
+def sample_rows(logits, temperature, top_k, top_p, keys):
+    """``sample_token`` a row, for traced per-row settings: ``logits``
+    [B, V] float32, ``temperature`` / ``top_p`` [B] float32, ``top_k``
+    [B] int32 and one key a row -> [B] int32. A row at temperature 0 is
+    its argmax (first index on ties); every other row draws what
+    ``sample_token(logits[i:i + 1], ..., key=keys[i])`` draws. The sort
+    sits under a ``lax.cond`` on "any row samples": a batch of greedy
+    rows pays the argmax and nothing else (the serving step's sampler)."""
+    greedy = jnp.argmax(logits, -1).astype(jnp.int32)
+    V = logits.shape[-1]
+
+    def sampled(_):
+        sl = logits / jnp.where(temperature > 0, temperature, 1.0)[:, None]
+        # (unstable: values alone are sorted, and the TPU compiler takes
+        # 6-9 s over it where the stable sort of a vocabulary takes 20-24)
+        asc = jax.lax.sort(sl, dimension=1, is_stable=False)
+        kth = jnp.take_along_axis(
+            asc, (V - jnp.clip(top_k, 1, V))[:, None], -1)
+        cut_k = (top_k > 0)[:, None]
+        sl = jnp.where(cut_k & (sl < kth), -jnp.inf, sl)
+        # the masked row sorted again is the sorted row masked: one sort
+        desc = jnp.where(cut_k & (asc < kth), -jnp.inf, asc)[:, ::-1]
+        cum = jnp.cumsum(jax.nn.softmax(desc, -1), -1)
+        cutoff = jnp.take_along_axis(
+            desc, jnp.sum(cum < top_p[:, None], -1)[:, None], -1)
+        sl = jnp.where((top_p < 1.0)[:, None] & (sl < cutoff), -jnp.inf, sl)
+        drawn = jax.vmap(lambda k, row: jax.random.categorical(
+            k, row[None, :])[0])(keys, sl)
+        return jnp.where(temperature > 0, drawn.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(temperature > 0), sampled,
+                        lambda _: greedy, None)
 
 
 def generate_loop(prefill, decode, input_ids, max_new_tokens: int = 32,

@@ -25,15 +25,24 @@ work lists as traced inputs — no shape ever changes, so recompilation
 is structurally impossible; the ``step_traces`` counter (incremented at
 trace time) makes that checkable from tests.
 
+The step samples its own tokens and hands back ``[max_batch]`` int32;
+a decode row of the next step reads its input from that array on the
+device. The run loop therefore keeps one step in flight (ISSUE 32): it
+plans, packs and dispatches step n+1 while the device runs step n, then
+harvests step n (``_dispatch`` / ``_harvest``; ``step()`` by hand runs
+the two in turn).
+
 Telemetry goes through ``observability.metrics`` (queue depth,
 running/waiting gauges, TTFT and inter-token-latency histograms,
 token/preemption counters — names in docs/SERVING.md).
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time
 import warnings
+from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence
 
 import jax
@@ -41,8 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..profiler import RecordEvent
-from .kv_cache import PagedKVCache, chain_hash
-from .scheduler import Request, RequestState, Scheduler
+from .kv_cache import NULL_BLOCK, PagedKVCache, chain_hash
+from .scheduler import Request, RequestState, Scheduler, Unharvested
 
 __all__ = ["ServingEngine", "RequestHandle", "serving_metrics"]
 
@@ -95,6 +104,13 @@ def _build_serving_metrics(reg) -> dict:
             "serving_preemptions_total", "sequences preempted (recompute)"),
         "steps": reg.counter(
             "serving_engine_steps_total", "compiled steps run, by kind"),
+        "dispatched": reg.counter(
+            "serving_steps_dispatched_total",
+            "compiled steps by order: ahead (dispatched while the step "
+            "before was still unharvested) / serial, and a serial step "
+            "by reason (idle: nothing was in flight; preempt / cow / abort "
+            "/ numerics: the step before was harvested first, see "
+            "docs/SERVING.md)"),
         "rpa_steps": reg.counter(
             "serving_rpa_steps_total",
             "RPA kernel grid steps a kv head and layer, by kind: live "
@@ -198,6 +214,18 @@ def _a_group(arrays):
     return tuple(jnp.asarray(a) for a in arrays)
 
 
+@dataclass
+class _Flight:
+    """A dispatched step the host has not harvested: what it ran and the
+    device arrays that hold what it has to say."""
+    step: int
+    #: (sequence, new tokens, is a prefill chunk, samples a token)
+    entries: list
+    tokens: jax.Array                    # [max_batch] int32 sampled tokens
+    moe_rows: Optional[jax.Array] = None
+    taps: Optional[dict] = None          # the numerics twin's extra output
+
+
 class RequestHandle:
     """Caller-side view of a submitted request (thread-safe wait)."""
 
@@ -256,7 +284,7 @@ class ServingEngine:
     be a mapping by group name), from which the pools are built.
     Optional: ``moe_expert_rows()`` (the rows each held expert took in the
     traced step, ``[layers, held]`` int32: returned by the compiled step
-    beside the logits and published by the commit span and
+    beside the tokens and published by the commit span and
     ``serving_moe_expert_rows_total``) and
     ``clear_decode_side_effects()``."""
 
@@ -471,6 +499,27 @@ class ServingEngine:
         #: so it equals the number of compiles of the ONE unified step
         self.step_traces = 0
         self._step = self._build_step()
+        # what a step is handed where no row reads the step before
+        # (token array of zeros; the source rows are it less one) and
+        # where no row samples (settings, key base, key counts): built once
+        self._no_tokens = jnp.zeros((self.max_batch,), jnp.int32)
+        if self.mesh is not None:     # placed as a step's own tokens are
+            self._no_tokens = jax.device_put(self._no_tokens,
+                                             self._replicated())
+        self._greedy = (jnp.zeros((3, self.max_batch), jnp.float32),
+                        jax.random.key_data(jax.random.key(0)),
+                        jnp.zeros((self.max_batch,), jnp.uint32))
+        self._base_key = (None, None)   # the host stream's, and its words
+        #: steps dispatched and not harvested, oldest first: one between
+        #: two turns of the run loop, two between a turn's dispatch and
+        #: its harvest, none outside ``step()`` called by hand
+        self._flights = collections.deque()
+        #: sequences whose last token is sampled (a finish by length):
+        #: the next harvest takes their slot and pages back
+        self._retiring: List[Request] = []
+        #: set where the step in flight must be harvested before the
+        #: next is planned (``abort`` of a sequence in it)
+        self._harvest_first: Optional[str] = None
         # numerics twin (docs/OBSERVABILITY.md#numerics): an instrumented
         # build of the SAME unified step, compiled lazily on the first
         # sampled step when PADDLE_TPU_NUMERICS is armed — it substitutes
@@ -543,7 +592,7 @@ class ServingEngine:
         from paddle_tpu.jit.functional import functional_state
         from paddle_tpu.quantization.weight_only import quantize_state
         with self._lock:
-            active = self.scheduler.num_running + self.scheduler.num_waiting
+            active = len(self._handles)
             if active:
                 raise RuntimeError(
                     f"cannot swap weights with {active} request(s) in "
@@ -618,6 +667,20 @@ class ServingEngine:
                 out[k] = jax.device_put(v, NamedSharding(self.mesh, spec))
         self._st = out
 
+    def _key_words(self, key):
+        """The raw words of the host stream's base key (kept: the base
+        changes only with the seed)."""
+        if self._base_key[0] is not key:
+            self._base_key = (key, jax.random.key_data(key))
+        return self._base_key[1]
+
+    def _replicated(self):
+        """Whole on every device of the engine's mesh; None without one."""
+        if self.mesh is None:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+        return NamedSharding(self.mesh, PartitionSpec())
+
     # -- the one compiled step ---------------------------------------------
     def _build_step(self, instrument: bool = False):
         import contextlib
@@ -625,6 +688,7 @@ class ServingEngine:
         from paddle_tpu.core.autograd import no_grad
         from paddle_tpu.core.tensor import Tensor
         from paddle_tpu.jit.functional import swap_state
+        from paddle_tpu.models.generation import sample_rows
         from paddle_tpu.observability import numerics
         from paddle_tpu.ops import paged_attention as pa
         from paddle_tpu.quantization.weight_only import QuantizedLeaf
@@ -648,12 +712,21 @@ class ServingEngine:
 
         group_of = self.cache.group_of_layer
         windows = [g.window for g in self.cache.groups]
+        whole = self._replicated()
 
         def step(stt, tokens, k_pools, v_pools, k_scales, v_scales,
-                 bts, cu, ctx, sid, pos, ssqs, sbks, stls, last_idx, aid):
+                 bts, cu, ctx, sid, pos, ssqs, sbks, stls, last_idx, aid,
+                 prev_tokens, src, samp, key_base, key_counts):
             # executes at trace time only — counting compiles is the
             # point (the compile-once guard tests read it)
             self.step_traces += 1  # analysis: allow(trace-attr-mutation)
+            # a decode row's input, where the step before sampled it and
+            # the host has not read it: row ``src`` of that step's token
+            # array (-1: the host's token stands). The token never leaves
+            # the device between the two steps.
+            fed = jnp.concatenate([src, jnp.full((1,), -1, src.dtype)])[sid]
+            tokens = jnp.where(fed >= 0, prev_tokens[jnp.maximum(fed, 0)],
+                               tokens[0])[None]
             # weight-only quantization: dequantize the (values, scales)
             # leaves HERE, inside the trace, so XLA fuses the multiply
             # into the consuming matmuls and swap_state sees plain
@@ -694,8 +767,28 @@ class ServingEngine:
                 vss = tuple(c.v_scale.data for c in new_caches)
             else:
                 kss, vss = (), ()
-            out = (logits.data[:, 0].astype(jnp.float32), kps, vps,
-                   kss, vss) + rows
+            # the tokens are sampled here: the [max_batch, V] logits stay
+            # in the program. samp: temperature, top_k, top_p a row (0 at a
+            # greedy row); a sampled row's key is the host stream's,
+            # fold_in(key_base, its count) (the base as its raw words: a
+            # typed key among the arguments takes the jitted call off its
+            # fast path)
+            base = jax.random.wrap_key_data(key_base)
+            keys = jax.vmap(lambda c: jax.random.fold_in(base, c))(
+                key_counts)
+            rows_v = logits.data[:, 0]
+            if whole is not None:
+                # a vocabulary split over the model-parallel axis is
+                # gathered once, as the logits' way to the host was; the
+                # argmax and the sorts then run on whole rows
+                rows_v = jax.lax.with_sharding_constraint(rows_v, whole)
+            sampled = sample_rows(
+                rows_v.astype(jnp.float32), samp[0],
+                samp[1].astype(jnp.int32), samp[2], keys)
+            if whole is not None:
+                # the next step takes the array back as it is placed here
+                sampled = jax.lax.with_sharding_constraint(sampled, whole)
+            out = (sampled, kps, vps, kss, vss) + rows
             if not instrument:
                 return out
             # trace-time fill of the execution-order cell (jax pytrees
@@ -763,7 +856,8 @@ class ServingEngine:
                     jnp.asarray(pos), _a_group(m.step_seq for m in maps),
                     _a_group(m.step_blk for m in maps),
                     _a_group(m.step_tile for m in maps), jnp.asarray(last_idx),
-                    jnp.asarray(aid))
+                    jnp.asarray(aid), self._no_tokens, self._no_tokens - 1,
+                    *self._greedy)
             finally:
                 self._clear_model_side_effects()
 
@@ -781,6 +875,7 @@ class ServingEngine:
         self._m_tokens = m["tokens"]
         self._m_preempt = m["preemptions"]
         self._m_steps = m["steps"]
+        self._m_dispatched = m["dispatched"]
         self._m_rpa_steps = m["rpa_steps"]
         self._m_kv_released = m["kv_released"]
         self._m_moe_rows = m["moe_rows"]
@@ -1039,51 +1134,100 @@ class ServingEngine:
 
     def step(self) -> bool:
         """Plan + run one unified token-packed step (all live decode
-        slots + the packed prefill chunks). Returns whether any work
-        happened."""
+        slots + the packed prefill chunks) and hand out its tokens: the
+        serial order of the two halves the run loop overlaps, dispatch
+        then harvest at once. Returns whether any work happened."""
+        did = self._dispatch()
+        while self._flights:
+            self._harvest()
+        return did
+
+    def _turn(self):
+        """One turn of the run loop: plan, pack and dispatch the next step
+        while the device runs the one in flight, then harvest that one."""
+        did = self._dispatch()
+        if self._flights and (len(self._flights) > 1 or not did):
+            self._harvest()
+
+    def _plan(self):
+        """The step's rows: ``(decode, prefills)`` as the scheduler plans
+        them. Raises ``Unharvested`` where the plan cannot be made without
+        a token still on the device."""
+        plan = self.scheduler.schedule()
+        if self._ledger is not None:
+            # step-boundary occupancy sample: bill each slotted
+            # request's previous holding level for the elapsed
+            # interval (scheduler.preempt/finish tick pre-free,
+            # so no interval is lost when blocks go back)
+            self._ledger.note_occupancy_many(self.scheduler.slotted())
+        # belt-and-braces against plan staleness: never act on a
+        # sequence that lost its slot/blocks during planning (a
+        # later allocation in the same plan may have preempted
+        # it)
+        decode = [s for s in plan.decode
+                  if s.slot is not None
+                  and s.state is RequestState.RUNNING]
+        prefills = [(s, n_tok) for (s, n_tok) in plan.prefills
+                    if s.slot is not None
+                    and s.state is RequestState.PREFILL]
+        return decode, prefills
+
+    def _dispatch(self) -> bool:
+        """The first half of a step, under the engine lock: plan, pack,
+        call the compiled program, and advance every sequence by what
+        needs no token value. With a step in flight this runs while the
+        device does; where the plan needs that step's values (the cases
+        below) it is harvested first and this one goes out serially.
+        Returns whether a step was dispatched."""
+        from paddle_tpu.observability import numerics
+
         n = self._decode_steps + 1
         with self._leaf("serving.lock", n):
             self._lock.acquire()
         try:
+            # why the step in flight has to be harvested before this one
+            # is planned: an abort took a sequence out of it; the
+            # scheduler wants to preempt a sequence whose newest token is
+            # on the device; a copy-on-write block copy beside a step that
+            # writes; the numerics twin's step, read at once
+            first = self._harvest_first if self._flights else None
+            self._harvest_first = None
             with self._leaf("serving.plan", n):
-                plan = self.scheduler.schedule()
-                if self._ledger is not None:
-                    # step-boundary occupancy sample: bill each slotted
-                    # request's previous holding level for the elapsed
-                    # interval (scheduler.preempt/finish tick pre-free,
-                    # so no interval is lost when blocks go back)
-                    self._ledger.note_occupancy_many(
-                        self.scheduler.slotted())
-                # belt-and-braces against plan staleness: never act on a
-                # sequence that lost its slot/blocks during planning (a
-                # later allocation in the same plan may have preempted
-                # it)
-                decode = [s for s in plan.decode
-                          if s.slot is not None
-                          and s.state is RequestState.RUNNING]
-                prefills = [(s, n_tok) for (s, n_tok) in plan.prefills
-                            if s.slot is not None
-                            and s.state is RequestState.PREFILL]
+                if first is None:
+                    try:
+                        decode, prefills = self._plan()
+                    except Unharvested:
+                        first = "preempt"
+                    else:
+                        cow = any(s.cow_src is not None for s, _ in prefills)
+                        if self._flights and (
+                                cow or numerics.sample_this_step(n)):
+                            first = "cow" if cow else "numerics"
+            if first is not None:
+                while self._flights:
+                    self._harvest()
+                with self._leaf("serving.plan", n):
+                    decode, prefills = self._plan()
             if decode or prefills:
-                self._run_unified(decode, prefills)
-            with self._leaf("serving.gauges", n):
-                if decode or prefills:
-                    # healthz liveness stamp: a wedged-but-listening
-                    # server shows a growing last_step_age_seconds
-                    from paddle_tpu.observability import fleet
-                    fleet.note_step()
-                self._update_gauges()
+                self._run_unified(decode, prefills, first or "idle")
+            elif not self._flights:
+                # nothing ran and nothing is left to harvest: the gauges
+                # of a call that found no work
+                with self._leaf("serving.gauges", n):
+                    self._update_gauges()
             return bool(decode or prefills)
         finally:
             self._lock.release()
 
-    def _run_unified(self, decode: List[Request],
-                     prefills: List[tuple]):
+    def _run_unified(self, decode: List[Request], prefills: List[tuple],
+                     serial_reason: str):
         """Pack the planned work into the flat token budget, build the
         step's ragged metadata (token→sequence map, per-token positions,
         the RPA kernel's work lists) host-side, run the ONE compiled
-        step, and harvest per-sequence results."""
-        from paddle_tpu.observability import trace
+        step, and advance the sequences; the step's tokens stay on the
+        device until ``_harvest``."""
+        from paddle_tpu.core import generator as G
+        from paddle_tpu.observability import numerics, trace
 
         n_step = self._decode_steps + 1
         leaf = self._leaf("serving.pack", n_step)
@@ -1114,11 +1258,14 @@ class ServingEngine:
                                       seq.tables[0][seq.cow_index])
                 self.scheduler._release_cow(seq)
 
-        entries = [(seq, 1, False) for seq in decode] + \
-                  [(seq, n, True) for seq, n in prefills]
+        # a chunk that ends its prompt samples a token, a decode row always
+        entries = [(seq, 1, False, True) for seq in decode] + \
+                  [(seq, n, True,
+                    seq.prefill_pos + n == len(seq.pending_tokens))
+                   for seq, n in prefills]
         T, S = self.step_tokens, self.max_batch
         assert len(entries) <= S and \
-            sum(n for _, n, _ in entries) <= T, "scheduler over-packed"
+            sum(e[1] for e in entries) <= T, "scheduler over-packed"
         tokens = np.zeros((1, T), np.int32)
         groups = self.cache.groups
         bts = [np.zeros((S + 1, self.cache.max_blocks_per_seq), np.int32)
@@ -1129,16 +1276,30 @@ class ServingEngine:
         pos = np.zeros((T,), np.int32)
         last_idx = np.zeros((S,), np.int32)
         aid = np.zeros((T,), np.int32)     # padding -> slot 0 (base)
+        src = np.full((S,), -1, np.int32)  # the host's token stands
+        samp, key_base, key_counts = None, None, None
         kv_lens = []
         off = 0
-        for i, (seq, n, is_prefill) in enumerate(entries):
+        for i, (seq, n, is_prefill, samples) in enumerate(entries):
             if is_prefill:
                 tokens[0, off:off + n] = seq.pending_tokens[
                     seq.prefill_pos:seq.prefill_pos + n]
                 c = seq.prefill_pos
             else:
-                tokens[0, off] = seq.last_token()
+                if seq.unharvested > 0:
+                    # sampled by the step in flight: read on the device
+                    src[i] = seq.token_row
+                else:
+                    tokens[0, off] = seq.last_token()
                 c = seq.num_cached
+            if samples and seq.temperature > 0:
+                # the key of a sampled row: the host stream's next, one a
+                # sampled row in row order (folded in the program)
+                if samp is None:
+                    samp = np.zeros((3, S), np.float32)
+                    key_counts = np.zeros((S,), np.uint32)
+                samp[:, i] = (seq.temperature, seq.top_k, seq.top_p)
+                key_base, key_counts[i] = G.next_key_parts()
             for bt, table in zip(bts, seq.tables):
                 bt[i] = self.cache.pad_block_table(table)
             ctx[i] = c
@@ -1158,8 +1319,6 @@ class ServingEngine:
             # cached all-sentinel one instead of rebuilding per step
             maps = self._null_step_maps
 
-        from paddle_tpu.observability import numerics
-
         # numerics sampling (docs/OBSERVABILITY.md#numerics): on a
         # sampled step the instrumented twin SUBSTITUTES for the plain
         # step — same program values (taps are identity), one extra
@@ -1174,13 +1333,16 @@ class ServingEngine:
                     self._build_step(instrument=True)
             step_fn = self._numerics_step
 
+        # one step ahead: the step before is still unharvested (it runs,
+        # or waits its turn, on the device)
+        ahead = int(bool(self._flights))
+        prev = self._flights[-1].tokens if ahead else self._no_tokens
         leaf.end()
         leaf = self._leaf(
             "serving.dispatch", n_step, decode_rows=len(decode),
             prefill_rows=len(prefills),
-            prefill_tokens=sum(n for _, n in prefills),
-            **_row_args(f"{n}@{ctx[i]}"
-                        for i, (_, n, _) in enumerate(entries)))
+            prefill_tokens=sum(n for _, n in prefills), ahead=ahead,
+            **_row_args(f"{e[1]}@{ctx[i]}" for i, e in enumerate(entries)))
         leaf.begin()
         t0 = time.perf_counter_ns()
         compiles0 = self.step_traces
@@ -1193,10 +1355,13 @@ class ServingEngine:
                 _a_group(m.step_seq for m in maps),
                 _a_group(m.step_blk for m in maps),
                 _a_group(m.step_tile for m in maps), jnp.asarray(last_idx),
-                jnp.asarray(aid))
+                jnp.asarray(aid), prev, jnp.asarray(src),
+                *(self._greedy if samp is None else
+                  (jnp.asarray(samp), self._key_words(key_base),
+                   jnp.asarray(key_counts))))
             if step_fn is not self._step:
                 out, taps_out = out[:-1], out[-1]
-            logits, kps, vps, kss, vss, *moe_rows = out
+            sampled, kps, vps, kss, vss, *moe_rows = out
         except Exception as e:
             # RESOURCE_EXHAUSTED gets one postmortem (ledger owners +
             # the unified step's memory report) before re-raising into
@@ -1230,29 +1395,20 @@ class ServingEngine:
                         leaf.args[f"rpa_{kind}_{g.name}"] = getattr(m, kind)
                 if g.window is not None:
                     leaf.args[f"rpa_pages_causal_{g.name}"] = m.pages_causal
-        leaf.end()
         self._m_steps.inc(kind="unified")
-        leaf = self._leaf("serving.fetch", n_step)
-        leaf.begin()
-        arr = np.asarray(logits)
-        if taps_out is not None:
-            try:
-                h = jax.device_get(taps_out)
-                order = self._numerics_order or list(h)
-                numerics.get_observatory().record_decode(
-                    {n: tuple(float(v) for v in h[n])
-                     for n in order if n in h})
-            except Exception:
-                warnings.warn("[numerics] decode sample publication "
-                              "failed", RuntimeWarning)
-        leaf.end()
+        if ahead:
+            self._m_dispatched.inc(order="ahead")
+        else:
+            self._m_dispatched.inc(order="serial", reason=serial_reason)
+        self._flights.append(_Flight(
+            n_step, entries, sampled, moe_rows[0] if moe_rows else None,
+            taps_out))
 
-        leaf = self._leaf("serving.commit", n_step)
-        leaf.begin()
-        if moe_rows:
-            self._publish_moe_rows(np.asarray(moe_rows[0]), leaf)
-        tokens_out = 0
-        for i, (seq, n, is_prefill) in enumerate(entries):
+        # advance: what of a step's outcome needs no token value. The
+        # device is at work; the next plan reads the sequences as the
+        # step leaves them
+        windowed = any(g.window is not None for g in groups)
+        for i, (seq, n, is_prefill, samples) in enumerate(entries):
             if is_prefill:
                 if trace.active() is not None:
                     # compile attribution: a chunk that rode the step
@@ -1268,33 +1424,90 @@ class ServingEngine:
                 if self._ledger is not None:
                     self._ledger.note_prefill(seq, n, compiled)
                 seq.prefill_pos += n
-                seq.num_cached += n
                 seq.prefilled_tokens += n
                 self._prompt_tokens_prefilled += n
                 self._m_tokens.inc(n, kind="prompt")
-                self._commit_cached_blocks(seq)
-                if seq.prefill_pos == len(seq.pending_tokens):
-                    # prompt fully cached: sample the continuation (the
-                    # request's first token — or, after preemption, the
-                    # next)
-                    tok = self._sample(arr[i], seq)
-                    seq.state = RequestState.RUNNING
-                    self._emit_token(seq, tok)
-                    tokens_out += 1
-            else:
-                seq.num_cached += 1
-                self._commit_cached_blocks(seq)
-                tok = self._sample(arr[i], seq)
-                self._emit_token(seq, tok)
-                tokens_out += 1
-        if any(g.window is not None for g in groups):
-            for seq, _, _ in entries:
-                if seq.slot is not None:     # still holds its pages
-                    for name, n in self.scheduler.release_behind_window(
-                            seq).items():
-                        self._m_kv_released.inc(n, group=name)
-        leaf.args["tokens_out"] = tokens_out
+            seq.num_cached += n
+            if samples:
+                # prompt fully cached: the continuation is sampled (the
+                # request's first token — or, after preemption, the next)
+                seq.state = RequestState.RUNNING
+                seq.num_sampled += 1
+                seq.token_row = i
+                if seq.all_sampled:
+                    self._retiring.append(seq)
+            self._commit_cached_blocks(seq)
+            if windowed:
+                # the pages no token still to come can see; this step's
+                # own rows read them off the tables packed above
+                for name, k in self.scheduler.release_behind_window(
+                        seq).items():
+                    self._m_kv_released.inc(k, group=name)
         leaf.end()
+
+    def _harvest(self):
+        """The second half of the oldest step in flight: wait for its
+        tokens (``serving.fetch``, outside the engine lock: ``submit``
+        and ``abort`` do not wait a device step), then hand them out
+        (``serving.commit``): emit, finish on EOS or length, register the
+        blocks the new tokens filled, take back what sequences at their
+        last token hold. A row whose sequence finished meanwhile (EOS a
+        step ago, an abort) is dropped."""
+        from paddle_tpu.observability import fleet, numerics
+
+        with self._lock:
+            if not self._flights:       # another driver's harvest took it
+                return
+            flight = self._flights[0]
+        n_step = flight.step
+        leaf = self._leaf("serving.fetch", n_step)
+        leaf.begin()
+        toks = np.asarray(flight.tokens)
+        if flight.taps is not None:
+            try:
+                h = jax.device_get(flight.taps)
+                order = self._numerics_order or list(h)
+                numerics.get_observatory().record_decode(
+                    {n: tuple(float(v) for v in h[n])
+                     for n in order if n in h})
+            except Exception:
+                warnings.warn("[numerics] decode sample publication "
+                              "failed", RuntimeWarning)
+        leaf.end()
+
+        leaf = self._leaf("serving.commit", n_step)
+        leaf.begin()
+        with self._lock:
+            if not self._flights or self._flights[0] is not flight:
+                leaf.end()              # (a second driver: ``step()`` by
+                return                  # hand beside the run loop)
+            self._flights.popleft()
+            if flight.moe_rows is not None:
+                self._publish_moe_rows(np.asarray(flight.moe_rows), leaf)
+            tokens_out = 0
+            for i, (seq, _, _, samples) in enumerate(flight.entries):
+                if not samples or seq.done:
+                    continue
+                # blocks the tokens harvested so far filled, BEFORE the
+                # new token can finish the request
+                self._commit_cached_blocks(seq)
+                self._emit_token(seq, self._sample(toks[i], seq))
+                tokens_out += 1
+            for seq in self._retiring:
+                if not seq.done:
+                    # sampled to its length, its last step dispatched:
+                    # slot and pages go back before the next plan; the
+                    # last token's harvest finishes the request
+                    self._commit_cached_blocks(seq)
+                    self.scheduler.release(seq)
+            self._retiring.clear()
+            leaf.args["tokens_out"] = tokens_out
+            leaf.end()
+            with self._leaf("serving.gauges", n_step):
+                # healthz liveness stamp: a wedged-but-listening
+                # server shows a growing last_step_age_seconds
+                fleet.note_step()
+                self._update_gauges()
 
     def _publish_moe_rows(self, rows: np.ndarray, leaf):
         """``rows`` [layers, held experts]: the token rows the step's
@@ -1309,16 +1522,21 @@ class ServingEngine:
 
     def _commit_cached_blocks(self, seq: Request):
         """Register every newly-completed full block in the prefix
-        index. Runs right after a step advanced ``num_cached`` and
-        BEFORE the sampled token can finish the request — a request
-        that ends this step still leaves its blocks cached (they park
-        as reclaimable when ``finish`` drops the refcounts). Committed
-        blocks are never written again (sequence writes land at
-        ``num_cached`` and beyond), so the index entry is immutable."""
+        index whose tokens the host knows: all of a prompt's as soon as a
+        step was dispatched over them, a block that holds generated
+        tokens once the last of them is harvested. Runs where a step
+        advanced ``num_cached`` and, at harvest, BEFORE the new token can
+        finish the request — a request that ends there still leaves its
+        blocks cached (they park as reclaimable when ``finish`` drops the
+        refcounts). Committed blocks are never written again (sequence
+        writes land at ``num_cached`` and beyond), so the index entry is
+        immutable; a block registered while the step that writes its last
+        row is in flight is read by later steps only."""
         if not self.prefix_cache_enabled:
             return
         bs = self.cache.block_size
-        full = seq.num_cached // bs
+        known = len(seq.prompt_tokens) + len(seq.generated)
+        full = min(seq.num_cached, known) // bs
         if full <= seq.committed_blocks:
             return
         # the cached token stream: pending covers prompt (+ recompute
@@ -1328,19 +1546,19 @@ class ServingEngine:
             d = chain_hash(seq.committed_hash,
                            stream[i * bs:(i + 1) * bs])
             # in every group: the step that wrote the block's last token
-            # wrote it in each (a window group releases only afterwards)
+            # wrote it in each (a window group releases only afterwards;
+            # a window shorter than a block may have let the page go)
             for g, table in zip(self.cache.groups, seq.tables):
-                g.prefix_cache.register(d, table[i])
+                if table[i] != NULL_BLOCK:
+                    g.prefix_cache.register(d, table[i])
             seq.committed_hash = d
         seq.committed_blocks = full
 
-    def _sample(self, logits_row: np.ndarray, seq: Request) -> int:
-        if seq.temperature == 0:
-            return int(np.argmax(logits_row))
-        from paddle_tpu.models.generation import sample_token
-        tok = sample_token(jnp.asarray(logits_row)[None, :],
-                           seq.temperature, seq.top_k, seq.top_p)
-        return int(np.asarray(tok)[0])
+    def _sample(self, token, seq: Request) -> int:
+        """A row's token as the host takes it from the step's token array
+        (the compiled step sampled it). The seam a test wraps to plant a
+        fault in what is served (tests/benchmark)."""
+        return int(token)
 
     def _emit_token(self, seq: Request, tok: int):
         now = time.perf_counter()
@@ -1440,7 +1658,8 @@ class ServingEngine:
         aborts requests that blew their deadline so abandoned work stops
         consuming engine capacity. Returns False when the request is
         unknown or already finished. Safe against a concurrent step():
-        both run under the engine lock, so no plan is in flight."""
+        both halves of one take the engine lock, and a row the aborted
+        sequence has in a step in flight is dropped at its harvest."""
         with self._cv:
             handle = self._handles.get(req_id)
             if handle is None:
@@ -1450,6 +1669,10 @@ class ServingEngine:
                 return False
             if seq in self.scheduler.waiting:
                 self.scheduler.waiting.remove(seq)
+            if any(seq is e[0] for f in self._flights for e in f.entries):
+                # a row of a step in flight: its harvest drops the row,
+                # and comes before the next plan
+                self._harvest_first = "abort"
             seq.error = reason
             # _finish records the request outcome; no extra inc here or
             # the serving_requests_total family double-counts the abort
@@ -1467,7 +1690,7 @@ class ServingEngine:
     # -- run loop ----------------------------------------------------------
     def has_pending(self) -> bool:
         with self._lock:
-            return self.scheduler.has_work()
+            return self.scheduler.has_work() or bool(self._flights)
 
     def run_until_idle(self):
         """Synchronous driver (tests / batch jobs): step until every
@@ -1493,23 +1716,27 @@ class ServingEngine:
             self._thread.start()
 
     def _run_loop(self):
+        """One step ahead: each turn dispatches the next step while the
+        device runs the one in flight, then harvests that one; a step's
+        tokens reach their callbacks while the next step runs."""
         while True:
             with self._cv:
-                if self._shutdown and not self.scheduler.has_work():
-                    return
-                if not self.scheduler.has_work():
+                if not self.has_pending():
+                    if self._shutdown:
+                        return
                     with RecordEvent("serving.idle_wait", cat="serving"):
                         self._cv.wait(timeout=0.1)
                     continue
             try:
-                self.step()
+                self._turn()
             except Exception as e:  # noqa: BLE001 — loop must not die silently
                 # a step failure (OOM, scheduling bug) would otherwise
                 # strand every pending handle forever: fail them all
                 # loudly and stop the loop
                 with self._cv:
-                    for seq in (list(self.scheduler.slotted())
-                                + list(self.scheduler.waiting)):
+                    self._flights.clear()
+                    self._retiring.clear()
+                    for seq in [h._req for h in self._handles.values()]:
                         seq.error = f"engine step failed: {e!r}"
                         self._finish(seq, "error", RequestState.FAILED)
                     self.scheduler.waiting.clear()
@@ -1527,7 +1754,7 @@ class ServingEngine:
             if deadline is not None and time.perf_counter() > deadline:
                 raise TimeoutError("engine drain timed out")
             with self._cv:
-                if self.scheduler.has_work():
+                if self.has_pending():
                     self._cv.wait(timeout=0.1)
 
     def shutdown(self, drain: bool = True, timeout: Optional[float] = None):
@@ -1538,8 +1765,9 @@ class ServingEngine:
         with self._cv:
             self._shutdown = True
             if not drain:
-                for seq in (list(self.scheduler.slotted())
-                            + list(self.scheduler.waiting)):
+                # every accepted request not finished: slotted, waiting,
+                # and those whose last token is still on the device
+                for seq in [h._req for h in self._handles.values()]:
                     seq.error = "engine shut down"
                     self._finish(seq, "aborted", RequestState.FAILED)
                 self.scheduler.waiting.clear()

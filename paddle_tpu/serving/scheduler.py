@@ -44,6 +44,15 @@ already-planned victim is knocked back to WAITING (slot released), and
 the engine filters such stale entries before acting — the
 protected-victim guarantee (no chunk is ever written through an
 all-null block table).
+
+The engine's run loop plans a step while the step before is still on the
+device (ISSUE 32): a sequence's ``num_sampled`` may then run ahead of
+``len(generated)``. Nothing of a plan needs a token's value but the
+recompute text of a victim: ``preempt`` raises :class:`Unharvested`
+before it touches a sequence whose newest token is not harvested, what
+the plan did until then stands, and the engine plans again after the
+harvest. A sequence sampled to its length (``all_sampled``) is not
+planned again.
 """
 from __future__ import annotations
 
@@ -56,9 +65,17 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 from .kv_cache import NULL_BLOCK, PagedKVCache
 
-__all__ = ["RequestState", "Request", "StepPlan", "Scheduler"]
+__all__ = ["RequestState", "Request", "StepPlan", "Scheduler", "Unharvested"]
 
 _req_counter = itertools.count()
+
+
+class Unharvested(Exception):
+    """The plan wants to preempt a sequence whose newest token the engine
+    has not read back from the device: its recompute text cannot be
+    written yet. Raised before the sequence is touched; what the plan did
+    until then stands (admissions, grown block tables), and planning again
+    after the harvest picks up from there."""
 
 
 class RequestState(Enum):
@@ -107,6 +124,11 @@ class Request:
     prefill_pos: int = 0     # pending tokens already cached
     num_cached: int = 0      # total tokens written to the KV cache
     generated: List[int] = field(default_factory=list)
+    #: tokens the compiled step has sampled for this request; those beyond
+    #: ``len(generated)`` (at most two) are on the device, not harvested
+    num_sampled: int = 0
+    #: the row of the newest sampled token in its step's token array
+    token_row: int = -1
     # -- prefix-cache state (ISSUE 15) -------------------------------------
     #: prompt tokens recovered from the prefix cache at the LAST admission
     cached_prompt_tokens: int = 0
@@ -158,6 +180,17 @@ class Request:
         """The decode-step input: the newest sampled, not-yet-cached
         token (prefill completion always samples one before decoding)."""
         return self.generated[-1]
+
+    @property
+    def unharvested(self) -> int:
+        """Sampled tokens still on the device."""
+        return self.num_sampled - len(self.generated)
+
+    @property
+    def all_sampled(self) -> bool:
+        """Every token it asked for is sampled: a finish by length, known
+        without a token's value."""
+        return self.num_sampled >= self.max_new_tokens
 
     def ttft(self) -> Optional[float]:
         if self.first_token_time is None:
@@ -342,7 +375,8 @@ class Scheduler:
         # earliest arrivals first: preemption victims come from the tail,
         # so a seq preempted mid-planning is simply never reached
         for seq in sorted(self.slotted(), key=lambda r: r.arrival_time):
-            if seq.state is not RequestState.RUNNING or seq.slot is None:
+            if seq.state is not RequestState.RUNNING or seq.slot is None \
+                    or seq.all_sampled:
                 continue
             if self._ensure_blocks(seq, seq.num_cached + 1):
                 batch.append(seq)
@@ -356,9 +390,10 @@ class Scheduler:
         seq.tables = [[] for _ in self.cache.groups]
 
     def release_behind_window(self, seq: Request) -> dict:
-        """After a step committed: a window group gives back every page
-        of ``seq`` whose last key no token still to come can see (it lies
-        at or before ``num_cached - window``). A page the prefix cache has
+        """Once a step is dispatched and ``num_cached`` advanced: a window
+        group gives back every page of ``seq`` whose last key no token
+        still to come can see (it lies at or before ``num_cached -
+        window``; the step in flight reads it off the table it was handed). A page the prefix cache has
         registered parks reclaimable and keeps its contents; the table
         entry becomes the null block. Returns ``{group name: pages}``."""
         out = {}
@@ -427,7 +462,11 @@ class Scheduler:
         prompt+generated as the new prefill text. Greedy decoding makes
         the resumed continuation token-identical. With the prefix cache
         on, the freed committed blocks PARK as reclaimable — readmission
-        re-matches them and recomputes only the uncached tail."""
+        re-matches them and recomputes only the uncached tail. The text
+        holds every generated token: a sequence with one still on the
+        device raises :class:`Unharvested`, untouched."""
+        if seq.unharvested > 0:
+            raise Unharvested(seq.req_id)
         from paddle_tpu.observability import requests as obs_requests
         led = obs_requests._active
         if led is not None:
@@ -458,11 +497,12 @@ class Scheduler:
             self.slots[seq.slot] = None
             seq.slot = None
 
-    def finish(self, seq: Request, state: RequestState,
-               reason: str = "stop"):
-        """Return every resource; the engine records metrics/callbacks.
+    def release(self, seq: Request):
+        """Return the slot and every page (nothing where they are gone).
         Registered blocks park in the reclaimable tier — a finished
-        request's prompt stays servable from cache."""
+        request's prompt stays servable from cache. Apart from ``finish``:
+        a sequence whose last token is sampled and not yet harvested needs
+        neither any more."""
         from paddle_tpu.observability import requests as obs_requests
         led = obs_requests._active
         if led is not None:
@@ -471,6 +511,11 @@ class Scheduler:
         self._release_cow(seq)
         self._free_blocks(seq)
         self.release_slot(seq)
+
+    def finish(self, seq: Request, state: RequestState,
+               reason: str = "stop"):
+        """Return every resource; the engine records metrics/callbacks."""
+        self.release(seq)
         seq.state = state
         seq.finish_reason = reason
         seq.finish_time = time.perf_counter()

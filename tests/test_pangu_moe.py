@@ -23,6 +23,7 @@ from paddle_tpu.ops.pallas.ragged_paged_attention import (
     build_step_maps, ragged_paged_attention, rpa_max_items, rpa_run_pages)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.kv_cache import PagedKVCache
+from serving_probe import keep_logits
 
 SEED = 5
 #: hidden 64, 4 heads of 16 + 8 (values 16), ranks 32 / 16, 8 experts 2 a
@@ -69,18 +70,10 @@ def test_full_forward_matches_the_reference(model):
 
 def _served_logits(engine, prompt, n):
     """Greedy tokens of one request and the logits row behind each."""
-    rows, sample = [], engine._sample
-
-    def keep(logits_row, seq):
-        rows.append(np.array(logits_row))
-        return sample(logits_row, seq)
-    engine._sample = keep
-    try:
-        h = engine.submit(prompt, max_new_tokens=n, temperature=0.0)
-        engine.run_until_idle()
-    finally:
-        engine._sample = sample
-    return h, np.stack(rows)
+    kept = keep_logits(engine)
+    h = engine.submit(prompt, max_new_tokens=n, temperature=0.0)
+    engine.run_until_idle()
+    return h, np.stack(kept[h.req_id])
 
 
 @pytest.mark.parametrize("impl", ["gather", "rpa"])

@@ -351,6 +351,9 @@ def test_abort_releases_queued_request():
     assert h1._req not in eng.scheduler.waiting and h1._req.slot is None
     assert eng.stats()["waiting"] + eng.stats()["running"] == 1
     eng.cache.assert_no_leaks()
+    # (the request ledger is one a process: leave nothing in flight for
+    # the tests of other files that a worker runs after this one)
+    eng.shutdown(drain=False)
 
 
 # ---------------- HTTP front-end ---------------------------------------------

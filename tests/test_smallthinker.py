@@ -22,6 +22,7 @@ from benchmark import sut_smallthinker as sut
 from benchmark.reference import smallthinker as ref
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.engine import serving_metrics
+from serving_probe import keep_logits
 
 TOL = 2e-5
 SEED = 3
@@ -65,12 +66,7 @@ def engine(model, **kw):
 def serve(eng, asks):
     """Run ``asks`` ``[(prompt, max_new)]`` to the end; returns the handles
     and, a request, the logits row behind each of its sampled tokens."""
-    rows, orig = {}, eng._sample
-
-    def sample(row, seq):
-        rows.setdefault(seq.req_id, []).append(np.array(row))
-        return orig(row, seq)
-    eng._sample = sample
+    rows = keep_logits(eng)
     handles = [eng.submit(p, max_new_tokens=n) for p, n in asks]
     eng.run_until_idle()
     return handles, [np.stack(rows[h.req_id]) for h in handles]
@@ -187,9 +183,9 @@ def test_a_sequence_never_holds_more_window_pages_than_the_bound(model):
             assert win == [0] * (len(win) - held) + win[len(win) - held:]
             most[when] = max(most[when], held)
 
-    def checked(decode, prefills):     # planned and allocated, not yet run
+    def checked(*planned):             # planned and allocated, not yet run
         holdings(0)
-        return run(decode, prefills)
+        return run(*planned)
     eng._run_unified = checked
     while eng.has_pending():
         eng.step()
