@@ -14,7 +14,10 @@ bf16; depth 8), with random weights made from a seed:
 3. server leg   — ``ServingEngine`` behind ``serving.Server`` on 127.0.0.1,
    six concurrent ``POST /generate`` requests (64..1,500 prompt tokens,
    half streamed, two sharing a 256-token prefix);
-4. four chips   — when jax reports >= 4 devices: the trainer on a
+4. drafted leg  — a small drafting model (``models/exaone_moe.py`` at head
+   and page widths the kernels take on the chip) served with
+   ``draft_tokens=1`` and without: the token streams must be equal;
+5. four chips   — when jax reports >= 4 devices: the trainer on a
    dp2 x mp2 mesh and the server on an mp4 mesh (otherwise stated as not
    run).
 
@@ -374,6 +377,65 @@ def server_leg(model_kw, engine_kw, prompts, prefix_len, new_tokens,
 
 
 # --------------------------------------------------------------- four chips --
+#: the drafted leg's model: a dense layer and one ``L L G L`` run, 8 query
+#: heads a KV head of 128, a window of one 128-token page, 4 of 8 experts
+DRAFTED_MODEL = dict(vocab_size=512, hidden_size=512, intermediate_size=1024,
+                     num_hidden_layers=5, num_attention_heads=8,
+                     num_key_value_heads=1, head_dim=128,
+                     moe_intermediate_size=256, num_experts=8,
+                     num_experts_per_tok=2, held_experts=(0, 2, 5, 7),
+                     max_position_embeddings=2048)
+DRAFTED_ENGINE = dict(max_batch=8, max_blocks={"window": 48, "full": 96},
+                      block_size=128, prefill_chunk=256)
+
+
+def drafted_leg(model_kw, engine_kw, prompt_lens, new_tokens, dtype="bfloat16"):
+    """Serve the same prompts through an engine that verifies a draft a
+    sequence and step (the run loop one step ahead) and through one that
+    does not: pass iff the token streams are equal, the step compiled once
+    and no page leaked. Returns the drafting engine's ``stats()["drafts"]``."""
+    import paddle_tpu as pt
+    from paddle_tpu.models.exaone_moe import (ExaoneMoeConfig,
+                                              ExaoneMoeForCausalLM)
+    from paddle_tpu.serving import ServingEngine
+
+    pt.seed(0)
+    kw = dict(model_kw)
+    kw.setdefault("sliding_windows",
+                  tuple(0 if i % 4 == 3 else engine_kw["block_size"]
+                        for i in range(8)))
+    model = ExaoneMoeForCausalLM(ExaoneMoeConfig(**kw))
+    if dtype == "bfloat16":
+        model.bfloat16()
+    model.eval()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, kw["vocab_size"], n).tolist()
+               for n in prompt_lens]
+    streams, drafts = {}, None
+    for d in (0, 1):
+        engine = ServingEngine(model, draft_tokens=d, **engine_kw)
+        engine.start()
+        handles = [engine.submit(p, max_new_tokens=new_tokens)
+                   for p in prompts]
+        for h in handles:
+            check(h.wait(600), f"drafted leg: request {h.req_id} finished")
+        engine.shutdown()
+        engine.cache.assert_no_leaks()
+        check(engine.step_traces == 1,
+              f"drafted leg: the step compiled once (draft_tokens={d}: "
+              f"{engine.step_traces})")
+        streams[d] = [h.token_ids for h in handles]
+        drafts = engine.stats().get("drafts", drafts)
+        del engine
+    check(streams[0] == streams[1],
+          "drafted leg: the drafted engine's tokens are the undrafted "
+          "engine's")
+    check(drafts["drafted"] > 0, "drafted leg: drafts were verified")
+    print(f"drafted leg: {len(prompts)} requests x {new_tokens} tokens "
+          f"equal with and without drafts; {drafts}")
+    return drafts
+
+
 def multichip_leg(one_chip_first_loss):
     """dp2 x mp2 training and mp4 serving on the first four devices, in
     device order as ``init_mesh`` lays them out."""
@@ -453,6 +515,11 @@ def run():
     print("[server leg]")
     engine, _ = server_leg(MODEL, ENGINE, PROMPTS, PREFIX_LEN, NEW_TOKENS)
     del engine
+    gc.collect()
+    _memory("after release")
+
+    print("[drafted leg]")
+    drafted_leg(DRAFTED_MODEL, DRAFTED_ENGINE, (40, 300, 700, 130), 48)
     gc.collect()
     _memory("after release")
 

@@ -374,7 +374,11 @@ class HeldExpertsLayer(Layer):
     all experts renormalised over the chosen, no scaling factor); with
     ``activation="relu"`` the gate is ``relu`` (ReGLU). ``router_input``
     (``forward``) is what the router reads where that is not the experts'
-    input ``h`` (a router placed before the block's attention).
+    input ``h`` (a router placed before the block's attention). With
+    ``selection_bias=True`` the layer holds a bias ``b`` an expert
+    (``router_bias``, zeros at birth) and the ``top_k`` largest of ``s + b``
+    are chosen; the weights are still those experts' ``s`` (DeepSeek-V3's
+    selection bias: it moves who is chosen, never what a choice weighs).
 
     ``w`` is normalised over all ``top_k`` chosen, held or not: the parts
     the shares of a deployment compute add up to the whole layer's
@@ -382,7 +386,8 @@ class HeldExpertsLayer(Layer):
     nothing stands in for them or their exchange. No capacity and no
     dropped token: the ``T * top_k`` assignments are sorted by held
     expert (those to absent experts last) and each held expert multiplies
-    exactly its rows, a ragged grouped product (``jax.lax.ragged_dot``,
+    exactly its rows, a ragged grouped product whose rows past the last
+    group are undefined and never read (``jax.lax.ragged_dot``,
     XLA's own grouped kernel on a TPU: at 16 experts of 7680 x 2048 and
     8,320 sorted rows it took 0.8-1.0 ms a product where a column-tiled
     Pallas grouped matmul took 2.3 and 7.0 ms, PR 27).
@@ -394,10 +399,13 @@ class HeldExpertsLayer(Layer):
 
     def __init__(self, d_model, d_hidden, num_experts, top_k, held=None,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
-                 init_std=0.02, score="sigmoid", activation="silu"):
+                 init_std=0.02, score="sigmoid", activation="silu",
+                 selection_bias=False):
         super().__init__()
         if score not in ("sigmoid", "softmax"):
             raise ValueError(f"score {score!r} (want sigmoid|softmax)")
+        if selection_bias and score != "sigmoid":
+            raise ValueError("a selection bias goes with sigmoid scores")
         if activation not in ("silu", "relu"):
             raise ValueError(f"activation {activation!r} (want silu|relu)")
         self.score, self.activation = score, activation
@@ -419,8 +427,10 @@ class HeldExpertsLayer(Layer):
             shape=[n, d_model, d_hidden], default_initializer=init)
         self.w_down = self.create_parameter(
             shape=[n, d_hidden, d_model], default_initializer=init)
+        self.router_bias = self.create_parameter(
+            shape=[num_experts], default_initializer=I.Constant(0.0)) \
+            if selection_bias else None
         self.last_rows = None
-
 
     def forward(self, x, token_mask=None, router_input=None):
         """x: [..., d_model] -> the held experts' part of the routed
@@ -438,6 +448,7 @@ class HeldExpertsLayer(Layer):
         softmax = self.score == "softmax"
         gate = jax.nn.relu if self.activation == "relu" else jax.nn.silu
         routed, masked = router_input is not None, token_mask is not None
+        biased = self.router_bias is not None
 
         def f(xa, gw, wg, wu, wd, *rest):
             lead = xa.shape[:-1]
@@ -451,7 +462,13 @@ class HeldExpertsLayer(Layer):
                 top_s, top_i = jax.lax.top_k(logits, K)
                 w = jax.nn.softmax(top_s, axis=-1)
             else:
-                top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), K)
+                s = jax.nn.sigmoid(logits)
+                if biased:
+                    _, top_i = jax.lax.top_k(
+                        s + rest[-1 - masked].astype(jnp.float32), K)
+                    top_s = jnp.take_along_axis(s, top_i, -1)
+                else:
+                    top_s, top_i = jax.lax.top_k(s, K)
                 w = top_s * scale
                 if norm:
                     w = w / (top_s.sum(-1, keepdims=True) + 1e-20)
@@ -472,12 +489,17 @@ class HeldExpertsLayer(Layer):
             # sorted order; an absent expert's row weighs nothing
             where = jnp.zeros((R,), jnp.int32).at[order].set(
                 jnp.arange(R, dtype=jnp.int32)).reshape(T, K)
-            w = jnp.where(slot < n, w, 0.0)
-            y = sum(out[where[:, k]].astype(jnp.float32) * w[:, k:k + 1]
+            # (a select, not a weight of nought: the grouped product
+            # leaves the rows past its last group as it found them, and
+            # on a TPU that memory may hold a NaN)
+            y = sum(jnp.where(slot[:, k:k + 1] < n,
+                              out[where[:, k]].astype(jnp.float32)
+                              * w[:, k:k + 1], 0.0)
                     for k in range(K))
             return y.astype(xa.dtype).reshape(xa.shape), sizes
 
         extra = (() if router_input is None else (router_input,)) \
+            + ((self.router_bias,) if biased else ()) \
             + (() if token_mask is None else (token_mask,))
         out, rows = apply_op(f, x, self.router, self.w_gate, self.w_up,
                              self.w_down, *extra, op_name="held_experts")

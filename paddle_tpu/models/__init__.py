@@ -15,6 +15,7 @@ from . import ernie  # noqa: F401
 from . import moe  # noqa: F401
 from . import pangu_moe  # noqa: F401
 from . import smallthinker  # noqa: F401
+from . import exaone_moe  # noqa: F401
 from . import dit  # noqa: F401
 from . import ppocr  # noqa: F401
 from .llama import LlamaConfig, LlamaModel, LlamaForCausalLM  # noqa: F401
@@ -22,15 +23,18 @@ from .ernie import ErnieConfig, ErnieModel, ErnieForSequenceClassification  # no
 from .moe import MoeConfig, MoeForCausalLM  # noqa: F401
 from .pangu_moe import PanguMoeConfig, PanguMoeForCausalLM  # noqa: F401
 from .smallthinker import SmallThinkerConfig, SmallThinkerForCausalLM  # noqa: F401
+from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM  # noqa: F401
 from .dit import DiTConfig, DiT  # noqa: F401
 from .ppocr import PPOCRRecConfig, PPOCRRecModel  # noqa: F401
 
 __all__ = [
-    "llama", "ernie", "moe", "pangu_moe", "smallthinker", "dit", "ppocr",
+    "llama", "ernie", "moe", "pangu_moe", "smallthinker", "exaone_moe", "dit",
+    "ppocr",
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
     "ErnieConfig", "ErnieModel", "ErnieForSequenceClassification",
     "MoeConfig", "MoeForCausalLM", "PanguMoeConfig", "PanguMoeForCausalLM",
     "SmallThinkerConfig", "SmallThinkerForCausalLM",
+    "ExaoneMoeConfig", "ExaoneMoeForCausalLM",
     "DiTConfig", "DiT",
     "PPOCRRecConfig", "PPOCRRecModel",
 ]

@@ -212,7 +212,7 @@ class StepMaps(NamedTuple):
 
 def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
                     block_size, max_items, max_seqs,
-                    run_pages=1, window=None) -> StepMaps:
+                    run_pages=1, window=None, slack=None) -> StepMaps:
     """Host-side (numpy) kernel work list for one engine step.
 
     ``cu_seqlens``: int array ``[num_seqs + 1]`` — prefix sums of the
@@ -235,7 +235,11 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
     ``max(0, context + tokens of the sequence before the tile's first -
     window + 1)``); ``step_blk`` stays the run's logical index, and
     ``pages_causal`` counts what the same walks would name without the
-    window. A tile no
+    window. ``slack`` (``[num_seqs]``, default nought): a sequence's
+    context may turn out that many keys shorter than ``kv_lens`` says (an
+    acceptance the device has not reported yet); a windowed walk then
+    starts where the shorter context's would, and its further end is the
+    longer's. A tile no
     sequence reaches owns one sentinel item (sequence ``max_seqs``, the
     all-null block-table row); the arrays' tail past ``step_tile[-1]``
     is never walked and carries the same.
@@ -265,8 +269,8 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
                 # the first page that holds a key the tile's first token
                 # of the sequence (position base + max(lo, cu)) can see
                 page0 = 0 if window is None else \
-                    max(0, base[s] + max(lo, cu[s]) - window + 1) \
-                    // block_size
+                    max(0, base[s] - (slack[s] if slack else 0)
+                        + max(lo, cu[s]) - window + 1) // block_size
                 run0 = page0 // run_pages
                 seqs += [s] * (runs - run0)
                 blks += range(run0, runs)

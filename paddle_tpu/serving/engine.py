@@ -111,6 +111,11 @@ def _build_serving_metrics(reg) -> dict:
             "by reason (idle: nothing was in flight; preempt / cow / abort "
             "/ numerics: the step before was harvested first, see "
             "docs/SERVING.md)"),
+        "drafts": reg.counter(
+            "serving_draft_tokens_total",
+            "draft tokens a step verified, by kind: drafted (rows that "
+            "carried a draft) / accepted (drafts the model's own choice "
+            "confirmed: each yields a second token from its step)"),
         "rpa_steps": reg.counter(
             "serving_rpa_steps_total",
             "RPA kernel grid steps a kv head and layer, by kind: live "
@@ -219,9 +224,12 @@ class _Flight:
     """A dispatched step the host has not harvested: what it ran and the
     device arrays that hold what it has to say."""
     step: int
-    #: (sequence, new tokens, is a prefill chunk, samples a token)
+    #: (sequence, new tokens, is a prefill chunk, samples a token, rows
+    #: of the new tokens that are drafts)
     entries: list
-    tokens: jax.Array                    # [max_batch] int32 sampled tokens
+    #: [max_batch] int32 sampled tokens; a drafting engine's [4, max_batch]:
+    #: the token, the token after an accepted draft, accepted, next draft
+    tokens: jax.Array
     moe_rows: Optional[jax.Array] = None
     taps: Optional[dict] = None          # the numerics twin's extra output
 
@@ -282,6 +290,11 @@ class ServingEngine:
     list of one a layer: layers of equal spec form a group with its own
     pools, block tables and kernel work list, and ``max_blocks`` may then
     be a mapping by group name), from which the pools are built.
+    With ``draft_tokens=1`` also a drafter: ``draft_cache_spec()`` (the
+    specs of its own layers, appended to the model's), a backbone that
+    takes ``keep_residual=True`` and returns the stream before its final
+    norm third, and ``draft(hidden, next_ids, caches=)`` (docs/SERVING.md
+    "Drafts and verify rows"); a model without them is refused.
     Optional: ``moe_expert_rows()`` (the rows each held expert took in the
     traced step, ``[layers, held]`` int32: returned by the compiled step
     beside the tokens and published by the commit span and
@@ -295,7 +308,8 @@ class ServingEngine:
                  attn_impl: Optional[str] = None,
                  prefix_cache: Optional[bool] = None,
                  mesh=None, quantize: Optional[str] = None,
-                 kv_dtype: Optional[str] = None, calibration=None):
+                 kv_dtype: Optional[str] = None, calibration=None,
+                 draft_tokens: int = 0):
         import os
 
         from paddle_tpu.jit.functional import functional_state
@@ -363,6 +377,26 @@ class ServingEngine:
                 f"{type(model).__name__} states no kv_cache_spec(): a "
                 f"served model returns its layers' LayerCacheSpec")
         specs = spec_fn()
+        #: drafts a greedy decoding sequence brings to a step (0 or 1):
+        #: the model's drafter guesses the token after next, the next
+        #: step verifies the guess beside the token and yields one or two
+        self.draft_tokens = int(draft_tokens)
+        if self.draft_tokens not in (0, 1):
+            raise ValueError(f"draft_tokens={draft_tokens!r} (want 0 or 1)")
+        if self.draft_tokens:
+            drafter = getattr(model, "draft_cache_spec", lambda: [])()
+            if not drafter:
+                raise TypeError(
+                    f"{type(model).__name__} states no drafter "
+                    f"(draft_cache_spec(), draft()): draft_tokens=1 needs "
+                    f"a model that publishes one")
+            if mesh is not None:
+                raise NotImplementedError(
+                    "draft_tokens with a tensor-parallel mesh")
+            # the drafter's layers follow the model's in the cache
+            specs = ([specs] * nl if hasattr(specs, "kv_heads")
+                     else list(specs)) + list(drafter)
+            nl += len(drafter)
         # one spec for every layer, or one a layer; the read kernel's
         # geometry (tile height, model-parallel split) is the first's
         spec = specs if hasattr(specs, "kv_heads") else specs[0]
@@ -454,9 +488,12 @@ class ServingEngine:
         # cost
         n_heads = cfg.num_attention_heads
         groups = self.cache.groups
+        # (a decode slot's rows: its own and, where it drafts, its draft's)
+        budget = self.max_batch * (1 + self.draft_tokens) \
+            + self.prefill_chunk
         self._tile_q = default_tile_q(n_heads // n_kv, dtype) \
             if self.attn_impl == "gather" else rpa_tile_q(
-                self.max_batch + self.prefill_chunk, n_heads, n_kv, hd,
+                budget, n_heads, n_kv, hd,
                 block_size, self.cache.max_blocks_per_seq,
                 groups[0].num_blocks, dtype=str(jnp.dtype(dtype)))
         # one tile height for every group's list (the tiles cut the one
@@ -464,7 +501,6 @@ class ServingEngine:
         self._tile_q = max([self._tile_q] + [
             default_tile_q(n_heads // g.spec.kv_heads, dtype)
             for g in groups[1:]])
-        budget = self.max_batch + self.prefill_chunk
         self.step_tokens = -(-budget // self._tile_q) * self._tile_q
         num_tiles = self.step_tokens // self._tile_q
 
@@ -493,7 +529,13 @@ class ServingEngine:
                                 for kw in self._maps_kw]
         self.scheduler = Scheduler(self.cache, self.max_batch,
                                    self.prefill_chunk,
-                                   step_tokens=self.step_tokens)
+                                   step_tokens=self.step_tokens,
+                                   draft_tokens=self.draft_tokens)
+        #: what the drafts came to: rows that carried one, those the
+        #: model's choice confirmed, tokens decoding sequences emitted
+        #: and the rows they were emitted from (``stats()["drafts"]``)
+        self._draft_counts = {"drafted": 0, "accepted": 0, "emitted": 0,
+                              "decode_seqs": 0}
 
         #: executable-compilation counter — incremented at TRACE time,
         #: so it equals the number of compiles of the ONE unified step
@@ -510,6 +552,15 @@ class ServingEngine:
                         jax.random.key_data(jax.random.key(0)),
                         jnp.zeros((self.max_batch,), jnp.uint32))
         self._base_key = (None, None)   # the host stream's, and its words
+        # a drafting engine's three more inputs at rest: each row's next
+        # token, the draft's row a slot, (samples, has a draft) a slot
+        self._no_drafts = (
+            jnp.zeros((self.step_tokens,), jnp.int32), self._no_tokens,
+            jnp.zeros((2, self.max_batch), jnp.int32)) \
+            if self.draft_tokens else ()
+        # the step before's output where there is none to read
+        self._no_prev = jnp.zeros((4, self.max_batch), jnp.int32) \
+            if self.draft_tokens else self._no_tokens
         #: steps dispatched and not harvested, oldest first: one between
         #: two turns of the run loop, two between a turn's dispatch and
         #: its harvest, none outside ``step()`` called by hand
@@ -696,6 +747,9 @@ class ServingEngine:
 
         model, backbone, project = self.model, self._backbone, self._project
         nl = self.model.cfg.num_hidden_layers
+        drafting = bool(self.draft_tokens)
+        nl_all = self.cache.num_layers      # with the drafter's layers
+        S = self.max_batch
         impl = self.attn_impl
         kv_quant = self.kv_dtype is not None
         n_slots = self.n_adapter_slots
@@ -714,9 +768,70 @@ class ServingEngine:
         windows = [g.window for g in self.cache.groups]
         whole = self._replicated()
 
+        def feed_drafting(tokens, ctx, pos, sid, last_idx, prev, src,
+                          last2, flags):
+            """A drafting engine's rows fed from the step before (``prev``
+            [4, max_batch]: token, token after an accepted draft, accepted,
+            next draft; ``src``: a slot's row there, -1 where the host's
+            values stand): the slot's newest token, its draft, and its
+            positions, which the host packed at their least and an accepted
+            draft moves on by one."""
+            T = tokens.shape[1]
+            r, fed = jnp.maximum(src, 0), src >= 0
+            newest = jnp.where(prev[2][r] > 0, prev[1][r], prev[0][r])
+            toks = tokens[0].at[jnp.where(fed, last_idx, T)].set(
+                newest, mode="drop")
+            toks = toks.at[jnp.where(fed & (flags[1] > 0), last2, T)].set(
+                prev[3][r], mode="drop")
+            shift = jnp.concatenate([jnp.where(fed, prev[2][r], 0),
+                                     jnp.zeros((1,), jnp.int32)])
+            return toks[None], ctx + shift, pos + shift[sid]
+
+        def verify_and_draft(tokens, caches, last_idx, sample, nxt, last2,
+                             flags):
+            """A drafting engine's forward (inside the model's swapped
+            state). A decoding sequence's rows are its newest token and,
+            where ``flags[1]``, the draft of the one after (row
+            ``last2``); ``last_idx`` is the row whose logits give its
+            next token (the first of the pair; a chunk's last row). The
+            draft is accepted where the model's own choice equals it, and
+            the second row's choice is then the token after. The drafter
+            runs over every row, fed the stream before the final norm and
+            each row's next token: ``nxt`` from the host (a prompt's next
+            token) and, where ``flags[0]`` (the row samples), the token
+            just chosen. Returns ``([4, max_batch] int32: token, token
+            after an accepted draft, accepted, next draft), caches)``."""
+            T = tokens.shape[1]
+            h, new_caches, resid = backbone(
+                Tensor(tokens), caches=caches[:nl], keep_residual=True)
+
+            def choose(hidden, rows):           # the head at those rows
+                return project(Tensor(hidden.data[0][rows][:, None, :])) \
+                    .data[:, 0].astype(jnp.float32)
+            logits = choose(h, jnp.concatenate([last_idx, last2]))
+            first = sample(logits[:S, None])
+            second = jnp.argmax(logits[S:], -1).astype(jnp.int32)
+            samples, has_draft = flags[0] > 0, flags[1] > 0
+            accepted = has_draft & (first == tokens[0][last2])
+            # each row's next token: the step's own choices where the
+            # host could not know them (out of range: dropped)
+            nxt = nxt.at[jnp.where(samples, last_idx, T)].set(
+                first, mode="drop")
+            nxt = nxt.at[jnp.where(has_draft, last2, T)].set(
+                second, mode="drop")
+            hd, draft_caches = model.draft(
+                Tensor(resid.data), Tensor(nxt[None]), caches=caches[nl:])
+            # the guess that is kept: at the draft's row where it was
+            # accepted (that row's next token is the model's own choice)
+            guess = jnp.argmax(
+                choose(hd, jnp.where(accepted, last2, last_idx)), -1)
+            out = jnp.stack([first, second, accepted.astype(jnp.int32),
+                             guess.astype(jnp.int32)])
+            return out, list(new_caches) + list(draft_caches)
+
         def step(stt, tokens, k_pools, v_pools, k_scales, v_scales,
                  bts, cu, ctx, sid, pos, ssqs, sbks, stls, last_idx, aid,
-                 prev_tokens, src, samp, key_base, key_counts):
+                 prev_tokens, src, samp, key_base, key_counts, *draft_in):
             # executes at trace time only — counting compiles is the
             # point (the compile-once guard tests read it)
             self.step_traces += 1  # analysis: allow(trace-attr-mutation)
@@ -724,9 +839,16 @@ class ServingEngine:
             # the host has not read it: row ``src`` of that step's token
             # array (-1: the host's token stands). The token never leaves
             # the device between the two steps.
-            fed = jnp.concatenate([src, jnp.full((1,), -1, src.dtype)])[sid]
-            tokens = jnp.where(fed >= 0, prev_tokens[jnp.maximum(fed, 0)],
-                               tokens[0])[None]
+            if drafting:
+                tokens, ctx, pos = feed_drafting(
+                    tokens, ctx, pos, sid, last_idx, prev_tokens, src,
+                    *draft_in[1:])
+            else:
+                fed = jnp.concatenate(
+                    [src, jnp.full((1,), -1, src.dtype)])[sid]
+                tokens = jnp.where(
+                    fed >= 0, prev_tokens[jnp.maximum(fed, 0)],
+                    tokens[0])[None]
             # weight-only quantization: dequantize the (values, scales)
             # leaves HERE, inside the trace, so XLA fuses the multiply
             # into the consuming matmuls and swap_state sees plain
@@ -743,20 +865,45 @@ class ServingEngine:
                 pool(k_pools, i), pool(v_pools, i), *metas[group_of[i]],
                 pool(k_scales, i), pool(v_scales, i),
                 impl=impl, mesh=self.mesh, window=windows[group_of[i]])
-                for i in range(nl)]
+                for i in range(nl_all)]
             # per-row LoRA dispatch: pin this step's token->slot ids for
             # the adapter hooks traced inside the backbone call
             adapters = (lora.adapter_ids(aid) if n_slots
                         else contextlib.nullcontext())
+
+            def sample(logits):
+                # ``logits`` [max_batch, 1, V]: the tokens are sampled in
+                # the program and the logits stay there. samp: temperature,
+                # top_k, top_p a row (0 at a greedy row); a sampled row's
+                # key is the host stream's, fold_in(key_base, its count)
+                # (the base as its raw words: a typed key among the
+                # arguments takes the jitted call off its fast path)
+                base = jax.random.wrap_key_data(key_base)
+                keys = jax.vmap(lambda c: jax.random.fold_in(base, c))(
+                    key_counts)
+                rows_v = logits[:, 0]
+                if whole is not None:
+                    # a vocabulary split over the model-parallel axis is
+                    # gathered once, as the logits' way to the host was;
+                    # the argmax and the sorts then run on whole rows
+                    rows_v = jax.lax.with_sharding_constraint(rows_v, whole)
+                return sample_rows(
+                    rows_v.astype(jnp.float32), samp[0],
+                    samp[1].astype(jnp.int32), samp[2], keys)
+
             with numerics.collect(instrument) as col, no_grad(), \
                     swap_state(model, stt, collect_buffers=False), \
                     adapters:
-                h, new_caches = backbone(Tensor(tokens), caches=caches)
-                # logits at each sequence's LAST packed token (rows of
-                # empty metadata slots gather token 0 — discarded by the
-                # host-side harvest)
-                hsel = Tensor(h.data[0][last_idx][:, None, :])
-                logits = project(hsel)             # [max_batch, 1, V]
+                if drafting:
+                    sampled, new_caches = verify_and_draft(
+                        tokens, caches, last_idx, sample, *draft_in)
+                else:
+                    h, new_caches = backbone(Tensor(tokens), caches=caches)
+                    # logits at each sequence's LAST packed token (rows of
+                    # empty metadata slots gather token 0 — discarded by
+                    # the host-side harvest)
+                    hsel = Tensor(h.data[0][last_idx][:, None, :])
+                    logits = project(hsel)         # [max_batch, 1, V]
                 # the rows each held expert took, where the model has
                 # such layers (trace time: the plain step gains nothing)
                 rows = () if moe_rows is None else (moe_rows().data,)
@@ -767,27 +914,12 @@ class ServingEngine:
                 vss = tuple(c.v_scale.data for c in new_caches)
             else:
                 kss, vss = (), ()
-            # the tokens are sampled here: the [max_batch, V] logits stay
-            # in the program. samp: temperature, top_k, top_p a row (0 at a
-            # greedy row); a sampled row's key is the host stream's,
-            # fold_in(key_base, its count) (the base as its raw words: a
-            # typed key among the arguments takes the jitted call off its
-            # fast path)
-            base = jax.random.wrap_key_data(key_base)
-            keys = jax.vmap(lambda c: jax.random.fold_in(base, c))(
-                key_counts)
-            rows_v = logits.data[:, 0]
-            if whole is not None:
-                # a vocabulary split over the model-parallel axis is
-                # gathered once, as the logits' way to the host was; the
-                # argmax and the sorts then run on whole rows
-                rows_v = jax.lax.with_sharding_constraint(rows_v, whole)
-            sampled = sample_rows(
-                rows_v.astype(jnp.float32), samp[0],
-                samp[1].astype(jnp.int32), samp[2], keys)
-            if whole is not None:
-                # the next step takes the array back as it is placed here
-                sampled = jax.lax.with_sharding_constraint(sampled, whole)
+            if not drafting:
+                sampled = sample(logits.data)
+                if whole is not None:
+                    # the next step takes the array back as placed here
+                    sampled = jax.lax.with_sharding_constraint(sampled,
+                                                               whole)
             out = (sampled, kps, vps, kss, vss) + rows
             if not instrument:
                 return out
@@ -856,8 +988,8 @@ class ServingEngine:
                     jnp.asarray(pos), _a_group(m.step_seq for m in maps),
                     _a_group(m.step_blk for m in maps),
                     _a_group(m.step_tile for m in maps), jnp.asarray(last_idx),
-                    jnp.asarray(aid), self._no_tokens, self._no_tokens - 1,
-                    *self._greedy)
+                    jnp.asarray(aid), self._no_prev, self._no_tokens - 1,
+                    *self._greedy, *self._no_drafts)
             finally:
                 self._clear_model_side_effects()
 
@@ -877,6 +1009,7 @@ class ServingEngine:
         self._m_steps = m["steps"]
         self._m_dispatched = m["dispatched"]
         self._m_rpa_steps = m["rpa_steps"]
+        self._m_drafts = m["drafts"]
         self._m_kv_released = m["kv_released"]
         self._m_moe_rows = m["moe_rows"]
         self._m_in_flight = m["in_flight"]
@@ -1189,7 +1322,9 @@ class ServingEngine:
             # is planned: an abort took a sequence out of it; the
             # scheduler wants to preempt a sequence whose newest token is
             # on the device; a copy-on-write block copy beside a step that
-            # writes; the numerics twin's step, read at once
+            # writes; the numerics twin's step, read at once. (A step that
+            # verified drafts is no such case: the next step's rows take
+            # their tokens and their positions from it on the device.)
             first = self._harvest_first if self._flights else None
             self._harvest_first = None
             with self._leaf("serving.plan", n):
@@ -1259,9 +1394,11 @@ class ServingEngine:
                 self.scheduler._release_cow(seq)
 
         # a chunk that ends its prompt samples a token, a decode row always
-        entries = [(seq, 1, False, True) for seq in decode] + \
+        # (a decode row brings its draft's row along)
+        entries = [(seq, 1 + seq.draft_rows, False, True, seq.draft_rows)
+                   for seq in decode] + \
                   [(seq, n, True,
-                    seq.prefill_pos + n == len(seq.pending_tokens))
+                    seq.prefill_pos + n == len(seq.pending_tokens), 0)
                    for seq, n in prefills]
         T, S = self.step_tokens, self.max_batch
         assert len(entries) <= S and \
@@ -1278,19 +1415,31 @@ class ServingEngine:
         aid = np.zeros((T,), np.int32)     # padding -> slot 0 (base)
         src = np.full((S,), -1, np.int32)  # the host's token stands
         samp, key_base, key_counts = None, None, None
-        kv_lens = []
+        drafting = bool(self.draft_tokens)
+        if drafting:
+            # each row's next token where the host knows it, the row of a
+            # slot's draft, and (samples, has a draft) a slot
+            nxt = np.zeros((T,), np.int32)
+            last2 = np.zeros((S,), np.int32)
+            flags = np.zeros((2, S), np.int32)
+        kv_lens, slack = [], []
         off = 0
-        for i, (seq, n, is_prefill, samples) in enumerate(entries):
+        for i, (seq, n, is_prefill, samples, drafts) in enumerate(entries):
             if is_prefill:
                 tokens[0, off:off + n] = seq.pending_tokens[
                     seq.prefill_pos:seq.prefill_pos + n]
                 c = seq.prefill_pos
+                if drafting:
+                    follow = seq.pending_tokens[c + 1:c + n + 1]
+                    nxt[off:off + len(follow)] = follow
             else:
                 if seq.unharvested > 0:
                     # sampled by the step in flight: read on the device
                     src[i] = seq.token_row
                 else:
                     tokens[0, off] = seq.last_token()
+                if drafts and seq.unharvested == 0:
+                    tokens[0, off + 1] = seq.draft
                 c = seq.num_cached
             if samples and seq.temperature > 0:
                 # the key of a sampled row: the host stream's next, one a
@@ -1307,13 +1456,22 @@ class ServingEngine:
             pos[off:off + n] = c + np.arange(n)
             aid[off:off + n] = seq.adapter_id
             cu[i + 1] = off + n
-            last_idx[i] = off + n - 1
-            kv_lens.append(c + n)
+            # the row whose logits are the next token's: the last, or
+            # the one before a draft's
+            last_idx[i] = off + n - 1 - drafts
+            if drafting:
+                last2[i] = off + n - 1
+                flags[:, i] = (samples, drafts)
+            # (at its longest: a draft in flight may add a key)
+            kv_lens.append(c + n + seq.pending_drafts)
+            slack.append(seq.pending_drafts)
             off += n
         cu[len(entries) + 1:] = off
         if self.attn_impl == "rpa":
+            unsure = {"slack": slack} if any(slack) else {}
             maps = [self._build_step_maps(cu[:len(entries) + 1], kv_lens,
-                                          **kw) for kw in self._maps_kw]
+                                          **kw, **unsure)
+                    for kw in self._maps_kw]
         else:
             # the gather path ignores the kernel work list; feed the
             # cached all-sentinel one instead of rebuilding per step
@@ -1336,12 +1494,15 @@ class ServingEngine:
         # one step ahead: the step before is still unharvested (it runs,
         # or waits its turn, on the device)
         ahead = int(bool(self._flights))
-        prev = self._flights[-1].tokens if ahead else self._no_tokens
+        prev = self._flights[-1].tokens if ahead else self._no_prev
         leaf.end()
         leaf = self._leaf(
             "serving.dispatch", n_step, decode_rows=len(decode),
             prefill_rows=len(prefills),
             prefill_tokens=sum(n for _, n in prefills), ahead=ahead,
+            **({"draft_rows": sum(e[4] for e in entries),
+                "draft_seqs": sum(1 for e in entries if e[4])}
+               if drafting else {}),
             **_row_args(f"{e[1]}@{ctx[i]}" for i, e in enumerate(entries)))
         leaf.begin()
         t0 = time.perf_counter_ns()
@@ -1358,7 +1519,9 @@ class ServingEngine:
                 jnp.asarray(aid), prev, jnp.asarray(src),
                 *(self._greedy if samp is None else
                   (jnp.asarray(samp), self._key_words(key_base),
-                   jnp.asarray(key_counts))))
+                   jnp.asarray(key_counts))),
+                *((jnp.asarray(nxt), jnp.asarray(last2), jnp.asarray(flags))
+                  if drafting else ()))
             if step_fn is not self._step:
                 out, taps_out = out[:-1], out[-1]
             sampled, kps, vps, kss, vss, *moe_rows = out
@@ -1408,7 +1571,7 @@ class ServingEngine:
         # device is at work; the next plan reads the sequences as the
         # step leaves them
         windowed = any(g.window is not None for g in groups)
-        for i, (seq, n, is_prefill, samples) in enumerate(entries):
+        for i, (seq, n, is_prefill, samples, drafts) in enumerate(entries):
             if is_prefill:
                 if trace.active() is not None:
                     # compile attribution: a chunk that rode the step
@@ -1427,7 +1590,9 @@ class ServingEngine:
                 seq.prefilled_tokens += n
                 self._prompt_tokens_prefilled += n
                 self._m_tokens.inc(n, kind="prompt")
-            seq.num_cached += n
+            # (a draft's row is written, and counts once it is accepted)
+            seq.num_cached += n - drafts
+            seq.pending_drafts += drafts
             if samples:
                 # prompt fully cached: the continuation is sampled (the
                 # request's first token — or, after preemption, the next)
@@ -1485,14 +1650,39 @@ class ServingEngine:
             if flight.moe_rows is not None:
                 self._publish_moe_rows(np.asarray(flight.moe_rows), leaf)
             tokens_out = 0
-            for i, (seq, _, _, samples) in enumerate(flight.entries):
+            drafting = bool(self.draft_tokens)
+            counts = dict.fromkeys(self._draft_counts, 0)
+            for i, (seq, _, is_prefill, samples, drafts) in enumerate(
+                    flight.entries):
+                seq.pending_drafts -= drafts
                 if not samples or seq.done:
                     continue
                 # blocks the tokens harvested so far filled, BEFORE the
                 # new token can finish the request
                 self._commit_cached_blocks(seq)
-                self._emit_token(seq, self._sample(toks[i], seq))
-                tokens_out += 1
+                if not drafting:
+                    self._emit_token(seq, self._sample(toks[i], seq))
+                    tokens_out += 1
+                    continue
+                token, after, accepted, seq.draft = (int(v)
+                                                     for v in toks[:, i])
+                self._emit_token(seq, self._sample(token, seq))
+                emitted = 1
+                accepted = bool(drafts and accepted and not seq.done)
+                if accepted:
+                    # the draft was the model's own choice: its row is
+                    # confirmed, and its logits chose one token more
+                    seq.num_cached += 1
+                    seq.num_sampled += 1
+                    self._commit_cached_blocks(seq)
+                    self._emit_token(seq, self._sample(after, seq))
+                    emitted = 2
+                tokens_out += emitted
+                counts["drafted"] += drafts
+                counts["accepted"] += accepted
+                if not is_prefill:
+                    counts["emitted"] += emitted
+                    counts["decode_seqs"] += 1
             for seq in self._retiring:
                 if not seq.done:
                     # sampled to its length, its last step dispatched:
@@ -1502,6 +1692,12 @@ class ServingEngine:
                     self.scheduler.release(seq)
             self._retiring.clear()
             leaf.args["tokens_out"] = tokens_out
+            if drafting:
+                leaf.args.update(counts)
+                for k, v in counts.items():
+                    self._draft_counts[k] += v
+                self._m_drafts.inc(counts["drafted"], kind="drafted")
+                self._m_drafts.inc(counts["accepted"], kind="accepted")
             leaf.end()
             with self._leaf("serving.gauges", n_step):
                 # healthz liveness stamp: a wedged-but-listening
@@ -1829,6 +2025,9 @@ class ServingEngine:
                               sorted(self._adapters.items())},
             },
         }
+        if self.draft_tokens:
+            out["drafts"] = dict(self._draft_counts,
+                                 draft_tokens=self.draft_tokens)
         if len(self.cache.groups) > 1:
             # a layer group each: pool size, blocks held by live sequences,
             # free, and parked in the prefix cache (the kv_blocks_* keys

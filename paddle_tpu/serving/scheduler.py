@@ -122,13 +122,25 @@ class Request:
     #: preemption (recompute)
     pending_tokens: List[int] = field(default=None)
     prefill_pos: int = 0     # pending tokens already cached
-    num_cached: int = 0      # total tokens written to the KV cache
+    #: tokens whose K/V the cache holds for good: the **confirmed** length.
+    #: A step with a draft row writes one row beyond it (the written
+    #: length); the row counts here once the draft is accepted
+    num_cached: int = 0
     generated: List[int] = field(default_factory=list)
     #: tokens the compiled step has sampled for this request; those beyond
     #: ``len(generated)`` (at most two) are on the device, not harvested
     num_sampled: int = 0
     #: the row of the newest sampled token in its step's token array
     token_row: int = -1
+    #: the drafter's guess of the token after the newest confirmed one
+    #: (an engine with ``draft_tokens``; None until a step has made one)
+    draft: Optional[int] = None
+    #: draft rows this sequence rides in the step being planned or run
+    draft_rows: int = 0
+    #: draft rows of its steps in flight: each may yet add one to
+    #: ``num_cached`` and ``num_sampled``, which are lower bounds until
+    #: those steps are harvested
+    pending_drafts: int = 0
     # -- prefix-cache state (ISSUE 15) -------------------------------------
     #: prompt tokens recovered from the prefix cache at the LAST admission
     cached_prompt_tokens: int = 0
@@ -208,7 +220,8 @@ class StepPlan:
     #: prefill chunks packed into this step's token budget, FCFS order:
     #: (sequence, number of prompt tokens to prefill)
     prefills: List[Tuple[Request, int]] = field(default_factory=list)
-    #: running sequences to advance one decode token
+    #: running sequences to advance: one row each, and ``draft_rows``
+    #: rows of drafts to verify
     decode: List[Request] = field(default_factory=list)
 
     @property
@@ -216,8 +229,12 @@ class StepPlan:
         return not self.prefills and not self.decode
 
     @property
+    def decode_tokens(self) -> int:
+        return sum(1 + s.draft_rows for s in self.decode)
+
+    @property
     def total_tokens(self) -> int:
-        return len(self.decode) + sum(n for _, n in self.prefills)
+        return self.decode_tokens + sum(n for _, n in self.prefills)
 
 
 class Scheduler:
@@ -225,18 +242,22 @@ class Scheduler:
     and a ``step_tokens`` per-step token budget."""
 
     def __init__(self, cache: PagedKVCache, max_batch: int,
-                 prefill_chunk: int, step_tokens: Optional[int] = None):
+                 prefill_chunk: int, step_tokens: Optional[int] = None,
+                 draft_tokens: int = 0):
         if prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         self.cache = cache
         self.max_batch = max_batch
         self.prefill_chunk = prefill_chunk
+        #: draft rows a greedy decoding sequence rides a step (0 or 1)
+        self.draft_tokens = int(draft_tokens)
+        decode_rows = max_batch * (1 + self.draft_tokens)
         # default budget: every decode slot plus one full chunk — the
         # worst mix the old two-executable engine could run per
         # iteration, now in one step
         self.step_tokens = int(step_tokens if step_tokens is not None
-                               else max_batch + prefill_chunk)
-        if self.step_tokens < max_batch + 1:
+                               else decode_rows + prefill_chunk)
+        if self.step_tokens < decode_rows + 1:
             raise ValueError(
                 f"step_tokens {self.step_tokens} can't cover "
                 f"{max_batch} decode slots plus any prefill")
@@ -280,7 +301,7 @@ class Scheduler:
         plan = StepPlan()
         plan.decode = self._plan_decode()
         plan.prefills = self._plan_prefills(
-            self.step_tokens - len(plan.decode))
+            self.step_tokens - plan.decode_tokens)
         return plan
 
     def _admit(self):
@@ -378,9 +399,24 @@ class Scheduler:
             if seq.state is not RequestState.RUNNING or seq.slot is None \
                     or seq.all_sampled:
                 continue
-            if self._ensure_blocks(seq, seq.num_cached + 1):
+            # pages for the written length at its longest: the row, a
+            # draft's row, and a draft in flight that may be accepted
+            # (never past the request's whole length: at least one token
+            # is still to come, two for a draft's row)
+            seq.draft_rows = self._draft_rows(seq)
+            if self._ensure_blocks(seq, seq.num_cached + seq.pending_drafts
+                                   + 1 + seq.draft_rows):
                 batch.append(seq)
         return batch
+
+    def _draft_rows(self, seq: Request) -> int:
+        """A greedy sequence that has a draft (on the host, or coming from
+        the step in flight) takes it along, unless the one token it may
+        still want needs no second."""
+        return int(
+            self.draft_tokens > 0 and seq.temperature == 0
+            and (seq.draft is not None or seq.unharvested > 0)
+            and seq.max_new_tokens - seq.num_sampled >= 2)
 
     # -- block management --------------------------------------------------
     def _free_blocks(self, seq: Request):
@@ -479,6 +515,7 @@ class Scheduler:
         seq.pending_tokens = list(seq.prompt_tokens) + list(seq.generated)
         seq.prefill_pos = 0
         seq.num_cached = 0
+        seq.draft, seq.draft_rows = None, 0    # the recompute drafts anew
         seq.cached_prompt_tokens = 0
         seq.committed_blocks = 0
         seq.committed_hash = seq.cache_seed

@@ -30,7 +30,7 @@ def keep_logits(engine) -> dict:
         if engine._flights:
             jax.effects_barrier()
             flight = engine._flights[0]
-            for i, (seq, _, _, samples) in enumerate(flight.entries):
+            for i, (seq, _, _, samples, _) in enumerate(flight.entries):
                 if samples and not seq.done:
                     kept.setdefault(seq.req_id, []).append(steps[-1][i])
         return harvest()

@@ -57,6 +57,24 @@ def test_server_leg_tiny_interpret():
     assert health["prefix_cache"]["hits"] >= 2
 
 
+def test_drafted_leg_tiny():
+    """The leg's own pass-or-fail at a tiny size: equal streams with and
+    without drafts under the run loop, drafts verified."""
+    drafts = chip_smoke.drafted_leg(
+        dict(vocab_size=8, hidden_size=64, intermediate_size=96,
+             num_hidden_layers=5, num_attention_heads=4,
+             num_key_value_heads=1, head_dim=16, moe_intermediate_size=32,
+             num_experts=8, num_experts_per_tok=2,
+             max_position_embeddings=256),
+        dict(max_batch=4, max_blocks={"window": 24, "full": 48},
+             block_size=8, prefill_chunk=16), (5, 23, 40), 16,
+        dtype="float32")
+    assert drafts["drafted"] > 0 and drafts["draft_tokens"] == 1
+    # (the family of expert rows is one a process: leave none behind)
+    from paddle_tpu.serving.engine import serving_metrics
+    serving_metrics()["moe_rows"].clear()
+
+
 def test_main_refuses_a_cpu(monkeypatch, capsys):
     """No accelerator: non-zero exit code, the platform named, no result
     line, and no compile cache placed."""

@@ -669,6 +669,66 @@ def test_window_and_full_kernels_compile_at_the_two_group_cell(
     assert not re.search(rpa_win.FULL_TRACE_PATTERN, names[1]), names
 
 
+def test_window_and_full_kernels_compile_at_the_drafted_cell(
+        v5e_chip, monkeypatch):
+    """8 query heads a KV head under a window of one page (128 = the page)
+    at the shapes of ``k-exaone-236b-a23b-serve-ep8-l5``, whose token axis
+    holds two rows a decode slot (the row and its draft's): a full layer's
+    call and a window layer's compile with Mosaic into an ``rpa`` and an
+    ``rpa_win`` custom call."""
+    import re
+    import jax
+    from benchmark import xplane
+    from benchmark.kernels import rpa_win
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "k-exaone-236b-a23b-serve-ep8-l5.json")) as f:
+        cfg = json.load(f)
+    eng, heads, kv, hd = (cfg["engine"], cfg["num_attention_heads"],
+                          cfg["num_key_value_heads"], cfg["head_dim"])
+    window, bs = cfg["sliding_window"], eng["block_size"]
+    assert window == bs == 128 and heads // kv == 8
+    tile = default_tile_q(heads // kv, jnp.bfloat16)
+    slots = eng["max_batch"] * (1 + eng["draft_tokens"])
+    tokens = -(-(slots + eng["prefill_chunk"]) // tile) * tile
+    seqs, width = eng["max_batch"] + 1, eng["max_blocks_per_seq"]
+    items = rpa_max_items(tokens // tile, eng["max_batch"], width)
+    win_items = rpa_max_items(tokens // tile, eng["max_batch"], width,
+                              window=window, tile_q=tile, block_size=bs)
+    # a walk under the window: the page the first key lies in, the pages
+    # of the tile's own keys, one more for where it starts in a page
+    assert win_items * width == items * (-(-(window + tile) // bs) + 1)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def pool(name):
+        return arr((eng["max_blocks"][name] + 1, kv, bs, hd), jnp.bfloat16)
+
+    def two_layers(q, kf, vf, kw, vw, btf, btw, cu, ctx, sf, bf, tf, sw, bw,
+                   tw):
+        return (ragged_paged_attention(q, kf, vf, btf, cu, ctx, sf, bf, tf),
+                ragged_paged_attention(q, kw, vw, btw, cu, ctx, sw, bw, tw,
+                                       window=window))
+
+    with _compiling_for_the_chip(monkeypatch):
+        text = jax.jit(two_layers).lower(
+            arr((tokens, heads, hd), jnp.bfloat16), pool("full"),
+            pool("full"), pool("window"), pool("window"),
+            arr((seqs, width)), arr((seqs, width)), arr((seqs + 1,)),
+            arr((seqs,)), arr((items,)), arr((items,)),
+            arr((tokens // tile + 1,)), arr((win_items,)),
+            arr((win_items,)), arr((tokens // tile + 1,))
+        ).compile().as_text()
+    names = sorted(
+        xplane.short_name(re.sub(r"^(ROOT )?", "", ln.strip()))
+        for ln in text.splitlines() if "tpu_custom_call" in ln)
+    assert len(names) == 2, names
+    assert re.search(rpa_win.FULL_TRACE_PATTERN, names[0]), names
+    assert re.search(rpa_win.TRACE_PATTERN, names[1]), names
+
+
 def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
     """The kernel's latent form (one 640-column pool, values its first 512
     columns, 128 query heads on the one page) at the shapes of
