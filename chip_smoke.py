@@ -206,7 +206,8 @@ def kernel_check(n_heads, n_kv, head_dim, seqs, *, max_batch, max_blocks,
     rng = np.random.RandomState(1)
     tile = default_tile_q(n_heads // n_kv, dtype)
     T = -(-(max_batch + prefill_chunk) // tile) * tile
-    run = rpa_run_pages(head_dim, block_size)
+    run = rpa_run_pages(block_size, head_dim, head_dim,
+                        jnp.dtype(dtype).itemsize, window=window)
     max_items = rpa_max_items(T // tile, max_batch, max_blocks_per_seq, run,
                               window=window, tile_q=tile,
                               block_size=block_size)

@@ -41,36 +41,42 @@ so the inner grid dimension walks one host-built **flat work list** for
 the whole call (``build_step_maps``), sorted by q tile. **A work item is
 ``(tile, sequence, run)``: a run of up to ``P`` consecutive pages of the
 sequence's block-table row that the tile's tokens can see**, named in
-scalar-prefetched int32 arrays (``step_blk[w]`` is the run: pages
-``P * step_blk[w] ... + P - 1``). The kernel makes its online-softmax
-update once an item: ``P`` pages of keys under one max / exp / sum pass
-and one rescale of the ``[rows, value_width]`` f32 accumulator, which is
-what a page-sized item spent most of its time on where the values are
-wide (a latent pool: 512 columns against 128 keys). ``P`` is read off
-the pool's shape (:func:`rpa_run_pages`: keys an item at least the value
-width), never set by a caller: 4 for a latent pool of 512 value columns in
-pages of 128, 1 for a K/V pool of head width 128 in the same pages.
+scalar-prefetched int32 arrays (``step_blk[w]`` is the run's first page:
+pages ``step_blk[w] ... + P - 1``). An item pays a grid step's fixed
+cost once for ``P`` pages. On a causal walk it makes one online-softmax
+update: ``P`` pages of keys under one max / exp / sum pass and one
+rescale of the ``[rows, value_width]`` f32 accumulator; on a window walk
+one update a page, in page order, so a row's output is bit for bit that
+of one-page items wherever the runs were laid (a drafting engine lays a
+window walk from the shorter context's first page). ``P`` is read off the
+pool's shape and the layer's window (:func:`rpa_run_pages`), never set by
+a caller: 4 for a latent pool of 512 value columns in pages of 128 (its
+accumulator), 4 for a K/V pool of head width 128 in bf16 pages of 128
+(an item's fetch pays for its fixed cost), 2 for the same pool under a
+window of one page.
 
 The q and output BlockSpecs are indexed by the item's tile, and the pool
 is handed to ``pallas_call`` under ``P`` BlockSpecs of one page each,
-whose index maps chase ``block_tables[step_seq[w], P * step_blk[w] + i]``
+whose index maps chase ``block_tables[step_seq[w], step_blk[w] + i]``
 straight from SMEM — the pipeline's revolving buffers double-buffer plain
 page DMAs exactly like the classic paged kernel
 (boom_attention_tricks.md §9–11), with no manual descriptors, and a q
 tile is fetched once for its whole run of items. A run's pages past the
 sequence's last resolve to the null page through the table's null padding
-(past the table's width the index is clamped to it) and the causal mask
-kills their keys: ``kpos`` counts from the run's index. The list is in
-CSR form: tile ``j`` owns items ``[step_tile[j], step_tile[j + 1])`` and
-``n_items = step_tile[-1]``; the online-softmax scratch is initialised
-at a tile's first item and the output block written at its last. A tile
-lists a sequence's runs only up to its **causal horizon** there (the
-pages that hold a key its last token of that sequence may see), not the
-pages the step's later tiles write. Under a layer's attention **window** (key
-``j`` visible to query ``i`` iff ``0 <= i - j < window``) the walk also
-starts late: at the run that holds the first key the tile's first token
-of that sequence can see, so a tile walks ``O(window + tile_q)`` keys
-however long the sequence is, and the pages the cache manager released
+(past the table's width the index is clamped to its last) and the causal
+mask kills their keys: ``kpos`` counts from the run's first page. The
+list is in CSR form: tile ``j`` owns items ``[step_tile[j], step_tile[j +
+1])`` and ``n_items = step_tile[-1]``; the online-softmax scratch is
+initialised at a tile's first item and the output block written at its
+last. A tile lists a sequence's runs only up to its **causal horizon**
+there (the pages that hold a key its last token of that sequence may
+see), not the pages the step's later tiles write. A walk's runs are laid
+from its first page: page 0 for a causal walk, and under a layer's
+attention **window** (key ``j`` visible to query ``i`` iff ``0 <= i - j <
+window``) the page that holds the first key the tile's first token of
+that sequence can see, so a tile walks ``O(window + tile_q)`` keys
+however long the sequence is, a walk that spans at most ``P`` pages is
+one item wherever it starts, and the pages the cache manager released
 behind the window (null in the table) are never named. The windowed call
 is ``rpa_win`` in a trace. Every tile owns at least one item — a
 tile of only padding tokens gets one sentinel item (sequence
@@ -82,15 +88,16 @@ carry ``m``/``l`` through), so prefill chunks (in-chunk causal via
 ``kpos <= ctx + (t - cu[s])``) and decode rows coexist in one tile.
 
 The arrays are sized by the static :func:`rpa_max_items` =
-``ceil(max_blocks_per_seq / P) * (num_q_tiles + max_seqs)``: a sequence
-is re-walked once per tile it spans, and all sequences together span at
-most ``num_q_tiles + n_seqs - 1`` tiles. The bound never uses the pool's
-size (sequences that share prefix pages are distinct rows that name the
-same pages), and it sizes arrays only — nothing walks it. A caller that
-holds per-tile maps ``[num_q_tiles, k]`` padded with the sentinel
-(``rpa_max_steps`` wide, naming runs the same way) may hand those
-instead: the wrapper compacts them into the same flat list on the device
-and both reach the one ``pallas_call``.
+``ceil(max_blocks_per_seq / P) * (num_q_tiles + max_seqs)`` (under a
+window, the pages a walk can span in place of the table's width): a
+sequence is re-walked once per tile it spans, and all sequences together
+span at most ``num_q_tiles + n_seqs - 1`` tiles. The bound never uses the
+pool's size (sequences that share prefix pages are distinct rows that
+name the same pages), and it sizes arrays only — nothing walks it. A
+caller that holds per-tile maps ``[num_q_tiles, k]`` padded with the
+sentinel (``rpa_max_steps`` wide, each entry a run's first page the same
+way) may hand those instead: the wrapper compacts them into the same
+flat list on the device and both reach the one ``pallas_call``.
 
 Off-TPU the kernel runs in Pallas interpret mode, which is what tier-1
 parity tests exercise on the CPU mesh (`tests/test_ragged_paged_attention.py`);
@@ -142,16 +149,47 @@ def default_tile_q(group: int, dtype) -> int:
     return tile
 
 
-def rpa_run_pages(value_width: int, block_size: int) -> int:
-    """Pages a work item names (``P``), read off the pool's shape: an
-    item's keys number at least the value width,
-    ``max(1, value_width // block_size)``. What a run amortises is the
-    kernel's ``[rows, value_width]`` f32 accumulator, rescaled once an
-    item: a run of ``P`` pages keeps that traffic at or under one
-    accumulator element a score element, the ratio of a flash kernel. 4
-    for a latent pool of 512 value columns in pages of 128 tokens, 1 for
-    a K/V pool of head width 128 in the same pages (an item is a page)."""
-    return max(1, int(value_width) // int(block_size))
+#: bytes a K/V work item fetches a kv head, at least: what the pipeline
+#: moves in the fixed cost of one grid step. Measured on a v5e (PR 34,
+#: the kernel alone at four cells' shapes, runs of 1 to 8 pages of
+#: ``[128, 128]`` bf16): a grid step costs 0.41-0.51 us besides 0.11-0.14
+#: us for each 64 KiB K and V page it fetches, so 3.7 pages' fetch pays
+#: for the fixed cost
+_ITEM_BYTES = 256 * 1024
+#: the longest run: the score tile ``[rows, P * block_size]`` f32 and the
+#: run's page buffers stay small in VMEM
+_MAX_RUN = 8
+
+
+def rpa_run_pages(block_size: int, key_width: int, value_width: int,
+                  itemsize: int, *, latent: bool = False,
+                  window=None) -> int:
+    """Pages a work item names (``P``), read off the pool's shape and the
+    layer's window, never set. A run of ``P`` pages spreads a cost paid
+    once an item over its pages:
+
+    - the rescale of a latent item's ``[rows, value_width]`` f32
+      accumulator: keys an item at least the value width, ``value_width //
+      block_size`` (at or under one accumulator element a score element, a
+      flash kernel's ratio);
+    - for a K/V pool, the grid step's fixed cost: the K and V pages of an
+      item fetch at least ``_ITEM_BYTES``. A latent item's rows are every
+      query head over its one page, and its accumulator sets its run.
+
+    Under a ``window`` the run is no longer than the pages one token's
+    window can span, ``ceil((window - 1) / block_size) + 1``: a longer
+    one fetches pages no token of a decode walk sees. At most
+    ``_MAX_RUN``. A latent pool of 512 value columns in pages of 128: 4;
+    a K/V pool of head width 128 in bf16 pages of 128: 4, under a window
+    of one page 2; 16-token pages: 8."""
+    run = int(value_width) // int(block_size)
+    if not latent:
+        page = int(block_size) * (int(key_width) + int(value_width)) \
+            * int(itemsize)
+        run = max(run, -(-_ITEM_BYTES // page))
+    if window is not None:
+        run = min(run, -(-(int(window) - 1) // int(block_size)) + 1)
+    return max(1, min(run, _MAX_RUN))
 
 
 def rpa_max_items(num_tiles: int, max_seqs: int, max_blocks_per_seq: int,
@@ -170,16 +208,12 @@ def rpa_max_items(num_tiles: int, max_seqs: int, max_blocks_per_seq: int,
     spans the keys from the first its first token sees to its last
     token's own, ``window + tile_q - 1`` of them: at most
     ``ceil((window + tile_q) / block_size) + 1`` pages, where that is
-    fewer than the table's width."""
+    fewer than the table's width, laid in runs from the first."""
     pages = max_blocks_per_seq
     if window is not None:
         pages = min(pages, -(-(int(window) + tile_q) // block_size) + 1)
-    # a walk of ``pages`` pages that starts anywhere in a run touches one
-    # run more than ``ceil(pages / run_pages)`` unless a run is a page
-    runs = -(-pages // run_pages) + (1 if window is not None
-                                     and run_pages > 1 else 0)
-    return min(runs, -(-max_blocks_per_seq // run_pages)) \
-        * (num_tiles + max_seqs)
+    # a walk's runs are laid from its first page
+    return -(-pages // run_pages) * (num_tiles + max_seqs)
 
 
 def rpa_max_steps(tile_q: int, max_blocks_per_seq: int,
@@ -198,7 +232,7 @@ def rpa_max_steps(tile_q: int, max_blocks_per_seq: int,
 class StepMaps(NamedTuple):
     """The flat work list of one engine step (:func:`build_step_maps`)."""
     step_seq: np.ndarray    # [max_items] int32 — item w's sequence
-    step_blk: np.ndarray    # [max_items] int32 — item w's run of kv pages
+    step_blk: np.ndarray    # [max_items] int32 — item w's run's first page
     step_tile: np.ndarray   # [num_q_tiles + 1] int32 — CSR tile pointers
     live: int               # items that name a real (sequence, run)
     pages: int              # real pages the live items name (<= P a run)
@@ -222,18 +256,19 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
     item names (:func:`rpa_run_pages` of the pool the kernel will read).
 
     Returns :class:`StepMaps`: the items sorted by q tile, tile ``j``'s
-    in ``[step_tile[j], step_tile[j + 1])``. An item ``(sequence, r)``
-    names the run of pages ``[r * run_pages, (r + 1) * run_pages)`` of
-    the sequence's block-table row, and a tile lists for each of its
-    sequences the runs up to the tile's **causal horizon** there: the
-    pages that hold a key the tile's last token of that sequence may see,
-    ``ceil((context + tokens of the sequence up to the tile's end) /
-    block_size)`` — not the pages the step's later tiles write. A run's
-    pages past that count are masked inside the kernel. With ``window``
-    the list starts, for each (tile, sequence), at the run that holds the
-    first key the tile's **first** token of that sequence can see (key
-    ``max(0, context + tokens of the sequence before the tile's first -
-    window + 1)``); ``step_blk`` stays the run's logical index, and
+    in ``[step_tile[j], step_tile[j + 1])``. An item ``(sequence, p)``
+    names the run of pages ``[p, p + run_pages)`` of the sequence's
+    block-table row (``step_blk`` is ``p``, the run's first page), and a
+    tile lists for each of its sequences the runs up to the tile's
+    **causal horizon** there: the pages that hold a key the tile's last
+    token of that sequence may see, ``ceil((context + tokens of the
+    sequence up to the tile's end) / block_size)`` — not the pages the
+    step's later tiles write. The runs are laid from the walk's first
+    page: page 0, or with ``window`` the page that holds the first key the
+    tile's **first** token of that sequence can see (key ``max(0, context
+    + tokens of the sequence before the tile's first - window + 1)``), so
+    a walk of at most ``run_pages`` pages is one item wherever it starts.
+    A run's pages past the horizon are masked inside the kernel.
     ``pages_causal`` counts what the same walks would name without the
     window. ``slack`` (``[num_seqs]``, default nought): a sequence's
     context may turn out that many keys shorter than ``kv_lens`` says (an
@@ -265,15 +300,15 @@ def build_step_maps(cu_seqlens, kv_lens, *, total_tokens, tile_q,
         while s < num_seqs and cu[s] < hi:
             if cu[s] < cu[s + 1]:   # a new_len == 0 slot owns no tokens
                 seen = -(-(base[s] + min(hi, cu[s + 1])) // block_size)
-                runs = -(-seen // run_pages)
                 # the first page that holds a key the tile's first token
-                # of the sequence (position base + max(lo, cu)) can see
+                # of the sequence (position base + max(lo, cu)) can see;
+                # the walk's runs are laid from there
                 page0 = 0 if window is None else \
                     max(0, base[s] - (slack[s] if slack else 0)
                         + max(lo, cu[s]) - window + 1) // block_size
-                run0 = page0 // run_pages
-                seqs += [s] * (runs - run0)
-                blks += range(run0, runs)
+                firsts = range(page0, seen, run_pages)
+                seqs += [s] * len(firsts)
+                blks += firsts
                 pages += seen - page0
                 pages_causal += seen
             s += 1
@@ -331,7 +366,6 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
     w = pl.program_id(1)
     j = to_ref[w]
     rows = tile_q * group
-    keys = run_pages * block_size
 
     @pl.when(w == tp_ref[j])
     def _init():
@@ -341,18 +375,10 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
 
     ss = ss_ref[w]
 
-    @pl.when(ss < max_seqs)
-    def _compute():
-        sb = sb_ref[w]
-        q = q_ref[...]                                  # [rows, hd]
-
-        def run_of(refs):       # the run's pages back to back: [keys, width]
-            pages = [r[...] for r in refs]
-            return pages[0] if run_pages == 1 else \
-                jnp.concatenate(pages, axis=0)
-
-        k = run_of(k_refs)
-        v = run_of(v_refs) if value_cols is None else k[:, :value_cols]
+    def update(q, k, v, first):
+        """One online-softmax update over ``k``'s keys (``first`` the
+        position of the first)."""
+        keys = k.shape[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
@@ -364,8 +390,7 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         r = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
         lo = cu_ref[ss] - j * tile_q        # sequence span, tile-relative
         hi = cu_ref[ss + 1] - j * tile_q
-        kpos = sb * keys + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 1)
+        kpos = first + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
         # one bound covers prior context, in-chunk causality, page
         # raggedness and the run's pages past the sequence's last (null
         # pages, or under the clamp the table's last: their ``kpos`` lies
@@ -381,8 +406,8 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
-        # rows with no live key in THIS item (another sequence's rows, or
-        # causally-dead decode rows) would contribute exp(MASK-MASK)=1
+        # rows with no live key in THIS update (another sequence's rows,
+        # or causally-dead decode rows) would contribute exp(MASK-MASK)=1
         # per column; zeroing them keeps their l at 0 so their m/l/acc
         # state rides through untouched (alpha re-scales acc by the same
         # factor l absorbs). A row is live iff its max rose above the
@@ -390,13 +415,37 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         p = jnp.where(m_cur > _MASK_VALUE, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # the one rescale of the accumulator an item: ``run_pages`` pages
-        # of keys for one pass over its [rows, vd] f32
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+
+    @pl.when(ss < max_seqs)
+    def _compute():
+        sb = sb_ref[w]
+        q = q_ref[...]                                  # [rows, hd]
+        if window is not None:
+            # a window walk starts where its first token's window does,
+            # which a drafting engine knows only to within its pending
+            # draft: one update a page, in page order, so a row's output
+            # does not depend on where the runs were laid (a page wholly
+            # masked for the row leaves its state as it was). The run
+            # still pays the grid step's fixed cost once
+            for i in range(run_pages):
+                update(q, k_refs[i][...], v_refs[i][...],
+                       (sb + i) * block_size)
+        else:
+            # a causal walk's runs lie on one grid from page 0: its pages
+            # back to back, one update and one rescale of the [rows, vd]
+            # f32 accumulator a run
+            def run_of(refs):
+                pages = [r[...] for r in refs]
+                return pages[0] if run_pages == 1 else \
+                    jnp.concatenate(pages, axis=0)
+            k = run_of(k_refs)
+            update(q, k, run_of(v_refs) if value_cols is None
+                   else k[:, :value_cols], sb * block_size)
 
     @pl.when(w + 1 == tp_ref[j + 1])
     def _finish():
@@ -417,7 +466,9 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
     latent = v_pool is None
     vd = int(value_cols) if latent else v_pool.shape[3]
     block_size = k_pool.shape[2]
-    run_pages = rpa_run_pages(vd, block_size)
+    run_pages = rpa_run_pages(block_size, k_pool.shape[3], vd,
+                              k_pool.dtype.itemsize, latent=latent,
+                              window=window)
     max_seqs = block_tables.shape[0] - 1
     table_width = block_tables.shape[1]
     rows = tile_q * group
@@ -438,13 +489,14 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
 
     def page_map(i):
         def kv_map(h, w, to, ss, sb, tp, bt, cu, ctx):
-            # scalar-prefetch chase: physical page i of this item's run.
-            # Past the sequence's pages the table's null padding gives
-            # the null page 0 (where its width is no multiple of the run
-            # the index is clamped to it: a page the mask kills); a
-            # sentinel item resolves through the sentinel table row
-            page = run_pages * sb[w] + i
-            if table_width % run_pages:
+            # scalar-prefetch chase: physical page i of this item's run,
+            # which starts at page ``sb[w]``. Past the sequence's pages
+            # the table's null padding gives the null page 0 (past the
+            # table's width the index is clamped to its last: a page the
+            # mask kills); a sentinel item resolves through the sentinel
+            # table row
+            page = sb[w] + i
+            if run_pages > 1:
                 page = jnp.minimum(page, table_width - 1)
             return (bt[ss[w], page], h, 0, 0)
         return kv_map
@@ -597,7 +649,8 @@ def rpa_tile_q(budget_tokens, n_heads, n_kv, head_dim, block_size,
             nxt += n_pages
         if nxt - 1 > pool_blocks:
             raise ValueError("synthetic workload exceeds pool")
-        run = rpa_run_pages(head_dim, block_size)
+        run = rpa_run_pages(block_size, head_dim, head_dim,
+                            jnp.dtype(dtype).itemsize)
         ssq, sbk, stl = build_step_maps(
             cu[:len(new_lens) + 1], kv_lens, total_tokens=T,
             tile_q=tile, block_size=block_size,

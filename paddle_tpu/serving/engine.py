@@ -505,12 +505,16 @@ class ServingEngine:
         num_tiles = self.step_tokens // self._tile_q
 
         # a group's work list: the pages an item names (the kernel reads
-        # it off the pool it is handed, the list's builder is told the
-        # same), the window where its layers have one, and the static
-        # length of its arrays
-        def maps_kw(sp):
+        # it off the pool it is handed and the layer's window, the list's
+        # builder is told the same), the window where its layers have
+        # one, and the static length of its arrays
+        def maps_kw(g):
+            sp = g.spec
             run = rpa_run_pages(
-                sp.value_cols if sp.latent else sp.value_dim, block_size)
+                block_size, sp.key_dim,
+                sp.value_cols if sp.latent else sp.value_dim,
+                self.cache.k_pools[g.layers[0]].dtype.itemsize,
+                latent=sp.latent, window=sp.window)
             return dict(
                 total_tokens=self.step_tokens, tile_q=self._tile_q,
                 block_size=block_size, max_seqs=self.max_batch,
@@ -519,7 +523,7 @@ class ServingEngine:
                     num_tiles, self.max_batch, self.cache.max_blocks_per_seq,
                     run, window=sp.window, tile_q=self._tile_q,
                     block_size=block_size))
-        self._maps_kw = [maps_kw(g.spec) for g in groups]
+        self._maps_kw = [maps_kw(g) for g in groups]
         # read by the accepted benchmark's test of its pages-per-item reader
         self._run_pages = self._maps_kw[0]["run_pages"]
         # the work lists of a step without work (one sentinel item a

@@ -158,7 +158,8 @@ def _latent_case(rng, seqs, block_size=8, heads=4, kd=24, vd=16, tile_q=8,
         pos[off:off + n] = c + np.arange(n)
         off += n
     q = rng.standard_normal((T, heads, kd)).astype(np.float32)
-    run = rpa_run_pages(vd, block_size)     # 2: an item is a run of pages
+    # 2: an item is a run of pages (the latent pool's value width)
+    run = rpa_run_pages(block_size, kd, vd, 4, latent=True)
     maps = build_step_maps(
         cu[:len(seqs) + 1], kv_lens, total_tokens=T, tile_q=tile_q,
         block_size=block_size, max_seqs=max_seqs, run_pages=run,

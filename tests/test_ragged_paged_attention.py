@@ -14,8 +14,10 @@ shapes — the 870s tier-1 cutoff counts dots):
   pages a tile can see: every visible (token, key) pair in exactly one
   item of its tile, no item beyond the tile's causal horizon, one
   sentinel item for a tile without work, the static bound honored under
-  adversarial packings), and both pool forms against their gather reader
-  and the eager reference at P in {1, 2, 4};
+  adversarial packings, a window walk laid in runs from its first page),
+  both pool forms against their gather reader and the eager reference at
+  P in {1, 2, 4} and at the rule's own P, under windows too, and the
+  rule's values at the cells' pool shapes;
 * the kernel's dynamic grid bound: parity where the work list is short,
   long, absent for whole tiles or names shared pages, in both forms the
   wrapper takes, and a Mosaic compile for a described v5e at the serving
@@ -25,9 +27,11 @@ shapes — the 870s tier-1 cutoff counts dots):
   pool's shape but parameter, write and bitcast (no whole-pool copy).
 """
 import contextlib
+import importlib
 import json
 import os
 import re
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -44,11 +48,13 @@ from paddle_tpu.ops.pallas.ragged_paged_attention import (
     rpa_max_steps, rpa_run_pages)
 from paddle_tpu.serving import ServingEngine
 
+RPA = importlib.import_module("paddle_tpu.ops.pallas.ragged_paged_attention")
+
 
 # ---------------- raw kernel parity ------------------------------------------
 def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
                  mbps=6, pool_blocks=24, pad_tiles=0, shared=(),
-                 value_cols=None):
+                 value_cols=None, window=None, run=None, dtype=np.float32):
     """Build one token-packed ragged scenario: ``seqs`` is a list of
     ``(new_len, context_len)`` — new_len 0 models a padding slot whose
     metadata row exists but owns no tokens. ``pad_tiles`` appends q tiles
@@ -56,10 +62,13 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
     sequence ``s`` names the first ``n_pages`` pages of the earlier
     ``s0`` as its own prefix (and holds the same keys there). With
     ``value_cols`` the K pool is read as a latent one (``n_kv`` 1): the
-    values are a key row's first ``value_cols`` columns. The work list's
-    items are runs of the pages the kernel will read off those shapes
-    (``rpa_run_pages``). Returns everything the two impls and the eager
-    oracle need."""
+    values are a key row's first ``value_cols`` columns. Under a
+    ``window`` the pages wholly behind a sequence's window are released:
+    null in its table, their rows junk (another sequence's by now). The
+    work list's items are runs of the pages the kernel reads off those
+    shapes (``rpa_run_pages``), or of ``run`` pages where it is given
+    (:func:`_run_rpa` then holds the kernel to it). Returns everything
+    the two impls and the eager oracle need."""
     n_heads = n_kv * grp
     max_seqs = len(seqs) + 1          # one extra never-used row
     total_new = sum(n for n, _ in seqs)
@@ -103,12 +112,22 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
             assert pre * block_size <= min(c, seqs[s0][1])
             fk[:pre * block_size] = full_k[s0][:pre * block_size]
             fv[:pre * block_size] = full_v[s0][:pre * block_size]
+        if dtype != np.float32:       # the oracle sees what the pool holds
+            fk, fv = (np.asarray(jnp.asarray(a, dtype).astype(jnp.float32))
+                      for a in (fk, fv))
         full_k.append(fk)
         full_v.append(fv)
         for t in range(c):            # prior context from earlier steps
             kp[bt[s, t // block_size], :, t % block_size] = fk[t]
             vp[bt[s, t // block_size], :, t % block_size] = fv[t]
+    if window is not None:
+        for s, (n, c) in enumerate(seqs):
+            behind = bt[s, :max(0, c - window + 1) // block_size]
+            kp[behind], vp[behind] = 1e3, -1e3
+            bt[s, :len(behind)] = 0
     q = rng.randn(T, n_heads, hd).astype(np.float32)
+    if dtype != np.float32:
+        q = np.asarray(jnp.asarray(q, dtype).astype(jnp.float32))
     knew = np.zeros((T, n_kv, hd), np.float32)
     vnew = np.zeros((T, n_kv, hd), np.float32)
     off = 0
@@ -117,52 +136,67 @@ def _ragged_case(rng, seqs, block_size, n_kv, grp, hd=16, tile_q=8,
         vnew[off:off + n] = full_v[s][c:]
         off += n
 
-    kp2 = write_tokens_to_pool(jnp.asarray(kp), jnp.asarray(knew),
-                               jnp.asarray(bt), jnp.asarray(sid),
-                               jnp.asarray(pos))
-    vp2 = write_tokens_to_pool(jnp.asarray(vp), jnp.asarray(vnew),
-                               jnp.asarray(bt), jnp.asarray(sid),
-                               jnp.asarray(pos))
+    kp2, vp2 = (write_tokens_to_pool(
+        jnp.asarray(pool, dtype), jnp.asarray(new, dtype), jnp.asarray(bt),
+        jnp.asarray(sid), jnp.asarray(pos)) for pool, new in ((kp, knew),
+                                                              (vp, vnew)))
     if value_cols is not None:
         assert n_kv == 1
         full_v = [fk[..., :value_cols] for fk in full_k]
-    run = rpa_run_pages(value_cols or hd, block_size)
+    rule = rpa_run_pages(block_size, hd, value_cols or hd,
+                         np.dtype(dtype).itemsize,
+                         latent=value_cols is not None, window=window)
+    run = rule if run is None else run
     maps = build_step_maps(cu[:len(seqs) + 1], kv_lens,
                            total_tokens=T, tile_q=tile_q,
                            block_size=block_size,
-                           max_items=rpa_max_items(T // tile_q, max_seqs,
-                                                   mbps, run),
-                           max_seqs=max_seqs, run_pages=run)
+                           max_items=rpa_max_items(
+                               T // tile_q, max_seqs, mbps, run,
+                               window=window, tile_q=tile_q,
+                               block_size=block_size),
+                           max_seqs=max_seqs, run_pages=run, window=window)
     return dict(q=q, kp=kp2, vp=vp2, bt=bt, cu=cu, ctx=ctx, sid=sid,
                 pos=pos, maps=maps, full_k=full_k, full_v=full_v,
                 seqs=seqs, max_seqs=max_seqs, grp=grp, hd=hd,
                 tile_q=tile_q, kv_lens=kv_lens, block_size=block_size,
-                run=run, mbps=mbps, value_cols=value_cols)
+                run=run, forced=run != rule, mbps=mbps,
+                value_cols=value_cols, window=window, dtype=dtype)
 
 
 def _run_rpa(c, maps=None):
+    """The kernel over case ``c``'s pools; where the case forced its run
+    length, the kernel's rule is held to it for the call."""
     ssq, sbk, stl = (maps or c["maps"])[:3]
     latent = c["value_cols"] is not None
-    return np.asarray(ragged_paged_attention(
-        jnp.asarray(c["q"]), c["kp"], None if latent else c["vp"],
-        jnp.asarray(c["bt"]), jnp.asarray(c["cu"]), jnp.asarray(c["ctx"]),
-        ssq, sbk, stl, value_cols=c["value_cols"]))
+    forced = mock.patch.object(RPA, "rpa_run_pages",
+                               lambda *a, **k: c["run"]) \
+        if c["forced"] else contextlib.nullcontext()
+    with forced:
+        out = ragged_paged_attention(
+            jnp.asarray(c["q"], c["dtype"]), c["kp"],
+            None if latent else c["vp"], jnp.asarray(c["bt"]),
+            jnp.asarray(c["cu"]), jnp.asarray(c["ctx"]), ssq, sbk, stl,
+            value_cols=c["value_cols"], window=c["window"])
+    return np.asarray(out.astype(jnp.float32))
 
 
 def _run_gather(c):
     args = [jnp.asarray(c[k]) for k in ("bt", "sid", "pos")]
     scale = 1.0 / np.sqrt(c["hd"])
+    q = jnp.asarray(c["q"], c["dtype"])
     if c["value_cols"] is not None:
         return np.asarray(ragged_latent_gather_attention(
-            jnp.asarray(c["q"]), c["kp"], *args,
-            value_cols=c["value_cols"], scale=scale))
+            q, c["kp"], *args, value_cols=c["value_cols"],
+            scale=scale).astype(jnp.float32))
     return np.asarray(ragged_gather_attention(
-        jnp.asarray(c["q"]), c["kp"], c["vp"], *args, scale=scale))
+        q, c["kp"], c["vp"], *args, scale=scale,
+        window=c["window"]).astype(jnp.float32))
 
 
 def _eager_oracle(case):
-    """Per-sequence dense softmax over the contiguous K/V — the ground
-    truth both paged impls must match."""
+    """Per-sequence dense softmax over the contiguous K/V (the keys a
+    token's window lets it see) — the ground truth both paged impls must
+    match."""
     q, seqs = case["q"], case["seqs"]
     grp, hd = case["grp"], case["hd"]
     scale = 1.0 / np.sqrt(hd)
@@ -173,7 +207,9 @@ def _eager_oracle(case):
         K, V = case["full_k"][s], case["full_v"][s]
         for i in range(n):
             t = off + i
-            kvis, vvis = K[:c + i + 1], V[:c + i + 1]
+            lo = 0 if case["window"] is None else \
+                max(0, c + i - case["window"] + 1)
+            kvis, vvis = K[lo:c + i + 1], V[lo:c + i + 1]
             for h in range(q.shape[1]):
                 kh = h // grp
                 sc = (kvis[:, kh] @ q[t, h]) * scale
@@ -211,36 +247,125 @@ def test_kernel_matches_gather_and_eager(block_size, grp):
 #: no context; a 6-token chunk across pages 0 and 1; then an empty tile.
 #: 7 and 6 pages a sequence and a table 7 wide: multiples of no run
 _RUN_MIX = [(13, 43), (1, 41), (1, 7), (0, 0), (1, 0), (6, 5)]
+#: the same under a window, in pages of 8 and a table 7 wide: decode pairs
+#: (a row and its draft) whose windows start in pages 1, 3 and 4 beside a
+#: 13-token chunk, a decode row, a slot without tokens and a 6-token
+#: chunk; then an empty tile. Under windows of 8 and 20 keys most walks
+#: start in the middle of a run of 2 or 4 pages
+_WIN_RUN_MIX = [(2, 17), (13, 30), (2, 33), (1, 47), (0, 0), (2, 44),
+                (6, 21)]
 
 
-@pytest.mark.parametrize("run", [1, 2, 4])
-@pytest.mark.parametrize("form", ["kv", "latent"])
+@pytest.mark.parametrize("run", [1, 2, 4, "rule"])
+@pytest.mark.parametrize("form", ["kv", "latent", "kv_window_8",
+                                  "kv_window_20"])
 def test_runs_of_pages_match_gather_and_eager(form, run):
-    """An item is a run of ``run`` pages (the kernel reads the count off
-    the pool's value width): both pool forms against their gather reader
-    and the eager reference, over page counts and a table width that are
-    no multiple of the run (its last pages resolve to the null page, or
-    under the clamp to the table's last, and are masked), shared prefix
-    pages, a chunk that straddles pages, decode rows beside a chunk in
-    one tile, an empty tile."""
-    rng = np.random.RandomState(17 * run + len(form))
-    kw = dict(n_kv=2, grp=2, hd=8 * run) if form == "kv" else \
-        dict(n_kv=1, grp=4, hd=8 * run + 8, value_cols=8 * run)
-    c = _ragged_case(rng, _RUN_MIX, 8, mbps=7, pad_tiles=1,
-                     shared=[(1, 0, 5)], **kw)
-    assert c["run"] == run and bool(c["bt"].shape[1] % run) == (run > 1)
+    """An item is a run of ``run`` pages (held there for the test, or the
+    pages the kernel's rule reads off the pool: a latent pool's value
+    width over the page, at least 256 KiB of K and V pages an item for a
+    K/V pool, 8 at most, under a window the pages one token's window
+    spans): both pool forms against their gather reader and the eager
+    reference, over page counts and a table width that are no multiple of
+    the run (its last pages resolve to the null page, or under the clamp
+    to the table's last, and are masked), shared prefix pages, a chunk
+    that straddles pages, decode rows and pairs beside a chunk in one
+    tile, an empty tile; under a window of one page and of several, walks
+    that start in the middle of a run and pages released behind the
+    window (junk rows a walk must not name). Under a window a run updates
+    the softmax state once a page: its output is that of items of one
+    page, bit for bit, wherever the runs were laid."""
+    seed = 17 * len(str(run)) + len(form)
+    rng = np.random.RandomState(seed)
+    window = int(form.rsplit("_", 1)[1]) if "window" in form else None
+    if form == "latent":
+        # the latent rule is its value width over the page
+        vd = 8 * (4 if run == "rule" else run)
+        kw = dict(n_kv=1, grp=4, hd=vd + 8, value_cols=vd,
+                  shared=[(1, 0, 5)])
+        mix, forced = _RUN_MIX, None
+    else:
+        kw = dict(n_kv=2, grp=2, hd=16, window=window)
+        if window is None:
+            kw["shared"] = [(1, 0, 5)]
+        mix, forced = (_RUN_MIX if window is None else _WIN_RUN_MIX,
+                       None if run == "rule" else run)
+    c = _ragged_case(rng, mix, 8, mbps=7, pad_tiles=1, run=forced,
+                     pool_blocks=30, **kw)
+    if window is not None:
+        pages = _ragged_case(np.random.RandomState(seed), mix, 8, mbps=7,
+                             pad_tiles=1, run=1, pool_blocks=30, **kw)
+    if run == "rule":
+        # float32 pages of 8 x 16: the fetch term asks for 256 runs, the
+        # cap gives 8; a window of 8 keys spans 2 pages, of 20 keys 4
+        assert c["run"] == {None: 4 if form == "latent" else 8, 8: 2,
+                            20: 4}[window]
+    else:
+        assert c["run"] == run
+    assert bool(c["bt"].shape[1] % c["run"]) == (c["run"] > 1)
     maps = c["maps"]
     assert maps.walked == maps.live + 1             # the empty tile
-    assert maps.live <= maps.pages <= run * maps.live
+    assert maps.live <= maps.pages <= c["run"] * maps.live
+    if window is not None and c["run"] > 1:
+        # runs are laid from a walk's first page: some start mid-run
+        firsts = maps.step_blk[:maps.walked][
+            maps.step_seq[:maps.walked] < c["max_seqs"]]
+        assert np.any(firsts % c["run"])
     out, ref = _run_rpa(c), _eager_oracle(c)
     valid = c["sid"] < c["max_seqs"]
     np.testing.assert_allclose(out[valid], ref[valid], atol=2e-5)
     np.testing.assert_allclose(out[valid], _run_gather(c)[valid], atol=2e-5)
     assert np.all(out[~valid] == 0.0)
+    if window is not None:
+        np.testing.assert_array_equal(out, _run_rpa(pages))
     # the per-tile form names the same runs
     per_tile = _run_rpa(c, _per_tile_maps(
-        c, rpa_max_steps(c["tile_q"], c["mbps"], run_pages=run)))
+        c, rpa_max_steps(c["tile_q"], c["mbps"], run_pages=c["run"])))
     np.testing.assert_array_equal(per_tile, out)
+
+
+@pytest.mark.parametrize("run", [2, 4])
+def test_a_window_walk_reads_the_same_wherever_its_runs_start(run):
+    """A drafting engine builds a window walk under ``slack`` (its pending
+    draft may be rejected): the walk then starts a page earlier where the
+    shorter context's window does, and its runs group the pages otherwise.
+    Every row's output is the same bit for bit: the kernel updates a window
+    walk's softmax state once a page. (With one update a run, bf16 serving
+    on the chip gave other tokens with drafts than without.)"""
+    seqs = [(2, 47), (1, 31), (2, 23), (13, 30), (1, 55)]
+    c = _ragged_case(np.random.RandomState(run), seqs, 8, n_kv=2, grp=2,
+                     mbps=8, pool_blocks=40, window=8, run=run,
+                     dtype=jnp.bfloat16)
+    cu = c["cu"][:len(seqs) + 1]
+    unsure = build_step_maps(
+        cu, c["kv_lens"], total_tokens=c["q"].shape[0], tile_q=8,
+        block_size=8, max_seqs=c["max_seqs"], run_pages=run, window=8,
+        slack=[1] * len(seqs), max_items=10 ** 4)
+    firsts = [{int(b) for b, q in zip(m.step_blk[:m.walked],
+                                      m.step_seq[:m.walked]) if q == 0}
+              for m in (c["maps"], unsure)]
+    assert firsts[0] != firsts[1]          # the runs are laid otherwise
+    np.testing.assert_array_equal(_run_rpa(c, unsure), _run_rpa(c))
+
+
+@pytest.mark.parametrize("window", [None, 128, 300])
+def test_a_kv_pool_at_the_cells_widths_reads_at_the_rules_run(window):
+    """A K/V pool of head width 128 in bf16 pages of 128 tokens, as the
+    serving cells hold it: the kernel reads it at the rule's own run, 4
+    pages (2 under a window of one page, 4 under one of three), and
+    agrees with the gather reader and the reference over decode pairs,
+    decode rows at depths that end anywhere in a run, and a chunk."""
+    rng = np.random.RandomState(3 if window is None else window)
+    seqs = [(2, 700), (1, 130), (24, 1000), (2, 255), (1, 9), (2, 520)]
+    c = _ragged_case(rng, seqs, 128, n_kv=1, grp=2, hd=128, mbps=9,
+                     pool_blocks=30, window=window, dtype=jnp.bfloat16)
+    assert not c["forced"]
+    assert c["run"] == {None: 4, 128: 2, 300: 4}[window]
+    out, ref = _run_rpa(c), _eager_oracle(c)
+    valid = c["sid"] < c["max_seqs"]
+    # bf16 probabilities and values: a few parts in a thousand
+    np.testing.assert_allclose(out[valid], ref[valid], atol=3e-2)
+    np.testing.assert_allclose(out[valid], _run_gather(c)[valid], atol=3e-2)
+    assert np.all(out[~valid] == 0.0)
 
 
 def _per_tile_maps(c, width):
@@ -340,9 +465,9 @@ def test_step_maps_cover_each_page_exactly_once(run):
     contributes the runs of ``run`` pages up to the tile's causal horizon
     in it (``ceil((context + its tokens up to the tile's end) /
     block_size)`` pages: nothing a later tile writes, nothing more, in
-    order), empty sequences contribute none, a tile without work owns one
-    sentinel item, and the tail past the live length carries the
-    sentinel."""
+    order), each named by its first page (0, ``run``, ...), empty
+    sequences contribute none, a tile without work owns one sentinel
+    item, and the tail past the live length carries the sentinel."""
     cu = np.array([0, 5, 5, 6, 16])  # seq 1 is a new_len == 0 slot
     kv_lens = [5, 8, 29, 47]         # seq 3: 10 tokens over tiles 0-1
     tile_q, bs, max_seqs = 8, 8, 6
@@ -357,7 +482,7 @@ def test_step_maps_cover_each_page_exactly_once(run):
             if cu[s] < cu[s + 1] and cu[s + 1] > lo and cu[s] < hi:
                 ctx = kv_lens[s] - (cu[s + 1] - cu[s])
                 seen = -(-(ctx + min(hi, cu[s + 1]) - cu[s]) // bs)
-                want[(j, s)] = list(range(-(-seen // run)))
+                want[(j, s)] = list(range(0, seen, run))
                 pages += seen
     # the 10-token chunk: tile 0 sees 37 + 2 keys (5 pages), tile 1 all 6
     assert len(want[(0, 3)]) == -(-5 // run)
@@ -400,7 +525,7 @@ def test_step_maps_stay_inside_the_static_bound(packing, run):
         # a sequence's new tokens fit its table
         cuts = [cu for cu in cuts if max(np.diff(cu)) <= mbps * bs]
         assert len(cuts) >= 40
-    full = list(range(-(-mbps // run)))
+    full = list(range(0, mbps, run))        # the runs' first pages
     for cu in cuts:
         n = len(cu) - 1
         bound = rpa_max_items(num_tiles, n, mbps, run)
@@ -419,42 +544,148 @@ def test_step_maps_stay_inside_the_static_bound(packing, run):
             <= len(full) * spans <= bound
 
 
+@pytest.mark.parametrize("window", [None, 4, 10])
 @pytest.mark.parametrize("run", [1, 2, 4])
-def test_every_visible_pair_lies_in_one_item_of_its_tile(run):
+def test_every_visible_pair_lies_in_one_item_of_its_tile(run, window):
     """The list's contract with the kernel's mask, on random steps: every
     (token, key) pair the token may see lies in exactly one item of the
-    token's tile; no item lies wholly beyond its tile's horizon (its
-    first key is one some token of the tile sees); the walk is inside the
-    static bound for this run."""
+    token's tile; a (tile, sequence) walk is laid in consecutive runs from
+    the page of the first key its first token sees (page 0 without a
+    window), so it is as few items as its pages allow, one where it spans
+    at most ``run`` pages; no item lies wholly beyond its tile's horizon
+    (its first key is one some token of the tile sees); the walk is inside
+    the static bound for this run and window."""
     tile_q, bs, mbps, max_seqs = 8, 4, 9, 7
-    rng = np.random.RandomState(run)
+    rng = np.random.RandomState(run + (window or 0))
+    one_item_walks = 0
     for _ in range(40):
         n = rng.randint(1, max_seqs + 1)
-        new = rng.choice([0, 1, 1, 2, 5, 11, 19], size=n)
+        new = rng.choice([0, 1, 1, 2, 2, 5, 11, 19], size=n)
         ctx = np.array([rng.randint(0, mbps * bs - m + 1) for m in new])
         cu = np.concatenate([[0], np.cumsum(new)])
         T = -(-max(int(cu[-1]), 1) // tile_q) * tile_q + tile_q
-        bound = rpa_max_items(T // tile_q, max_seqs, mbps, run)
+        bound = rpa_max_items(T // tile_q, max_seqs, mbps, run,
+                              window=window, tile_q=tile_q, block_size=bs)
         maps = build_step_maps(cu, ctx + new, total_tokens=T, tile_q=tile_q,
                                block_size=bs, max_items=bound,
-                               max_seqs=max_seqs, run_pages=run)
+                               max_seqs=max_seqs, run_pages=run,
+                               window=window)
         assert maps.walked <= bound
         got, _ = _covered(maps, max_seqs)
         keys = run * bs
         for s in range(n):
             for t in range(cu[s], cu[s + 1]):
-                runs = got[(t // tile_q, s)]
-                assert len(set(runs)) == len(runs)
-                # the token sees keys 0 .. ctx + (t - cu[s]): all inside
-                # the tile's runs, which start at 0 and are consecutive
-                assert runs == list(range(len(runs)))
-                assert ctx[s] + t - cu[s] < len(runs) * keys
-        for (j, s), runs in got.items():
+                firsts = got[(t // tile_q, s)]
+                p = ctx[s] + t - cu[s]            # the token's position
+                lo = 0 if window is None else max(0, p - window + 1)
+                # each key the token sees lies in exactly one run
+                for key in range(lo, p + 1):
+                    assert sum(f * bs <= key < f * bs + keys
+                               for f in firsts) == 1
+        for (j, s), firsts in got.items():
+            first_tok = max(j * tile_q, cu[s])
             last_tok = min((j + 1) * tile_q, cu[s + 1]) - 1
-            assert runs[-1] * keys <= ctx[s] + last_tok - cu[s]
+            p0 = ctx[s] + first_tok - cu[s]
+            page0 = 0 if window is None else max(0, p0 - window + 1) // bs
+            seen = -(-(ctx[s] + last_tok + 1 - cu[s]) // bs)
+            assert firsts == list(range(page0, seen, run))
+            if seen - page0 <= run:
+                assert len(firsts) == 1
+                one_item_walks += 1
+            assert firsts[-1] * bs <= ctx[s] + last_tok - cu[s]
         assert maps.pages == sum(
             -(-(ctx[s] + min((j + 1) * tile_q, cu[s + 1]) - cu[s]) // bs)
+            - (0 if window is None else max(
+                0, ctx[s] + max(j * tile_q, cu[s]) - cu[s] - window + 1)
+               // bs)
             for j, s in got)
+    assert one_item_walks > 0
+
+
+@pytest.mark.parametrize("window,run", [(4, 2), (8, 2), (8, 3), (10, 4)])
+def test_a_window_walk_of_at_most_run_pages_is_one_item(window, run):
+    """Decode rows and pairs at every offset of a page: under a window
+    whose walk spans at most ``run`` pages wherever it starts, each
+    (tile, sequence) is exactly one item, named by the page of its first
+    visible key: a run laid from a multiple of ``run`` would make a walk
+    that starts in a run's last page two items."""
+    bs, tile_q = 4, 8
+    for new in (1, 2):
+        for ctx in range(0, 40):
+            p0 = ctx                         # the first new token
+            spans = (ctx + new - 1) // bs - max(0, p0 - window + 1) // bs + 1
+            if spans > run:
+                continue
+            maps = build_step_maps([0, new], [ctx + new], total_tokens=8,
+                                   tile_q=tile_q, block_size=bs,
+                                   max_items=2, max_seqs=1, run_pages=run,
+                                   window=window)
+            assert maps.live == 1 == maps.walked
+            assert maps.step_blk[0] == max(0, p0 - window + 1) // bs
+            assert maps.pages == spans
+
+
+def test_the_run_length_is_read_off_the_pool_and_the_window():
+    """The rule's values at the pools the cells hold, and at the sizes
+    that bound it: the latent pool's accumulator (512 value columns in
+    pages of 128: 4, and 6 for a 96-column pool in pages of 16 whatever
+    its bytes), a K/V pool's fetch (at least 256 KiB of K and V pages an
+    item: 4 for head width 128 in bf16 pages of 128, 2 in float32, 8 at
+    most: 16-token pages), and under a window the pages one token's
+    window spans (a window of one page: 2; of 4,096 keys: no cap)."""
+    assert rpa_run_pages(128, 640, 512, 2, latent=True) == 4
+    assert rpa_run_pages(16, 128, 96, 4, latent=True) == 6
+    assert rpa_run_pages(128, 128, 128, 2) == 4
+    assert rpa_run_pages(128, 128, 128, 4) == 2
+    assert rpa_run_pages(16, 128, 128, 2) == 8
+    assert rpa_run_pages(16, 16, 16, 4) == 8
+    assert rpa_run_pages(128, 128, 128, 2, window=128) == 2
+    assert rpa_run_pages(128, 128, 128, 2, window=129) == 2
+    assert rpa_run_pages(128, 128, 128, 2, window=130) == 3
+    assert rpa_run_pages(128, 128, 128, 2, window=4096) == 4
+    assert rpa_run_pages(8, 16, 16, 4, window=1) == 1
+    # 1 at least, 8 at most, whatever the shapes
+    assert rpa_run_pages(1024, 512, 512, 4) == 1
+    assert rpa_run_pages(16, 2048, 1024, 2, latent=True) == 8
+
+
+def test_the_latent_list_at_run_4_is_the_parents():
+    """The latent pool's work list at P 4 names the pages it named before
+    runs were laid from a walk's first page: a causal walk starts at page
+    0, so item ``w``'s first page ``step_blk[w]`` is 4 x the run index the
+    parent named (the kernel then fetches ``bt[s, step_blk[w] + i]`` where
+    the parent fetched ``bt[s, 4 * run + i]``, and counts ``kpos`` from
+    the same key), and the output is the parent's arithmetic on the same
+    pages: the per-tile form, the gather reader and the reference agree."""
+    rng = np.random.RandomState(30)
+    c = _ragged_case(rng, _RUN_MIX, 8, n_kv=1, grp=4, hd=40, value_cols=32,
+                     mbps=7, pad_tiles=1, shared=[(1, 0, 5)])
+    assert c["run"] == 4 and not c["forced"]
+    maps = c["maps"]
+    # the parent's list: for each (tile, sequence), runs 0 .. ceil(seen/4)
+    parent_seq, parent_run = [], []
+    tile_q, cu = c["tile_q"], c["cu"]
+    for j in range(len(maps.step_tile) - 1):
+        lo, hi = j * tile_q, (j + 1) * tile_q
+        n0 = len(parent_seq)
+        for s, (n, ctx) in enumerate(_RUN_MIX):
+            if n and cu[s] < hi and cu[s + 1] > lo:
+                seen = -(-(ctx + min(hi, cu[s + 1]) - cu[s]) // 8)
+                parent_seq += [s] * -(-seen // 4)
+                parent_run += range(-(-seen // 4))
+        if len(parent_seq) == n0:
+            parent_seq.append(c["max_seqs"])
+            parent_run.append(0)
+    assert list(maps.step_seq[:maps.walked]) == parent_seq
+    assert list(maps.step_blk[:maps.walked]) == [4 * r for r in parent_run]
+    out = _run_rpa(c)
+    per_tile = _run_rpa(c, _per_tile_maps(
+        c, rpa_max_steps(c["tile_q"], c["mbps"], run_pages=4)))
+    np.testing.assert_array_equal(per_tile, out)
+    valid = c["sid"] < c["max_seqs"]
+    np.testing.assert_allclose(out[valid], _eager_oracle(c)[valid],
+                               atol=2e-5)
+    np.testing.assert_allclose(out[valid], _run_gather(c)[valid], atol=2e-5)
 
 
 # ---------------- the step's K/V write -----------------------------------------
@@ -588,9 +819,11 @@ def test_flat_list_kernel_compiles_with_mosaic_at_serving_shapes(
     tokens = eng["max_batch"] + eng["prefill_chunk"]
     assert tokens % tile == 0
     seqs = eng["max_batch"] + 1
-    assert rpa_run_pages(hd, eng["block_size"]) == 1    # an item is a page
+    # an item is a run of 4 pages: 256 KiB of K and V a kv head
+    run = rpa_run_pages(eng["block_size"], hd, hd, 2)
+    assert run == 4
     items = rpa_max_items(tokens // tile, eng["max_batch"],
-                          eng["max_blocks_per_seq"])
+                          eng["max_blocks_per_seq"], run)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -633,10 +866,13 @@ def test_window_and_full_kernels_compile_at_the_two_group_cell(
     assert (heads // kv, tile) == (7, 16)
     tokens = -(-(eng["max_batch"] + eng["prefill_chunk"]) // tile) * tile
     seqs, width = eng["max_batch"] + 1, eng["max_blocks_per_seq"]
-    items = rpa_max_items(tokens // tile, eng["max_batch"], width)
-    win_items = rpa_max_items(tokens // tile, eng["max_batch"], width,
+    # runs of 4 pages in both groups: a window of 4,096 keys caps nothing
+    run = rpa_run_pages(bs, hd, hd, 2)
+    assert run == rpa_run_pages(bs, hd, hd, 2, window=window) == 4
+    items = rpa_max_items(tokens // tile, eng["max_batch"], width, run)
+    win_items = rpa_max_items(tokens // tile, eng["max_batch"], width, run,
                               window=window, tile_q=tile, block_size=bs)
-    assert win_items * 128 == items * 34       # 34 pages a walk, not 128
+    assert win_items * 32 == items * 9    # 34 pages a walk, not 128
 
     def arr(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -693,12 +929,19 @@ def test_window_and_full_kernels_compile_at_the_drafted_cell(
     slots = eng["max_batch"] * (1 + eng["draft_tokens"])
     tokens = -(-(slots + eng["prefill_chunk"]) // tile) * tile
     seqs, width = eng["max_batch"] + 1, eng["max_blocks_per_seq"]
-    items = rpa_max_items(tokens // tile, eng["max_batch"], width)
+    # runs of 4 pages in the full group, of 2 under the window of one page
+    run, win_run = (rpa_run_pages(bs, hd, hd, 2, window=w)
+                    for w in (None, window))
+    assert (run, win_run) == (4, 2)
+    items = rpa_max_items(tokens // tile, eng["max_batch"], width, run)
     win_items = rpa_max_items(tokens // tile, eng["max_batch"], width,
-                              window=window, tile_q=tile, block_size=bs)
+                              win_run, window=window, tile_q=tile,
+                              block_size=bs)
     # a walk under the window: the page the first key lies in, the pages
-    # of the tile's own keys, one more for where it starts in a page
-    assert win_items * width == items * (-(-(window + tile) // bs) + 1)
+    # of the tile's own keys, one more for where it starts in a page (3),
+    # in runs of 2 from the first: 2 items, where a causal walk is 7
+    assert -(-(window + tile) // bs) + 1 == 3
+    assert win_items * 7 == items * 2
 
     def arr(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -749,7 +992,7 @@ def test_latent_kernel_compiles_at_the_latent_cell(v5e_chip, monkeypatch):
     seqs = eng["max_batch"] + 1
     # the run the rule gives at these shapes: 512 value columns in pages
     # of 128 tokens
-    run = rpa_run_pages(rank, eng["block_size"])
+    run = rpa_run_pages(eng["block_size"], cols, rank, 2, latent=True)
     assert run == 4
     items = rpa_max_items(tokens // tile, eng["max_batch"],
                           eng["max_blocks_per_seq"], run)
@@ -820,8 +1063,9 @@ def test_step_writes_its_pools_in_place_at_serving_shapes(
     seqs = eng["max_batch"] + 1
     items = rpa_max_items(
         tokens // tile, eng["max_batch"], eng["max_blocks_per_seq"],
-        rpa_run_pages(cfg["kv_lora_rank"] if latent else hd,
-                      eng["block_size"]))
+        rpa_run_pages(eng["block_size"], hd,
+                      cfg["kv_lora_rank"] if latent else hd, 2,
+                      latent=latent))
     pool_shape = (eng["max_blocks"] + 1, kv, eng["block_size"], hd)
 
     def arr(shape, dtype=jnp.int32):
@@ -1036,15 +1280,16 @@ def test_engine_counts_the_pages_its_items_name():
     """Each step's ``serving.dispatch`` span carries ``rpa_pages`` beside
     ``rpa_live`` / ``rpa_walked`` and ``serving_rpa_steps_total`` grows by
     the same under ``kind="pages"``: the pages the step's live items name,
-    between one and P an item (P read off the pool: 16 value columns in
-    pages of 4 tokens), and what the step's own list says."""
+    between one and P an item (P read off the pool: float32 K/V pages of 4
+    tokens x 16 wide ask for the longest run, 8), and what the step's own
+    list says."""
     from paddle_tpu.serving.engine import serving_metrics
     model = _tiny(3)
     eng = ServingEngine(model, max_batch=4, max_blocks=48, block_size=4,
                         prefill_chunk=16, attn_impl="rpa")
-    assert eng._run_pages == 4
+    assert eng._run_pages == 8
     assert eng._maps_kw[0]["max_items"] == rpa_max_items(
-        eng.step_tokens // eng._tile_q, 4, eng.cache.max_blocks_per_seq, 4)
+        eng.step_tokens // eng._tile_q, 4, eng.cache.max_blocks_per_seq, 8)
     build, leaf = eng._build_step_maps, eng._leaf
     built, dispatched = [], []
     eng._build_step_maps = lambda *a, **k: (
@@ -1068,14 +1313,14 @@ def test_engine_counts_the_pages_its_items_name():
         assert ev.args["rpa_live"] == m.live
         assert ev.args["rpa_walked"] == m.walked
         assert ev.args["rpa_pages"] == m.pages
-        assert 0 < m.live <= m.pages <= 4 * m.live
+        assert 0 < m.live <= m.pages <= 8 * m.live
     grown = {k: counter.value(kind=k) - before[k] for k in before}
     assert grown == {"live": sum(m.live for m in built),
                      "walked": sum(m.walked for m in built),
                      "pages": sum(m.pages for m in built)}
     # the 50-token prompt's later chunks see 5 to 13 pages a tile: some
     # runs are full, a walk's last one is not
-    assert 1.0 < grown["pages"] / grown["live"] < 4.0
+    assert 1.0 < grown["pages"] / grown["live"] < 8.0
 
 
 @pytest.mark.slow

@@ -3,7 +3,9 @@
 dense oracle (group 7 included, released pages null in the table), and
 ``build_step_maps(window=...)`` against a brute-force list of the (tile,
 sequence, run) triples that hold a visible key."""
+import importlib
 import os
+from unittest import mock
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -16,6 +18,8 @@ from paddle_tpu.ops.paged_attention import (ragged_gather_attention,
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     build_step_maps, default_tile_q, ragged_paged_attention, rpa_max_items,
     rpa_run_pages)
+
+RPA = importlib.import_module("paddle_tpu.ops.pallas.ragged_paged_attention")
 
 
 def _case(rng, seqs, *, window, block_size=8, n_kv=1, grp=7, hd=16,
@@ -68,7 +72,8 @@ def _case(rng, seqs, *, window, block_size=8, n_kv=1, grp=7, hd=16,
     kp = write_tokens_to_pool(jnp.asarray(kp), jnp.asarray(knew), *j)
     vp = write_tokens_to_pool(jnp.asarray(vp), jnp.asarray(vnew), *j)
     kv_lens = [n + c for n, c in seqs]
-    run = rpa_run_pages(hd, block_size)     # what the kernel reads off vp
+    # what the kernel reads off vp and the window
+    run = rpa_run_pages(block_size, hd, hd, 4, window=window)
     maps = build_step_maps(
         cu[:len(seqs) + 1], kv_lens, total_tokens=T, tile_q=tile_q,
         block_size=block_size, max_seqs=S, window=window, run_pages=run,
@@ -146,11 +151,19 @@ def test_group_7_tile_heights():
 
 
 def test_a_window_no_shorter_than_the_context_changes_nothing():
+    """The causal list under a window wider than every context reads what
+    a causal walk of one-page items reads, bit for bit (a window walk's
+    runs update the softmax state once a page)."""
     rng = np.random.default_rng(5)
     c = _case(rng, SEQS, window=None)
     wide = dict(c, window=128)
-    wide["maps"] = c["maps"]
-    np.testing.assert_array_equal(_rpa(c), _rpa(wide))
+    one = build_step_maps(
+        c["cu"][:len(SEQS) + 1], [n + x for n, x in SEQS],
+        total_tokens=c["q"].shape[0], tile_q=8, block_size=8,
+        max_seqs=len(SEQS) + 1, run_pages=1, max_items=10 ** 4)
+    with mock.patch.object(RPA, "rpa_run_pages", lambda *a, **k: 1):
+        pages = _rpa(dict(c, maps=one))
+    np.testing.assert_array_equal(_rpa(wide), pages)
 
 
 def test_dropping_the_window_fails_the_comparison():
@@ -166,24 +179,28 @@ def test_dropping_the_window_fails_the_comparison():
 
 
 # ------------------------------------------------------------ the list --
-def _brute(cu, kv_lens, tile_q, T, block_size, run_pages, window):
-    """Every (tile, sequence, run) whose pages hold a key some token of the
-    tile can see, by looking at every (token, key) pair."""
-    want = set()
+def _brute(cu, kv_lens, tile_q, block_size, window):
+    """``{(tile, sequence): the pages that hold a key some token of the
+    tile can see}``, by looking at every (token, key) pair."""
+    want = {}
     for s, kv in enumerate(kv_lens):
         base = kv - (cu[s + 1] - cu[s])
         for t in range(cu[s], cu[s + 1]):
             p = base + (t - cu[s])
             lo = 0 if window is None else max(0, p - window + 1)
-            for page in range(lo // block_size, p // block_size + 1):
-                want.add((t // tile_q, s, page // run_pages))
+            want.setdefault((t // tile_q, s), set()).update(
+                range(lo // block_size, p // block_size + 1))
     return want
 
 
 @pytest.mark.parametrize("window", [None, 8, 32, 100])
-@pytest.mark.parametrize("run_pages", [1, 2, 4])
+@pytest.mark.parametrize("run_pages", [1, 2, 3, 4])
 def test_the_list_names_exactly_the_runs_that_hold_a_visible_key(
         window, run_pages):
+    """A (tile, sequence) walk is the fewest runs that cover the pages
+    holding a key some token of the tile sees: consecutive runs laid from
+    the first such page, every one of them holding such a key, each such
+    page in exactly one run."""
     rng = np.random.default_rng(7)
     tile_q, block_size, S = 8, 8, 8
     for _ in range(8):
@@ -199,18 +216,21 @@ def test_the_list_names_exactly_the_runs_that_hold_a_visible_key(
             max_items=rpa_max_items(T // tile_q, S, mbps, run_pages,
                                     window=window, tile_q=tile_q,
                                     block_size=block_size))
-        got = set()
+        got = {}
         for j in range(T // tile_q):
             for w in range(m.step_tile[j], m.step_tile[j + 1]):
                 if m.step_seq[w] < S:
-                    got.add((j, int(m.step_seq[w]), int(m.step_blk[w])))
-        want = _brute(cu, kv, tile_q, T, block_size, run_pages, window)
-        # a tile lists a sequence's runs from its FIRST token's first
-        # visible run to its LAST token's own: contiguous, so a superset of
-        # the brute-force set by nothing (every run in between holds a key
-        # the first token sees or a later one does)
-        assert got == want
-        assert m.live == len(got)
+                    got.setdefault((j, int(m.step_seq[w])), []).append(
+                        int(m.step_blk[w]))
+        want = _brute(cu, kv, tile_q, block_size, window)
+        # a tile's visible pages of a sequence are contiguous: from its
+        # FIRST token's first visible page to its LAST token's own
+        assert set(got) == set(want)
+        for key, firsts in got.items():
+            pages = sorted(want[key])
+            assert pages == list(range(pages[0], pages[-1] + 1))
+            assert firsts == list(range(pages[0], pages[-1] + 1, run_pages))
+        assert m.live == sum(len(v) for v in got.values())
         causal = build_step_maps(
             cu, kv, total_tokens=T, tile_q=tile_q, block_size=block_size,
             max_seqs=S, run_pages=1, max_items=10 ** 5)

@@ -489,14 +489,15 @@ def test_rpa_walk_follows_live_work_under_one_executable():
                 for j in range(num_tiles))
         walks = [m.walked for m in built]
         pages = [m.pages for m in built]
-        assert all(m.live <= m.pages <= 4 * m.live for m in built)
+        assert all(m.live <= m.pages <= eng._run_pages * m.live
+                   for m in built)
         assert grown == (sum(walks), sum(m.live for m in built))
         assert counter.value(kind="pages") - before[2] == sum(pages)
     assert streams["rpa"] == streams["gather"]
     assert streams["rpa"][-1] == _eager_continuation(model, long_prompt, 4)
     # the bound moved with the work: a lone decode tail walks a few
     # items, the long prompt's last chunks the runs of pages each tile can
-    # see (an item names up to 4 pages here: 16-wide values in pages of 4)
+    # see (an item names up to 8 pages here: float32 K/V pages of 4 x 16)
     assert max(walks) >= 2 * min(walks), walks
     assert max(pages) >= 4 * min(pages), pages
 
