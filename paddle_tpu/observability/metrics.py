@@ -21,8 +21,8 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "get_registry", "start_exporter", "maybe_start_exporter",
-           "MetricsExporter"]
+           "get_registry", "label_key", "start_exporter",
+           "maybe_start_exporter", "MetricsExporter"]
 
 _LabelKey = Tuple[Tuple[str, str], ...]
 
@@ -49,6 +49,12 @@ def _max_label_sets() -> int:
 
 def _label_key(labels: Dict[str, object]) -> _LabelKey:
     return tuple(sorted((k, str(v)) for k, v in labels.items()))
+
+
+def label_key(**labels) -> _LabelKey:
+    """The key a family files ``labels`` under: build it once where the
+    same label sets recur (``Counter.inc_many``)."""
+    return _label_key(labels)
 
 
 def _escape_label(v: str) -> str:
@@ -115,6 +121,17 @@ class Counter(_Metric):
             key = self._admit(key)
             self._samples[key] = self._samples.get(key, 0.0) + amount
 
+    def inc_many(self, keys, amounts):
+        """``inc`` of each ``amounts[i]`` under ``keys[i]`` (from
+        ``label_key``), with one acquisition of the lock for all."""
+        if any(a < 0 for a in amounts):
+            raise ValueError("counters only go up; use a Gauge")
+        with self._lock:
+            samples = self._samples
+            for key, amount in zip(keys, amounts):
+                key = self._admit(key)
+                samples[key] = samples.get(key, 0.0) + amount
+
     def value(self, **labels) -> float:
         with self._lock:
             return float(self._samples.get(_label_key(labels), 0.0))
@@ -167,17 +184,20 @@ class Histogram(_Metric):
     def observe(self, value: float, **labels):
         key = _label_key(labels)
         with self._lock:
-            key = self._admit(key)
-            st = self._samples.get(key)
-            if st is None:
-                st = {"counts": [0] * len(self.buckets), "sum": 0.0,
-                      "count": 0}
-                self._samples[key] = st
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    st["counts"][i] += 1
-            st["sum"] += float(value)
-            st["count"] += 1
+            self._observe_locked(key, value)
+
+    def _observe_locked(self, key: _LabelKey, value: float):
+        """``observe`` with ``self._lock`` already held by the caller."""
+        key = self._admit(key)
+        st = self._samples.get(key)
+        if st is None:
+            st = {"counts": [0] * len(self.buckets), "sum": 0.0, "count": 0}
+            self._samples[key] = st
+        for i, b in enumerate(self.buckets):
+            if value <= b:
+                st["counts"][i] += 1
+        st["sum"] += float(value)
+        st["count"] += 1
 
     def stats(self, **labels) -> Optional[dict]:
         with self._lock:  # sum/count must come from one consistent state

@@ -22,10 +22,16 @@ profiler session runs (``jax.profiler.start_trace``, a ``Profiler`` with a
 TPU target, ``POST /debug/profile``) the span lands in the ``.xplane.pb`` on
 the profiler's own clock, beside the device plane, its ``args`` as the
 event's stats. With no session it is a flag check.
+
+``trace_gc()`` makes each collection of Python's cyclic collector such a
+span (``python.gc``) and an observation of the registry's
+``python_gc_pause_seconds``: the pauses in which no span of the program
+can advance.
 """
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -33,7 +39,7 @@ import time
 from typing import Callable, List, Optional
 
 __all__ = ["Profiler", "RecordEvent", "ProfilerTarget", "make_scheduler",
-           "export_chrome_tracing", "load_profiler_result"]
+           "export_chrome_tracing", "load_profiler_result", "trace_gc"]
 
 _state = {"active": None}
 
@@ -186,15 +192,19 @@ def _trace_args(args) -> dict:
             for k, v in args.items()}
 
 
-def annotate(name: str, args=None):
-    """An entered ``jax.profiler.TraceAnnotation`` for a span that begins
-    now, or None when no profiler session runs. The caller leaves it with
-    ``__exit__(None, None, None)``."""
+def _bind_trace_annotation():
     global _trace_annotation
     if _trace_annotation is None:
         from jax.profiler import TraceAnnotation
         _trace_annotation = TraceAnnotation
-    if not _trace_annotation.is_enabled():
+    return _trace_annotation
+
+
+def annotate(name: str, args=None):
+    """An entered ``jax.profiler.TraceAnnotation`` for a span that begins
+    now, or None when no profiler session runs. The caller leaves it with
+    ``__exit__(None, None, None)``."""
+    if not (_trace_annotation or _bind_trace_annotation()).is_enabled():
         return None
     ann = _trace_annotation(name, **_trace_args(args)) if args \
         else _trace_annotation(name)
@@ -276,6 +286,91 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+
+#: a collection's span (host clock, any thread) and its pause histogram
+GC_SPAN = "python.gc"
+GC_PAUSE_FAMILY = "python_gc_pause_seconds"
+#: 0.1 ms to 2 s
+GC_PAUSE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                    0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.0)
+
+
+class _GcSpans:
+    """The ``gc.callbacks`` hook: a ``RecordEvent`` (cat ``host``) from a
+    collection's ``start`` to its ``stop``, ``generation`` given at once,
+    ``collected`` and ``uncollectable`` as late args, and the pause
+    observed in the histogram. Collections never overlap (one at a time a
+    process, under the interpreter lock): one open span is all the state."""
+
+    def __init__(self, pauses):
+        from paddle_tpu.observability.metrics import label_key
+        self.pauses = pauses
+        self.key = label_key            # bound here, not inside a collection
+        self.span = None
+        self.t0 = 0
+        self.pending = []               # (generation, seconds) not yet filed
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self.t0 = time.perf_counter_ns()
+            self.span = RecordEvent(
+                GC_SPAN, args={"generation": info["generation"]}, cat="host")
+            self.span.begin()
+            return
+        span, self.span = self.span, None
+        if span is None:                # installed while a collection ran
+            return
+        span.args["collected"] = info["collected"]
+        span.args["uncollectable"] = info["uncollectable"]
+        span.end()
+        self.pending.append(
+            (info["generation"], (time.perf_counter_ns() - self.t0) / 1e9))
+        self._file()
+
+    def _file(self):
+        """Observe the pending pauses. A collection can begin while its own
+        thread holds the histogram's lock (a scrape copying it): the lock
+        is only tried, and what it cannot file waits for the next
+        collection."""
+        lock = self.pauses._lock
+        if not lock.acquire(blocking=False):
+            return
+        try:
+            for generation, seconds in self.pending:
+                self.pauses._observe_locked(self.key(generation=generation),
+                                            seconds)
+            self.pending.clear()
+        finally:
+            lock.release()
+
+
+_gc_spans: Optional[_GcSpans] = None
+_gc_install = threading.Lock()
+
+
+def trace_gc():
+    """Hook Python's collector into the program's tracing, once a process
+    however often it is called (``ServingEngine.start()`` calls it). Each
+    collection, on any thread, is a ``python.gc`` span and an observation of
+    ``python_gc_pause_seconds{generation}``; with no profiler session that
+    costs the span's flag checks and the observation. Returns the
+    histogram."""
+    global _gc_spans
+    with _gc_install:
+        if _gc_spans is None:
+            from paddle_tpu.observability.metrics import get_registry
+            pauses = get_registry().histogram(
+                GC_PAUSE_FAMILY,
+                "pauses of Python's cyclic collector by generation, each "
+                "also a python.gc span in a profiler session's trace",
+                buckets=GC_PAUSE_BUCKETS)
+            # what the hook would otherwise import inside a collection
+            _bind_trace_annotation()
+            _flight()
+            _gc_spans = _GcSpans(pauses)
+            gc.callbacks.append(_gc_spans)
+    return _gc_spans.pauses
 
 
 def record_op(name: str, inputs=None):

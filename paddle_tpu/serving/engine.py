@@ -49,7 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..profiler import RecordEvent
+from ..observability.metrics import label_key
+from ..profiler import RecordEvent, trace_gc
 from .kv_cache import NULL_BLOCK, PagedKVCache, chain_hash
 from .scheduler import Request, RequestState, Scheduler, Unharvested
 
@@ -1016,6 +1017,7 @@ class ServingEngine:
         self._m_drafts = m["drafts"]
         self._m_kv_released = m["kv_released"]
         self._m_moe_rows = m["moe_rows"]
+        self._moe_keys = []             # label keys, (layer, expert) flat
         self._m_in_flight = m["in_flight"]
         self._m_kv_block_seconds = m["kv_block_seconds"]
         self._m_kv_headroom = m["kv_headroom"]
@@ -1713,12 +1715,18 @@ class ServingEngine:
         """``rows`` [layers, held experts]: the token rows the step's
         routed experts took. On the commit span: their sum, the fullest
         expert's and how many (layer, expert) pairs took any; in the
-        registry: every (layer, expert) count."""
+        registry: every (layer, expert) count, under one acquisition of the
+        family's lock, with label keys built once an engine."""
         leaf.args.update(moe_rows=int(rows.sum()), moe_max=int(rows.max()),
                          moe_live=int(np.count_nonzero(rows)))
-        for layer, expert in zip(*np.nonzero(rows)):
-            self._m_moe_rows.inc(int(rows[layer, expert]),
-                                 layer=str(layer), expert=str(expert))
+        if len(self._moe_keys) != rows.size:     # the first step's shape
+            self._moe_keys = [label_key(layer=layer, expert=expert)
+                              for layer in range(rows.shape[0])
+                              for expert in range(rows.shape[1])]
+        live = np.flatnonzero(rows)
+        keys = self._moe_keys
+        self._m_moe_rows.inc_many([keys[i] for i in live.tolist()],
+                                  rows.ravel()[live].tolist())
 
     def _commit_cached_blocks(self, seq: Request):
         """Register every newly-completed full block in the prefix
@@ -1905,7 +1913,9 @@ class ServingEngine:
                     "undersized for the admitted requests")
 
     def start(self):
-        """Background step loop (the server front-end's mode)."""
+        """Background step loop (the server front-end's mode). Arms the
+        collector's spans (``profiler.trace_gc``) first."""
+        trace_gc()
         with self._lock:
             if self._thread is not None:
                 return

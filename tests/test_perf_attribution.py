@@ -264,7 +264,7 @@ class TestBenchReportGate:
 _FAMILY_PREFIXES = ("comm_", "train_", "serving_", "ckpt_",
                     "resilience_", "data_", "loader_", "attribution_",
                     "hbm_", "fleet_", "goodput_", "job_", "numerics_",
-                    "quantization_")
+                    "quantization_", "python_")
 
 #: backticked doc tokens that look like families but are not registry
 #: metrics: `comm_bytes` is the chrome-trace counter-track name,
@@ -393,6 +393,7 @@ def _registered_families():
     from paddle_tpu.observability.numerics import numerics_metrics
     from paddle_tpu.observability.requests import request_metrics
     from paddle_tpu.observability.slo import slo_metrics
+    from paddle_tpu.profiler import trace_gc
     from paddle_tpu.resilience.counters import (
         nonfinite_counter, preemption_counter, rollback_counter,
         watchdog_metrics)
@@ -416,6 +417,7 @@ def _registered_families():
     slo_metrics()
     nonfinite_counter(), rollback_counter(), preemption_counter()
     watchdog_metrics()
+    trace_gc()
     return {n for n in get_registry().names()
             if n.startswith(_FAMILY_PREFIXES)}
 
