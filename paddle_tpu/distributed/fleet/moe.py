@@ -34,8 +34,9 @@ Two dispatch formulations behind the same API (``dispatch_mode``):
 :class:`HeldExpertsLayer` is the other expert layer: gated (SwiGLU)
 experts of which this chip is *told which it holds*, a router over all
 of them, and **no capacity**: every assignment to a held expert is
-computed (a ragged grouped product over the rows sorted by expert), so a
-token's output never depends on what else is in the batch. It is what
+computed (grouped products over the rows laid out by expert,
+``ops/pallas/moe_gmm.py``), so a token's output never depends on what
+else is in the batch. It is what
 expert parallelism asks of one chip without its exchange, and what a
 benchmark configuration that holds a chip's share of the experts runs.
 """
@@ -384,13 +385,16 @@ class HeldExpertsLayer(Layer):
     the shares of a deployment compute add up to the whole layer's
     routed output. What the absent experts would add is left out and
     nothing stands in for them or their exchange. No capacity and no
-    dropped token: the ``T * top_k`` assignments are sorted by held
-    expert (those to absent experts last) and each held expert multiplies
-    exactly its rows, a ragged grouped product whose rows past the last
-    group are undefined and never read (``jax.lax.ragged_dot``,
-    XLA's own grouped kernel on a TPU: at 16 experts of 7680 x 2048 and
-    8,320 sorted rows it took 0.8-1.0 ms a product where a column-tiled
-    Pallas grouped matmul took 2.3 and 7.0 ms, PR 27).
+    dropped token: the ``T * top_k`` assignments are laid out by held
+    expert in row tiles (those to absent experts last) and each held
+    expert multiplies exactly its tiles, by two Pallas kernels
+    (``ops/pallas/moe_gmm.py``: gate and up in one pass, then down) that
+    stream each held expert's weights once a step; rows outside the
+    tiles are undefined and never read. Forward only: training of
+    dropless experts is ROADMAP M1. Measured alone on a TPU v5e, a
+    layer's three products at 16 experts of 6144 x 2048 with 220 of
+    3,072 rows live took 3.94 ms as ``jax.lax.ragged_dot`` calls and 2.0
+    in the kernels, against a floor of 1.47 for reading the weights.
 
     ``forward`` leaves the rows each held expert took, ``[len(held)]``
     int32, in ``self.last_rows`` (a traced value inside a compiled step:
@@ -440,13 +444,13 @@ class HeldExpertsLayer(Layer):
         import jax
         import jax.numpy as jnp
 
+        from paddle_tpu.ops.pallas import moe_gmm
+
         E, K, n = self.num_experts, self.top_k, len(self.held)
         scale, norm = self.routed_scaling_factor, self.norm_topk_prob
         local_of = np.full((E,), n, np.int32)      # global id -> held slot
         local_of[list(self.held)] = np.arange(n, dtype=np.int32)
-        product = jax.lax.ragged_dot
         softmax = self.score == "softmax"
-        gate = jax.nn.relu if self.activation == "relu" else jax.nn.silu
         routed, masked = router_input is not None, token_mask is not None
         biased = self.router_bias is not None
 
@@ -476,22 +480,23 @@ class HeldExpertsLayer(Layer):
             if masked:
                 vm = jnp.broadcast_to(rest[-1].astype(bool), lead).reshape(T)
                 slot = jnp.where(vm[:, None], slot, n)
-            # the T*K assignments sorted by held expert, absent ones last
+            # the T*K assignments laid out by held expert in row tiles
+            # (their height from the rows an expert takes at a full
+            # budget), absent ones after the last tile; token t's k-th
+            # sits at row where[t, k]
             R = T * K
-            flat = slot.reshape(R)
-            order = jnp.argsort(flat, stable=True)
-            sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
-            rows = xt[order // K]                            # [R, d]
-            act = gate(product(rows, wg, sizes)) \
-                * product(rows, wu, sizes)
-            out = product(act.astype(xt.dtype), wd, sizes)   # [R, d]
-            # combine, gather-only: where assignment (t, k) sits in the
-            # sorted order; an absent expert's row weighs nothing
-            where = jnp.zeros((R,), jnp.int32).at[order].set(
-                jnp.arange(R, dtype=jnp.int32)).reshape(T, K)
-            # (a select, not a weight of nought: the grouped product
-            # leaves the rows past its last group as it found them, and
-            # on a TPU that memory may hold a NaN)
+            tm = moe_gmm.row_tile(R / E)
+            sizes, starts, tiles, dest = moe_gmm.layout(slot.reshape(R), n,
+                                                        tm)
+            src = jnp.zeros((moe_gmm.laid_rows(R, n, tm),), jnp.int32) \
+                .at[dest].set(jnp.arange(R, dtype=jnp.int32) // K)
+            act = moe_gmm.gate_up(xt[src], wg, wu, starts, tiles, tm=tm,
+                                  activation=self.activation)
+            out = moe_gmm.down(act, wd, starts, tiles, tm=tm)
+            where = dest.reshape(T, K)
+            # combine, gather-only. A select, not a weight of nought: the
+            # rows outside the kernels' tiles (an absent expert's among
+            # them) are undefined, and on a TPU that memory may hold a NaN
             y = sum(jnp.where(slot[:, k:k + 1] < n,
                               out[where[:, k]].astype(jnp.float32)
                               * w[:, k:k + 1], 0.0)

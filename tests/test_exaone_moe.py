@@ -199,26 +199,42 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 
 def test_rows_the_grouped_product_leaves_undefined_are_never_read(
         monkeypatch):
-    """``ragged_dot`` defines only the rows of its groups. On a TPU the
-    rest is whatever the buffer held (found on the chip, PR 33: NaN at
-    some step widths, and a weight of nought times NaN is NaN); planted
-    here as NaN, the layer's output must not change."""
-    import jax
+    """The grouped products (``ops/pallas/moe_gmm.py``) define only the
+    rows of their groups. On a TPU the rest is whatever the buffer held
+    (found on a TPU v5e: NaN at some step widths, and a weight of
+    nought times NaN is NaN): the rows past the last group, an absent
+    expert's, and the padding between groups that aligns each to a row
+    tile. Planted here as NaN, the layer's output must not change."""
     import jax.numpy as jnp
     from paddle_tpu.distributed.fleet import HeldExpertsLayer
+    from paddle_tpu.ops.pallas import moe_gmm
     pt.seed(3)
     layer = HeldExpertsLayer(32, 16, 8, 2, held=[1, 4, 6])
     x = pt.to_tensor(np.random.default_rng(0).normal(
         size=(2, 9, 32)).astype(np.float32))
     mask = pt.to_tensor(np.arange(18).reshape(2, 9) < 15)
     want = np.asarray(layer(x, token_mask=mask).data)
-    real = jax.lax.ragged_dot
+    real = {name: getattr(moe_gmm, name)
+            for name in ("layout", "gate_up", "down")}
+    groups = {}
 
-    def poisoned(lhs, rhs, sizes):
-        out = real(lhs, rhs, sizes)
-        live = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(sizes)
-        return jnp.where(live, out, jnp.nan)
-    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+    def layout(slot, n, tm):
+        sizes, starts, tiles, dest = real["layout"](slot, n, tm)
+        groups.update(sizes=sizes, starts=starts)
+        return sizes, starts, tiles, dest
+
+    def poisoned(name):
+        def product(*args, **kw):
+            out = real[name](*args, **kw)
+            row = jnp.arange(out.shape[0])[:, None]
+            live = ((row >= groups["starts"])
+                    & (row < groups["starts"] + groups["sizes"])).any(-1)
+            assert not live.all()          # padding between groups, at least
+            return jnp.where(live[:, None], out, jnp.nan)
+        return product
+    monkeypatch.setattr(moe_gmm, "layout", layout)
+    monkeypatch.setattr(moe_gmm, "gate_up", poisoned("gate_up"))
+    monkeypatch.setattr(moe_gmm, "down", poisoned("down"))
     got = np.asarray(layer(x, token_mask=mask).data)
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got, want)
