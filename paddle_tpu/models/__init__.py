@@ -16,6 +16,7 @@ from . import moe  # noqa: F401
 from . import pangu_moe  # noqa: F401
 from . import smallthinker  # noqa: F401
 from . import exaone_moe  # noqa: F401
+from . import granite_hybrid  # noqa: F401
 from . import dit  # noqa: F401
 from . import ppocr  # noqa: F401
 from .llama import LlamaConfig, LlamaModel, LlamaForCausalLM  # noqa: F401
@@ -24,17 +25,20 @@ from .moe import MoeConfig, MoeForCausalLM  # noqa: F401
 from .pangu_moe import PanguMoeConfig, PanguMoeForCausalLM  # noqa: F401
 from .smallthinker import SmallThinkerConfig, SmallThinkerForCausalLM  # noqa: F401
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM  # noqa: F401
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig, GraniteHybridForCausalLM)
 from .dit import DiTConfig, DiT  # noqa: F401
 from .ppocr import PPOCRRecConfig, PPOCRRecModel  # noqa: F401
 
 __all__ = [
-    "llama", "ernie", "moe", "pangu_moe", "smallthinker", "exaone_moe", "dit",
-    "ppocr",
+    "llama", "ernie", "moe", "pangu_moe", "smallthinker", "exaone_moe",
+    "granite_hybrid", "dit", "ppocr",
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
     "ErnieConfig", "ErnieModel", "ErnieForSequenceClassification",
     "MoeConfig", "MoeForCausalLM", "PanguMoeConfig", "PanguMoeForCausalLM",
     "SmallThinkerConfig", "SmallThinkerForCausalLM",
     "ExaoneMoeConfig", "ExaoneMoeForCausalLM",
+    "GraniteHybridConfig", "GraniteHybridForCausalLM",
     "DiTConfig", "DiT",
     "PPOCRRecConfig", "PPOCRRecModel",
 ]

@@ -49,10 +49,25 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["LayerCacheSpec", "RaggedLayerCache", "attend",
+__all__ = ["LayerCacheSpec", "StateCacheSpec", "RaggedLayerCache",
+           "StateLayerCache", "attend",
            "write_tokens_to_pool", "gather_pool", "ragged_gather_attention",
            "ragged_latent_gather_attention", "paged_attention_impl",
-           "quantize_kv_slots"]
+           "quantize_kv_slots", "tokens_a_row", "unpack_rows"]
+
+#: lanes of a TPU vector register: the minor width a pool's row is laid at
+LANES = 128
+
+
+def tokens_a_row(width: int) -> int:
+    """Tokens a pool row holds for K or V rows ``width`` wide: 2 for half
+    the lanes (a head width of 64), stored two tokens to a ``LANES``-wide
+    row so that the pool's minor dimension is whole lanes (XLA lays a
+    ``[.., block_size, 64]`` pool out head-width major, and every flat
+    write and every kernel read then copies the whole pool to another
+    layout: on a TPU v5e 29 ms of a 115 ms serving step over four layers'
+    pools); 1 otherwise."""
+    return 2 if 2 * int(width) == LANES else 1
 
 
 class LayerCacheSpec(NamedTuple):
@@ -87,6 +102,28 @@ class LayerCacheSpec(NamedTuple):
         """A K and a V pool of one width (GQA/MHA layers)."""
         return cls(int(kv_heads), int(head_dim), int(head_dim),
                    window=None if window is None else int(window))
+
+
+class StateCacheSpec(NamedTuple):
+    """What a state-space layer keeps of a sequence: no pages, one row of
+    fixed size a serving slot, whatever the sequence's length. ``conv`` is
+    ``(width - 1, channels)``: the causal convolution's last inputs;
+    ``ssm`` is ``(heads, head_dim, state_dim)``: the recurrent state;
+    both stored in the model's dtype. A served model states it
+    for such a layer in place of a :class:`LayerCacheSpec`; the layers
+    that state one form the cache's **state group** (docs/SERVING.md
+    "State layers")."""
+    conv: tuple
+    ssm: tuple
+
+
+def unpack_rows(pool, width):
+    """A pool of several tokens a row (:func:`tokens_a_row`) as
+    ``[num_blocks + 1, n_kv, block_size, width]``; any other as it is."""
+    if pool.shape[-1] == width:
+        return pool
+    nb, n_kv, rows, lanes = pool.shape
+    return pool.reshape(nb, n_kv, rows * (lanes // width), width)
 
 
 def gather_pool(pool, block_tables):
@@ -165,6 +202,29 @@ class RaggedLayerCache(NamedTuple):
                 if getattr(self, n) is not None]
 
 
+class StateLayerCache(NamedTuple):
+    """One state-space layer's view of its state rows in the unified
+    serving step: row ``rows[e]`` of each pool is step entry ``e``'s (its
+    slot's, one more than the slot: row 0 is the **null row** that budget
+    padding resolves to and no sequence owns). An entry whose first row
+    this step is at position 0 (``context_lens[e] == 0``) starts from zero
+    state, so a slot's row needs no clearing between its sequences. The
+    token axis' metadata is the step's, as in :class:`RaggedLayerCache`."""
+    ssm: object           # [slots + 1, heads, head_dim, state_dim]
+    conv: object          # [slots + 1, (width - 1) * channels]
+    rows: object          # [max_seqs + 1] int32 entry -> row (0: none)
+    cu_seqlens: object    # [max_seqs + 2] int32 token-span prefix sums
+    context_lens: object  # [max_seqs + 1] int32 tokens cached before
+    seq_ids: object       # [T] int32 token -> entry (max_seqs = pad)
+    positions: object     # [T] int32 absolute position per token
+
+    def pools(self):
+        return (self.ssm, self.conv)
+
+    def with_pools(self, pools):
+        return self._replace(ssm=pools[0], conv=pools[1])
+
+
 def paged_attention_impl(impl=None, *, quantized: bool = False) -> str:
     """Who reads the pages: ``impl`` where one is given (``"rpa"`` |
     ``"gather"``), else rpa on TPU and gather elsewhere; always gather
@@ -205,17 +265,41 @@ def write_tokens_to_pool(pool, new, block_tables, seq_ids, positions):
     nothing and one row scatter updates it in place. Indexed as
     ``pool.at[block, :, slot]`` XLA wants a token's ``[n_kv, hd]`` tile
     contiguous and copies the whole pool to ``[block, slot, head, hd]``
-    and back around every write (docs/SERVING.md "Pool layout")."""
-    n_kv, bs = pool.shape[1:3]
+    and back around every write (docs/SERVING.md "Pool layout"). In a
+    pool of several tokens a row (:func:`tokens_a_row`) a token's ``hd``
+    numbers go to its place in its row."""
+    n_kv, per_page = pool.shape[1:3]
+    pack = pool.shape[3] // new.shape[-1] if pool.ndim == 4 else 1
+    bs = per_page * pack
     nblk = block_tables.shape[1]
     pos = positions.astype(jnp.int32)
     phys = block_tables[seq_ids, jnp.clip(pos // bs, 0, nblk - 1)]
     slot = jnp.where(phys == 0, 0, pos % bs)
-    rows = (phys[:, None] * n_kv + jnp.arange(n_kv, dtype=jnp.int32)) * bs \
-        + slot[:, None]
+    rows = (phys[:, None] * n_kv + jnp.arange(n_kv, dtype=jnp.int32)) \
+        * per_page + (slot // pack)[:, None]
     row = pool.shape[3:]              # (hd,), or () in a scale pool
-    return pool.reshape((-1,) + row).at[rows.reshape(-1)].set(
-        new.astype(pool.dtype).reshape((-1,) + row)).reshape(pool.shape)
+    flat = pool.reshape((-1,) + row)
+    new = new.astype(pool.dtype)
+    if pack == 2:
+        # whole rows, each the token's half beside its row partner's: this
+        # step's partner (the token before or after it, where it lands in
+        # the same row: a sequence's tokens lie together in order), else
+        # what the row holds. Partners then write the same row twice
+        # alike, and the scatter stays one of whole rows (a scatter of
+        # half rows is a loop of single updates on the chip)
+        old = flat[rows]                                 # [T, n_kv, 2 hd]
+        lead = rows[:, 0]
+        after = jnp.concatenate([lead[1:] == lead[:-1], jnp.zeros(1, bool)])
+        before = jnp.concatenate([jnp.zeros(1, bool), after[:-1]])
+        first = (slot % 2 == 0)[:, None, None]
+        w = new.shape[-1]
+        lo = jnp.where(first, new, jnp.where(
+            before[:, None, None], jnp.roll(new, 1, 0), old[..., :w]))
+        hi = jnp.where(~first, new, jnp.where(
+            after[:, None, None], jnp.roll(new, -1, 0), old[..., w:]))
+        new = jnp.concatenate([lo, hi], axis=-1)
+    return flat.at[rows.reshape(-1)].set(
+        new.reshape((-1,) + row)).reshape(pool.shape)
 
 
 def _write_step_kv(pools, news, block_tables, seq_ids, positions, tp):
@@ -279,8 +363,9 @@ def ragged_gather_attention(q, k_pool, v_pool, block_tables, seq_ids,
     T, n_heads, hd = q.shape
     n_kv = k_pool.shape[1]
     grp = n_heads // n_kv
-    keys = gather_pool(k_pool, block_tables)   # [max_seqs+1, L, n_kv, hd]
-    vals = gather_pool(v_pool, block_tables)
+    # [max_seqs+1, L, n_kv, hd]
+    keys = gather_pool(unpack_rows(k_pool, hd), block_tables)
+    vals = gather_pool(unpack_rows(v_pool, hd), block_tables)
     kt = keys[seq_ids]                         # [T, L, n_kv, hd]
     vt = vals[seq_ids]
     if k_scale is not None:

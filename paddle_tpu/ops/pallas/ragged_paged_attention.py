@@ -353,7 +353,7 @@ def _flatten_maps(step_seq, step_blk, max_seqs):
 # =========================== kernel ==========================================
 def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
                 q_ref, *rest, tile_q, group, block_size, max_seqs,
-                sm_scale, run_pages, value_cols=None, window=None):
+                sm_scale, run_pages, value_cols=None, window=None, pack=1):
     # ``rest``: the run's ``run_pages`` key pages (one ref a page), then
     # as many value pages, then the output and the scratch. With
     # ``value_cols`` the page is a latent one and its values are the
@@ -375,6 +375,31 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
 
     ss = ss_ref[w]
 
+    def page(ref):
+        """A page as ``[block_size, width]`` rows; a page of ``pack``
+        tokens a row (``ops/paged_attention.tokens_a_row``) as its
+        lane-groups one under the other: the keys of in-row place 0, then
+        those of place 1, ... (``key_pos`` gives each its position)."""
+        rows_ = ref[...]
+        if pack == 1:
+            return rows_
+        width = rows_.shape[1] // pack
+        return jnp.concatenate([rows_[:, i * width:(i + 1) * width]
+                                for i in range(pack)], axis=0)
+
+    def key_pos(keys):
+        """``[rows, keys]``: each key's place after ``first`` in pages laid
+        out by ``page`` (shifts and masks: ``block_size`` and ``pack`` are
+        powers of two where ``pack`` > 1)."""
+        c = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        if pack == 1:
+            return c
+        per_page = block_size // pack
+        lp, lr = per_page.bit_length() - 1, pack.bit_length() - 1
+        lb = block_size.bit_length() - 1
+        return ((c >> lb) << lb) + ((c & (per_page - 1)) << lr) \
+            + ((c >> lp) & (pack - 1))
+
     def update(q, k, v, first):
         """One online-softmax update over ``k``'s keys (``first`` the
         position of the first)."""
@@ -390,7 +415,7 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
         r = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
         lo = cu_ref[ss] - j * tile_q        # sequence span, tile-relative
         hi = cu_ref[ss + 1] - j * tile_q
-        kpos = first + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        kpos = first + key_pos(keys)
         # one bound covers prior context, in-chunk causality, page
         # raggedness and the run's pages past the sequence's last (null
         # pages, or under the clamp the table's last: their ``kpos`` lies
@@ -433,14 +458,14 @@ def _rpa_kernel(to_ref, ss_ref, sb_ref, tp_ref, bt_ref, cu_ref, ctx_ref,
             # masked for the row leaves its state as it was). The run
             # still pays the grid step's fixed cost once
             for i in range(run_pages):
-                update(q, k_refs[i][...], v_refs[i][...],
+                update(q, page(k_refs[i]), page(v_refs[i]),
                        (sb + i) * block_size)
         else:
             # a causal walk's runs lie on one grid from page 0: its pages
             # back to back, one update and one rescale of the [rows, vd]
             # f32 accumulator a run
             def run_of(refs):
-                pages = [r[...] for r in refs]
+                pages = [page(r) for r in refs]
                 return pages[0] if run_pages == 1 else \
                     jnp.concatenate(pages, axis=0)
             k = run_of(k_refs)
@@ -464,9 +489,16 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
     the key page."""
     n_kv, tg, hd = q_heads.shape
     latent = v_pool is None
-    vd = int(value_cols) if latent else v_pool.shape[3]
-    block_size = k_pool.shape[2]
-    run_pages = rpa_run_pages(block_size, k_pool.shape[3], vd,
+    # a K/V pool may hold ``pack`` tokens a row (a head width under the
+    # lanes: ``ops/paged_attention.tokens_a_row``); a page is then
+    # ``block_size / pack`` rows of the pool's lanes
+    pack = 1 if latent else k_pool.shape[3] // hd
+    vd = int(value_cols) if latent else v_pool.shape[3] // pack
+    rows_a_page, block_size = k_pool.shape[2], k_pool.shape[2] * pack
+    if pack > 1 and (pack & (pack - 1) or block_size & (block_size - 1)):
+        raise ValueError(f"{pack} tokens a row in pages of {block_size}: "
+                         f"both are powers of two")
+    run_pages = rpa_run_pages(block_size, k_pool.shape[3] // pack, vd,
                               k_pool.dtype.itemsize, latent=latent,
                               window=window)
     max_seqs = block_tables.shape[0] - 1
@@ -482,7 +514,7 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
     kernel = functools.partial(
         _rpa_kernel, tile_q=tile_q, group=group, block_size=block_size,
         max_seqs=max_seqs, sm_scale=sm_scale, run_pages=run_pages,
-        value_cols=vd if latent else None, window=window)
+        value_cols=vd if latent else None, window=window, pack=pack)
 
     def q_map(h, w, to, ss, sb, tp, bt, cu, ctx):
         return (h, to[w], 0)
@@ -504,8 +536,8 @@ def _rpa_call(q_heads, k_pool, v_pool, step_seq, step_blk, step_tile,
     def page_specs(width):
         # one page a BlockSpec: the pipeline double-buffers plain page
         # DMAs, a run is ``run_pages`` of them side by side
-        return [pl.BlockSpec((None, None, block_size, width), page_map(i))
-                for i in range(run_pages)]
+        return [pl.BlockSpec((None, None, rows_a_page, width * pack),
+                             page_map(i)) for i in range(run_pages)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
@@ -574,7 +606,9 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, cu_seqlens,
     group = n_heads // n_kv
     if (v_pool is None) != (value_cols is not None):
         raise ValueError("value_cols goes with a latent pool (v_pool None)")
-    vd = int(value_cols) if v_pool is None else v_pool.shape[3]
+    # (a pool of two tokens a row is twice the head width wide)
+    vd = int(value_cols) if v_pool is None \
+        else v_pool.shape[3] // (k_pool.shape[3] // hd)
     step_seq = jnp.asarray(step_seq, jnp.int32)
     step_blk = jnp.asarray(step_blk, jnp.int32)
     if step_tile is None:

@@ -178,6 +178,11 @@ def _build_serving_metrics(reg) -> dict:
         "prefix_evictions": reg.counter(
             "serving_prefix_cache_evictions",
             "reclaimable cached blocks repurposed by the allocator"),
+        "prefix_declined": reg.counter(
+            "serving_prefix_declined_total",
+            "admissions that found a registered prefix and were refused "
+            "it, by reason (state: the model has state layers, whose "
+            "state at the prefix's end no page holds)"),
         "prefix_token_fraction": reg.gauge(
             "serving_prefix_cached_token_fraction",
             "cumulative fraction of prompt tokens served from the prefix "
@@ -300,7 +305,11 @@ class ServingEngine:
     traced step, ``[layers, held]`` int32: returned by the compiled step
     beside the tokens and published by the commit span and
     ``serving_moe_expert_rows_total``) and
-    ``clear_decode_side_effects()``."""
+    ``clear_decode_side_effects()``. A layer may state an
+    ``ops.paged_attention.StateCacheSpec`` instead of pages (a state-space
+    layer): it is handed a ``StateLayerCache`` and keeps a state row a slot
+    (docs/SERVING.md "State layers"); such a model is served without
+    drafts, a mesh, LoRA slots, int8 pools or block export."""
 
     def __init__(self, model, max_batch: int = 8, max_blocks=64,
                  block_size: int = 16, prefill_chunk: int = 16,
@@ -378,6 +387,22 @@ class ServingEngine:
                 f"{type(model).__name__} states no kv_cache_spec(): a "
                 f"served model returns its layers' LayerCacheSpec")
         specs = spec_fn()
+        #: the layers that keep a state row a slot instead of pages
+        self.state_layers = [] if hasattr(specs, "kv_heads") else [
+            i for i, sp in enumerate(specs)
+            if isinstance(sp, pa.StateCacheSpec)]
+        if self.state_layers:
+            refused = [what for what, on in (
+                ("draft_tokens", draft_tokens), ("mesh=", mesh),
+                ("LoRA slots", self.n_adapter_slots),
+                ("kv_dtype='int8'", self.kv_dtype)) if on]
+            if refused:
+                raise NotImplementedError(
+                    f"{', '.join(refused)} over state layers "
+                    f"{self.state_layers}: a drafted row's state cannot be "
+                    f"rolled back, and a state row is neither sharded, "
+                    f"adapted nor quantized (docs/SERVING.md \"State "
+                    f"layers\")")
         #: drafts a greedy decoding sequence brings to a step (0 or 1):
         #: the model's drafter guesses the token after next, the next
         #: step verifies the guess beside the token and yields one or two
@@ -399,8 +424,10 @@ class ServingEngine:
                      else list(specs)) + list(drafter)
             nl += len(drafter)
         # one spec for every layer, or one a layer; the read kernel's
-        # geometry (tile height, model-parallel split) is the first's
-        spec = specs if hasattr(specs, "kv_heads") else specs[0]
+        # geometry (tile height, model-parallel split) is the first paged
+        # layer's
+        spec = specs if hasattr(specs, "kv_heads") else next(
+            sp for sp in specs if hasattr(sp, "kv_heads"))
         n_kv = spec.kv_heads
         hd = spec.key_dim
         #: block-granular prefix-cache KV reuse (ISSUE 15) — on by
@@ -459,7 +486,9 @@ class ServingEngine:
                                   max_blocks_per_seq=max_blocks_per_seq,
                                   dtype=dtype,
                                   prefix_cache=self.prefix_cache_enabled,
-                                  kv_dtype=self.kv_dtype)
+                                  kv_dtype=self.kv_dtype,
+                                  state_rows=(int(max_batch) + 1
+                                              if self.state_layers else None))
         if self.mesh is not None:
             self.cache.shard_pools(self.mesh, self._mp_axis)
         if self.kv_dtype is not None:
@@ -602,7 +631,8 @@ class ServingEngine:
         self._published_block_seconds = 0.0
         # prefix-cache counter cursors (registry counters are process-
         # global; publish per-engine deltas like preemptions do)
-        self._published_prefix = {"lookups": 0, "hits": 0, "evictions": 0}
+        self._published_prefix = {"lookups": 0, "hits": 0, "evictions": 0,
+                                   "declined": 0}
         self._prompt_tokens_prefilled = 0
         self._init_metrics()
 
@@ -771,6 +801,7 @@ class ServingEngine:
 
         group_of = self.cache.group_of_layer
         windows = [g.window for g in self.cache.groups]
+        n_paged = len(self.cache.groups)
         whole = self._replicated()
 
         def feed_drafting(tokens, ctx, pos, sid, last_idx, prev, src,
@@ -866,7 +897,9 @@ class ServingEngine:
             shared = [Tensor(a) for a in (cu, ctx, sid, pos)]
             metas = [[Tensor(bt)] + shared + [Tensor(a) for a in work]
                      for bt, *work in zip(bts, ssqs, sbks, stls)]
-            caches = [pa.RaggedLayerCache(
+            caches = [pa.StateLayerCache(
+                pool(k_pools, i), pool(v_pools, i), Tensor(bts[n_paged]),
+                *shared) if group_of[i] is None else pa.RaggedLayerCache(
                 pool(k_pools, i), pool(v_pools, i), *metas[group_of[i]],
                 pool(k_scales, i), pool(v_scales, i),
                 impl=impl, mesh=self.mesh, window=windows[group_of[i]])
@@ -912,8 +945,9 @@ class ServingEngine:
                 # the rows each held expert took, where the model has
                 # such layers (trace time: the plain step gains nothing)
                 rows = () if moe_rows is None else (moe_rows().data,)
-            kps = tuple(c.k_pool.data for c in new_caches)
-            vps = tuple(data(c.v_pool) for c in new_caches)
+            kps = tuple(data(c.pools()[0]) for c in new_caches)
+            vps = tuple(data(c.conv if isinstance(c, pa.StateLayerCache)
+                             else c.v_pool) for c in new_caches)
             if kv_quant:
                 kss = tuple(c.k_scale.data for c in new_caches)
                 vss = tuple(c.v_scale.data for c in new_caches)
@@ -983,12 +1017,14 @@ class ServingEngine:
         last_idx = np.zeros((S,), np.int32)
         aid = np.zeros((T,), np.int32)
         maps = self._null_step_maps
+        tables = [bt for _ in maps] + ([np.zeros((S + 1,), np.int32)]
+                                       if self.state_layers else [])
         with self._lock:
             try:
                 return self._step.lower(
                     self._st, jnp.asarray(tokens), self.cache.k_pools,
                     self.cache.v_pools, self.cache.k_scales,
-                    self.cache.v_scales, _a_group(bt for _ in maps),
+                    self.cache.v_scales, _a_group(tables),
                     jnp.asarray(cu), jnp.asarray(ctx), jnp.asarray(sid),
                     jnp.asarray(pos), _a_group(m.step_seq for m in maps),
                     _a_group(m.step_blk for m in maps),
@@ -1026,6 +1062,7 @@ class ServingEngine:
         self._m_prefix_lookups = m["prefix_lookups"]
         self._m_prefix_hits = m["prefix_hits"]
         self._m_prefix_evictions = m["prefix_evictions"]
+        self._m_prefix_declined = m["prefix_declined"]
         self._m_prefix_token_fraction = m["prefix_token_fraction"]
         self._m_adapter_requests = m["adapter_requests"]
         m["adapter_slots"].set(self.n_adapter_slots)
@@ -1099,6 +1136,10 @@ class ServingEngine:
                 if new > 0:
                     counter.inc(new)
                     self._published_prefix[key] += new
+            new = pc.declined - self._published_prefix["declined"]
+            if new > 0:
+                self._m_prefix_declined.inc(new, reason="state")
+                self._published_prefix["declined"] += new
             seen = pc.hit_tokens + self._prompt_tokens_prefilled
             if seen:
                 self._m_prefix_token_fraction.set(pc.hit_tokens / seen)
@@ -1413,6 +1454,11 @@ class ServingEngine:
         groups = self.cache.groups
         bts = [np.zeros((S + 1, self.cache.max_blocks_per_seq), np.int32)
                for _ in groups]
+        if self.state_layers:
+            # the state group's "table": each entry's state row, its slot's
+            # (row 0, the null row, for padding)
+            rows = np.zeros((S + 1,), np.int32)
+            bts.append(rows)
         cu = np.zeros((S + 2,), np.int32)
         ctx = np.zeros((S + 1,), np.int32)
         sid = np.full((T,), S, np.int32)   # sentinel = padding
@@ -1457,6 +1503,8 @@ class ServingEngine:
                 key_base, key_counts[i] = G.next_key_parts()
             for bt, table in zip(bts, seq.tables):
                 bt[i] = self.cache.pad_block_table(table)
+            if self.state_layers:
+                rows[i] = seq.slot + 1
             ctx[i] = c
             sid[off:off + n] = i
             pos[off:off + n] = c + np.arange(n)
@@ -1509,6 +1557,13 @@ class ServingEngine:
             **({"draft_rows": sum(e[4] for e in entries),
                 "draft_seqs": sum(1 for e in entries if e[4])}
                if drafting else {}),
+            # state rows the step reads and writes, those it starts from
+            # zero, and the step's rows by kind
+            **({"ssm_slots": len(entries),
+                "ssm_fresh": int((ctx[:len(entries)] == 0).sum()),
+                "ssm_decode_rows": len(decode),
+                "ssm_prefill_rows": sum(n for _, n in prefills)}
+               if self.state_layers else {}),
             **_row_args(f"{e[1]}@{ctx[i]}" for i, e in enumerate(entries)))
         leaf.begin()
         t0 = time.perf_counter_ns()
@@ -2047,6 +2102,8 @@ class ServingEngine:
             # free, and parked in the prefix cache (the kv_blocks_* keys
             # above read the first group, the fractions the tightest)
             out["kv_groups"] = {g.name: g.usage() for g in self.cache.groups}
+        if self.cache.state_group is not None:
+            out["state_group"] = self.cache.state_group.usage()
         if pc is not None:
             s = pc.stats()
             s["hit_rate"] = round(s["hits"] / max(s["lookups"], 1), 4)

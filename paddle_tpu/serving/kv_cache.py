@@ -39,6 +39,10 @@ Three parts:
   A model may state a spec a layer: layers of equal spec form a
   :class:`CacheGroup` with its own allocator, prefix index and pool size
   (window layers beside full ones, docs/SERVING.md "Layer groups").
+  A state-space layer states a
+  :class:`~paddle_tpu.ops.paged_attention.StateCacheSpec` instead: such
+  layers form the :class:`StateGroup`, one row a serving slot and no
+  pages (docs/SERVING.md "State layers").
 
 Sizing math (docs/SERVING.md): a request of total length ``T`` (prompt +
 generated) holds ``ceil(T / block_size)`` blocks, so worst-case pool
@@ -61,8 +65,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.paged_attention import StateCacheSpec, tokens_a_row
+
 __all__ = ["BlockAllocator", "CacheGroup", "PagedKVCache", "PrefixCache",
-           "chain_hash"]
+           "StateGroup", "chain_hash"]
 
 #: physical block id reserved as the write-off target for padding
 NULL_BLOCK = 0
@@ -275,6 +281,11 @@ class PrefixCache:
         self.hits = 0
         self.evictions = 0
         self.hit_tokens = 0      # prompt tokens served from the cache
+        #: lookups that found a registered prefix and were refused it, and
+        #: the tokens they would have reused: a cache with state layers
+        #: has no state at a block's boundary (``PagedKVCache.match``)
+        self.declined = 0
+        self.declined_tokens = 0
         allocator._evict_cb = self._on_evict
 
     def __len__(self) -> int:
@@ -316,6 +327,8 @@ class PrefixCache:
             "hits": self.hits,
             "evictions": self.evictions,
             "hit_tokens": self.hit_tokens,
+            "declined": self.declined,
+            "declined_tokens": self.declined_tokens,
             "entries": len(self._index),
         }
 
@@ -385,12 +398,31 @@ class CacheGroup:
         return a.num_free() / cap, a.num_reclaimable() / cap
 
 
+class StateGroup:
+    """The state-space layers of a cache: one
+    :class:`~paddle_tpu.ops.paged_attention.StateCacheSpec` and, a layer,
+    a pool of ``rows`` state rows (a serving slot's is ``slot + 1``; row 0
+    is the null row). No allocator, no block table, no prefix index: a
+    sequence's row is its slot's from admission to release, and a step that
+    starts a sequence at position 0 starts it from zero state."""
+
+    def __init__(self, spec, layers: Sequence[int], rows: int):
+        self.spec, self.layers, self.rows = spec, tuple(layers), int(rows)
+
+    def usage(self) -> dict:
+        return {"rows": self.rows, "layers": len(self.layers),
+                "conv": tuple(self.spec.conv), "ssm": tuple(self.spec.ssm)}
+
+
 def _group_layers(specs):
     """Layers of equal spec, in order of first appearance:
     ``[(name, spec, [layer, ...])]``. A group is named for what sets it
-    apart: ``window``, ``latent``, else ``full``."""
+    apart: ``window``, ``latent``, else ``full``. State layers form no
+    paged group."""
     groups = []
     for i, sp in enumerate(specs):
+        if isinstance(sp, StateCacheSpec):
+            continue
         for g in groups:
             if g[1] == sp:
                 g[2].append(i)
@@ -421,12 +453,16 @@ class PagedKVCache:
     order of first appearance): its own pools' size, allocator and prefix
     index. ``num_blocks`` is a number (every group's) or a mapping by
     group name. Allocators, prefix indexes and specs are the groups': a
-    model of one spec has a list of one."""
+    model of one spec has a list of one. Layers that state a
+    ``StateCacheSpec`` form :attr:`state_group` (None without them): their
+    ``k_pools`` entry is the recurrent state's pool and their ``v_pools``
+    entry the convolution's, ``state_rows`` rows each."""
 
     def __init__(self, num_layers: int, num_blocks, block_size: int,
                  spec, max_blocks_per_seq: Optional[int] = None,
                  dtype=jnp.float32, prefix_cache: bool = False,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None,
+                 state_rows: Optional[int] = None):
         if block_size < 1:
             raise ValueError("block_size must be >= 1")
         if kv_dtype not in (None, "int8"):
@@ -437,6 +473,21 @@ class PagedKVCache:
             raise ValueError(f"{len(specs)} layer specs for {num_layers} "
                              f"layers")
         grouped = _group_layers(specs)
+        state = [i for i, sp in enumerate(specs)
+                 if isinstance(sp, StateCacheSpec)]
+        self.state_group = None
+        if state:
+            kinds = {specs[i] for i in state}
+            if len(kinds) > 1:
+                raise NotImplementedError(
+                    f"state layers of {len(kinds)} specs: {sorted(kinds)}")
+            if state_rows is None or kv_dtype is not None:
+                raise ValueError("state layers need state_rows (the slots "
+                                 "+ 1) and keep no int8 pages")
+            self.state_group = StateGroup(specs[state[0]], state, state_rows)
+        if not grouped:
+            raise ValueError("a paged cache needs at least one attention "
+                             "layer")
         if isinstance(num_blocks, dict):
             names = [g[0] for g in grouped]
             if sorted(num_blocks) != sorted(names):
@@ -452,7 +503,8 @@ class PagedKVCache:
                              "not latent pages nor several groups")
         self.groups = [CacheGroup(n, sp, layers, nb, block_size, prefix_cache)
                        for (n, sp, layers), nb in zip(grouped, sizes)]
-        self.group_of_layer = [0] * num_layers
+        #: each layer's paged group; None for a state layer
+        self.group_of_layer = [None] * num_layers
         for gi, g in enumerate(self.groups):
             for i in g.layers:
                 self.group_of_layer[i] = gi
@@ -467,12 +519,25 @@ class PagedKVCache:
 
         # +1: physical block 0 is the null block and backs no sequence
         def pool(g, width):
-            return jnp.zeros((g.num_blocks + 1, g.spec.kv_heads, block_size,
-                              width), store)
-        by_layer = [self.groups[gi] for gi in self.group_of_layer]
-        self.k_pools = tuple(pool(g, g.spec.key_dim) for g in by_layer)
-        self.v_pools = tuple(None if g.spec.latent
-                             else pool(g, g.spec.value_dim) for g in by_layer)
+            # a K/V row narrower than the lanes is stored several tokens a
+            # row (``tokens_a_row``): the pool's minor dimension whole lanes
+            pack = 1 if g.spec.latent or kv_dtype is not None \
+                or block_size & (block_size - 1) else tokens_a_row(width)
+            return jnp.zeros((g.num_blocks + 1, g.spec.kv_heads,
+                              block_size // pack, width * pack), store)
+        by_layer = [None if gi is None else self.groups[gi]
+                    for gi in self.group_of_layer]
+        sg = self.state_group
+
+        def rows(*shape):     # a state layer's pool: one row a slot + 1
+            return jnp.zeros((sg.rows,) + shape, dtype)
+        self.k_pools = tuple(
+            rows(*sg.spec.ssm) if g is None else pool(g, g.spec.key_dim)
+            for g in by_layer)
+        self.v_pools = tuple(
+            rows(int(np.prod(sg.spec.conv))) if g is None
+            else None if g.spec.latent else pool(g, g.spec.value_dim)
+            for g in by_layer)
         if kv_dtype == "int8":
             # per-token-slot, per-head dequant multipliers, paged like
             # the pools themselves so block tables address both
@@ -486,7 +551,13 @@ class PagedKVCache:
         self._copy_fn = None  # lazily-jitted COW block copy
 
     def sole_group(self, what: str) -> CacheGroup:
-        """The one group of a one-group cache; ``what`` refuses others."""
+        """The one group of a one-group cache; ``what`` refuses others, and
+        a cache with state layers (a block id says nothing of their rows)."""
+        if self.state_group is not None:
+            raise NotImplementedError(
+                f"{what} moves pages; layers "
+                f"{list(self.state_group.layers)} keep a state row a slot "
+                f"that no page holds (docs/SERVING.md \"State layers\")")
         if len(self.groups) > 1:
             raise NotImplementedError(
                 f"{what} addresses one block id across every layer; this "
@@ -511,10 +582,14 @@ class PagedKVCache:
 
     def pool_bytes(self) -> dict:
         """Bytes the pools hold, by kind: ``latent`` (one-pool latent
-        pages) and ``kv`` (K and V pools, with their int8 scales)."""
+        pages), ``kv`` (K and V pools, with their int8 scales) and, where
+        the model has state layers, ``state`` (their state rows)."""
         out = {"latent": 0, "kv": 0}
+        if self.state_group is not None:
+            out["state"] = 0
         for i, gi in enumerate(self.group_of_layer):
-            kind = "latent" if self.groups[gi].spec.latent else "kv"
+            kind = "state" if gi is None else \
+                "latent" if self.groups[gi].spec.latent else "kv"
             out[kind] += sum(
                 int(p[i].nbytes) for p in (self.k_pools, self.v_pools,
                                            self.k_scales, self.v_scales)
@@ -657,7 +732,9 @@ class PagedKVCache:
         A one-group cache may match the whole prompt (the scheduler caps
         it by copy-on-write); several groups have no block copy and leave
         at least one token to prefill. Lookups and hits count on the first
-        group's index."""
+        group's index. A cache with state layers matches nothing: reusing a
+        prefix needs the state at its boundary, which no page holds; a
+        lookup that found blocks counts as ``declined``."""
         lead = self.groups[0].prefix_cache
         lead.lookups += 1
         bs = self.block_size
@@ -670,6 +747,11 @@ class PagedKVCache:
                 break
             digests.append(d)
             parent = d
+        if self.state_group is not None:
+            if digests:
+                lead.declined += 1
+                lead.declined_tokens += len(digests) * bs
+            return [[] for _ in self.groups], []
         n = len(digests)
         while n > 0 and not all(
                 g.prefix_cache.lookup(digests[i]) is not None
