@@ -210,7 +210,7 @@ class StateLayerCache(NamedTuple):
     this step is at position 0 (``context_lens[e] == 0``) starts from zero
     state, so a slot's row needs no clearing between its sequences. The
     token axis' metadata is the step's, as in :class:`RaggedLayerCache`."""
-    ssm: object           # [slots + 1, heads, head_dim, state_dim]
+    ssm: object           # [slots + 1, state_dim, heads * head_dim]
     conv: object          # [slots + 1, (width - 1) * channels]
     rows: object          # [max_seqs + 1] int32 entry -> row (0: none)
     cu_seqlens: object    # [max_seqs + 2] int32 token-span prefix sums

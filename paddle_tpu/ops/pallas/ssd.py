@@ -4,10 +4,11 @@ writes it back in place.
 
 A state-space layer keeps, a sequence, one state ``h`` of ``[H, P, N]``
 numbers (heads, head width, state width) in a pool of one row a serving
-slot. The step's rows are packed back to back on one token axis (decode
-rows of one token, prefill chunks of several); entry ``e`` owns rows
-``[starts[e], starts[e] + lens[e])`` and the pool's row ``rows[e]``. Over
-its rows, in order,
+slot, laid out ``[N, H*P]``: the state width on sublanes, (head, head
+width) along lanes. The step's rows are packed back to back on one token
+axis (decode rows of one token, prefill chunks of several); entry ``e``
+owns rows ``[starts[e], starts[e] + lens[e])`` and the pool's row
+``rows[e]``. Over its rows, in order,
 
     h_t = exp(a_t) h_{t-1} + xd_t (x) B_t        a_t = dt_t A   (a head)
     y_t = h_t C_t                                 xd_t = dt_t x_t
@@ -22,17 +23,19 @@ pipeline fetches row ``e + 1`` while entry ``e`` computes and every live
 row crosses HBM once each way; a row no entry names is neither read nor
 written. Inside a step the update is float32, whatever the pool holds:
 
-- **one row** (a decode row, or a chunk's last token): the state as
-  ``[H*P, N]`` in blocks of 128 rows, each scaled by its rows' decay and
-  given the outer product ``xd_t B_t`` (the ``xd`` and decay columns come
-  from one transpose of the token's lane-dense rows), and ``y`` the rows'
-  products with ``C_t`` put back on lanes by one transpose;
+- **one row** (a decode row, or a chunk's last token): elementwise work
+  over the state's ``[N, 128]`` lane blocks. ``B_t`` and ``C_t`` become one
+  ``[N, 128]`` tile each, down the sublanes, built once an entry; the
+  token's decay and ``xd`` are lane-dense rows, broadcast over sublanes.
+  A block is ``h' = decay h + B_t xd``, and its ``y`` the sum of ``h' C_t``
+  down the sublanes, written lane-dense: no lane reduction, no transpose;
 - **several rows**: the chunked (SSD) form on the MXU, ``lc`` rows at a
   time in order. For a head, with ``cs`` the rows' running sum of ``a``
   over the chunk: ``y = ((exp(cs_t - cs_s))_{s<=t} * C B^T) xd + exp(cs)
-  (C h^T)`` and ``h' = exp(cs_end) h + (exp(cs_end - cs) xd)^T B``. The
-  chunk reads its rows from the step's token arrays, which stay in VMEM
-  for the whole call (a window of ``lc`` rows at a row offset).
+  (C h)`` and ``h' = exp(cs_end) h + B^T (exp(cs_end - cs) xd)``, ``h`` the
+  head's ``[N, P]``. The chunk reads its rows from the step's token arrays,
+  which stay in VMEM for the whole call (a window of ``lc`` rows at a row
+  offset).
 
 The call is ``ssd.N`` in a profiler trace (``benchmark/kernels/ssd.py``).
 Off a TPU the same mathematics runs as a sequential scan in XLA
@@ -67,7 +70,7 @@ def _mode() -> str:
 
 
 def ssd_update(state, xd, a, B, C, rows, starts, lens, fresh, n_live):
-    """``state`` [R, H, P, N] (the pool); ``xd`` [T, H, P] float32
+    """``state`` [R, N, H*P] (the pool); ``xd`` [T, H, P] float32
     (``dt x``); ``a`` [T, H] float32 (``dt A``); ``B``, ``C`` [T, N];
     ``rows``, ``starts``, ``lens``, ``fresh`` [E] int32, the first
     ``n_live`` entries live. Returns ``(y [T, H, P] float32, state')``;
@@ -95,23 +98,25 @@ def _owner(starts, lens, n_live, T):
 
 def _scan(state, xd, a, B, C, rows, starts, lens, fresh, n_live):
     """The recurrence row by row: the oracle and the CPU's path."""
-    T, E = xd.shape[0], rows.shape[0]
+    T, H, P = xd.shape
+    E = rows.shape[0]
     owner = _owner(starts, lens, n_live, T)
-    h0 = jnp.where(fresh[:, None, None, None] > 0, 0.0,
-                   state[rows].astype(jnp.float32))
+    h0 = jnp.where(fresh[:, None, None] > 0, 0.0,
+                   state[rows].astype(jnp.float32))       # [E, N, H*P]
     h0 = jnp.concatenate([h0, jnp.zeros_like(h0[:1])])   # E: no owner
+    dec = jnp.repeat(jnp.exp(a), P, axis=1)               # [T, H*P]
 
     def row(h, inp):
-        e, x, at, bt, ct = inp
-        he = jnp.exp(at)[:, None, None] * h[e] + x[:, :, None] * bt
-        return h.at[e].set(he), jnp.einsum("hpn,n->hp", he, ct,
+        e, x, dt, bt, ct = inp
+        he = dt * h[e] + bt[:, None] * x
+        return h.at[e].set(he), jnp.einsum("nl,n->l", he, ct,
                                            precision=_HI)
-    h, y = jax.lax.scan(row, h0, (owner, xd, a, B, C))
-    y = jnp.where((owner < E)[:, None, None], y, 0.0)
+    h, y = jax.lax.scan(row, h0, (owner, xd.reshape(T, H * P), dec, B, C))
+    y = jnp.where((owner < E)[:, None], y, 0.0)
     live = (jnp.arange(E) < n_live) & (lens > 0)
     dest = jnp.where(live, rows, state.shape[0])         # past the end: dropped
     state = state.at[dest].set(h[:E].astype(state.dtype), mode="drop")
-    return y, state
+    return y.reshape(T, H, P), state
 
 
 # =========================== kernel ==========================================
@@ -123,26 +128,24 @@ def _kernel(tok_ref, row_ref, len_ref, fresh_ref,
     i = pl.program_id(0)
     n, s = len_ref[i], tok_ref[i]
     fresh = fresh_ref[i] > 0
+    N = st_ref.shape[0]
 
     @pl.when(n == 1)
     def _one_row():
-        b, c = b1_ref[...], c1_ref[...]                         # [1, N]
-        # the token's xd and decay, a number a state row, as columns: the
-        # lane-dense [nb, lanes] rows stacked and transposed once
+        # B_t and C_t down the sublanes: the [1, N] rows stacked and
+        # transposed once, each column broadcast along a block's lanes
         stack = jnp.concatenate(
-            [xd1_ref[...], dec1_ref[...],
-             jnp.zeros((lanes - 2 * nb, lanes), jnp.float32)], axis=0)
-        cols = stack.T                                          # [lanes, lanes]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (lanes, lanes), 1)
-        ys = jnp.zeros((lanes, lanes), jnp.float32)
+            [b1_ref[...], c1_ref[...],
+             jnp.zeros((_LANES - 2, N), jnp.float32)], axis=0)
+        cols = stack.T                                          # [N, 128]
+        b = jnp.broadcast_to(cols[:, 0:1], (N, lanes))
+        c = jnp.broadcast_to(cols[:, 1:2], (N, lanes))
         for j in range(nb):
-            blk = st_ref[j * lanes:(j + 1) * lanes, :].astype(jnp.float32)
-            blk = jnp.where(fresh, 0.0, blk)
-            blk = cols[:, nb + j:nb + j + 1] * blk + cols[:, j:j + 1] * b
-            st_out[j * lanes:(j + 1) * lanes, :] = blk.astype(st_out.dtype)
-            ys = jnp.where(lane == j,
-                           jnp.sum(blk * c, axis=1, keepdims=True), ys)
-        ydec_ref[...] = ys.T[:nb, :]
+            at = slice(j * lanes, (j + 1) * lanes)
+            h = jnp.where(fresh, 0.0, st_ref[:, at].astype(jnp.float32))
+            h = dec1_ref[j:j + 1, :] * h + b * xd1_ref[j:j + 1, :]
+            st_out[:, at] = h.astype(st_out.dtype)
+            ydec_ref[j:j + 1, :] = jnp.sum(h * c, axis=0, keepdims=True)
 
     @pl.when(n > 1)
     def _chunks():
@@ -151,7 +154,10 @@ def _kernel(tok_ref, row_ref, len_ref, fresh_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (lc, lc), 1)
         causal = r >= col
         tril = causal.astype(jnp.float32)
-        ones = jnp.ones((lc, lc), jnp.float32)
+        # the chunk's whole sum in every row, as many rows as the chunk and
+        # the state width need (Mosaic broadcasts a column along lanes, not
+        # one number to a matrix)
+        ones = jnp.ones((max(lc, N), lc), jnp.float32)
         ri = jax.lax.broadcasted_iota(jnp.int32, (lc, 1), 0)
 
         def chunk(k, carry):
@@ -161,6 +167,7 @@ def _kernel(tok_ref, row_ref, len_ref, fresh_ref,
             off = r0 - w0
             live = (ri >= off) & (ri < off + m)                 # [lc, 1]
             bw = jnp.where(live, bres_ref[pl.ds(w0, lc), :], 0.0)
+            bwt = bw.T                                          # [N, lc]
             cw = cres_ref[pl.ds(w0, lc), :]
             cb = jax.lax.dot_general(cw, bw, (((1,), (1,)), ((), ())),
                                      precision=_HI,
@@ -170,30 +177,31 @@ def _kernel(tok_ref, row_ref, len_ref, fresh_ref,
                 aw = jnp.where(live, ab_ref[j, pl.ds(w0, lc), :], 0.0)
                 cs = jnp.dot(tril, aw, precision=_HI,
                              preferred_element_type=jnp.float32)
-                # the chunk's whole sum in every row (Mosaic broadcasts a
-                # column along lanes, not one number to a matrix)
                 tot = jnp.dot(ones, aw, precision=_HI,
                               preferred_element_type=jnp.float32)
                 cst = cs.T                                      # [128, lc]
                 x = jnp.where(live, xdb_ref[j, pl.ds(w0, lc), :], 0.0)
-                outs = []
+                at = pl.ds(pl.multiple_of(j * lanes, lanes), lanes)
+                hb = h_sc[:, at]                                # [N, lanes]
+                outs, hs = [], []
                 for q in range(hpb):
                     csc, csr = cs[:, q:q + 1], cst[q:q + 1, :]
-                    end = tot[:, q:q + 1]                        # [lc, 1]
+                    end = tot[:, q:q + 1]               # [max(lc, N), 1]
                     decay = jnp.where(causal, jnp.exp(csc - csr), 0.0)
                     xq = x[:, q * hd:(q + 1) * hd]                # [lc, P]
-                    at = pl.ds(pl.multiple_of(j * lanes + q * hd, hd), hd)
-                    h = h_sc[at, :]                             # [P, N]
+                    h = hb[:, q * hd:(q + 1) * hd]                # [N, P]
                     y = jnp.dot(decay * cb, xq, precision=_HI,
                                 preferred_element_type=jnp.float32)
-                    y += jnp.exp(csc) * jax.lax.dot_general(
-                        cw, h, (((1,), (1,)), ((), ())), precision=_HI,
+                    y += jnp.exp(csc) * jnp.dot(
+                        cw, h, precision=_HI,
                         preferred_element_type=jnp.float32)
-                    w = (xq * jnp.exp(end - csc)).T              # [P, lc]
-                    h_sc[at, :] = jnp.exp(end[:hd]) * h + jnp.dot(
-                        w, bw, precision=_HI,
-                        preferred_element_type=jnp.float32)
+                    w = xq * jnp.exp(end[:lc] - csc)              # [lc, P]
+                    hs.append(jnp.exp(end[:N]) * h + jnp.dot(
+                        bwt, w, precision=_HI,
+                        preferred_element_type=jnp.float32))
                     outs.append(y)
+                h_sc[:, at] = hs[0] if hpb == 1 else jnp.concatenate(
+                    hs, axis=1)
                 y = outs[0] if hpb == 1 else jnp.concatenate(outs, axis=1)
                 ych_ref[j, pl.ds(w0, lc), :] = jnp.where(
                     live, y, ych_ref[j, pl.ds(w0, lc), :])
@@ -207,13 +215,13 @@ def _kernel(tok_ref, row_ref, len_ref, fresh_ref,
 
 def _kernel_call(state, xd, a, B, C, rows, starts, lens, fresh, n_live, *,
                  interpret):
-    R, H, P, N = state.shape
-    T = xd.shape[0]
-    HP = H * P
+    R, N, HP = state.shape
+    T, H, P = xd.shape
     lanes = min(_LANES, HP)
-    if HP % lanes or lanes % P or 2 * (HP // lanes) > lanes:
-        raise ValueError(f"state of {H} heads x {P}: the kernel lays a "
-                         f"head's rows within {lanes}-row blocks")
+    if HP != H * P or HP % lanes or lanes % P:
+        raise ValueError(f"state of {HP} lanes for {H} heads x {P}: the "
+                         f"kernel lays a head's lanes within {lanes}-lane "
+                         f"blocks")
     nb, hpb = HP // lanes, lanes // P
     lc = min(_CHUNK, T)
     # what one row reads (by its token): lane-dense [nb, lanes] rows
@@ -243,11 +251,11 @@ def _kernel_call(state, xd, a, B, C, rows, starts, lens, fresh, n_live, *,
                   pl.BlockSpec((None, 1, N), by_token),
                   whole(nb, T, lanes), whole(nb, T, _LANES),
                   whole(T, N), whole(T, N),
-                  pl.BlockSpec((None, HP, N), by_row)],
-        out_specs=[pl.BlockSpec((None, HP, N), by_row),
+                  pl.BlockSpec((None, N, HP), by_row)],
+        out_specs=[pl.BlockSpec((None, N, HP), by_row),
                    pl.BlockSpec((None, nb, lanes), by_token),
                    whole(nb, T, lanes)],
-        scratch_shapes=[pltpu.VMEM((HP, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, HP), jnp.float32)],
     )
     resident = 2 * 4 * (2 * nb * T * lanes + nb * T * _LANES + 2 * T * N)
     rowbytes = 4 * HP * N * state.dtype.itemsize + 4 * HP * N
@@ -255,7 +263,7 @@ def _kernel_call(state, xd, a, B, C, rows, starts, lens, fresh, n_live, *,
         functools.partial(_kernel, nb=nb, lanes=lanes, hpb=hpb, hd=P, lc=lc,
                           T=T),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((R, HP, N), state.dtype),
+        out_shape=[jax.ShapeDtypeStruct((R, N, HP), state.dtype),
                    jax.ShapeDtypeStruct((T, nb, lanes), jnp.float32),
                    jax.ShapeDtypeStruct((nb, T, lanes), jnp.float32)],
         # operand 12 (the pool, after the 4 scalar lists and 8 inputs) is
@@ -267,11 +275,10 @@ def _kernel_call(state, xd, a, B, C, rows, starts, lens, fresh, n_live, *,
         interpret=interpret,
         # the device op's name in a profiler trace (``ssd.N custom-call``)
         name="ssd",
-    )(starts, rows, lens, fresh, xd1, dec1, b1, c1, xdb, ab, B, C,
-      state.reshape(R, HP, N))
+    )(starts, rows, lens, fresh, xd1, dec1, b1, c1, xdb, ab, B, C, state)
     owner = _owner(starts, lens, n_live, T)
     one = (lens[jnp.minimum(owner, lens.shape[0] - 1)] == 1)[:, None]
     y = jnp.where(one, ydec.reshape(T, HP),
                   ych.transpose(1, 0, 2).reshape(T, HP))
     y = jnp.where((owner < rows.shape[0])[:, None], y, 0.0)
-    return y.reshape(T, H, P), st.reshape(R, H, P, N)
+    return y.reshape(T, H, P), st

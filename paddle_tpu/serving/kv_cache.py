@@ -531,8 +531,14 @@ class PagedKVCache:
 
         def rows(*shape):     # a state layer's pool: one row a slot + 1
             return jnp.zeros((sg.rows,) + shape, dtype)
+
+        def state_rows():
+            # a row's recurrent state as ``ops/pallas/ssd.py`` reads it:
+            # the state width on sublanes, (head, head width) along lanes
+            heads, head_dim, state_dim = sg.spec.ssm
+            return rows(state_dim, heads * head_dim)
         self.k_pools = tuple(
-            rows(*sg.spec.ssm) if g is None else pool(g, g.spec.key_dim)
+            state_rows() if g is None else pool(g, g.spec.key_dim)
             for g in by_layer)
         self.v_pools = tuple(
             rows(int(np.prod(sg.spec.conv))) if g is None

@@ -103,6 +103,27 @@ def test_chunks_then_decode_beside_other_chunks_agree_with_the_reference(
     eng.cache.assert_no_leaks()
 
 
+def test_state_pools_are_laid_out_as_the_kernel_reads_them(
+        model, monkeypatch):
+    """Each state layer's recurrent-state pool is ``[max_batch + 1, N,
+    H*P]`` (the state width on sublanes, heads along lanes), the layout
+    ``ops/pallas/ssd.py`` takes; served with the kernel itself (in
+    interpret mode), four short prompts that decode for most of their
+    steps still give the reference's logits."""
+    from paddle_tpu.ops.pallas import ssd
+    monkeypatch.setattr(ssd, "_mode", lambda: "interpret")
+    H, P, N = (CFG["mamba_n_heads"], CFG["mamba_d_head"],
+               CFG["mamba_d_state"])
+    eng = engine(model)
+    for i in eng.cache.state_group.layers:
+        assert eng.cache.k_pools[i].shape == (ENGINE["max_batch"] + 1, N,
+                                              H * P)
+    asks = list(zip(prompts(6, 9, 5, 12, seed=4), (24, 20, 28, 16)))
+    handles, rows = serve(eng, asks)
+    assert max(gaps(handles, rows, asks)) < TOL
+    eng.cache.assert_no_leaks()
+
+
 def test_a_reused_slot_and_a_preempted_sequence_start_from_zero_state(
         model):
     """Two slots for five requests (each slot serves several sequences in

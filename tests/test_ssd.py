@@ -1,9 +1,10 @@
 """The Mamba-2 state update over a serving step's ragged rows
 (``ops/pallas/ssd.py``): the kernel in interpret mode against the XLA
 scan it stands beside (decode rows and several-row chunks of fresh and of
-continuing sequences, a chunk longer than the kernel's 128-row window, the
-state held in float32 and in bfloat16; rows that no entry names keep their
-bytes), and what the kernels of a served hybrid model compile to for a
+continuing sequences, a chunk longer than the kernel's 128-row window,
+decode rows alone over a state of two 128-lane blocks, the state held in
+float32 and in bfloat16; rows that no entry names keep their bytes), and
+what the kernels of a served hybrid model compile to for a
 described TPU v5e at its published widths (nothing runs there)."""
 import os
 import re
@@ -18,7 +19,8 @@ from paddle_tpu.ops.pallas import ssd
 
 def _case(T, R, entries, dtype, H=4, P=8, N=16, seed=0):
     """Inputs for ``entries`` ``[(row, rows, fresh)]`` packed in order
-    from token 0, two padding entries after them."""
+    from token 0, two padding entries after them; the pool ``[R, N,
+    H*P]``."""
     rng = np.random.default_rng(seed)
     E = len(entries) + 2
     lists = np.zeros((4, E), np.int32)
@@ -26,7 +28,7 @@ def _case(T, R, entries, dtype, H=4, P=8, N=16, seed=0):
     for i, (row, n, fresh) in enumerate(entries):
         lists[:, i] = (row, off, n, fresh)
         off += n
-    return (jnp.asarray(rng.normal(size=(R, H, P, N)), dtype),
+    return (jnp.asarray(rng.normal(size=(R, N, H * P)), dtype),
             jnp.asarray(0.3 * rng.normal(size=(T, H, P)), jnp.float32),
             jnp.asarray(-rng.uniform(0.01, 1.0, (T, H)), jnp.float32),
             jnp.asarray(rng.normal(size=(T, N)), jnp.float32),
@@ -34,16 +36,20 @@ def _case(T, R, entries, dtype, H=4, P=8, N=16, seed=0):
             *(jnp.asarray(v) for v in lists), len(entries))
 
 
-@pytest.mark.parametrize("T, entries, dtype", [
+@pytest.mark.parametrize("T, entries, dtype, widths", [
     # decode rows of continuing and fresh sequences beside two chunks
-    (20, [(1, 1, 0), (3, 1, 1), (2, 9, 0), (5, 7, 1)], jnp.float32),
+    (20, [(1, 1, 0), (3, 1, 1), (2, 9, 0), (5, 7, 1)], jnp.float32, {}),
     # a chunk of 140 over the 128-row window (the window clamped at the
     # step's end), a fresh chunk, decode rows on both sides
     (200, [(1, 1, 0), (4, 1, 0), (2, 140, 0), (5, 40, 1), (3, 1, 1)],
-     jnp.bfloat16),
-], ids=["rows-and-chunks", "long-chunk-bf16"])
-def test_the_kernel_is_the_scan(monkeypatch, T, entries, dtype):
-    args = _case(T, 7, entries, dtype)
+     jnp.bfloat16, {}),
+    # decode rows alone, continuing and fresh, over 4 heads of 64 x 128:
+    # two 128-lane blocks, y summed down 128 sublanes
+    (8, [(2, 1, 0), (5, 1, 1), (1, 1, 0), (6, 1, 0), (4, 1, 1)],
+     jnp.bfloat16, dict(H=4, P=64, N=128)),
+], ids=["rows-and-chunks", "long-chunk-bf16", "decode-rows-bf16"])
+def test_the_kernel_is_the_scan(monkeypatch, T, entries, dtype, widths):
+    args = _case(T, 7, entries, dtype, **widths)
     monkeypatch.setattr(ssd, "_mode", lambda: "xla")
     y0, s0 = ssd.ssd_update(*args)
     monkeypatch.setattr(ssd, "_mode", lambda: "interpret")
@@ -105,9 +111,9 @@ def _kernel_names(compiled):
 
 
 def test_the_state_update_compiles_at_granite_micro_widths(one_chip, mosaic):
-    """64 heads of 64 x 128, a bf16 pool of 129 rows, a step of 640 rows:
-    one Mosaic call named as ``benchmark/kernels/ssd.py`` finds it, the
-    pool updated in place."""
+    """64 heads of 64 x 128, a bf16 pool of 129 rows ``[R, N, H*P]``, a
+    step of 640 rows: one Mosaic call named as ``benchmark/kernels/ssd.py``
+    finds it, the pool updated in place."""
     from benchmark.kernels import ssd as yardstick
     H, P, N, T, R, E = 64, 64, 128, 640, 129, 128
 
@@ -115,7 +121,7 @@ def test_the_state_update_compiles_at_granite_micro_widths(one_chip, mosaic):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     i32 = jnp.int32
     compiled = jax.jit(ssd.ssd_update, donate_argnums=0).lower(
-        arr((R, H, P, N), jnp.bfloat16), arr((T, H, P), jnp.float32),
+        arr((R, N, H * P), jnp.bfloat16), arr((T, H, P), jnp.float32),
         arr((T, H), jnp.float32), arr((T, N), jnp.float32),
         arr((T, N), jnp.float32), arr((E,), i32), arr((E,), i32),
         arr((E,), i32), arr((E,), i32), arr((), i32)).compile()
