@@ -17,6 +17,7 @@ from . import pangu_moe  # noqa: F401
 from . import smallthinker  # noqa: F401
 from . import exaone_moe  # noqa: F401
 from . import granite_hybrid  # noqa: F401
+from . import phi4flash  # noqa: F401
 from . import dit  # noqa: F401
 from . import ppocr  # noqa: F401
 from .llama import LlamaConfig, LlamaModel, LlamaForCausalLM  # noqa: F401
@@ -27,18 +28,20 @@ from .smallthinker import SmallThinkerConfig, SmallThinkerForCausalLM  # noqa: F
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM  # noqa: F401
 from .granite_hybrid import (  # noqa: F401
     GraniteHybridConfig, GraniteHybridForCausalLM)
+from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM  # noqa: F401
 from .dit import DiTConfig, DiT  # noqa: F401
 from .ppocr import PPOCRRecConfig, PPOCRRecModel  # noqa: F401
 
 __all__ = [
     "llama", "ernie", "moe", "pangu_moe", "smallthinker", "exaone_moe",
-    "granite_hybrid", "dit", "ppocr",
+    "granite_hybrid", "phi4flash", "dit", "ppocr",
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
     "ErnieConfig", "ErnieModel", "ErnieForSequenceClassification",
     "MoeConfig", "MoeForCausalLM", "PanguMoeConfig", "PanguMoeForCausalLM",
     "SmallThinkerConfig", "SmallThinkerForCausalLM",
     "ExaoneMoeConfig", "ExaoneMoeForCausalLM",
     "GraniteHybridConfig", "GraniteHybridForCausalLM",
+    "Phi4FlashConfig", "Phi4FlashForCausalLM",
     "DiTConfig", "DiT",
     "PPOCRRecConfig", "PPOCRRecModel",
 ]

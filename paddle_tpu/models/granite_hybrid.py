@@ -47,7 +47,7 @@ from paddle_tpu.observability import numerics
 from paddle_tpu.ops.paged_attention import LayerCacheSpec, StateCacheSpec
 
 __all__ = ["GraniteHybridConfig", "GraniteHybridModel",
-           "GraniteHybridForCausalLM"]
+           "GraniteHybridForCausalLM", "causal_conv_rows"]
 
 
 def _micro_layout() -> Tuple[str, ...]:
@@ -126,6 +126,44 @@ def _rms(x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
 
 
+def causal_conv_rows(raw, cw, cb, conv, rows, cu, ctx, sid, pos):
+    """A state layer's causal depthwise convolution over the step's packed
+    rows ``raw`` [T, C] (weights ``cw`` [K, C], bias ``cb``): a row's
+    earlier inputs are this step's rows of its entry or, before its first,
+    the entry's convolution state row (``conv`` [slots + 1, (K - 1) * C],
+    entry ``e``'s row ``rows[e]``; zero where the entry starts at position
+    0). Returns ``(pre-activation [T, C] float32, conv', fresh [E], new
+    rows an entry [E])``, each entry's last ``K - 1`` inputs written as
+    its new state row."""
+    T, C = raw.shape
+    K, S1 = cw.shape[0], rows.shape[0]
+    xbc, cw = raw.astype(jnp.float32), cw.astype(jnp.float32)
+    fresh = ctx == 0                                     # [S1]
+    past = conv[rows].reshape(S1, K - 1, C)
+    past = jnp.where(fresh[:, None, None], 0, past)
+    flat = past.reshape(S1 * (K - 1), C)
+    j = pos - ctx[sid]                 # a row's place in its entry
+    acc = cb.astype(jnp.float32) + xbc * cw[K - 1]
+    for k in range(1, K):
+        held = flat[sid * (K - 1) + jnp.clip(K - 1 + j - k, 0, K - 2)
+                    ].astype(jnp.float32)
+        prev = jnp.where((j >= k)[:, None], jnp.roll(xbc, k, 0), held)
+        acc = acc + prev * cw[K - 1 - k]
+    # each entry's last K - 1 inputs are its new convolution state
+    n = cu[1:S1 + 1] - cu[:S1]
+    keep = []
+    for m in range(K - 1):
+        i = n - (K - 1) + m
+        keep.append(jnp.where(
+            (i >= 0)[:, None],
+            raw[jnp.clip(cu[:S1] + i, 0, T - 1)],
+            past[jnp.arange(S1), jnp.clip(n + m, 0, K - 2)]))
+    dest = jnp.where(n > 0, rows, conv.shape[0])
+    conv = conv.at[dest].set(
+        jnp.stack(keep, 1).reshape(S1, -1).astype(conv.dtype), mode="drop")
+    return acc, conv, fresh, n
+
+
 class GraniteMamba2(nn.Layer):
     """The Mamba-2 mixer; its state is a ``StateLayerCache``'s rows."""
 
@@ -161,40 +199,14 @@ class GraniteMamba2(nn.Layer):
         def mix(xa, w_in, cw, cb, a_log, dt_b, dd, gw, w_out, ssm, conv,
                 rows, cu, ctx, sid, pos):
             from paddle_tpu.ops.pallas.ssd import ssd_update
-            H, P, N, K = (cfg.mamba_n_heads, cfg.mamba_d_head,
-                          cfg.mamba_d_state, cfg.mamba_d_conv)
+            H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
             di, C = cfg.d_inner, cfg.conv_dim
             T, S1 = xa.shape[1], rows.shape[0]
             zxd = xa[0] @ w_in
             z, raw, dt = zxd[:, :di], zxd[:, di:di + C], zxd[:, di + C:]
-            xbc, cw = raw.astype(jnp.float32), cw.astype(jnp.float32)
-            # the convolution: a row's earlier inputs are this step's rows
-            # of its entry or, before its first, the state row's
-            fresh = ctx == 0                                     # [S1]
-            past = conv[rows].reshape(S1, K - 1, C)
-            past = jnp.where(fresh[:, None, None], 0, past)
-            flat = past.reshape(S1 * (K - 1), C)
-            j = pos - ctx[sid]                 # a row's place in its entry
-            acc = cb.astype(jnp.float32) + xbc * cw[K - 1]
-            for k in range(1, K):
-                held = flat[sid * (K - 1) + jnp.clip(K - 1 + j - k, 0, K - 2)
-                            ].astype(jnp.float32)
-                prev = jnp.where((j >= k)[:, None], jnp.roll(xbc, k, 0), held)
-                acc = acc + prev * cw[K - 1 - k]
+            acc, conv, fresh, n = causal_conv_rows(raw, cw, cb, conv, rows,
+                                                   cu, ctx, sid, pos)
             xbc = jax.nn.silu(acc)
-            # each entry's last K - 1 inputs are its new convolution state
-            n = cu[1:S1 + 1] - cu[:S1]
-            keep = []
-            for m in range(K - 1):
-                i = n - (K - 1) + m
-                keep.append(jnp.where(
-                    (i >= 0)[:, None],
-                    raw[jnp.clip(cu[:S1] + i, 0, T - 1)],
-                    past[jnp.arange(S1), jnp.clip(n + m, 0, K - 2)]))
-            dest = jnp.where(n > 0, rows, conv.shape[0])
-            conv = conv.at[dest].set(
-                jnp.stack(keep, 1).reshape(S1, -1).astype(conv.dtype),
-                mode="drop")
             xs = xbc[:, :di].reshape(T, H, P).astype(jnp.float32)
             B = xbc[:, di:di + N]
             Cm = xbc[:, di + N:]

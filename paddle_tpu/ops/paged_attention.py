@@ -49,7 +49,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["LayerCacheSpec", "StateCacheSpec", "RaggedLayerCache",
+__all__ = ["LayerCacheSpec", "StateCacheSpec", "ReadCacheSpec",
+           "RaggedLayerCache",
            "StateLayerCache", "attend",
            "write_tokens_to_pool", "gather_pool", "ragged_gather_attention",
            "ragged_latent_gather_attention", "paged_attention_impl",
@@ -108,13 +109,27 @@ class StateCacheSpec(NamedTuple):
     """What a state-space layer keeps of a sequence: no pages, one row of
     fixed size a serving slot, whatever the sequence's length. ``conv`` is
     ``(width - 1, channels)``: the causal convolution's last inputs;
-    ``ssm`` is ``(heads, head_dim, state_dim)``: the recurrent state;
-    both stored in the model's dtype. A served model states it
+    ``ssm`` is ``(heads, head_dim, state_dim)``: the recurrent state
+    (a Mamba-1 layer, whose state is ``[state_dim, channels]`` with no
+    heads, states ``(channels, 1, state_dim)``); both stored in the
+    model's dtype, laid ``[state_dim, heads * head_dim]``. A served model
+    states it
     for such a layer in place of a :class:`LayerCacheSpec`; the layers
     that state one form the cache's **state group** (docs/SERVING.md
     "State layers")."""
     conv: tuple
     ssm: tuple
+
+
+class ReadCacheSpec(NamedTuple):
+    """A layer that keeps nothing of its own and reads layer ``source``'s
+    pages: it projects queries only and attends over the K/V that
+    ``source`` wrote (a YOCO cross-attention layer). It has no pool, no
+    allocator and no block table; it is handed a
+    :class:`RaggedLayerCache` over ``source``'s pools and group table,
+    and :func:`attend` called without ``k`` and ``v`` writes nothing
+    (docs/SERVING.md "Layers that read another layer's pages")."""
+    source: int
 
 
 def unpack_rows(pool, width):
@@ -402,7 +417,7 @@ def ragged_latent_gather_attention(q, pool, block_tables, seq_ids,
     return jnp.einsum("thl,tlc->thc", w, rows[..., :value_cols])
 
 
-def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
+def attend(cache, q, k=None, v=None, *, scale=None, value_cols=0):
     """One layer's share of the unified serving step: write the step's
     rows into ``cache``'s pools, read the pages, return ``(out, cache')``.
     The one place that knows the cache's fields; it branches on what the
@@ -412,6 +427,8 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
       already position-encoded; ``out`` [T, n_heads, hd]. Where the cache
       carries scale pools (int8 KV) the new rows are quantized per
       (token, head) before the scatter and the read dequantizes.
+    * **read only** (``k`` and ``v`` None, a :class:`ReadCacheSpec`
+      layer): the pools are read as they stand and written nowhere.
     * **a latent pool** (``v_pool`` None, MLA read **absorbed**): ``q``
       [T, n_heads, kd] is ``[W_UK^T q_nope | q_rope]``, ``k`` [T, kd] the
       step's rows ``[c | k_rope]``, whose first ``value_cols`` columns
@@ -424,7 +441,12 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
     work = (bt, cache.cu_seqlens, cache.context_lens, cache.step_seq,
             cache.step_blk, cache.step_tile)
     latent = cache.v_pool is None
-    if latent != (v is None):
+    reads = k is None and not latent
+    if reads and (v is not None or cache.k_scale is not None):
+        raise NotImplementedError(
+            "a layer that reads another layer's pages reads K/V pools of "
+            "the model's dtype, not int8 ones")
+    if not reads and latent != (v is None):
         raise NotImplementedError(
             "latent attention is served over a latent pool (v_pool None) "
             "and K/V attention over a K and a V pool")
@@ -468,8 +490,9 @@ def attend(cache, q, k, v=None, *, scale=None, value_cols=0):
         return out, cache._replace(k_pool=k_pool, v_pool=v_pool,
                                    k_scale=k_scale, v_scale=v_scale)
 
-    k_pool, v_pool = _write_step_kv((cache.k_pool, cache.v_pool), (k, v),
-                                    bt, sid, pos, tp)
+    k_pool, v_pool = (cache.k_pool, cache.v_pool) if reads else \
+        _write_step_kv((cache.k_pool, cache.v_pool), (k, v), bt, sid, pos,
+                       tp)
     if not rpa:
         out = ragged_gather_attention(q, k_pool, v_pool, bt, sid, pos,
                                       scale=scale, window=window)

@@ -128,6 +128,12 @@ def _build_serving_metrics(reg) -> dict:
             "serving_kv_pages_released_total",
             "pages a window layer group gave back behind its sequences' "
             "windows, by group"),
+        "rows": reg.counter(
+            "serving_rows_total",
+            "packed rows by the part of the model they ran: self (the "
+            "layers before a cross-decoder) / cross (the cross-decoder: "
+            "the sampling rows); models with layers that read another "
+            "layer's pages only"),
         "moe_rows": reg.counter(
             "serving_moe_expert_rows_total",
             "token rows the step's routed experts took, by layer and by "
@@ -387,22 +393,41 @@ class ServingEngine:
                 f"{type(model).__name__} states no kv_cache_spec(): a "
                 f"served model returns its layers' LayerCacheSpec")
         specs = spec_fn()
-        #: the layers that keep a state row a slot instead of pages
-        self.state_layers = [] if hasattr(specs, "kv_heads") else [
-            i for i, sp in enumerate(specs)
-            if isinstance(sp, pa.StateCacheSpec)]
-        if self.state_layers:
+        #: the layers that keep a state row a slot instead of pages, and
+        #: those that read another layer's pages and keep none
+        self.state_layers, self.read_layers = ([], []) if hasattr(
+            specs, "kv_heads") else ([
+                i for i, sp in enumerate(specs)
+                if isinstance(sp, pa.StateCacheSpec)], [
+                i for i, sp in enumerate(specs)
+                if isinstance(sp, pa.ReadCacheSpec)])
+        if self.state_layers or self.read_layers:
             refused = [what for what, on in (
                 ("draft_tokens", draft_tokens), ("mesh=", mesh),
                 ("LoRA slots", self.n_adapter_slots),
                 ("kv_dtype='int8'", self.kv_dtype)) if on]
             if refused:
+                where = (f"state layers {self.state_layers}"
+                         if self.state_layers else
+                         f"layers {self.read_layers} that read another's "
+                         f"pages")
                 raise NotImplementedError(
-                    f"{', '.join(refused)} over state layers "
-                    f"{self.state_layers}: a drafted row's state cannot be "
-                    f"rolled back, and a state row is neither sharded, "
-                    f"adapted nor quantized (docs/SERVING.md \"State "
-                    f"layers\")")
+                    f"{', '.join(refused)} over {where}: a drafted row's "
+                    f"state cannot be rolled back, and a state row or a "
+                    f"shared page is neither sharded, adapted nor quantized "
+                    f"(docs/SERVING.md \"State layers\")")
+        #: the first layer of the model's cross-decoder, where it has one:
+        #: layers from there on change nothing a cache keeps, so the step
+        #: runs them on each entry's sampling row alone (docs/SERVING.md
+        #: "The cross-decoder on the sampling rows")
+        self.cross_from = getattr(model, "cross_decoder_start",
+                                  lambda: None)()
+        if self.cross_from is not None and (
+                any(i >= self.cross_from for i in self.state_layers)
+                or any(i < self.cross_from for i in self.read_layers)):
+            raise ValueError(
+                f"a cross-decoder from layer {self.cross_from} holds no "
+                f"state layer and every layer that reads another's pages")
         #: drafts a greedy decoding sequence brings to a step (0 or 1):
         #: the model's drafter guesses the token after next, the next
         #: step verifies the guess beside the token and yields one or two
@@ -561,6 +586,25 @@ class ServingEngine:
         # by the gather read — built once, not per step)
         self._null_step_maps = [build_step_maps([0], [], **kw)
                                 for kw in self._maps_kw]
+        # the cross-decoder's token axis: a row an entry that samples, the
+        # slots rounded up to the tile; its layers read the pages of the
+        # group of the layer they read, under a work list of their own
+        self.cross_tokens = 0
+        if self.cross_from is not None:
+            src = self.cache.source_of_layer[self.read_layers[0]] \
+                if self.read_layers else self.cache.groups[0].layers[0]
+            self._cross_group = self.cache.group_of_layer[src]
+            self.cross_tokens = -(-self.max_batch // self._tile_q) \
+                * self._tile_q
+            kw = self._maps_kw[self._cross_group]
+            self._cross_maps_kw = dict(
+                kw, total_tokens=self.cross_tokens, max_items=rpa_max_items(
+                    self.cross_tokens // self._tile_q, self.max_batch,
+                    self.cache.max_blocks_per_seq, kw["run_pages"],
+                    window=kw["window"], tile_q=self._tile_q,
+                    block_size=block_size))
+            self._null_cross_maps = build_step_maps(
+                [0], [], **self._cross_maps_kw)
         self.scheduler = Scheduler(self.cache, self.max_batch,
                                    self.prefill_chunk,
                                    step_tokens=self.step_tokens,
@@ -588,13 +632,21 @@ class ServingEngine:
         self._base_key = (None, None)   # the host stream's, and its words
         # a drafting engine's three more inputs at rest: each row's next
         # token, the draft's row a slot, (samples, has a draft) a slot
-        self._no_drafts = (
+        self._no_extra = (
             jnp.zeros((self.step_tokens,), jnp.int32), self._no_tokens,
             jnp.zeros((2, self.max_batch), jnp.int32)) \
             if self.draft_tokens else ()
         # the step before's output where there is none to read
         self._no_prev = jnp.zeros((4, self.max_batch), jnp.int32) \
             if self.draft_tokens else self._no_tokens
+        # a cross-decoder's inputs at rest: no entry samples
+        if self.cross_from is not None:
+            S = self.max_batch
+            m = self._null_cross_maps
+            self._no_extra = tuple(jnp.asarray(a) for a in (
+                np.zeros((S + 2,), np.int32), np.zeros((S + 1,), np.int32),
+                np.full((self.cross_tokens,), S, np.int32), m.step_seq,
+                m.step_blk, m.step_tile))
         #: steps dispatched and not harvested, oldest first: one between
         #: two turns of the run loop, two between a turn's dispatch and
         #: its harvest, none outside ``step()`` called by hand
@@ -865,9 +917,18 @@ class ServingEngine:
                              guess.astype(jnp.int32)])
             return out, list(new_caches) + list(draft_caches)
 
+        reads, specs = self.cache.source_of_layer, self.cache.specs
+        cross = self.cross_from is not None
+        if cross:
+            cross_group = self._cross_group
+
         def step(stt, tokens, k_pools, v_pools, k_scales, v_scales,
                  bts, cu, ctx, sid, pos, ssqs, sbks, stls, last_idx, aid,
-                 prev_tokens, src, samp, key_base, key_counts, *draft_in):
+                 prev_tokens, src, samp, key_base, key_counts, *extra):
+            # ``extra``: a drafting engine's rows fed from the step before
+            # (``feed_drafting``), or a cross-decoder's token axis
+            # (cu, ctx, seq ids a cross row, its group's work list)
+            draft_in = extra if drafting else ()
             # executes at trace time only — counting compiles is the
             # point (the compile-once guard tests read it)
             self.step_traces += 1  # analysis: allow(trace-attr-mutation)
@@ -897,13 +958,38 @@ class ServingEngine:
             shared = [Tensor(a) for a in (cu, ctx, sid, pos)]
             metas = [[Tensor(bt)] + shared + [Tensor(a) for a in work]
                      for bt, *work in zip(bts, ssqs, sbks, stls)]
-            caches = [pa.StateLayerCache(
-                pool(k_pools, i), pool(v_pools, i), Tensor(bts[n_paged]),
-                *shared) if group_of[i] is None else pa.RaggedLayerCache(
-                pool(k_pools, i), pool(v_pools, i), *metas[group_of[i]],
-                pool(k_scales, i), pool(v_scales, i),
-                impl=impl, mesh=self.mesh, window=windows[group_of[i]])
-                for i in range(nl_all)]
+            if cross:
+                # a cross row is its entry's sampling row: its position is
+                # its entry's keys less one (``ctx_x``)
+                cu_x, ctx_x, sid_x, *work_x = extra
+                rows_x = last_idx[jnp.minimum(sid_x, S - 1)]
+                meta_x = [Tensor(bts[cross_group])] + [Tensor(a) for a in (
+                    cu_x, ctx_x, sid_x, ctx_x[sid_x], *work_x)]
+
+            def read_meta(i):
+                g = group_of[reads[i]]
+                return meta_x if cross else metas[g], windows[g]
+
+            caches = []
+            for i in range(nl_all):
+                if specs[i] is None:            # a layer that keeps nothing
+                    caches.append(None)
+                elif reads[i] is not None:
+                    # (the model hands it the pools its source wrote)
+                    meta, win = read_meta(i)
+                    caches.append(pa.RaggedLayerCache(
+                        None, None, *meta, impl=impl, mesh=self.mesh,
+                        window=win))
+                elif group_of[i] is None:
+                    caches.append(pa.StateLayerCache(
+                        pool(k_pools, i), pool(v_pools, i),
+                        Tensor(bts[n_paged]), *shared))
+                else:
+                    caches.append(pa.RaggedLayerCache(
+                        pool(k_pools, i), pool(v_pools, i),
+                        *metas[group_of[i]], pool(k_scales, i),
+                        pool(v_scales, i), impl=impl, mesh=self.mesh,
+                        window=windows[group_of[i]]))
             # per-row LoRA dispatch: pin this step's token->slot ids for
             # the adapter hooks traced inside the backbone call
             adapters = (lora.adapter_ids(aid) if n_slots
@@ -936,18 +1022,33 @@ class ServingEngine:
                     sampled, new_caches = verify_and_draft(
                         tokens, caches, last_idx, sample, *draft_in)
                 else:
-                    h, new_caches = backbone(Tensor(tokens), caches=caches)
+                    if cross:
+                        # the backbone runs the cross-decoder on the cross
+                        # rows alone and returns their stream: an entry's
+                        # is its cross row
+                        h, new_caches = backbone(
+                            Tensor(tokens), caches=caches,
+                            rows=Tensor(rows_x))
+                        at = jnp.minimum(cu_x[:S], self.cross_tokens - 1)
+                    else:
+                        h, new_caches = backbone(Tensor(tokens),
+                                                 caches=caches)
+                        at = last_idx
                     # logits at each sequence's LAST packed token (rows of
-                    # empty metadata slots gather token 0 — discarded by
-                    # the host-side harvest)
-                    hsel = Tensor(h.data[0][last_idx][:, None, :])
+                    # empty metadata slots gather token 0, or a cross row
+                    # not theirs — discarded by the host-side harvest)
+                    hsel = Tensor(h.data[0][at][:, None, :])
                     logits = project(hsel)         # [max_batch, 1, V]
                 # the rows each held expert took, where the model has
                 # such layers (trace time: the plain step gains nothing)
                 rows = () if moe_rows is None else (moe_rows().data,)
-            kps = tuple(data(c.pools()[0]) for c in new_caches)
-            vps = tuple(data(c.conv if isinstance(c, pa.StateLayerCache)
-                             else c.v_pool) for c in new_caches)
+            # (a layer that reads another's pages returns none)
+            kps = tuple(None if reads[i] is not None or c is None
+                        else data(c.pools()[0])
+                        for i, c in enumerate(new_caches))
+            vps = tuple(None if c is None else data(
+                c.conv if isinstance(c, pa.StateLayerCache) else c.v_pool)
+                for c in new_caches)
             if kv_quant:
                 kss = tuple(c.k_scale.data for c in new_caches)
                 vss = tuple(c.v_scale.data for c in new_caches)
@@ -1030,7 +1131,7 @@ class ServingEngine:
                     _a_group(m.step_blk for m in maps),
                     _a_group(m.step_tile for m in maps), jnp.asarray(last_idx),
                     jnp.asarray(aid), self._no_prev, self._no_tokens - 1,
-                    *self._greedy, *self._no_drafts)
+                    *self._greedy, *self._no_extra)
             finally:
                 self._clear_model_side_effects()
 
@@ -1050,6 +1151,7 @@ class ServingEngine:
         self._m_steps = m["steps"]
         self._m_dispatched = m["dispatched"]
         self._m_rpa_steps = m["rpa_steps"]
+        self._m_rows = m["rows"]
         self._m_drafts = m["drafts"]
         self._m_kv_released = m["kv_released"]
         self._m_moe_rows = m["moe_rows"]
@@ -1521,6 +1623,9 @@ class ServingEngine:
             slack.append(seq.pending_drafts)
             off += n
         cu[len(entries) + 1:] = off
+        extra = (nxt, last2, flags) if drafting else ()
+        if self.cross_from is not None:
+            extra = self._cross_axis(entries, kv_lens)
         if self.attn_impl == "rpa":
             unsure = {"slack": slack} if any(slack) else {}
             maps = [self._build_step_maps(cu[:len(entries) + 1], kv_lens,
@@ -1564,6 +1669,11 @@ class ServingEngine:
                 "ssm_decode_rows": len(decode),
                 "ssm_prefill_rows": sum(n for _, n in prefills)}
                if self.state_layers else {}),
+            # rows that ran the cross-decoder (its sampling rows) and the
+            # keys they read there, a cross layer
+            **({"cross_rows": int((extra[2] < S).sum()),
+                "cross_keys": int(extra[1].sum() + (extra[2] < S).sum())}
+               if self.cross_from is not None else {}),
             **_row_args(f"{e[1]}@{ctx[i]}" for i, e in enumerate(entries)))
         leaf.begin()
         t0 = time.perf_counter_ns()
@@ -1581,8 +1691,7 @@ class ServingEngine:
                 *(self._greedy if samp is None else
                   (jnp.asarray(samp), self._key_words(key_base),
                    jnp.asarray(key_counts))),
-                *((jnp.asarray(nxt), jnp.asarray(last2), jnp.asarray(flags))
-                  if drafting else ()))
+                *(jnp.asarray(a) for a in extra))
             if step_fn is not self._step:
                 out, taps_out = out[:-1], out[-1]
             sampled, kps, vps, kss, vss, *moe_rows = out
@@ -1620,6 +1729,13 @@ class ServingEngine:
                 if g.window is not None:
                     leaf.args[f"rpa_pages_causal_{g.name}"] = m.pages_causal
         self._m_steps.inc(kind="unified")
+        if self.read_layers:
+            # rows that ran the layers before the cross-decoder, and rows
+            # that ran it: the sampling rows, or every row without one
+            n_rows = int(off)
+            self._m_rows.inc(n_rows, part="self")
+            self._m_rows.inc(int((extra[2] < S).sum()) if self.cross_from
+                             is not None else n_rows, part="cross")
         if ahead:
             self._m_dispatched.inc(order="ahead")
         else:
@@ -1670,6 +1786,30 @@ class ServingEngine:
                         seq).items():
                     self._m_kv_released.inc(k, group=name)
         leaf.end()
+
+    def _cross_axis(self, entries, kv_lens):
+        """A cross-decoder's token axis for the step's ``entries``: a row
+        an entry that samples, in entry order (its sampling row, whose
+        keys are its ``kv_lens``). Returns ``(cu, ctx, seq ids, work list
+        over the read group's pages)``: ``ctx`` an entry's keys before its
+        row, seq id ``max_batch`` at a row no entry owns."""
+        S, T = self.max_batch, self.cross_tokens
+        cu = np.zeros((S + 2,), np.int32)
+        ctx = np.zeros((S + 1,), np.int32)
+        sid = np.full((T,), S, np.int32)
+        keys = []
+        r = 0
+        for i, (_, _, _, samples, _) in enumerate(entries):
+            if samples:
+                sid[r], ctx[i] = i, kv_lens[i] - 1
+                r += 1
+            cu[i + 1] = r
+            keys.append(kv_lens[i] if samples else 0)
+        cu[len(entries) + 1:] = r
+        maps = self._build_step_maps(
+            cu[:len(entries) + 1], keys, **self._cross_maps_kw) \
+            if self.attn_impl == "rpa" else self._null_cross_maps
+        return cu, ctx, sid, maps.step_seq, maps.step_blk, maps.step_tile
 
     def _harvest(self):
         """The second half of the oldest step in flight: wait for its

@@ -42,7 +42,10 @@ Three parts:
   A state-space layer states a
   :class:`~paddle_tpu.ops.paged_attention.StateCacheSpec` instead: such
   layers form the :class:`StateGroup`, one row a serving slot and no
-  pages (docs/SERVING.md "State layers").
+  pages (docs/SERVING.md "State layers"). A layer that states a
+  :class:`~paddle_tpu.ops.paged_attention.ReadCacheSpec` keeps nothing:
+  it reads another layer's pool through that layer's group table
+  (docs/SERVING.md "Layers that read another layer's pages").
 
 Sizing math (docs/SERVING.md): a request of total length ``T`` (prompt +
 generated) holds ``ceil(T / block_size)`` blocks, so worst-case pool
@@ -65,7 +68,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.paged_attention import StateCacheSpec, tokens_a_row
+from ..ops.paged_attention import ReadCacheSpec, StateCacheSpec, tokens_a_row
 
 __all__ = ["BlockAllocator", "CacheGroup", "PagedKVCache", "PrefixCache",
            "StateGroup", "chain_hash"]
@@ -417,11 +420,11 @@ class StateGroup:
 def _group_layers(specs):
     """Layers of equal spec, in order of first appearance:
     ``[(name, spec, [layer, ...])]``. A group is named for what sets it
-    apart: ``window``, ``latent``, else ``full``. State layers form no
-    paged group."""
+    apart: ``window``, ``latent``, else ``full``. State layers and layers
+    that read another's pages form no paged group."""
     groups = []
     for i, sp in enumerate(specs):
-        if isinstance(sp, StateCacheSpec):
+        if sp is None or isinstance(sp, (StateCacheSpec, ReadCacheSpec)):
             continue
         for g in groups:
             if g[1] == sp:
@@ -456,7 +459,11 @@ class PagedKVCache:
     model of one spec has a list of one. Layers that state a
     ``StateCacheSpec`` form :attr:`state_group` (None without them): their
     ``k_pools`` entry is the recurrent state's pool and their ``v_pools``
-    entry the convolution's, ``state_rows`` rows each."""
+    entry the convolution's, ``state_rows`` rows each. A layer that states
+    a ``ReadCacheSpec`` has None in both: :attr:`source_of_layer` names
+    the paged layer whose pools and group table it reads. A layer whose
+    spec is None keeps nothing and reads nothing (its mixer holds no
+    state: a gated memory unit)."""
 
     def __init__(self, num_layers: int, num_blocks, block_size: int,
                  spec, max_blocks_per_seq: Optional[int] = None,
@@ -473,6 +480,8 @@ class PagedKVCache:
             raise ValueError(f"{len(specs)} layer specs for {num_layers} "
                              f"layers")
         grouped = _group_layers(specs)
+        #: each layer's spec as the model stated it
+        self.specs = specs
         state = [i for i, sp in enumerate(specs)
                  if isinstance(sp, StateCacheSpec)]
         self.state_group = None
@@ -508,6 +517,17 @@ class PagedKVCache:
         for gi, g in enumerate(self.groups):
             for i in g.layers:
                 self.group_of_layer[i] = gi
+        #: the layer whose pages a read-only layer reads; None for others
+        self.source_of_layer = [
+            sp.source if isinstance(sp, ReadCacheSpec) else None
+            for sp in specs]
+        for i, src in enumerate(self.source_of_layer):
+            if src is not None and (not 0 <= src < num_layers
+                                    or self.group_of_layer[src] is None
+                                    or self.groups[self.group_of_layer[
+                                        src]].spec.latent):
+                raise ValueError(f"layer {i} reads layer {src}'s pages: "
+                                 f"that layer keeps no K/V pages")
         self.num_layers = num_layers
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq or max(sizes)
@@ -537,13 +557,16 @@ class PagedKVCache:
             # the state width on sublanes, (head, head width) along lanes
             heads, head_dim, state_dim = sg.spec.ssm
             return rows(state_dim, heads * head_dim)
+        # (no pool where a layer reads another's or keeps nothing)
+        bare = [src is not None or sp is None
+                for sp, src in zip(specs, self.source_of_layer)]
         self.k_pools = tuple(
-            state_rows() if g is None else pool(g, g.spec.key_dim)
-            for g in by_layer)
+            None if none else state_rows() if g is None
+            else pool(g, g.spec.key_dim) for g, none in zip(by_layer, bare))
         self.v_pools = tuple(
-            rows(int(np.prod(sg.spec.conv))) if g is None
+            None if none else rows(int(np.prod(sg.spec.conv))) if g is None
             else None if g.spec.latent else pool(g, g.spec.value_dim)
-            for g in by_layer)
+            for g, none in zip(by_layer, bare))
         if kv_dtype == "int8":
             # per-token-slot, per-head dequant multipliers, paged like
             # the pools themselves so block tables address both
@@ -594,6 +617,8 @@ class PagedKVCache:
         if self.state_group is not None:
             out["state"] = 0
         for i, gi in enumerate(self.group_of_layer):
+            if self.k_pools[i] is None:
+                continue            # reads another layer's pool, or none
             kind = "state" if gi is None else \
                 "latent" if self.groups[gi].spec.latent else "kv"
             out[kind] += sum(
